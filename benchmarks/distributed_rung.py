@@ -8,7 +8,8 @@ planner lowering the driver's dryrun_multichip validates, but timed and
 differentially checked against pandas. Wall times are CPU-mesh times, for
 trend tracking only; they are not comparable to the TPU ladder.
 
-Prints ONE JSON line: {"q3_s": ..., "agg_s": ..., "n_devices": 8, "ok": true}
+Prints ONE JSON line:
+{"q3_s": ..., "agg_s": ..., "platform": "cpu", "n_devices": 8, "ok": true}
 """
 from __future__ import annotations
 
@@ -219,6 +220,7 @@ def main(iters: int = 3) -> None:
                       "skewed_join_s": round(best_skew, 3),
                       "skewed_join_rows": nk,
                       "aqe": aqe_counts,
+                      "platform": devs[0].platform,
                       "n_devices": n_dev, "rows": n, "ok": True}))
 
 

@@ -8,31 +8,52 @@ aggregation.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 import pyarrow as pa
 
 
-def gen_lineitem(n_rows: int, seed: int = 42) -> pa.Table:
+_RETURNFLAGS = ["A", "N", "R"]
+_LINESTATUSES = ["O", "F"]
+_SHIPMODES = ["AIR", "MAIL", "RAIL", "SHIP", "TRUCK", "REG AIR", "FOB"]
+
+
+def _pick(rng, names, n_rows: int) -> pa.Array:
+    """``pa.array(rng.choice(names, n_rows))`` without the numpy unicode
+    array in between (whose conversion dominates generation time): the
+    same draws — ``choice`` over a list IS ``randint`` over its indices —
+    taken straight from an Arrow dictionary."""
+    idx = rng.randint(0, len(names), n_rows)
+    return pa.array(names).take(pa.array(idx))
+
+
+def gen_lineitem(n_rows: int, seed=42,
+                 total_rows: Optional[int] = None) -> pa.Table:
+    """``n_rows`` lineitem rows from ``seed`` (an int, or a sequence of
+    ints such as ``(seed, chunk)`` when a large table is generated chunk
+    by chunk to bound host memory). ``total_rows`` is the row count of
+    the whole table the rows belong to — it sizes the order-key domain —
+    and defaults to ``n_rows``."""
     rng = np.random.RandomState(seed)
+    total = n_rows if total_rows is None else total_rows
     base = np.datetime64("1992-01-01")
     shipdate = base + rng.randint(0, 2526, n_rows)  # through 1998-11-28
     receiptdate = shipdate + rng.randint(1, 31, n_rows)
     qty = rng.randint(1, 51, n_rows).astype(np.float64)
     price = np.round(rng.uniform(900.0, 105000.0, n_rows), 2)
     return pa.table({
-        "l_orderkey": pa.array(rng.randint(1, n_rows // 4 + 2, n_rows)),
+        "l_orderkey": pa.array(rng.randint(1, total // 4 + 2, n_rows)),
         "l_quantity": pa.array(qty),
         "l_extendedprice": pa.array(price),
         "l_discount": pa.array(np.round(rng.randint(0, 11, n_rows) / 100.0,
                                         2)),
         "l_tax": pa.array(np.round(rng.randint(0, 9, n_rows) / 100.0, 2)),
-        "l_returnflag": pa.array(rng.choice(["A", "N", "R"], n_rows)),
-        "l_linestatus": pa.array(rng.choice(["O", "F"], n_rows)),
+        "l_returnflag": _pick(rng, _RETURNFLAGS, n_rows),
+        "l_linestatus": _pick(rng, _LINESTATUSES, n_rows),
         "l_shipdate": pa.array(shipdate.astype("datetime64[D]")),
         "l_receiptdate": pa.array(receiptdate.astype("datetime64[D]")),
-        "l_shipmode": pa.array(rng.choice(
-            ["AIR", "MAIL", "RAIL", "SHIP", "TRUCK", "REG AIR", "FOB"],
-            n_rows)),
+        "l_shipmode": _pick(rng, _SHIPMODES, n_rows),
     })
 
 

@@ -15,48 +15,38 @@ import jax as _jax
 # the user opts into approximate float, but parity mode needs x64 on.
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: large variadic sorts compile in ~40 s
-# per signature on TPU; caching makes that a once-ever cost (the analog of
-# the reference shipping precompiled fatbins per architecture). Override
-# with SRTPU_COMPILE_CACHE=/path or disable with SRTPU_COMPILE_CACHE=0.
+# Persistent XLA compilation cache: sort-bearing kernels take the longest
+# to compile, and the cache makes that a once-per-checkout cost (the analog
+# of the reference shipping precompiled fatbins per architecture). Where it
+# lives is decided from OUTSIDE: when JAX_COMPILATION_CACHE_DIR is set, jax
+# reads it itself and no repo code sets a directory. Otherwise it is ONE
+# fixed directory inside the checkout — the path is part of nothing that
+# moves (no fingerprint, pid, time or temp name), so every process of a
+# checkout shares it.
 import os as _os
 
-def _machine_fingerprint() -> str:
-    """CPU-feature fingerprint partitioning the cache per machine type.
-
-    XLA:CPU persists AOT executables specialized to the compiling host's
-    ISA features; jax loads them on a DIFFERENT host with only a warning
-    ("could lead to execution errors such as SIGILL") — measured here as
-    a segfault ~92% into the test suite when the cache was written by an
-    avx512-richer machine. TPU executables are target-serialized and
-    machine-independent, but they ride the same cache dir, so the whole
-    dir is keyed: same machine -> warm cache across rounds (critical:
-    first-ever sort-kernel compiles take minutes); new machine -> cold
-    but correct."""
-    import hashlib
-    import platform
-    raw = platform.machine() + ";" + platform.processor()
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    raw += ";" + " ".join(sorted(line.split()))
-                    break
-    except OSError:
-        pass
-    return "m-" + hashlib.sha1(raw.encode()).hexdigest()[:10]
+#: learned state the engine writes beside the sources (git-ignored): the
+#: default compile cache below and the adaptive-stats file
+#: (plan/stats_store.py)
+STATE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".srtpu_cache")
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(STATE_DIR, "xla")
 
 
-_cache_dir = _os.environ.get("SRTPU_COMPILE_CACHE",
-                             _os.path.expanduser("~/.cache/srtpu_xla"))
-if _cache_dir and _cache_dir != "0":
-    try:
-        _cache_dir = _os.path.join(_cache_dir, _machine_fingerprint())
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        _jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # cache is an optimization, never a hard dependency
-        pass
+def compile_cache_dir_is_external() -> bool:
+    """True when the environment placed the compile cache: repo code
+    (this module, plan/exec_cache.configure_from_conf) then sets none."""
+    return bool(_os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+
+
+if not compile_cache_dir_is_external():
+    _jax.config.update("jax_compilation_cache_dir",
+                       DEFAULT_COMPILE_CACHE_DIR)
+# every executable is persisted, however quick its compile: a second
+# process over the same queries then pays trace time only
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+_jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 from .version import __version__
 from .types import Schema, StructField

@@ -365,9 +365,9 @@ class TpuProjectExec(TpuExec):
                     src = batch.column_by_name(leaf)
                     if isinstance(src, ByteRectColumn) and src.ascii_only:
                         from ..columnar.strrect import RECT_MAX_BYTES
-                        from ..exprs.pallas_rect import PALLAS_ENABLED
+                        from ..exprs.pallas_rect import pallas_enabled
                         cap = int(ctx.conf.get(RECT_MAX_BYTES))
-                        pls = bool(ctx.conf.get(PALLAS_ENABLED))
+                        pls = pallas_enabled(ctx.conf)
                         try:
                             with ctx.semaphore.held():
                                 out[i] = self._rect_eval(expr, src, i,

@@ -25,7 +25,8 @@ import numpy as np
 
 from ..config import register
 
-__all__ = ["PALLAS_ENABLED", "pallas_match", "pallas_available"]
+__all__ = ["PALLAS_ENABLED", "pallas_enabled", "pallas_match",
+           "pallas_available"]
 
 PALLAS_ENABLED = register(
     "spark.rapids.tpu.sql.pallas.enabled", False,
@@ -33,8 +34,24 @@ PALLAS_ENABLED = register(
     "endswith/locate and the literal LIKE forms) through hand-written "
     "Pallas TPU kernels instead of the fused XLA ops "
     "(exprs/pallas_rect.py). On the CPU backend the kernels run in "
-    "interpreter mode (tests); OFF by default until measured faster "
-    "than XLA on the deployment backend.")
+    "interpreter mode (tests). On a TPU backend enabling this FAILS: "
+    "the chip's compiler refuses the kernel as written (see "
+    "TPU_LOWERING_REFUSAL in exprs/pallas_rect.py). OFF by default.")
+
+#: what the TPU's compiler said when ``_match_kernel`` was lowered with
+#: ``interpret=False`` for a described v5e (jax 0.9.0, libtpu 0.0.34; PR
+#: 21, widths 32 and 128, 4096 rows). The kernel had only ever run
+#: interpreted.
+TPU_LOWERING_REFUSAL = (
+    "spark.rapids.tpu.sql.pallas.enabled cannot be used on a TPU backend: "
+    "the chip's compiler refuses the byte-rectangle match kernel "
+    "(exprs/pallas_rect.py). Lowered for a v5e with interpret=False, "
+    "contains/locate raise 'RecursionError: maximum recursion depth "
+    "exceeded', and startswith raises 'MosaicError: INTERNAL: Mosaic "
+    "failed to compile TPU kernel: Target does not support this "
+    "comparison' at arith.cmpi over vector<8x128x4xi8> (the per-byte "
+    "uint8 lane compare). Leave the conf off — the fused XLA ops are the "
+    "default — until the kernel is rewritten (ROADMAP Design 4).")
 
 #: rows per grid step: uint8 tiles want >= 32 sublanes; 256 rows keeps
 #: each block's VMEM footprint at 256*W bytes (W <= 1024)
@@ -51,6 +68,16 @@ def pallas_available() -> bool:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def pallas_enabled(conf) -> bool:
+    """The conf, read where the exec decides its string path. On a TPU
+    backend a True value raises with the compiler's own words instead of
+    letting the query die later in a raw lowering error."""
+    on = bool(conf.get(PALLAS_ENABLED))
+    if on and not _interpret():
+        raise NotImplementedError(TPU_LOWERING_REFUSAL)
+    return on
 
 
 @functools.lru_cache(maxsize=None)

@@ -46,23 +46,37 @@ class OutOfDeviceMemory(RuntimeError):
     """Unrecoverable: nothing left to spill and input cannot be split."""
 
 
-def _device_hbm_bytes() -> int:
+#: budget base on the CPU backend, whose devices report no memory limit
+_CPU_ASSUMED_BYTES = 8 * 1024 * 1024 * 1024
+
+
+def _pinned_or_first_device():
+    """The device the engine computes on: an explicitly pinned default
+    device (tests pin 'cpu') is honoured, and other backends are NEVER
+    initialized just for bookkeeping — touching the TPU client here would
+    block if another process holds the chip."""
     import jax
-    try:
-        # honour an explicitly pinned default device (tests pin 'cpu') and
-        # NEVER initialize other backends just for bookkeeping — touching the
-        # TPU client here would block if another process holds the chip
-        dd = jax.config.jax_default_device
-        if dd is not None:
-            d = jax.devices(dd)[0] if isinstance(dd, str) else dd
-        else:
-            d = jax.local_devices()[0]
-        stats = d.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return 8 * 1024 * 1024 * 1024  # assume 8 GiB if the backend won't say
+    dd = jax.config.jax_default_device
+    if dd is not None:
+        return jax.devices(dd)[0] if isinstance(dd, str) else dd
+    return jax.local_devices()[0]
+
+
+def _device_hbm_bytes() -> int:
+    """Memory limit of the device the engine computes on. An accelerator
+    that does not report its ``bytes_limit`` is an error, never a guess:
+    a budget invented for a chip either wastes most of its HBM or plans
+    past it."""
+    d = _pinned_or_first_device()
+    stats = d.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if d.platform == "cpu":
+        return _CPU_ASSUMED_BYTES
+    raise RuntimeError(
+        f"device {d} ({d.platform}) reports no bytes_limit in "
+        f"memory_stats() ({stats!r}): set "
+        "spark.rapids.tpu.memory.hbm.limitBytes explicitly")
 
 
 class MemoryManager:
@@ -143,6 +157,12 @@ class MemoryManager:
             return inst
 
     # ------------------------------------------------------------ accounting
+    @property
+    def state_machine(self) -> str:
+        """Which OOM accounting twin this manager runs: "native"
+        (native/oom_state.cpp through mem/native.py) or "python"."""
+        return "native" if self._native is not None else "python"
+
     @property
     def device_used(self) -> int:
         if self._native is not None:

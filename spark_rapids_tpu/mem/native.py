@@ -1,40 +1,60 @@
 """ctypes binding to the native OOM state machine (native/oom_state.cpp).
 
-Builds the shared library on demand with g++ (cached beside the source);
-`load()` returns None when no compiler is available so the Python twin in
-manager.py keeps working — same pattern as the reference where RmmSpark is
-mandatory native but our runtime degrades gracefully.
+Builds the shared library on demand with g++ from the committed source
+(the ``.so`` is git-ignored, never shipped); `load()` returns None when the
+build fails so the Python twin in manager.py keeps working — same pattern
+as the reference where RmmSpark is mandatory native but our runtime
+degrades. The degradation is logged with the compiler's words, never
+silent: ``MemoryManager.state_machine`` says which twin is in use.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Optional, Sequence
 
-__all__ = ["load", "NativeOomState"]
+__all__ = ["load", "NativeOomState", "build_shared_lib"]
 
-_SRC = os.path.join(os.path.dirname(__file__), "..", "native",
-                    "oom_state.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "..", "native",
-                   "liboom_state.so")
+log = logging.getLogger(__name__)
+
+_NATIVE_DIR = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                           "..", "native"))
 _LOCK = threading.Lock()
 _lib = None          # tpulint: guarded-by _LOCK
 _tried = False       # tpulint: guarded-by _LOCK
 
 
-def _build() -> Optional[str]:
-    src = os.path.abspath(_SRC)
-    so = os.path.abspath(_SO)
+def build_shared_lib(stem: str, extra_flags: Sequence[str] = ()
+                     ) -> Optional[str]:
+    """``native/<stem>.cpp`` -> ``native/lib<stem>.so`` (rebuilt when the
+    source is newer). The compile lands under a per-process name and is
+    renamed into place, so concurrent first users (pytest workers) never
+    load a half-written library. Returns None, with a logged warning
+    carrying the compiler's output, when the build fails."""
+    src = os.path.join(_NATIVE_DIR, stem + ".cpp")
+    so = os.path.join(_NATIVE_DIR, f"lib{stem}.so")
     if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
         return so
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(["g++", "-O2", "-fPIC", "-shared", "-std=c++17",
-                        "-pthread", src, "-o", so], check=True,
+                        *extra_flags, src, "-o", tmp], check=True,
                        capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return so
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        log.warning("native build of %s failed (%s: %s)%s; the Python "
+                    "twin is used instead", src, type(e).__name__, e,
+                    ": " + detail.decode("utf-8", "replace")[-2000:]
+                    if detail else "")
+        try:
+            os.remove(tmp)
+        except OSError:
+            pass
         return None
 
 
@@ -44,7 +64,7 @@ def load():
         if _tried:
             return _lib
         _tried = True
-        so = _build()
+        so = build_shared_lib("oom_state", ("-pthread",))
         if so is None:
             return None
         lib = ctypes.CDLL(so)

@@ -3,24 +3,22 @@
 
 Spilled batches append into large slab files through a C++ block store
 with CRC32 verification on read-back; one store per spill directory,
-shared by every MemoryManager pointing at it. Falls back to None when no
-compiler is available — SpillableBatch then uses per-batch Arrow IPC
-files (the pure-Python tier).
+shared by every MemoryManager pointing at it. Falls back to None (with a
+logged warning, mem/native.build_shared_lib) when the build fails —
+SpillableBatch then uses per-batch Arrow IPC files (the pure-Python
+tier).
 """
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Dict, Optional
 
+from .native import build_shared_lib
+
 __all__ = ["NativeSpillStore", "get_store"]
 
-_SRC = os.path.join(os.path.dirname(__file__), "..", "native",
-                    "spill_store.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "..", "native",
-                   "libspill_store.so")
 _LOCK = threading.Lock()
 _lib = None          # tpulint: guarded-by _LOCK
 _tried = False       # tpulint: guarded-by _LOCK
@@ -33,16 +31,10 @@ def _load_lib():
         if _tried:
             return _lib
         _tried = True
-        src, so = os.path.abspath(_SRC), os.path.abspath(_SO)
-        try:
-            if not (os.path.exists(so)
-                    and os.path.getmtime(so) >= os.path.getmtime(src)):
-                subprocess.run(
-                    ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", src,
-                     "-o", so], check=True, capture_output=True, timeout=120)
-            lib = ctypes.CDLL(so)
-        except Exception:
+        so = build_shared_lib("spill_store")
+        if so is None:
             return None
+        lib = ctypes.CDLL(so)
         lib.sp_open.restype = ctypes.c_void_p
         lib.sp_open.argtypes = [ctypes.c_char_p, ctypes.c_int64]
         lib.sp_write.restype = ctypes.c_int64

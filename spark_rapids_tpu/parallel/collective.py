@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 from ..exprs.base import DVal, EvalContext, Expression
 from ..exec.groupby_core import segmented_groupby

@@ -52,10 +52,13 @@ COMPILE_CACHE_DIR = register(
     "spark.rapids.tpu.compile.cache.dir", "",
     "Directory for the persistent compiled-executable tier (JAX's "
     "on-disk compilation cache: serialized XLA executables keyed by "
-    "module fingerprint). Empty keeps the process default "
-    "(SRTPU_COMPILE_CACHE, ~/.cache/srtpu_xla). Point every serving "
-    "process of a fleet at a shared directory so a repeat query pays "
-    "zero compile even in a fresh process (docs/tuning.md).",
+    "module fingerprint). Empty keeps the process default (the fixed "
+    ".srtpu_cache/xla directory inside the checkout). Ignored when the "
+    "JAX_COMPILATION_CACHE_DIR environment variable is set: the "
+    "environment then owns the location and no engine code sets one. "
+    "Point every serving process of a fleet at a shared directory so a "
+    "repeat query pays zero compile even in a fresh process "
+    "(docs/tuning.md).",
     commonly_used=True)
 
 COMPILE_CACHE_MAX_BYTES = register(
@@ -326,6 +329,8 @@ def configure_from_conf(conf) -> Optional[str]:
     ExecContext construction — the metrics/tracer installation pattern.
     Returns the active cache dir (or None when persistence is off)."""
     import jax
+
+    from .. import compile_cache_dir_is_external
     cur = jax.config.jax_compilation_cache_dir
     # check-then-set under the lock: two ExecContexts constructed
     # concurrently must agree on ONE process default, not race to
@@ -336,6 +341,10 @@ def configure_from_conf(conf) -> Optional[str]:
         default_dir = _PROC_DEFAULT_DIR[0]
     want = (str(conf.get(COMPILE_CACHE_DIR) or "").strip()
             or default_dir)
+    if compile_cache_dir_is_external():
+        # JAX_COMPILATION_CACHE_DIR placed the cache from outside: the
+        # conf override stands down with the import-time default
+        want = cur or ""
     if want != (cur or ""):
         try:
             jax.config.update("jax_compilation_cache_dir", want or None)

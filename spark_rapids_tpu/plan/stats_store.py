@@ -10,7 +10,9 @@ flip). Plan signatures are content-addressed (cost._fingerprint_table), so
 they mean the same thing in the next process; this module gives them the
 same lifetime the XLA compile cache gives kernels.
 
-Format: one JSON file next to the XLA cache —
+Format: one JSON file beside the default compile-cache directory
+(``spark_rapids_tpu.STATE_DIR``, inside the checkout; ``SRTPU_STATS_PATH``
+relocates it) —
   {"version": 2, "walls": [[sig, placement, count, min_s], ...],
    "rows": [[sig, rows], ...],
    "ops": [[op_kind, placement, rows, seconds], ...],
@@ -56,9 +58,8 @@ def _path() -> str:
     p = os.environ.get("SRTPU_STATS_PATH")
     if p:
         return os.path.expanduser(p)
-    cache = os.environ.get("SRTPU_XLA_CACHE_DIR",
-                           os.path.expanduser("~/.cache/srtpu_xla"))
-    return os.path.join(cache, "adaptive_stats.json")
+    from .. import STATE_DIR
+    return os.path.join(STATE_DIR, "adaptive_stats.json")
 
 
 def store_path() -> str:

@@ -515,3 +515,55 @@ def test_fetch_packed_roundtrip_all_dtypes():
     for orig, back in zip(arrays, got):
         assert back.dtype == orig.dtype, (back.dtype, orig.dtype)
         np.testing.assert_array_equal(np.asarray(back), orig)
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 1 << 34}, 1 << 34),
+    ("tpu", None, RuntimeError),
+    ("tpu", {"bytes_in_use": 0}, RuntimeError),
+    ("cpu", None, 8 << 30),
+])
+def test_hbm_budget_is_read_not_guessed(monkeypatch, platform, stats, want):
+    """An accelerator that does not report bytes_limit is an error (the
+    old code swallowed every exception and assumed 8 GiB); only the CPU
+    backend, which never reports one, gets the assumed size."""
+    from spark_rapids_tpu.mem import manager
+
+    class FakeDevice:
+        def __init__(self):
+            self.platform = platform
+
+        def memory_stats(self):
+            return stats
+    monkeypatch.setattr(manager, "_pinned_or_first_device", FakeDevice)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            manager._device_hbm_bytes()
+    else:
+        assert manager._device_hbm_bytes() == want
+
+
+def test_native_build_failure_is_logged_not_hidden(monkeypatch, caplog):
+    """A failed g++ build returns None (the Python twin takes over) and
+    says so, with the compiler's words."""
+    import logging
+    import subprocess
+    from spark_rapids_tpu.mem import native
+
+    def failing_run(cmd, **kw):
+        raise subprocess.CalledProcessError(1, cmd, stderr=b"no such g++")
+    monkeypatch.setattr(native.os.path, "exists", lambda p: False)
+    monkeypatch.setattr(native.subprocess, "run", failing_run)
+    with caplog.at_level(logging.WARNING, logger=native.log.name):
+        assert native.build_shared_lib("oom_state") is None
+    assert "no such g++" in caplog.text and "Python twin" in caplog.text
+
+
+def test_memory_manager_names_its_state_machine():
+    mm = MemoryManager(1 << 20, 1 << 20, "/tmp/srtpu_spill_t")
+    assert mm.state_machine == "python"      # native is opt-in per ctor
+    from spark_rapids_tpu.mem.native import load
+    native_mm = MemoryManager(1 << 20, 1 << 20, "/tmp/srtpu_spill_t",
+                              use_native=True)
+    assert native_mm.state_machine == ("native" if load() is not None
+                                       else "python")

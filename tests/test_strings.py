@@ -703,6 +703,23 @@ def test_rect_edgecases_empty_and_all_space():
     assert_tpu_and_cpu_equal(q)
 
 
+def test_pallas_conf_on_tpu_fails_with_the_compilers_words(monkeypatch):
+    """The chip's compiler refuses the match kernel (PR 21): enabling the
+    conf on a TPU backend must say so, not die in a raw lowering error.
+    On the CPU the kernels stay available in interpreter mode."""
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.exprs import pallas_rect
+    on = TpuConf({"spark.rapids.tpu.sql.pallas.enabled": True})
+    assert pallas_rect.pallas_enabled(on) is True
+    assert pallas_rect.pallas_enabled(TpuConf()) is False
+    monkeypatch.setattr(pallas_rect, "_interpret", lambda: False)  # "tpu"
+    assert pallas_rect.pallas_enabled(TpuConf()) is False
+    with pytest.raises(NotImplementedError) as ei:
+        pallas_rect.pallas_enabled(on)
+    assert "Target does not support this comparison" in str(ei.value)
+    assert "RecursionError" in str(ei.value)
+
+
 def test_pallas_rect_predicates_differential():
     """r5: the Pallas sliding-match kernels (interpret mode on CPU) must
     agree with both the XLA rect ops and the host engine."""

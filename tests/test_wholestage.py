@@ -238,19 +238,78 @@ def test_corrupt_persistent_entry_falls_back_to_recompile(tmp_path):
     assert exec_cache.trim_persistent(cache_dir, 1) >= 1
 
 
-def test_compile_cache_dir_not_sticky_across_sessions(tmp_path):
-    """A session with an EMPTY compile.cache.dir conf must get the
-    process default back — not the previous session's override."""
-    import jax
-    from spark_rapids_tpu.config import TpuConf
-    exec_cache.configure_from_conf(TpuConf())   # settle on the default
-    default = jax.config.jax_compilation_cache_dir or ""
-    override = str(tmp_path / "session_cache")
-    exec_cache.configure_from_conf(
-        TpuConf({"spark.rapids.tpu.compile.cache.dir": override}))
-    assert jax.config.jax_compilation_cache_dir == override
-    exec_cache.configure_from_conf(TpuConf())
-    assert (jax.config.jax_compilation_cache_dir or "") == default
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: what a fresh process reports about the compile cache: the directory
+#: after import, after a session conf tried to re-point it, and the
+#: engine's own notion of the default and of the stats file
+_CACHE_PROBE = """
+import json, jax, spark_rapids_tpu as pkg
+from spark_rapids_tpu.config import TpuConf
+from spark_rapids_tpu.plan import exec_cache, stats_store
+at_import = jax.config.jax_compilation_cache_dir
+exec_cache.configure_from_conf(
+    TpuConf({"spark.rapids.tpu.compile.cache.dir": "/conf/override"}))
+print(json.dumps({"at_import": at_import,
+                  "after_conf": jax.config.jax_compilation_cache_dir,
+                  "default": pkg.DEFAULT_COMPILE_CACHE_DIR,
+                  "stats": stats_store.store_path()}))
+"""
+
+
+def _probe_cache_dir(env_dir):
+    """Run _CACHE_PROBE in a fresh CPU-pinned interpreter with (or,
+    None, without) JAX_COMPILATION_CACHE_DIR."""
+    import json
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "SRTPU_STATS_PATH")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       capture_output=True, text=True, timeout=240)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", ["conf_override_not_sticky",
+                                  "env_places_cache_repo_stands_down",
+                                  "default_is_fixed_in_checkout"])
+def test_compile_cache_dir_rule(case, tmp_path, monkeypatch):
+    """Who places the persistent compile cache: the environment
+    (JAX_COMPILATION_CACHE_DIR) when set — then no repo code sets a
+    directory, not at import and not from compile.cache.dir — else ONE
+    fixed directory inside the checkout, the same in every process,
+    which a session conf may re-point and an empty conf restores."""
+    if case == "conf_override_not_sticky":
+        import jax
+        from spark_rapids_tpu.config import TpuConf
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        exec_cache.configure_from_conf(TpuConf())   # settle on the default
+        default = jax.config.jax_compilation_cache_dir or ""
+        override = str(tmp_path / "session_cache")
+        exec_cache.configure_from_conf(
+            TpuConf({"spark.rapids.tpu.compile.cache.dir": override}))
+        assert jax.config.jax_compilation_cache_dir == override
+        exec_cache.configure_from_conf(TpuConf())
+        assert (jax.config.jax_compilation_cache_dir or "") == default
+    elif case == "env_places_cache_repo_stands_down":
+        got = _probe_cache_dir("/x")
+        assert got["at_import"] == "/x"
+        assert got["after_conf"] == "/x"
+        # the learned stats stay inside the checkout either way
+        assert got["stats"].startswith(_REPO + os.sep)
+    else:
+        first, second = _probe_cache_dir(None), _probe_cache_dir(None)
+        want = os.path.join(_REPO, ".srtpu_cache", "xla")
+        assert first["at_import"] == first["default"] == want
+        assert first["after_conf"] == "/conf/override"
+        assert second == first
+        assert first["stats"] == os.path.join(
+            _REPO, ".srtpu_cache", "adaptive_stats.json")
 
 
 def test_trim_persistent_evicts_oldest_first(tmp_path):
