@@ -261,7 +261,16 @@ class TpuSession:
         """Run a SQL query over registered temp views (ANSI analytics
         subset — see spark_rapids_tpu.sql)."""
         from ..sql import lower_statement
-        return lower_statement(self, text, self._views)
+        from ..trace import core as trace_core
+        tracer, mine = trace_core.query_tracer(self.conf)
+        if tracer is None:
+            return lower_statement(self, text, self._views)
+        try:
+            with tracer.span("plan.sql", cat="plan"):
+                return lower_statement(self, text, self._views)
+        finally:
+            if mine:
+                trace_core.release_query_tracer(tracer)
 
     def create_temp_view(self, name: str, df: "DataFrame") -> None:
         self._views[name.lower()] = df
@@ -685,6 +694,14 @@ class DataFrame:
         return self.plan.schema().names()
 
     def _physical(self, conf=None):
+        from ..trace import core as trace_core
+        tr = trace_core.TRACER       # single branch when tracing is off
+        if tr is None:
+            return self._plan_physical(conf)
+        with tr.span("plan.physical", cat="plan"):
+            return self._plan_physical(conf)
+
+    def _plan_physical(self, conf):
         return plan_query(self.plan, conf or self.session.conf,
                           mesh=getattr(self.session, "mesh", None),
                           mesh_auto=getattr(self.session, "mesh_is_auto",
@@ -728,13 +745,53 @@ class DataFrame:
         return over
 
     def _execute_wrapped(self, consume):
+        """Every materializing sink goes through here: the query under
+        its ``query`` span. The span opens BEFORE planning and closes
+        after the event log and the metrics, so every span of the query
+        has it as an ancestor and carries its ordinal ``q``; while a
+        ``jax.profiler`` session runs and no tracer is installed, the
+        query gets an annotate-only tracer for its own length
+        (trace/core.py). Tracing off: one conf lookup + one
+        ``is_enabled()`` a query."""
+        from ..trace import core as trace_core
+        tracer, mine = trace_core.query_tracer(self.session.conf)
+        if tracer is None:
+            return self._execute_query(consume)
+        q = next(self.session._query_seq)
+        qargs = {}
+        out_path = (str(self.session.conf.get(trace_core.TRACE_OUTPUT))
+                    if tracer.recording else "")
+        try:
+            with tracer.span("query", cat="query", args=qargs, q=q):
+                return self._execute_query(consume, q, qargs,
+                                           out_path or None)
+        finally:
+            if mine:
+                trace_core.release_query_tracer(tracer)
+            if out_path:
+                # written once the query span has closed, so that the
+                # artifact holds it
+                from ..trace.export import write_chrome_trace
+                try:
+                    write_chrome_trace(out_path, tracer)
+                except Exception as e:  # noqa: BLE001
+                    # tracing must never fail a query — but a silently
+                    # missing artifact after paying the recording
+                    # overhead must at least be loud
+                    import logging
+                    logging.getLogger(__name__).warning(
+                        "could not write trace to %s: %s", out_path, e)
+
+    def _execute_query(self, consume, q=None, qargs=None, trace_path=None):
         """Run the physical plan through the full execution pipeline
         (explainOnly guard, LORE wrap, profiler, task metrics, fault
-        dumps) — every materializing sink goes through here. Speculative
-        join sizing is reset per query, validated after the consume, and
-        transparently retried with exact sizing on overflow; plans with
-        side effects (file writes) run with speculation OFF so a retry
-        can never duplicate output files."""
+        dumps). Speculative join sizing is reset per query, validated
+        after the consume, and transparently retried with exact sizing
+        on overflow; plans with side effects (file writes) run with
+        speculation OFF so a retry can never duplicate output files.
+        ``q`` / ``qargs``: the ordinal and the args of the open ``query``
+        span (None when tracing is off); ``trace_path``: where
+        ``_execute_wrapped`` writes this query's trace afterwards."""
         # stale-telemetry guard: a query that RAISES must not leave the
         # prior run's summary behind for callers to misattribute — and a
         # non-distributed query must not inherit the last cluster run's
@@ -771,7 +828,6 @@ class DataFrame:
         from ..aux.lore import lore_wrap
         from ..aux.metrics import TaskMetrics
         from ..columnar.batch import SpeculativeOverflow
-        from ..trace import core as trace_core
         physical = lore_wrap(physical, run_conf or self.session.conf)
         ctx = self.session.exec_context()
         if run_conf is not None:
@@ -790,8 +846,6 @@ class DataFrame:
                 for code, n in sorted(codes.items()):
                     mreg0.counter("srtpu_placement_fallback_total",
                                   code=code, op=op).inc(n)
-        tracer = trace_core.ensure_tracer_from_conf(ctx.conf)
-        t0q = tracer.now() if tracer is not None else 0
         side_effects = isinstance(self.plan, L.WriteFile)
         ctx.speculations.clear()
         ctx.speculate = (ctx.conf.join_speculative_sizing
@@ -828,7 +882,8 @@ class DataFrame:
         tracker = _srv.tracker if _srv is not None else None
         if (elog is not None or tracker is not None or frec is not None
                 or sentinel is not None or slo is not None):
-            qid = next(self.session._query_seq)
+            # a traced query's id is its span's ordinal
+            qid = q if q is not None else next(self.session._query_seq)
             digest = _resolve_digest()
         if elog is not None:
             elog.write({"event": "queryStart", "queryId": qid,
@@ -850,7 +905,6 @@ class DataFrame:
             # ladder) carry the in-flight query's digest + coded report
             frec.set_query({"queryId": qid, "planDigest": digest,
                             "placement": placement_summary})
-        trace_path = None
         import time as _time
         # executable-cache counters around the run: zero in-process
         # misses AND zero backend-compile seconds = a COMPILE-FREE run,
@@ -993,29 +1047,13 @@ class DataFrame:
             ladder_rung = ctx.take_ladder_rung()
             prof.maybe_stop()
             self.session.last_query_metrics = tm.finish()
-            if tracer is not None:
-                # the whole-query span wraps the existing TaskMetrics
-                # capture: one umbrella every operator span nests under;
-                # it carries the placement verdict so the trace alone
-                # answers "did this query even touch the device"
-                qargs = {"ok": ok}
+            if qargs is not None:
+                # the query span carries the placement verdict so the
+                # trace alone answers "did this query even touch the
+                # device"
+                qargs["ok"] = ok
                 if report is not None:
                     qargs["placement"] = report.verdict
-                tracer.complete("query", t0q, cat="query", args=qargs)
-                out_path = str(ctx.conf.get(trace_core.TRACE_OUTPUT))
-                if out_path:
-                    from ..trace.export import write_chrome_trace
-                    try:
-                        write_chrome_trace(out_path, tracer)
-                        trace_path = out_path
-                    except Exception as e:  # noqa: BLE001
-                        # tracing must never fail a query — but a
-                        # silently missing artifact after paying the
-                        # recording overhead must at least be loud
-                        import logging
-                        logging.getLogger(__name__).warning(
-                            "could not write trace to %s: %s",
-                            out_path, e)
             if degs and report is not None:
                 # runtime pressure degradations join the query's coded
                 # placement report: explain-analyze renderers, the

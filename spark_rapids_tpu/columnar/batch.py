@@ -117,7 +117,8 @@ class ColumnarBatch:
     def num_rows(self) -> int:
         nr = self._num_rows
         if not isinstance(nr, int):
-            nr = int(nr)            # device sync
+            from .transfer import traced_device_get
+            nr = int(traced_device_get(nr, "d2h.num_rows"))   # device sync
             cap = next((c.padded_len for c in self.columns
                         if isinstance(c, DeviceColumn)), None)
             if cap is not None and nr > cap:

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..types import (DataType, DecimalType, STRING, TIMESTAMP, DATE,
                      from_arrow, to_arrow)
+from .transfer import traced_device_get
 
 __all__ = ["DeviceColumn", "HostColumn", "Column"]
 
@@ -103,9 +104,8 @@ class DeviceColumn:
     # -- host materialization ---------------------------------------------
     def to_numpy(self, num_rows: int):
         """Return (values, validity) host arrays truncated to num_rows."""
-        d = np.asarray(jax.device_get(self.data))[:num_rows]
-        v = np.asarray(jax.device_get(self.validity))[:num_rows]
-        return d, v
+        d, v = traced_device_get((self.data, self.validity))
+        return d[:num_rows], v[:num_rows]
 
     def arrow_from_host(self, d: np.ndarray, v: np.ndarray):
         """Assemble the arrow array from already-fetched host (data,
@@ -203,9 +203,8 @@ class DictColumn(DeviceColumn):
     def to_arrow(self, num_rows: int):
         if self.host_mirror is not None:
             return self.host_mirror.slice(0, num_rows)
-        codes = np.asarray(jax.device_get(self.data))[:num_rows]
-        v = np.asarray(jax.device_get(self.validity))[:num_rows]
-        return self.arrow_from_host(codes, v)
+        codes, v = traced_device_get((self.data, self.validity))
+        return self.arrow_from_host(codes[:num_rows], v[:num_rows])
 
     def __repr__(self):
         return (f"DictColumn(card={len(self.dictionary)}, "

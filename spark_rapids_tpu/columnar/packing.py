@@ -132,16 +132,14 @@ def fetch_packed(arrays):
     """Fetch a list of device arrays in at most two transfers; returns
     numpy arrays with the original dtypes/shapes."""
     from ..trace import core as trace_core
-    flat = list(arrays)
+    from .transfer import traced_device_get
+    flat = tuple(arrays)
     specs = [(np.dtype(a.dtype), tuple(a.shape)) for a in flat]
     tr = trace_core.TRACER           # single branch when tracing is off
     if tr is None:
-        u32, f64 = jax.device_get(_pack(tuple(flat)))
-        return unpack_streams(u32, f64, specs)
-    from .transfer import trace_fetch
-    t0 = tr.now()
-    packed = _pack(tuple(flat))      # pack-kernel dispatch (async)
-    t1 = tr.now()
-    u32, f64 = jax.device_get(packed)
-    trace_fetch(t0, t1, int(u32.nbytes + f64.nbytes))
+        packed = _pack(flat)
+    else:
+        with tr.span("d2h.dispatch", cat="transfer"):
+            packed = _pack(flat)     # pack-kernel dispatch (async)
+    u32, f64 = traced_device_get(packed)
     return unpack_streams(u32, f64, specs)

@@ -29,6 +29,7 @@ import numpy as np
 from ..config import register
 from ..types import STRING
 from .column import DeviceColumn
+from .transfer import traced_device_get
 
 __all__ = ["ByteRectColumn", "encode_string_rect", "RECT_MAX_BYTES",
            "rect_width_bucket", "pack_words", "unpack_words",
@@ -220,10 +221,8 @@ class ByteRectColumn(DeviceColumn):
         return DVal(StrVal(self.data, self.lengths), self.validity, STRING)
 
     def to_numpy(self, num_rows: int):
-        import jax
-        rect = np.asarray(jax.device_get(self.data))[:num_rows]
-        ln = np.asarray(jax.device_get(self.lengths))[:num_rows]
-        v = np.asarray(jax.device_get(self.validity))[:num_rows]
+        rect, ln, v = (a[:num_rows] for a in traced_device_get(
+            (self.data, self.lengths, self.validity)))
         w = rect.shape[1]
         mask = np.arange(w)[None, :] < np.where(v, ln, 0)[:, None]
         vals = np.empty(num_rows, object)
@@ -239,17 +238,15 @@ class ByteRectColumn(DeviceColumn):
     def to_arrow(self, num_rows: int):
         if self.host_mirror is not None:
             return self.host_mirror.slice(0, num_rows)
-        import jax
-        rect = np.asarray(jax.device_get(self.data))
-        ln = np.asarray(jax.device_get(self.lengths))
-        v = np.asarray(jax.device_get(self.validity))
+        rect, ln, v = traced_device_get(
+            (self.data, self.lengths, self.validity))
         return decode_rect_numpy(rect, ln, v, num_rows)
 
     def arrow_from_host(self, d, v):
         # d arrives as the fetched rectangle rows when the batched sink
         # fetch resolved this column (packing flattens 2-D arrays)
         if isinstance(d, np.ndarray) and d.ndim == 2:
-            ln = np.asarray(self.lengths)[:len(d)]
+            ln = traced_device_get(self.lengths)[:len(d)]
             return decode_rect_numpy(d, ln, np.asarray(v), len(d))
         return super().arrow_from_host(d, v)
 

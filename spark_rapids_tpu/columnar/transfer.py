@@ -28,7 +28,7 @@ import numpy as np
 from ..trace import core as trace_core
 
 __all__ = ["encode_columns", "decode_with_len", "worthwhile", "RAW",
-           "traced_device_put"]
+           "traced_device_put", "traced_device_get"]
 
 RAW = ("raw",)
 
@@ -210,43 +210,37 @@ def decode_with_len(dev_arrays, specs, params, padded_len: int):
 # ---------------------------------------------------------------------------
 
 def traced_device_put(host_arrays, label: str = "h2d"):
-    """``jax.device_put`` with H2D attribution when tracing is on: the
-    DISPATCH span (host-side enqueue, what the query thread pays even
-    asynchronously) is recorded separately from the DEVICE span (the
-    block_until_ready wait covering the actual transfer), so the
-    profile can split host time from device/transfer time. When tracing
-    is off this is exactly one branch around a plain device_put."""
+    """``jax.device_put`` with H2D attribution when tracing is on: a
+    ``<label>.dispatch`` span around the ENQUEUE (what the query thread
+    pays; the put stays asynchronous, the transfer itself is on the
+    device trace) and the ``h2d.bytes`` counter. When tracing is off
+    this is exactly one branch around a plain device_put."""
     import jax
     tr = trace_core.TRACER
     if tr is None:
         return jax.device_put(host_arrays)
     nbytes = sum(getattr(a, "nbytes", 0) for a in host_arrays)
-    t0 = tr.now()
-    out = jax.device_put(host_arrays)
-    t1 = tr.now()
-    tr.complete(f"{label}.dispatch", t0, t1, cat="transfer",
-                args={"bytes": nbytes, "arrays": len(host_arrays)})
-    # the wait is only forced while TRACING: attribution requires the
-    # transfer boundary, and an async put would bill it to whichever
-    # kernel happens to touch the arrays first
-    jax.block_until_ready(out)
-    tr.complete(f"{label}.device", t1, cat="transfer",
-                args={"bytes": nbytes})
+    with tr.span(f"{label}.dispatch", cat="transfer",
+                 args={"bytes": nbytes, "arrays": len(host_arrays)}):
+        out = jax.device_put(host_arrays)
     tr.counter("h2d.bytes", {"bytes": nbytes}, cat="transfer")
     return out
 
 
-def trace_fetch(t0_ns: int, t1_ns: int, nbytes: int,
-                label: str = "d2h") -> None:
-    """Record a device->host fetch that already happened: dispatch span
-    ``t0..t1`` (building/enqueueing the pack kernel) and transfer span
-    ``t1..now`` (the blocking device_get). Callers guard on the tracer
-    themselves so the disabled path stays a single branch."""
+def traced_device_get(arrays, label: str = "d2h"):
+    """``jax.device_get`` — THE blocking fetch of the query path: the
+    host waits for the chip to finish what ``arrays`` (an array or a
+    pytree of arrays) depend on, then copies them. With tracing on, a
+    ``<label>.transfer`` span covers that wait and the ``d2h.bytes``
+    counter counts what came back; off, one branch around the get."""
+    import jax
     tr = trace_core.TRACER
     if tr is None:
-        return
-    tr.complete(f"{label}.dispatch", t0_ns, t1_ns, cat="transfer",
-                args={"bytes": nbytes})
-    tr.complete(f"{label}.transfer", t1_ns, cat="transfer",
-                args={"bytes": nbytes})
+        return jax.device_get(arrays)
+    nbytes = sum(getattr(a, "nbytes", 0)
+                 for a in jax.tree_util.tree_leaves(arrays))
+    with tr.span(f"{label}.transfer", cat="transfer",
+                 args={"bytes": nbytes}):
+        out = jax.device_get(arrays)
     tr.counter("d2h.bytes", {"bytes": nbytes}, cat="transfer")
+    return out

@@ -28,6 +28,7 @@ import numpy as np
 
 from ..columnar import ColumnarBatch, DeviceColumn, HostColumn, concat_batches
 from ..columnar.bucketing import bucket_for
+from ..columnar.transfer import traced_device_get
 from ..exprs.aggregates import AggregateExpression
 from ..exprs.base import (BoundReference, DVal, EvalContext, Expression,
                           collect_param_literals, literal_scalars,
@@ -1103,7 +1104,8 @@ class TpuHashAggregateExec(TpuExec):
                 cols, jnp.int32(batch.num_rows_raw), batch.padded_len,
                 self._upd_scalars)
             specs = self._fast_k.out_specs[batch.padded_len]
-        u32, f64 = jax.device_get(packed)       # the ONE round trip
+        # the ONE round trip
+        u32, f64 = traced_device_get(packed, "d2h.agg")
         got = unpack_streams(u32, f64, specs)
         n = int(got[0])
         if n > self.OPTIMISTIC_GROUPS:
@@ -1250,12 +1252,12 @@ class TpuHashAggregateExec(TpuExec):
             if not self.groupings:
                 counts = [1] * len(window)
             elif len(window) == 1:
-                counts = [int(window[0][1])]
+                counts = [int(traced_device_get(window[0][1],
+                                                "d2h.groups"))]
             else:
                 def resolve_counts():
-                    import numpy as _np
-                    return [int(x) for x in
-                            _np.asarray(jnp.stack([w[1] for w in window]))]
+                    return [int(x) for x in traced_device_get(
+                        jnp.stack([w[1] for w in window]), "d2h.groups")]
                 counts = with_retry_no_split(resolve_counts, ctx=ctx,
                                              op=self._exec_id)
             for (outs, _, dispatch, base, n_disp), n in zip(window,
@@ -1500,12 +1502,13 @@ class TpuHashAggregateExec(TpuExec):
                 ngs = [r[1] for r in raws if isinstance(r, tuple)]
                 if len(ngs) > 1:
                     def resolve():
-                        import numpy as _np
-                        return [int(x) for x in _np.asarray(jnp.stack(ngs))]
+                        return [int(x) for x in traced_device_get(
+                            jnp.stack(ngs), "d2h.groups")]
                     counts = iter(with_retry_no_split(resolve, ctx=ctx,
                                                       op=self._exec_id))
                 else:
-                    counts = iter([int(ngs[0])] if ngs else [])
+                    counts = iter([int(traced_device_get(
+                        ngs[0], "d2h.groups"))] if ngs else [])
                 merged_level = []
                 for r in raws:
                     if not isinstance(r, tuple):

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..columnar import ColumnarBatch, DeviceColumn, concat_batches
 from ..columnar.bucketing import bucket_for
+from ..columnar.transfer import traced_device_get
 from ..exprs.base import DVal, EvalContext, Expression
 from ..exprs.compiler import (_compact_kernel, eval_predicate_device,
                               filter_batch_device, gather_batch_device)
@@ -304,7 +305,7 @@ def _finish_pair_join(join_type: str, lb: ColumnarBatch, rb: ColumnarBatch,
     joins) and the full cross product (nested loop)."""
     pair_schema = Schema(list(lb.schema.fields) + list(rb.schema.fields))
     if condition is not None:
-        n_pairs = int(jnp.sum(live))
+        n_pairs = int(traced_device_get(jnp.sum(live), "d2h.join_count"))
         lo = gather_batch_device(lb, l_row, n_pairs, int(l_row.shape[0]))
         ro = gather_batch_device(rb, r_row, n_pairs, int(r_row.shape[0]))
         pairs = ColumnarBatch(lo.columns + ro.columns, n_pairs, pair_schema)
@@ -332,9 +333,8 @@ def _finish_pair_join(join_type: str, lb: ColumnarBatch, rb: ColumnarBatch,
         else zl
     ur = jnp.logical_and(mr == 0, rmask) if join_type in ("right", "full") \
         else jnp.zeros_like(rmask)
-    n_match = int(jnp.sum(match))
-    n_ul = int(jnp.sum(ul))
-    n_ur = int(jnp.sum(ur))
+    n_match, n_ul, n_ur = (int(n) for n in traced_device_get(
+        (jnp.sum(match), jnp.sum(ul), jnp.sum(ur)), "d2h.join_count"))
     if join_type == "inner":
         n_ul = n_ur = 0
         ul, ur = zl, jnp.zeros_like(rmask)
@@ -659,7 +659,7 @@ class TpuHashJoinExec(TpuExec):
             ctx.speculations.append((total, out_p, ck,
                                      getattr(self, 'plan_sig', None)))
         else:
-            n_out = int(total)
+            n_out = int(traced_device_get(total, "d2h.join_count"))
             _TOTAL_STATS[ck] = n_out
             out_p = bucket_for(max(n_out, 1))
         left_nullable = 1 if self.join_type in ("right", "full") else 0
@@ -743,7 +743,7 @@ class TpuHashJoinExec(TpuExec):
         (s_orig, cnt_l, cnt_r, start_l, start_r, _pairs, offsets, total,
          _ng) = kern(lcols, rcols, jnp.int32(lb.num_rows),
                      jnp.int32(rb.num_rows), lb.padded_len, rb.padded_len)
-        n_out = int(total)
+        n_out = int(traced_device_get(total, "d2h.join_count"))
         out_p = bucket_for(max(n_out, 1))
         cfg = jnp.zeros(3, dtype=jnp.int32)
         l_row, r_row = _gather_index_kernel(

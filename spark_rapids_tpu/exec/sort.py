@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import ColumnarBatch, DeviceColumn, concat_batches
+from ..columnar.transfer import traced_device_get
 from ..exprs.base import DVal, EvalContext
 from ..mem import SpillableBatch, with_retry_no_split, wrap_spillables
 from ..plan.logical import SortOrder
@@ -255,7 +256,9 @@ class TpuSortExec(TpuExec):
                         k = max(min(n, -(-budget * n // total_rows)), 1)
                         idx = jnp.asarray(
                             np.linspace(0, n - 1, num=k, dtype=np.int64))
-                        samp = [np.asarray(jnp.take(op, idx)) for op in ops]
+                        samp = traced_device_get(
+                            [jnp.take(op, idx) for op in ops],
+                            "d2h.sort_sample")
                         return SpillableBatch(run, ctx.memory), samp
                 run_sb, samp = with_retry_no_split(sort_one, ctx=ctx,
                                                    op=self._exec_id)
@@ -291,8 +294,8 @@ class TpuSortExec(TpuExec):
             arrays = [(c.data, c.validity) for c in run.columns]
             cols, counts = _split_kernel(arrays, pid, run.padded_len,
                                          n_buckets + 2)
-            return PartitionedBatches(cols, np.asarray(counts)[:n_buckets],
-                                      run.schema)
+            counts = traced_device_get(counts, "d2h.sort_counts")
+            return PartitionedBatches(cols, counts[:n_buckets], run.schema)
 
         bucket_slices = scatter_spillables(ctx, runs, bucket_run, n_buckets)
 

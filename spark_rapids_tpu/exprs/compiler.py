@@ -536,7 +536,9 @@ def gather_batch_device(batch: ColumnarBatch, indices, num_rows: int,
     _lane_rebuild(batch, spans, outs, new_cols)
     if len(dev_pos) < len(new_cols):
         import pyarrow as pa
-        idx = np.asarray(indices)[:int(num_rows)].astype(np.int64)
+        from ..columnar.transfer import traced_device_get
+        idx, n = traced_device_get((indices, num_rows), "d2h.gather_map")
+        idx = idx[:int(n)].astype(np.int64)
         null_row = idx < 0
         pa_idx = pa.array(np.where(null_row, 0, idx), mask=null_row)
         for i, c in enumerate(batch.columns):

@@ -114,22 +114,25 @@ class DeviceSemaphore:
             return
         self._maybe_watchdog()
         tr = trace_core.TRACER
-        t0n = tr.now() if tr is not None else 0
         t0 = time.perf_counter()
         with self._lock:
             self.waiting += 1
         try:
-            acquired = self._wait_acquire()
+            if tr is None:
+                acquired = self._wait_acquire()
+            else:
+                sargs = {"permits": self._permits}
+                with tr.span("semaphore.wait", cat="sem", args=sargs):
+                    acquired = self._wait_acquire()
+                    if not acquired:
+                        # the timed-out wait is the WORST contention
+                        # case — the profiler must see it, not just
+                        # successful acquires
+                        sargs["timeout"] = True
         finally:
             with self._lock:
                 self.waiting -= 1
         if not acquired:
-            if tr is not None:
-                # the timed-out wait is the WORST contention case — the
-                # profiler must see it, not just successful acquires
-                tr.complete("semaphore.wait", t0n, cat="sem",
-                            args={"permits": self._permits,
-                                  "timeout": True})
             raise TimeoutError(
                 f"device semaphore not acquired within {self._timeout}s; "
                 f"diagnostics: {self.diagnostics()}")
@@ -169,9 +172,6 @@ class DeviceSemaphore:
                            detail=f"reclaimed permit of dead thread "
                                   f"{stale['name']!r} (recycled ident); "
                                   f"diagnostics: {self.diagnostics()}")
-        if tr is not None:
-            tr.complete("semaphore.wait", t0n, cat="sem",
-                        args={"permits": self._permits})
         self._held.count = 1
         # chaos site: a holder that stalls WITH the permit (the stuck-
         # holder scenario the wedge watchdog diagnoses; aux/fault.py)

@@ -104,9 +104,11 @@ def analyze(events: List[dict]) -> dict:
     top_ops = sorted(ops.items(),
                      key=lambda kv: (-kv[1]["self_us"], kv[0]))
 
-    # transfers come as (dispatch, device/transfer) span PAIRS sharing
-    # the bytes arg: count transfers and bytes from the dispatch spans
-    # only, time from both halves
+    # an upload is ONE <label>.dispatch span (its enqueue; older traces
+    # add a .device span for a wait the tracer forced); a fetch is ONE
+    # d2h.*.transfer span (the blocking get), with a d2h.dispatch span
+    # before it only where a pack kernel was enqueued. Count an H2D per
+    # .dispatch and a D2H per .transfer; time from every span
     h2d_n = h2d_b = d2h_n = d2h_b = 0
     h2d_us = d2h_us = dispatch_us = device_us = 0.0
     for e in _spans(events):
@@ -126,7 +128,7 @@ def analyze(events: List[dict]) -> dict:
                 h2d_b += nbytes
         elif name.startswith("d2h"):
             d2h_us += dur
-            if is_dispatch:
+            if name.endswith(".transfer"):
                 d2h_n += 1
                 d2h_b += nbytes
 

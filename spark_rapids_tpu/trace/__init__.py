@@ -11,10 +11,12 @@ tuning recommendations, the role the reference's profiling tool plays
 over Spark event logs.
 """
 from .core import (TRACE_BUFFER_SPANS, TRACE_ENABLED, TRACE_OUTPUT, Tracer,
-                   active_tracer, ensure_tracer_from_conf, install_tracer)
+                   active_tracer, ensure_tracer_from_conf, install_tracer,
+                   query_tracer, release_query_tracer)
 from .export import chrome_trace, load_chrome_trace, write_chrome_trace
 
 __all__ = ["Tracer", "active_tracer", "install_tracer",
-           "ensure_tracer_from_conf", "TRACE_ENABLED", "TRACE_BUFFER_SPANS",
+           "ensure_tracer_from_conf", "query_tracer",
+           "release_query_tracer", "TRACE_ENABLED", "TRACE_BUFFER_SPANS",
            "TRACE_OUTPUT", "chrome_trace", "write_chrome_trace",
            "load_chrome_trace"]

@@ -18,9 +18,17 @@ Design contract (ISSUE 4):
   ``spark.rapids.tpu.trace.buffer.spans`` slots; overflow drops the
   OLDEST events and counts the drops (a trace must never OOM the
   process it is observing);
-* **nested spans** — a contextvar carries the current span id, so a
-  child operator's span records its parent without any global stack
-  (threads and generators interleave safely).
+* **nested spans** — a contextvar carries the current span id and the
+  query ordinal ``q`` of the enclosing ``query`` span, so a child
+  operator's span records its parent and its query without any global
+  stack (threads and generators interleave safely);
+* **two sinks, one recorder** — a span goes to the ring buffer and,
+  while a ``jax.profiler`` session runs, also into the profiler's own
+  trace as a ``TraceAnnotation`` named ``srtpu/<cat>/<name>``: the
+  engine's spans then sit on the clock of the device's ``XLA Ops``
+  line. A query that starts while a session runs and no tracer is
+  installed gets an ANNOTATE-ONLY tracer (no ring buffer, no dict per
+  span) for its own length (:func:`query_tracer`).
 """
 from __future__ import annotations
 
@@ -31,13 +39,15 @@ import pickle
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from ..config import register
 
 __all__ = ["Tracer", "active_tracer", "install_tracer",
-           "ensure_tracer_from_conf", "TRACE_ENABLED", "TRACE_BUFFER_SPANS",
-           "TRACE_OUTPUT"]
+           "ensure_tracer_from_conf", "query_tracer", "release_query_tracer",
+           "TRACE_ENABLED", "TRACE_BUFFER_SPANS", "TRACE_OUTPUT"]
 
 TRACE_ENABLED = register(
     "spark.rapids.tpu.trace.enabled", False,
@@ -65,41 +75,58 @@ TRACE_OUTPUT = register(
 TRACER: Optional["Tracer"] = None
 
 _SPAN_IDS = itertools.count(1)
-_CUR_SPAN: contextvars.ContextVar[int] = contextvars.ContextVar(
-    "srtpu_trace_span", default=0)
+#: (id of the innermost open span, query ordinal ``q`` it belongs to)
+_CUR_SPAN: contextvars.ContextVar[Tuple[int, Optional[int]]] = \
+    contextvars.ContextVar("srtpu_trace_span", default=(0, None))
 
 
 class _SpanCtx:
     """Reusable span context manager (allocated only when tracing is ON)."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "sid", "t0", "token")
+    __slots__ = ("tracer", "name", "cat", "args", "q", "sid", "t0",
+                 "token", "ann")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args, q):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self.q = q
 
     def __enter__(self):
         self.sid = next(_SPAN_IDS)
+        if self.q is None:
+            self.q = _CUR_SPAN.get()[1]
+        self.ann = None
+        if _Annotation.is_enabled():
+            # the profiler sink: the same span on the device trace's clock
+            meta = {} if self.q is None else {"q": self.q}
+            if self.args and "exec" in self.args:
+                meta["exec"] = self.args["exec"]
+            self.ann = _Annotation(f"srtpu/{self.cat}/{self.name}", **meta)
+            self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
-        self.token = _CUR_SPAN.set(self.sid)
+        self.token = _CUR_SPAN.set((self.sid, self.q))
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
         parent = 0
         try:
             _CUR_SPAN.reset(self.token)
-            parent = _CUR_SPAN.get()
+            parent = _CUR_SPAN.get()[0]
         except Exception:   # token from another context: best effort
             pass
-        self.tracer._emit({"ph": "X", "name": self.name, "cat": self.cat,
-                           "ts": self.t0, "dur": t1 - self.t0,
-                           "pid": self.tracer.pid,
-                           "tid": threading.get_ident(),
-                           "id": self.sid, "parent": parent,
-                           "args": self.args})
+        tracer = self.tracer
+        if tracer.recording:
+            tracer._emit({"ph": "X", "name": self.name, "cat": self.cat,
+                          "ts": self.t0, "dur": t1 - self.t0,
+                          "pid": tracer.pid,
+                          "tid": threading.get_ident(),
+                          "id": self.sid, "parent": parent, "q": self.q,
+                          "args": self.args})
         return False
 
 
@@ -108,10 +135,15 @@ class Tracer:
 
     Events are plain dicts in Chrome-trace shape with NANOSECOND
     ``ts``/``dur`` (the exporter converts to microseconds): ``ph`` is
-    ``X`` (complete span), ``C`` (counter) or ``i`` (instant)."""
+    ``X`` (complete span), ``C`` (counter) or ``i`` (instant). With
+    ``recording=False`` the tracer is annotate-only: :meth:`span` still
+    enters the profiler's annotation, nothing reaches the buffer, and
+    what cannot be an annotation (:meth:`complete`, :meth:`counter`,
+    :meth:`instant`) is skipped."""
 
     def __init__(self, max_events: int = 65536,
-                 proc_name: Optional[str] = None):
+                 proc_name: Optional[str] = None, recording: bool = True):
+        self.recording = recording
         self.pid = os.getpid()
         self.proc_name = proc_name or f"pid-{self.pid}"
         #: perf_counter -> wall-clock offset, captured once: lets the
@@ -135,30 +167,42 @@ class Tracer:
             self._buf.append(ev)
 
     def span(self, name: str, cat: str = "exec",
-             args: Optional[dict] = None) -> _SpanCtx:
-        """Context manager recording one complete span around its body."""
-        return _SpanCtx(self, name, cat, args)
+             args: Optional[dict] = None,
+             q: Optional[int] = None) -> _SpanCtx:
+        """Context manager recording one complete span around its body.
+        ``q`` is given by the ``query`` span alone (the session's query
+        ordinal); every span opened inside it inherits it."""
+        return _SpanCtx(self, name, cat, args, q)
 
     def complete(self, name: str, t0_ns: int, t1_ns: Optional[int] = None,
                  cat: str = "exec", args: Optional[dict] = None) -> None:
         """Record a span that already happened: ``t0_ns`` from
-        :meth:`now` before the work, end defaulting to now."""
+        :meth:`now` before the work, end defaulting to now. Written
+        after the fact, so it reaches the ring buffer only, never the
+        profiler's trace."""
+        if not self.recording:
+            return
         if t1_ns is None:
             t1_ns = time.perf_counter_ns()
+        parent, q = _CUR_SPAN.get()
         self._emit({"ph": "X", "name": name, "cat": cat, "ts": t0_ns,
                     "dur": t1_ns - t0_ns, "pid": self.pid,
                     "tid": threading.get_ident(),
-                    "id": next(_SPAN_IDS), "parent": _CUR_SPAN.get(),
+                    "id": next(_SPAN_IDS), "parent": parent, "q": q,
                     "args": args})
 
     def counter(self, name: str, values: Dict[str, float],
                 cat: str = "counter") -> None:
+        if not self.recording:
+            return
         self._emit({"ph": "C", "name": name, "cat": cat,
                     "ts": time.perf_counter_ns(), "pid": self.pid,
                     "tid": threading.get_ident(), "args": dict(values)})
 
     def instant(self, name: str, cat: str = "event",
                 args: Optional[dict] = None) -> None:
+        if not self.recording:
+            return
         self._emit({"ph": "i", "s": "t", "name": name, "cat": cat,
                     "ts": time.perf_counter_ns(), "pid": self.pid,
                     "tid": threading.get_ident(), "args": args})
@@ -264,3 +308,30 @@ def ensure_tracer_from_conf(conf) -> Optional[Tracer]:
             TRACER = Tracer(max_events=int(conf.get(TRACE_BUFFER_SPANS)),
                             proc_name="driver")
         return TRACER
+
+
+def query_tracer(conf) -> Tuple[Optional[Tracer], bool]:
+    """The tracer a query (or a ``sql()`` call) starts under, and whether
+    it was installed HERE for this query alone: the installed one, else
+    the conf's, else — while a ``jax.profiler`` session runs, whoever
+    started it — a fresh annotate-only tracer, which the caller hands to
+    :func:`release_query_tracer` at the query's end. With tracing off
+    and no session this is one conf lookup and one ``is_enabled()``."""
+    global TRACER
+    tr = ensure_tracer_from_conf(conf)
+    if tr is not None or not _Annotation.is_enabled():
+        return tr, False
+    with _INSTALL_LOCK:
+        if TRACER is None:
+            TRACER = Tracer(proc_name="driver", recording=False)
+            return TRACER, True
+        return TRACER, False
+
+
+def release_query_tracer(tracer: Tracer) -> None:
+    """Remove the annotate-only tracer :func:`query_tracer` installed,
+    unless another one has taken its place since."""
+    global TRACER
+    with _INSTALL_LOCK:
+        if TRACER is tracer:
+            TRACER = None

@@ -24,6 +24,9 @@ def _to_chrome(ev: dict) -> dict:
     if ev["ph"] == "i":
         out["s"] = ev.get("s", "t")
     args = ev.get("args")
+    if ev.get("q") is not None:
+        # the query ordinal every span of one query shares (trace/core.py)
+        args = {**(args or {}), "q": ev["q"]}
     if args:
         out["args"] = args
     return out

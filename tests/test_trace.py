@@ -155,6 +155,202 @@ def test_query_trace_written_and_loadable(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# one tracer, two sinks: the profiler's clock, spans where the work happens
+# ---------------------------------------------------------------------------
+
+#: the operator pipeline (exec/), not the one-program fragment of parallel/
+_OPERATOR_CONF = {"spark.rapids.tpu.sql.optimizer.enabled": False,
+                  "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+                  "spark.rapids.tpu.distributed.enabled": False}
+
+
+def _fact(n=3000):
+    return pa.table({"k": pa.array(np.arange(n) % 7),
+                     "v": pa.array(np.arange(n, dtype=np.float64))})
+
+
+def _grouped(s):
+    return (s.create_dataframe(_fact()).group_by("k")
+            .agg(F.sum(F.col("v")).with_name("sv")))
+
+
+def _xs(tracer):
+    return [e for e in tracer.snapshot() if e["ph"] == "X"]
+
+
+def test_profiler_session_gets_engine_spans(tmp_path):
+    """While a jax.profiler session runs — whoever started it — and no
+    tracer is installed, a query annotates itself into the profiler's
+    trace: its spans sit on the device trace's clock, properly nested,
+    and nothing stays installed. Without a session nothing is installed
+    at all."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from spark_rapids_tpu.trace import core as trace_core
+    s = tpu_session(_OPERATOR_CONF)
+    s.create_temp_view("t", s.create_dataframe(_fact()))
+    text = "select k, sum(v) as sv from t group by k"
+    installs = []
+    real = trace_core.Tracer
+
+    class Counted(real):
+        def __init__(self, *a, **kw):
+            installs.append(kw)
+            super().__init__(*a, **kw)
+
+    trace_core.Tracer = Counted
+    try:
+        assert s.sql(text).collect_arrow().num_rows == 7   # no session
+        assert installs == [] and trace_core.TRACER is None
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            df = s.sql(text)
+            assert trace_core.TRACER is None        # gone after sql() too
+            assert df.collect_arrow().num_rows == 7
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        trace_core.Tracer = real
+    assert trace_core.TRACER is None
+    # one annotate-only tracer for sql(), one for the query
+    assert installs == [{"proc_name": "driver", "recording": False}] * 2
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("srtpu/")]
+    names = [n for n, *_ in spans]
+    assert "srtpu/plan/plan.sql" in names
+    assert "srtpu/plan/plan.physical" in names
+    assert any(n.startswith("srtpu/transfer/d2h") for n in names), names
+    (query,) = [sp for sp in spans if sp[0] == "srtpu/query/query"]
+    q = query[3]["q"]
+    # every operator of the plan has its own exec annotation, by its id
+    ops = {sp[3]["exec"] for sp in spans if sp[0].startswith("srtpu/exec/")}
+    assert {o.split("@")[0] for o in ops} >= {"InMemoryScanExec",
+                                              "TpuHashAggregateExec"}, ops
+    for name, a, b, stats in spans:
+        if name == "srtpu/plan/plan.sql":
+            assert b <= query[1]                # sql() precedes the query
+            continue
+        assert query[1] <= a and b <= query[2], (name, a, b, query)
+        assert stats["q"] == q, (name, stats)
+    # properly nested: two spans are disjoint or one holds the other
+    for i, (_, a, b, _) in enumerate(spans):
+        for _, c, d, _ in spans[i + 1:]:
+            assert b <= c or d <= a or (a <= c and d <= b) \
+                or (c <= a and b <= d), (a, b, c, d)
+
+
+def test_traced_upload_never_blocks(monkeypatch):
+    """With a tracer installed an upload stays an asynchronous enqueue:
+    no block_until_ready (a tracer must not change what it measures),
+    and still the enqueue span and the bytes counter."""
+    import jax
+    from spark_rapids_tpu.columnar.transfer import traced_device_put
+    waits = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(x) or x)
+    tr = install_tracer(Tracer())
+    host = [np.arange(1000, dtype=np.int64), np.ones(1000, dtype=bool)]
+    out = traced_device_put(host, label="h2d.test")
+    assert waits == []
+    assert [np.asarray(o).tolist() for o in out] == \
+        [h.tolist() for h in host]
+    evs = tr.snapshot()
+    (span,) = [e for e in evs if e["ph"] == "X"]
+    assert span["name"] == "h2d.test.dispatch" and span["cat"] == "transfer"
+    assert span["args"] == {"bytes": 9000, "arrays": 2}
+    (ctr,) = [e for e in evs if e["ph"] == "C"]
+    assert ctr["name"] == "h2d.bytes" and ctr["args"] == {"bytes": 9000}
+
+
+def test_every_fetch_happens_inside_a_d2h_span(monkeypatch):
+    """The blocking gets are where a one-client query waits for the chip:
+    every jax.device_get of a global aggregate, a grouped aggregate and a
+    join + sort goes through traced_device_get, i.e. happens while a
+    d2h.*.transfer span is the innermost open one."""
+    import jax
+    from spark_rapids_tpu.trace import core as trace_core
+    seen = []
+    real = jax.device_get
+
+    def spying(x):
+        seen.append(trace_core._CUR_SPAN.get()[0])
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", spying)
+    s = tpu_session(_OPERATOR_CONF)
+    fact = s.create_dataframe(_fact())
+    dim = s.create_dataframe(pa.table({
+        "k": pa.array(np.arange(7)),
+        "w": pa.array(np.arange(7, dtype=np.float64))}))
+    queries = {
+        "global": fact.agg(F.sum(F.col("v")).with_name("sv")),
+        "grouped": _grouped(s),
+        "join_sort": fact.join(dim, on="k").sort("v").limit(50),
+    }
+    for name, df in queries.items():
+        tr = install_tracer(Tracer())
+        del seen[:]
+        assert df.collect_arrow().num_rows > 0
+        install_tracer(None)
+        by_id = {e["id"]: e["name"] for e in _xs(tr)}
+        assert seen, name                      # at least one fetch a query
+        for sid in seen:
+            span = by_id.get(sid, "")
+            assert span.startswith("d2h") and span.endswith(".transfer"), \
+                (name, span)
+
+
+def test_spans_of_a_query_share_its_ordinal():
+    """Every span of a query reaches that query's ``query`` span by
+    ``parent`` and carries its ``q``; the next query gets another."""
+    tr = install_tracer(Tracer())
+    s = tpu_session(_OPERATOR_CONF)
+    df = _grouped(s)
+    df.collect_arrow()
+    df.collect_arrow()
+    spans = _xs(tr)
+    by_id = {e["id"]: e for e in spans}
+    queries = [e for e in spans if e["name"] == "query"]
+    assert len(queries) == 2
+    assert queries[0]["q"] != queries[1]["q"]
+    assert all(e["parent"] == 0 and e["cat"] == "query" for e in queries)
+    for e in spans:
+        root = e
+        while root["parent"]:
+            root = by_id[root["parent"]]
+        assert root["name"] == "query", e
+        assert e["q"] == root["q"] is not None, e
+    assert {e["q"] for e in spans} == {q["q"] for q in queries}
+
+
+def test_planning_is_inside_the_query_span():
+    """The query span opens before planning: plan.physical lies inside it
+    and ends before the first operator span starts."""
+    tr = install_tracer(Tracer())
+    _grouped(tpu_session(_OPERATOR_CONF)).collect_arrow()
+    spans = _xs(tr)
+    (query,) = [e for e in spans if e["name"] == "query"]
+    (plan,) = [e for e in spans if e["name"] == "plan.physical"]
+    assert plan["cat"] == "plan" and plan["parent"] == query["id"]
+    assert query["ts"] <= plan["ts"]
+    assert plan["ts"] + plan["dur"] <= query["ts"] + query["dur"]
+    first_exec = min(e["ts"] for e in spans if e["cat"] == "exec")
+    assert plan["ts"] + plan["dur"] <= first_exec
+    # the verdict the span is closed with
+    assert query["args"]["ok"] is True and "placement" in query["args"]
+
+
+# ---------------------------------------------------------------------------
 # distributed: 3 workers, one merged timeline
 # ---------------------------------------------------------------------------
 
