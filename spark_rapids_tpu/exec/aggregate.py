@@ -34,6 +34,7 @@ from ..exprs.base import (BoundReference, DVal, EvalContext, Expression,
                           collect_param_literals, literal_scalars,
                           literal_slot_map, parameterized_keys)
 from ..mem import SpillableBatch, with_retry_no_split
+from ..trace import core as trace_core
 from ..types import STRING, Schema, StructField
 from .base import ESSENTIAL, ExecContext, TpuExec
 from .groupby_core import segmented_groupby
@@ -387,6 +388,35 @@ def _get_kernel(key_exprs, aggs, schema, mode, partial_counts=None,
     return k
 
 
+def _count_carry(batches: int, flushes: int) -> None:
+    """Tracer counter ``agg.carry``, once per aggregate execution: input
+    batches folded into a device-resident carry, and carries that had to
+    leave it for the windowed path (docs/profiling.md)."""
+    tr = trace_core.TRACER
+    if tr is not None:
+        tr.counter("agg.carry", {"batches": batches, "flushes": flushes},
+                   cat="exec")
+
+
+def _kernel_cols(batch: ColumnarBatch) -> list:
+    """A batch's columns as the direct kernels take them: (data,
+    validity) per device column, None for what lives on the host."""
+    return [(c.data, c.validity) if isinstance(c, DeviceColumn) else None
+            for c in batch.columns]
+
+
+def _direct_strides(cards, nkeys: int):
+    """Per-key strides of the direct-addressed slot number
+    gid = sum(code_i * stride_i); every key takes cards[i] + 1 codes (the
+    last one is NULL), the first key is the most significant."""
+    strides = []
+    stride = jnp.int32(1)
+    for i in reversed(range(nkeys)):
+        strides.insert(0, stride)
+        stride = stride * (cards[i] + 1)
+    return strides
+
+
 class TpuHashAggregateExec(TpuExec):
     """Device hash aggregate. String group keys are DICTIONARY-ENCODED at
     the exec boundary (TPU-first design: strings live on the host; the
@@ -576,10 +606,15 @@ class TpuHashAggregateExec(TpuExec):
         if isinstance(g, ColumnRef) and g.name in batch.schema.names():
             src = batch.column_by_name(g.name)
         if isinstance(src, DictColumn):
-            gmap = np.asarray(
-                [d.setdefault(s_, len(d)) for s_ in src.dictionary],
-                dtype=np.int32)
-            return src.data, src.validity, gmap, False
+            # the exec-local dictionary only appends, so the remap of a
+            # source dictionary stays true for every later batch that
+            # carries the same one (the scan cache's batches do)
+            seen = self._gmaps.get(j)
+            if seen is None or seen[0] is not src.dictionary:
+                seen = self._gmaps[j] = (src.dictionary, np.asarray(
+                    [d.setdefault(s_, len(d)) for s_ in src.dictionary],
+                    dtype=np.int32))
+            return src.data, src.validity, seen[1], False
         arr = g.eval_host(batch)
         if isinstance(arr, pa.ChunkedArray):
             arr = arr.combine_chunks()
@@ -735,19 +770,34 @@ class TpuHashAggregateExec(TpuExec):
         cached = _AGG_KERNEL_CACHE.get(key)
         if cached is not None:
             return cached
+        core = self._build_direct_core(g_bucket)
+        spec_cell = {}
+        finish = self._build_direct_finish(g_bucket, spec_cell)
+
+        @functools.partial(jax.jit, static_argnums=(2,))
+        def fast_direct(cols, num_rows, padded_len, cards, scalars,
+                        code_pairs, remaps):
+            return finish(*core(cols, num_rows, padded_len, cards, scalars,
+                                code_pairs, remaps), padded_len)
+
+        fast_direct.out_specs = spec_cell
+        fast_direct.n_param_slots = core.n_param_slots
+        _AGG_KERNEL_CACHE[key] = fast_direct
+        return fast_direct
+
+    def _build_direct_finish(self, g_bucket: int, spec_cell: dict):
+        """The traced TAIL shared by the fused single-batch kernel and the
+        carried path's once-a-query kernel: finalize every aggregate over
+        the compacted G slots and pack (count, columns cut to the
+        optimistic bound) for ONE fetch. The packed layout is recorded in
+        ``spec_cell[spec_key]`` while tracing."""
         aggs, pcounts = self.aggs, self._partial_counts
         nkeys = len(self._kernel_groupings)
         ptypes = [f.dtype for f in self._partial_schema.fields]
         OPT = self.OPTIMISTIC_GROUPS
         G = g_bucket
-        core = self._build_direct_core(G)
 
-        @functools.partial(jax.jit, static_argnums=(2,))
-        def fast_direct(cols, num_rows, padded_len, cards, scalars,
-                        code_pairs, remaps):
-            key_outs, partial_outs, num_groups = core(
-                cols, num_rows, padded_len, cards, scalars,
-                code_pairs, remaps)
+        def finish(key_outs, partial_outs, num_groups, spec_key):
             outs = list(key_outs)
             live = jnp.arange(G, dtype=jnp.int32) < num_groups
             ord_ = 0
@@ -763,46 +813,151 @@ class TpuHashAggregateExec(TpuExec):
             from ..columnar.packing import pack_traced
             flat = [num_groups] + [x for d, v in outs
                                    for x in (d[:OPT], v[:OPT])]
-            spec_cell[padded_len] = [(np.dtype(x.dtype), tuple(x.shape))
-                                     for x in flat]
+            spec_cell[spec_key] = [(np.dtype(x.dtype), tuple(x.shape))
+                                   for x in flat]
             return pack_traced(flat)
 
-        spec_cell = {}
-        fast_direct.out_specs = spec_cell
-        fast_direct.n_param_slots = core.n_param_slots
-        _AGG_KERNEL_CACHE[key] = fast_direct
-        return fast_direct
+        return finish
 
-    def _get_direct_update_kernel(self, g_bucket: int):
-        """Direct-addressing UPDATE kernel for the multi-batch first pass:
-        the dense one-hot pipeline of _get_fast_direct_kernel but emitting
-        the sort-path update contract (compacted key-code rows + update
-        partials + num_groups) so the merge/finalize phases are shared
-        with the sort path. All-dictionary keys with a small cardinality
-        product only. The point is COMPILE time as much as run time: the
-        1M-row variadic-sort update kernel is the slowest module of the
-        engine to compile, while this kernel is elementwise + one-hot
-        reductions that compile in seconds."""
-        key = ("directupd", g_bucket) + self._kernel_key
-        cached = _AGG_KERNEL_CACHE.get(key)
-        if cached is not None:
-            return cached
-        core = self._build_direct_core(g_bucket)
-        direct_update = jax.jit(core, static_argnums=(2,))
-        direct_update.n_param_slots = core.n_param_slots
-        _AGG_KERNEL_CACHE[key] = direct_update
-        return direct_update
+    def _get_carry_kernels(self, g_bucket: int):
+        """The multi-batch first pass of the direct-addressed group-by as
+        ONE running partial on the device: ``(fold, tail, flush)``.
+
+        ``fold(carry, batch operands) -> carry'`` runs the dense pipeline
+        of _build_direct_core over the batch and merges the result into
+        the carry with each aggregate's own ``merge``, slot onto slot, so
+        a batch costs one dispatch, no fetch and no host-side slicing.
+        ``carry`` = the G-sized partials and the occupancy, stacked into
+        a few arrays; the cards its slots are laid out by travel beside
+        it. The slot of a group depends on the (traced) cards,
+        which grow with the exec-local dictionaries, so the carry's slots
+        are re-derived under THIS batch's cards inside the same merge: a
+        later batch may bring a new key value, a first NULL, or cross
+        into a larger bucket (the carry then simply has fewer slots than
+        G). ``tail(carry, cards)`` is the once-a-query end: compaction +
+        finalize + pack for one fetch. ``flush(carry, cards)`` compacts it
+        into the sort path's update contract (key-code rows + partials +
+        num_groups) for a query that leaves the direct path midway."""
+        key = ("carry", self.OPTIMISTIC_GROUPS,
+               g_bucket) + self._kernel_key
+        kernels = _AGG_KERNEL_CACHE.get(key)
+        if kernels is None:
+            kernels = _AGG_KERNEL_CACHE[key] = \
+                self._build_carry_kernels(g_bucket)
+        return kernels
+
+    def _build_carry_kernels(self, g_bucket: int):
+        """(fold, tail, flush), jitted; memoized by _get_carry_kernels in
+        _AGG_KERNEL_CACHE beside the other aggregate kernels (hence the
+        adhoc-jit waivers)."""
+        from ..columnar.segmented import seg_sum
+        aggs, pcounts = self.aggs, self._partial_counts
+        nkeys = len(self._kernel_groupings)
+        pfields = self._partial_schema.fields[nkeys:]
+        ptypes = [f.dtype for f in pfields]
+        pos_partials = self._position_partials()
+        G = g_bucket
+        core = self._build_direct_core(G)
+        spec_cell = {}
+        finish = self._build_direct_finish(G, spec_cell)
+        # The carry crosses the jit boundary as FEW arrays: one [n, G]
+        # block per distinct partial dtype plus one bool block (every
+        # validity, then occupancy). Enqueueing a call costs this chip's
+        # runtime about 75 us per OUTPUT buffer (1 output 0.2 ms, 25
+        # outputs 2 ms; inputs are nearly free — PERF.md, PR 26), which at
+        # one array per partial is more than a batch's device work.
+        np_dtypes = [np.dtype(f.dtype.np_dtype) for f in pfields]
+        blocks = sorted(set(np_dtypes), key=str)
+        rows_of = {dt: [o for o, d in enumerate(np_dtypes) if d == dt]
+                   for dt in blocks}
+
+        def stack(parts, occ):
+            return (tuple(jnp.stack([parts[o][0] for o in rows_of[dt]])
+                          for dt in blocks),
+                    jnp.stack([v for _, v in parts] + [occ]))
+
+        def unstack(carry):
+            data, valid = carry
+            parts = [None] * len(np_dtypes)
+            for dt, block in zip(blocks, data):
+                for r, o in enumerate(rows_of[dt]):
+                    parts[o] = (block[r], valid[o])
+            return parts, valid[-1]
+
+        @functools.partial(  # tpulint: disable=adhoc-jit
+            jax.jit, static_argnums=(5,))
+        def fold(carry, c_cards, row_base, cols, num_rows, padded_len,
+                 cards, scalars, code_pairs, remaps):
+            c_parts, c_occ = unstack(carry)
+            dense, occ = core.dense(cols, num_rows, padded_len, cards,
+                                    scalars, code_pairs, remaps)
+            dense = list(dense)
+            for val_o, pos_o in pos_partials:
+                # First/Last: batch-local row positions become global
+                d, v = dense[pos_o - nkeys]
+                dense[pos_o - nkeys] = (
+                    jnp.where(dense[val_o - nkeys][1], d + row_base, d), v)
+            # the carry's slots under this batch's layout (the NULL code
+            # of a key is its cardinality, so it moves as cards grow)
+            slot = jnp.arange(c_occ.shape[0], dtype=jnp.int32)
+            c_strides = _direct_strides(c_cards, nkeys)
+            strides = _direct_strides(cards, nkeys)
+            moved = jnp.zeros_like(slot)
+            for i in range(nkeys):
+                code = (slot // c_strides[i]) % (c_cards[i] + 1)
+                code = jnp.where(code == c_cards[i], cards[i], code)
+                moved = moved + code * strides[i]
+            gid = jnp.concatenate([
+                jnp.where(c_occ, moved, G),
+                jnp.where(occ, jnp.arange(G, dtype=jnp.int32), G)])
+            merged = []
+            ord_ = 0
+            for a, n in zip(aggs, pcounts):
+                parts = [DVal(jnp.concatenate([c_parts[o][0], dense[o][0]]),
+                              jnp.concatenate([c_parts[o][1], dense[o][1]]),
+                              ptypes[o])
+                         for o in range(ord_, ord_ + n)]
+                merged.extend(a.merge(parts, gid, G))
+                ord_ += n
+            occ = seg_sum(jnp.ones(gid.shape, jnp.int32), gid,
+                          num_segments=G) > 0
+            return stack(merged, occ)
+
+        @functools.cache
+        def empty_carry():
+            """No group yet: what the first batch of a query folds into
+            (zeros of the partial types; built once per kernel)."""
+            return (tuple(jnp.zeros((len(rows_of[dt]), G), dt)
+                          for dt in blocks),
+                    jnp.zeros((len(np_dtypes) + 1, G), jnp.bool_))
+
+        @jax.jit  # tpulint: disable=adhoc-jit
+        def tail(carry, cards):
+            return finish(*core.compact(*unstack(carry), cards), None)
+
+        @jax.jit  # tpulint: disable=adhoc-jit
+        def flush(carry, cards):
+            return core.compact(*unstack(carry), cards)
+
+        fold.empty_carry = empty_carry
+        fold.n_param_slots = core.n_param_slots
+        tail.out_specs = spec_cell
+        return fold, tail, flush
 
     def _build_direct_core(self, g_bucket: int):
         """The direct-addressing groupby pipeline SHARED by the fused
-        single-batch kernel and the multi-batch update kernel (one
+        single-batch kernel and the carried multi-batch kernels (one
         implementation — null-key handling, stride packing, and pre-stage
-        fusion cannot diverge between the two paths). Returns a traceable
+        fusion cannot diverge between the paths). Returns a traceable
         fn (cols, num_rows, padded_len, cards, scalars, code_pairs,
         remaps) -> (key_outs, partial_outs, num_groups) with compacted
         G-sized outputs; partial validities are ANDed with occupancy but
         NOT with the live prefix (callers needing fetch-stable tails mask
-        with ``slot < num_groups`` themselves)."""
+        with ``slot < num_groups`` themselves). Its two halves are
+        attributes: ``core.dense`` (same operands -> per-slot update
+        partials + occupancy, slots addressed by gid) and
+        ``core.compact(partials, occ, cards)`` (occupied slots moved to
+        the front, key codes rebuilt from the slot numbers)."""
         aggs = self.aggs
         nkeys = len(self._kernel_groupings)
         value_exprs = [a.input_exprs() for a in aggs]
@@ -827,8 +982,8 @@ class TpuHashAggregateExec(TpuExec):
         # distinct flag — whenever a non-appended key was present)
         dict_ords = tuple(self._dict_keys)
 
-        def core(cols, num_rows, padded_len, cards, scalars,
-                 code_pairs, remaps):
+        def dense(cols, num_rows, padded_len, cards, scalars,
+                  code_pairs, remaps):
             from ..columnar.segmented import onehot_gather
             # dictionary remap FUSED into the kernel (a standalone remap
             # would be one more dispatch per key)
@@ -857,11 +1012,7 @@ class TpuHashAggregateExec(TpuExec):
                                    scalars, slots)
                 keep = ectx.row_mask()
             # gid from packed codes; null occupies the extra slot per key
-            strides = []
-            stride = jnp.int32(1)
-            for i in reversed(range(nkeys)):
-                strides.insert(0, stride)
-                stride = stride * (cards[i] + 1)
+            strides = _direct_strides(cards, nkeys)
             gid = jnp.zeros(padded_len, dtype=jnp.int32)
             for i in range(nkeys):
                 cd, cv = code_cols[i]
@@ -874,6 +1025,10 @@ class TpuHashAggregateExec(TpuExec):
             for a, vs in zip(aggs, vals):
                 partial_dense.extend(a.update(vs, gid, G, keep))
             occ = seg_sum(keep.astype(jnp.int32), gid, num_segments=G) > 0
+            return partial_dense, occ
+
+        def compact(partial_dense, occ, cards):
+            strides = _direct_strides(cards, nkeys)
             num_groups = jnp.sum(occ).astype(jnp.int32)
             pos = jnp.where(occ, prefix_sum(occ, jnp.int32) - 1, G)
             slot = jnp.arange(G, dtype=jnp.int32)
@@ -894,6 +1049,14 @@ class TpuHashAggregateExec(TpuExec):
                 partial_outs.append((cd, cv))
             return key_outs, partial_outs, num_groups
 
+        def core(cols, num_rows, padded_len, cards, scalars,
+                 code_pairs, remaps):
+            partial_dense, occ = dense(cols, num_rows, padded_len, cards,
+                                       scalars, code_pairs, remaps)
+            return compact(partial_dense, occ, cards)
+
+        core.dense = dense
+        core.compact = compact
         core.n_param_slots = len(slots)
         return core
 
@@ -1011,7 +1174,7 @@ class TpuHashAggregateExec(TpuExec):
     def _direct_operands(self, batch: ColumnarBatch):
         """(cards_dev, pairs, padded_remaps, Gb) when direct addressing
         applies to this batch, else None — the shared operand builder of
-        the fused single-batch and multi-batch update call sites."""
+        the fused single-batch and the carried multi-batch call sites."""
         if not self._direct_keys_ok():
             return None
         # current dictionary sizes are a lower bound on post-encode sizes:
@@ -1041,20 +1204,17 @@ class TpuHashAggregateExec(TpuExec):
             # fallback path (r5 rehearsal OOM). The split sort path
             # handles these shapes there.
             return None
-        padded_remaps = tuple(
-            jnp.asarray(np.pad(r, (0, max(Gb - len(r), 0)))[:Gb])
-            for r in remaps)
-        return jnp.asarray(cards), tuple(pairs), padded_remaps, Gb
-
-    def _direct_update_args(self, batch: ColumnarBatch):
-        """When the multi-batch first pass can use the direct-addressing
-        update kernel for this batch, return (kernel, args); else None."""
-        ops = self._direct_operands(batch)
-        if ops is None:
-            return None
-        cards, pairs, padded_remaps, Gb = ops
-        kern = self._get_direct_update_kernel(Gb)
-        return kern, (cards, pairs, padded_remaps)
+        # the remap tables and cards are uploaded when a value changed
+        # since the previous batch, not per batch
+        up = self._direct_uploaded
+        if up is None or up[0] != Gb or not np.array_equal(up[1], cards) \
+                or not all(np.array_equal(a, b)
+                           for a, b in zip(up[2], remaps)):
+            up = self._direct_uploaded = (
+                Gb, cards, remaps, jnp.asarray(cards), tuple(
+                    jnp.asarray(np.pad(r, (0, max(Gb - len(r), 0)))[:Gb])
+                    for r in remaps))
+        return up[3], tuple(pairs), up[4], Gb
 
     def _fast_single_batch(self, ctx, batch: ColumnarBatch,
                            update_k) -> Optional[ColumnarBatch]:
@@ -1064,14 +1224,7 @@ class TpuHashAggregateExec(TpuExec):
         dispatch or fetch adds its full latency. Returns None when the
         group count exceeds the optimistic bound (caller takes the
         classic path)."""
-        import jax
-        from ..columnar.column import arrow_from_numpy
-        from ..columnar.packing import unpack_streams
-        from ..types import STRING
-        base_cols = []
-        for c in batch.columns:
-            base_cols.append((c.data, c.validity)
-                             if isinstance(c, DeviceColumn) else None)
+        base_cols = _kernel_cols(batch)
         nkeys = len(self.groupings)
         packed = None
         if nkeys > 0:
@@ -1104,7 +1257,16 @@ class TpuHashAggregateExec(TpuExec):
                 cols, jnp.int32(batch.num_rows_raw), batch.padded_len,
                 self._upd_scalars)
             specs = self._fast_k.out_specs[batch.padded_len]
-        # the ONE round trip
+        return self._fetch_result(packed, specs)
+
+    def _fetch_result(self, packed, specs) -> Optional[ColumnarBatch]:
+        """The ONE round trip of a query whose kernel finalized and packed
+        on the device: fetch, unpack, decode dictionary keys — the final
+        HOST batch, or None when the group count passed the optimistic
+        bound (the packed columns are cut to it)."""
+        from ..columnar.column import arrow_from_numpy
+        from ..columnar.packing import unpack_streams
+        from ..types import STRING
         u32, f64 = traced_device_get(packed, "d2h.agg")
         got = unpack_streams(u32, f64, specs)
         n = int(got[0])
@@ -1126,10 +1288,41 @@ class TpuHashAggregateExec(TpuExec):
                                            f.dtype))
         return ColumnarBatch(out_cols, n, self._schema)
 
+    def _position_partials(self) -> List[Tuple[int, int]]:
+        """(value ordinal, position ordinal) in the partial schema per
+        First/Last aggregate: their within-batch row positions must become
+        GLOBAL before partials of different batches merge, or ties between
+        different batches' firsts break cross-batch arrival order (caught
+        by test_agg_multibatch_first_last_order_dependent)."""
+        from ..exprs.aggregates import First, Last
+        out = []
+        ord_ = len(self.groupings)
+        for ai, a in enumerate(self.aggs):
+            if isinstance(a, (First, Last)):
+                out.append((ord_, ord_ + 1))
+            ord_ += self._partial_counts[ai]
+        return out
+
+    def _device_count(self, num_rows):
+        """A batch's row count as the int32 device scalar the kernels
+        take; a host int is uploaded once per distinct value and query
+        (every batch of a scan but the last has the same)."""
+        if not isinstance(num_rows, int):
+            return jnp.int32(num_rows)
+        dev = self._counts_dev.get(num_rows)
+        if dev is None:
+            dev = self._counts_dev[num_rows] = jnp.int32(num_rows)
+        return dev
+
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         from ..config import AGG_OPTIMISTIC_GROUPS
         self.OPTIMISTIC_GROUPS = int(ctx.conf.get(AGG_OPTIMISTIC_GROUPS))
         self._dicts = [dict() for _ in self._dict_keys]
+        #: per dictionary key: (source dictionary, its remap) last seen
+        self._gmaps = {}
+        #: the direct path's operands as last uploaded (_direct_operands)
+        self._direct_uploaded = None
+        self._counts_dev = {}
         self._fast_k = None
         in_schema = (self.children[0].output_schema()
                      if self.pre_stages else None)
@@ -1205,6 +1398,7 @@ class TpuHashAggregateExec(TpuExec):
             out = with_retry_no_split(run_fast, ctx=ctx, op=self._exec_id)
             if out is not None:
                 disp_m.add(1)    # fused update+finalize: one module
+                _count_carry(0, 0)
                 _FAST_GROUPS[self._kernel_key] = out.num_rows
                 rows_m.add(out.num_rows)
                 yield out
@@ -1212,10 +1406,13 @@ class TpuHashAggregateExec(TpuExec):
 
         import itertools
         pending = [b for b in (first, second) if b is not None]
-        # phase 1: dispatch EVERY batch's update kernel without syncing —
-        # the kernels overlap in the device queue (a per-batch
-        # int(num_groups) costs one device round trip EACH, pure latency
-        # that grows with the batch count).
+        # phase 1, direct-addressed keys: every batch FOLDS into one
+        # running partial on the device (_get_carry_kernels) — a dispatch
+        # a batch, then one tail dispatch and one fetch a query.
+        # phase 1, everything else: dispatch EVERY batch's update kernel
+        # without syncing — the kernels overlap in the device queue (a
+        # per-batch int(num_groups) costs one device round trip EACH, pure
+        # latency that grows with the batch count).
         # Outputs are sliced immediately to a SPECULATIVE group bucket
         # (stat from previous runs of this kernel) so at most one
         # input-bucket-sized output is live at a time; the stacked count
@@ -1232,19 +1429,11 @@ class TpuHashAggregateExec(TpuExec):
         row_base = 0     # global row offset of the next batch
         # (sliced outs, num_groups dev scalar, dispatch, base, n_disp)
         window = []
-
-        #: (value ordinal, position ordinal) per First/Last aggregate:
-        #: their within-batch row positions must become GLOBAL before the
-        #: merge, or ties between different batches' firsts break
-        #: cross-batch arrival order (caught by
-        #: test_agg_multibatch_first_last_order_dependent)
-        from ..exprs.aggregates import First, Last
-        pos_partials = []
-        ord_ = len(self.groupings)
-        for ai, a in enumerate(self.aggs):
-            if isinstance(a, (First, Last)):
-                pos_partials.append((ord_, ord_ + 1))
-            ord_ += self._partial_counts[ai]
+        pos_partials = self._position_partials()
+        #: (running partial, its cards, its tail and flush kernels) while
+        #: the direct path holds; G-sized: never sliced, fetched or spilled
+        carry = None
+        carried = flushes = 0
 
         def flush_window():
             if not window:
@@ -1285,59 +1474,91 @@ class TpuHashAggregateExec(TpuExec):
                 partials.append(SpillableBatch(pb, ctx.memory))
             window.clear()
 
+        def _spec_slice(d_, v):
+            from ..exprs.base import StrVal
+            if isinstance(d_, StrVal):
+                if spec < d_.bytes_.shape[0]:
+                    return (StrVal(d_.bytes_[:spec],
+                                   d_.lengths[:spec]), v[:spec])
+                return (d_, v)
+            if spec < d_.shape[0]:
+                return (d_[:spec], v[:spec])
+            return (d_, v)
+
+        def enqueue(dispatch, base, n_disp):
+            """One update's modules into the window: ``dispatch`` returns
+            (key + partial outputs, group count on the device)."""
+            disp_m.add(n_disp)
+
+            def first_pass():
+                with ctx.semaphore.held():
+                    outs, ng = dispatch()
+                    return [_spec_slice(d_, v) for d_, v in outs], ng
+            # idempotent over the retained input -> retry-safe
+            outs, ng = with_retry_no_split(first_pass, ctx=ctx,
+                                           op=self._exec_id)
+            window.append((outs, ng, dispatch, base, n_disp))
+            if len(window) >= WINDOW:
+                flush_window()
+
+        def flush_carry():
+            """The direct path stopped applying in mid-query (the slot
+            product passed the bound, a key column left the device): the
+            carry joins the windowed path as ONE ordinary partial. Its
+            First/Last positions are global already, hence base 0."""
+            nonlocal carry, flushes
+            state, c_cards, _, flush_k = carry
+            carry = None
+            flushes += 1
+
+            def dispatch():
+                key_outs, partial_outs, ng = flush_k(state, c_cards)
+                return list(key_outs) + list(partial_outs), ng
+            enqueue(dispatch, 0, 1)
+
         try:
             for batch in itertools.chain(pending, it):
                 batch = batch.ensure_device()
                 if self._rect_mode:
                     batch = self._ensure_rect_cols(
                         batch, self._rect_key_ordinals_for(batch))
-                direct = self._direct_update_args(batch)
-                if direct is not None:
-                    kern, (cards, pairs, remaps) = direct
-                    _check_scalar_slots(kern, self._upd_scalars)
-                    n_disp = 1
-                    disp_m.add(n_disp)
+                ops = self._direct_operands(batch)
+                if ops is not None:
+                    cards, pairs, remaps, Gb = ops
+                    fold, tail, flush_k = self._get_carry_kernels(Gb)
+                    _check_scalar_slots(fold, self._upd_scalars)
+                    state, c_cards = (carry[:2] if carry is not None
+                                      else (fold.empty_carry(), cards))
+                    base = jnp.int64(row_base) if pos_partials else None
 
-                    def dispatch(b=batch, k=kern, c=cards, p=pairs, r=remaps):
-                        base_cols = [(cc.data, cc.validity)
-                                     if isinstance(cc, DeviceColumn) else None
-                                     for cc in b.columns]
-                        ko, po, ng = k(base_cols, jnp.int32(b.num_rows_raw),
-                                       b.padded_len, c, self._upd_scalars,
-                                       p, r)
-                        return list(ko) + list(po), ng
+                    def step():
+                        with ctx.semaphore.held():
+                            return fold(
+                                state, c_cards, base, _kernel_cols(batch),
+                                self._device_count(batch.num_rows_raw),
+                                batch.padded_len, cards, self._upd_scalars,
+                                pairs, remaps)
+                    # replaced only after the call returned: a retry sees
+                    # the same (carry, batch)
+                    carry = (with_retry_no_split(step, ctx=ctx,
+                                                 op=self._exec_id),
+                             cards, tail, flush_k)
+                    disp_m.add(1)
+                    carried += 1
                 else:
+                    if carry is not None:
+                        flush_carry()
                     codes = [] if self._rect_mode else self._augment(batch)
-                    n_disp = getattr(update_k_split, "n_dispatches", 1)
-                    disp_m.add(n_disp)
 
                     def dispatch(b=batch, extra=codes):
                         return self._run_kernel_raw(
                             update_k_split, b, extra_cols=extra,
                             scalars=self._upd_scalars)
-
-                def _spec_slice(d_, v):
-                    from ..exprs.base import StrVal
-                    if isinstance(d_, StrVal):
-                        if spec < d_.bytes_.shape[0]:
-                            return (StrVal(d_.bytes_[:spec],
-                                           d_.lengths[:spec]), v[:spec])
-                        return (d_, v)
-                    if spec < d_.shape[0]:
-                        return (d_[:spec], v[:spec])
-                    return (d_, v)
-
-                def first_pass(d=dispatch):
-                    with ctx.semaphore.held():
-                        outs, ng = d()
-                        return [_spec_slice(d_, v) for d_, v in outs], ng
-                # idempotent over the input batch -> retry-safe
-                outs, ng = with_retry_no_split(first_pass, ctx=ctx,
-                                               op=self._exec_id)
-                window.append((outs, ng, dispatch, row_base, n_disp))
+                    enqueue(dispatch, row_base,
+                            getattr(update_k_split, "n_dispatches", 1))
                 row_base += batch.padded_len
-                if len(window) >= WINDOW:
-                    flush_window()
+            if carry is not None and (partials or window):
+                flush_carry()
             flush_window()
         except BaseException:
             # fatal error (or cooperative QueryTimeout) mid-update:
@@ -1346,6 +1567,23 @@ class TpuHashAggregateExec(TpuExec):
             for sb in partials:
                 sb.close()
             raise
+        _count_carry(carried, flushes)
+
+        if carry is not None:
+            # every batch went into the one carry: one tail dispatch
+            # (compaction + finalize + pack) and the query's ONE fetch
+            state, c_cards, tail, _ = carry
+
+            def run_tail():
+                with ctx.semaphore.held():
+                    return self._fetch_result(tail(state, c_cards),
+                                              tail.out_specs[None])
+            out = with_retry_no_split(run_tail, ctx=ctx, op=self._exec_id)
+            disp_m.add(1)
+            _FAST_GROUPS[self._kernel_key] = out.num_rows
+            rows_m.add(out.num_rows)
+            yield out
+            return
 
         total = sum(sb.device_bytes() for sb in partials)
         if (self.groupings and partials

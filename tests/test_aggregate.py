@@ -192,12 +192,13 @@ def test_nan_is_a_value_not_null():
 
 
 # ---------------------------------------------------------------------------
-# Multi-batch first pass: direct-addressing update kernel + one stacked
-# count fetch (r4: per-batch int(num_groups) cost a device round trip each)
+# Multi-batch first pass: the direct-addressed carry, or the sort kernels
+# + one stacked count fetch a window (r4: per-batch int(num_groups) cost a
+# device round trip each)
 # ---------------------------------------------------------------------------
 
 def test_agg_multibatch_string_keys_direct():
-    """All-dict keys, small cardinality product -> direct update kernel."""
+    """All-dict keys, small cardinality product -> the carried direct path."""
     from data_gen import StringGen
 
     def q(s):
@@ -226,6 +227,137 @@ def test_agg_multibatch_two_string_keys_with_nulls():
             F.sum(F.col("v")).with_name("s"),
             F.count(F.col("v")).with_name("c"))
     assert_tpu_and_cpu_equal(q)
+
+
+# ---------------------------------------------------------------------------
+# The carried first pass (ISSUE 26): on the direct-addressed path every
+# batch folds into ONE running partial on the device — one dispatch a
+# batch, one tail dispatch and one fetch a query
+# ---------------------------------------------------------------------------
+
+_CARRY_CONF = {"spark.rapids.tpu.sql.optimizer.enabled": False,
+               "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+               "spark.rapids.tpu.distributed.enabled": False}
+
+
+def _letters(rng, n, alphabet, null_share=0.0):
+    import numpy as np
+    import pyarrow as pa
+    vals = np.asarray(alphabet, dtype=object)[rng.integers(0, len(alphabet),
+                                                           n)]
+    mask = rng.random(n) < null_share
+    return pa.array(vals, type=pa.string(), mask=mask)
+
+
+def _carry_case(name):
+    """(partitions, aggregates, conf, (batches, flushes) expected of the
+    ``agg.carry`` counter) of one case of test_agg_carried_first_pass."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(26)
+    sums = [F.sum(F.col("v")).with_name("s"),
+            F.count(F.col("v")).with_name("c"),
+            F.count_star().with_name("n"),
+            F.avg(F.col("v")).with_name("a"),
+            F.min(F.col("i")).with_name("mn"),
+            F.max(F.col("i")).with_name("mx")]
+
+    def part(n, keys, j=("x", "y"), null_keys=0.0, null_vals=0.0):
+        return pa.table({
+            "k": _letters(rng, n, keys, null_keys),
+            "j": _letters(rng, n, j, null_keys),
+            "v": pa.array(rng.normal(size=n), mask=rng.random(n) < null_vals),
+            "i": pa.array(rng.integers(-1000, 1000, n),
+                          mask=rng.random(n) < null_vals)})
+
+    conf = dict(_CARRY_CONF)
+    if name == "null_keys_null_values":
+        # one key is all NULL in the first batch: its NULL slot moves
+        # when the first value arrives
+        parts = [part(700, ["a", "b"], null_keys=0.2, null_vals=0.3)
+                 for _ in range(4)]
+        parts[0] = parts[0].set_column(
+            1, "j", pa.nulls(700, type=pa.string()))
+        return parts, sums, conf, (4, 0)
+    if name == "new_key_in_last_batch":
+        parts = [part(500, ["a", "b"], null_keys=0.1) for _ in range(3)]
+        parts.append(part(500, ["a", "b", "zz"], j=("x", "y", "w")))
+        return parts, sums, conf, (4, 0)
+    if name == "dictionary_crosses_bucket":
+        # 3 x 3 slots fit the 16 bucket; 27 letters take the 256 bucket
+        wide = [chr(ord("a") + c) for c in range(26)] + ["zz"]
+        parts = [part(600, ["a", "b"]), part(600, ["a", "b"], null_keys=0.1),
+                 part(600, wide, null_keys=0.1), part(600, wide)]
+        return parts, sums, conf, (4, 0)
+    if name == "empty_batch_in_middle":
+        parts = [part(400, ["a", "b", "c"], null_vals=0.2) for _ in range(4)]
+        parts.insert(2, parts[0].slice(0, 0))
+        return parts, sums, conf, (5, 0)
+    if name == "first_last_mix":
+        # First/Last carry their GLOBAL row positions
+        parts = [part(500, ["a", "b", "c"], null_vals=0.2) for _ in range(5)]
+        aggs = sums[:3] + [F.first(F.col("i")).with_name("f"),
+                           F.last(F.col("i")).with_name("l")]
+        return parts, aggs, conf, (5, 0)
+    if name == "decimal_limb_sums":
+        # a decimal SUM's three limbs merge (and re-normalise) in the carry
+        import decimal
+        parts = []
+        for _ in range(4):
+            t = part(500, ["a", "b", "c"], null_keys=0.1)
+            cents = rng.integers(-10**12, 10**12, 500)
+            parts.append(t.append_column("d", pa.array(
+                [decimal.Decimal(int(c)) / 100 for c in cents],
+                type=pa.decimal128(15, 2), mask=rng.random(500) < 0.1)))
+        aggs = sums[:3] + [F.sum(F.col("d")).with_name("ds"),
+                           F.min(F.col("d")).with_name("dm")]
+        return parts, aggs, conf, (4, 0)
+    if name == "leaves_direct_path_midway":
+        # the slot product passes optimisticGroups in the third batch:
+        # the carry is flushed as one partial, the sort kernels go on
+        wide = [chr(ord("a") + c) for c in range(26)]
+        parts = [part(500, ["a", "b"]), part(500, ["a", "b"]),
+                 part(500, wide, null_keys=0.1), part(500, wide)]
+        conf["spark.rapids.tpu.sql.agg.optimisticGroups"] = 16
+        return parts, sums[:3] + [F.first(F.col("i")).with_name("f")], \
+            conf, (2, 1)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "null_keys_null_values", "new_key_in_last_batch",
+    "dictionary_crosses_bucket", "empty_batch_in_middle", "first_last_mix",
+    "decimal_limb_sums", "leaves_direct_path_midway"])
+def test_agg_carried_first_pass(name):
+    """The carried path equals the CPU engine AND the same rows as ONE
+    batch, and says through ``agg.carry`` how many batches it folded."""
+    import pyarrow as pa
+    from harness import (_assert_frames_equal, _canon, cpu_session,
+                         tpu_session)
+    from spark_rapids_tpu.api.dataframe import DataFrame
+    from spark_rapids_tpu.plan import logical as L
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+    from spark_rapids_tpu.types import Schema, from_arrow
+    parts, aggs, conf, expected = _carry_case(name)
+    schema = Schema.of(**{f.name: from_arrow(f.type)
+                          for f in parts[0].schema})
+
+    def q(s, tables):
+        return DataFrame(s, L.LogicalScan(tables, schema)) \
+            .group_by("k", "j").agg(*aggs)
+
+    tr = install_tracer(Tracer())
+    try:
+        carried = q(tpu_session(conf), parts).to_pandas()
+    finally:
+        install_tracer(None)
+    counts = [e["args"] for e in tr.snapshot()
+              if e["ph"] == "C" and e["name"] == "agg.carry"]
+    assert counts == [{"batches": expected[0], "flushes": expected[1]}]
+    one = q(tpu_session(conf), [pa.concat_tables(parts)]).to_pandas()
+    cpu = q(cpu_session(conf), parts).to_pandas()
+    _assert_frames_equal(_canon(carried, True), _canon(cpu, True), True)
+    _assert_frames_equal(_canon(carried, True), _canon(one, True), True)
 
 
 def test_agg_multibatch_speculation_overflow_redo():

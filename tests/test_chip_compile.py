@@ -161,29 +161,53 @@ def test_fused_groupby_kernel_q6(session, one_chip):
                           _update_scalars(agg, one_chip)), FAST_LIMIT_S)
 
 
-def test_direct_onehot_update_kernel_q1(session, one_chip):
-    """exec/aggregate.py:_build_direct_core — q1's per-batch update: two
-    dictionary-coded string keys grouped by direct one-hot addressing
-    (G=16: (3+1)x(2+1) key slots), eight aggregates, fused filter."""
+def _q1_carry(session, one_chip):
+    """Q1's aggregate, its carried kernels at G=16 ((3+1)x(2+1) key slots)
+    and the carry's shapes on the described chip."""
     import jax
     from benchmarks import queries_sql as Q
     from spark_rapids_tpu.columnar.segmented import bucket_segments
     agg = _agg_of(session, Q.TPCH_Q1)
     assert agg._dict_keys == [0, 1] and agg._direct_keys_ok()
+    g = bucket_segments((3 + 1) * (2 + 1))
+    fold, tail, _flush = agg._build_carry_kernels(g)
+    carry = jax.tree_util.tree_map(lambda a: _abstract(a, one_chip),
+                                   fold.empty_carry())
+    return agg, g, fold, tail, carry
+
+
+def test_direct_onehot_update_kernel_q1(session, one_chip):
+    """exec/aggregate.py:_build_carry_kernels, ``fold`` — q1's one
+    dispatch a batch: two dictionary-coded string keys grouped by direct
+    one-hot addressing, eight aggregates, fused filter, and the batch's
+    partials merged into the running ones slot onto slot."""
+    import jax
+    agg, g, fold, _tail, carry = _q1_carry(session, one_chip)
     in_schema = agg.children[0].output_schema()
     names = in_schema.names()
     coded = {names.index("l_returnflag"), names.index("l_linestatus")}
-    g = bucket_segments((3 + 1) * (2 + 1))
-    kernel = jax.jit(agg._build_direct_core(g), static_argnums=(2,))
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pairs = tuple((sds((BATCH,), np.int32), sds((BATCH,), np.bool_))
                   for _ in coded)
     remaps = tuple(sds((g,), np.int32) for _ in coded)
-    _compile(kernel.lower(_cols(in_schema, BATCH, one_chip, coded),
-                          sds((), np.int32), BATCH, sds((2,), np.int32),
-                          _update_scalars(agg, one_chip), pairs, remaps),
+    cards = sds((2,), np.int32)
+    _compile(fold.lower(carry, cards, None,
+                        _cols(in_schema, BATCH, one_chip, coded),
+                        sds((), np.int32), BATCH, cards,
+                        _update_scalars(agg, one_chip), pairs, remaps),
+             FAST_LIMIT_S)
+
+
+def test_direct_carry_tail_kernel_q1(session, one_chip):
+    """exec/aggregate.py:_build_carry_kernels, ``tail`` — q1's one tail
+    dispatch a query: occupancy compaction, finalize, pack for the one
+    fetch."""
+    import jax
+    _agg, _g, _fold, tail, carry = _q1_carry(session, one_chip)
+    _compile(tail.lower(carry, jax.ShapeDtypeStruct((2,), np.int32,
+                                                    sharding=one_chip)),
              FAST_LIMIT_S)
 
 

@@ -161,24 +161,24 @@ def test_union_agg_int_key_direct_addressing():
 
     def drop_direct():
         for k in [k for k in AG._AGG_KERNEL_CACHE
-                  if k[0] in ("fastdirect", "directupd")]:
+                  if k[0] in ("fastdirect", "carry")]:
             AG._AGG_KERNEL_CACHE.pop(k)
 
     def direct_kinds():
         return {k[0] for k in AG._AGG_KERNEL_CACHE
-                if k[0] in ("fastdirect", "directupd")}
+                if k[0] in ("fastdirect", "carry")}
 
     drop_direct()
     assert_tpu_and_cpu_equal(q, conf=_CONF, approximate_float=True,
                              ignore_order=False)
     assert "fastdirect" in direct_kinds(), \
         "single-batch int-key query missed the fused direct path"
-    # multi-batch: direct UPDATE partials (codes) merge across batches
+    # multi-batch: every batch folds into the direct path's carry
     drop_direct()
     assert_tpu_and_cpu_equal(q_parts, conf=_CONF,
                              approximate_float=True, ignore_order=False)
-    assert "directupd" in direct_kinds(), \
-        "multi-batch int-key query missed the direct update path"
+    assert "carry" in direct_kinds(), \
+        "multi-batch int-key query missed the carried direct path"
 
 
 def test_cpu_twin_nan_cross_batch_and_big_ints():

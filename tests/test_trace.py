@@ -310,6 +310,60 @@ def test_every_fetch_happens_inside_a_d2h_span(monkeypatch):
                 (name, span)
 
 
+def _agg_trace(table, key):
+    """One traced run, in a session of its own, of a two-aggregate
+    group-by of ``table`` (6 batches) by ``key``: (spans, counters, the
+    aggregate's operator metrics)."""
+    s = tpu_session(_OPERATOR_CONF)
+    df = s.create_dataframe(table, num_partitions=6).group_by(key).agg(
+        F.sum(F.col("v")).with_name("sv"), F.avg(F.col("v")).with_name("av"))
+    tr = install_tracer(Tracer())
+    try:
+        assert df.collect_arrow().num_rows > 0
+    finally:
+        install_tracer(None)
+    counters = [e for e in tr.snapshot() if e["ph"] == "C"]
+    (agg_m,) = [m for eid, m in
+                dict(s.last_query_metrics["operators"]).items()
+                if eid.startswith("TpuHashAggregateExec@")]
+    return _xs(tr), counters, agg_m
+
+
+def test_direct_aggregate_is_one_fetch_a_query():
+    """A 6-batch aggregate over direct-addressable keys folds every batch
+    into one carry on the device: ONE blocking fetch inside the
+    aggregate's span and no group-count fetch, 6 + 1 dispatches, and the
+    ``agg.carry`` counter says so. A sort-path aggregate (an int key of
+    unproven cardinality) reads batches 0."""
+    n = 6000
+    t = pa.table({"k": pa.array(np.array(["a", "b", "c"], dtype=object)
+                                [np.arange(n) % 3]),
+                  "m": pa.array(np.arange(n) % 7),
+                  "v": pa.array(np.arange(n, dtype=np.float64))})
+    spans, counters, agg_m = _agg_trace(t, "k")
+    by_id = {e["id"]: e for e in spans}
+
+    def inside_aggregate(e):
+        while e["parent"]:
+            e = by_id[e["parent"]]
+            if e["name"] == "TpuHashAggregateExec":
+                return True
+        return False
+
+    fetches = [e["name"] for e in spans if e["name"].startswith("d2h")
+               and e["name"].endswith(".transfer") and inside_aggregate(e)]
+    assert fetches == ["d2h.agg.transfer"]
+    assert not [e for e in spans if e["name"].startswith("d2h.groups")]
+    assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
+        [{"batches": 6, "flushes": 0}]
+    assert agg_m["updateDispatches"] == 7, agg_m
+
+    _, counters, agg_m = _agg_trace(t, "m")
+    assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
+        [{"batches": 0, "flushes": 0}]
+    assert agg_m["updateDispatches"] > 7, agg_m
+
+
 def test_spans_of_a_query_share_its_ordinal():
     """Every span of a query reaches that query's ``query`` span by
     ``parent`` and carries its ``q``; the next query gets another."""
