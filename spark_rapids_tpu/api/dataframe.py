@@ -936,6 +936,18 @@ class DataFrame:
         ctx.take_ladder_rung()               # per-query reset
         degs: List[dict] = []
 
+        from ..exprs import decimal_rules
+
+        def _consume_checked(p):
+            """The sink, then the decimal overflow counts its kernels
+            left for it (exprs/decimal_rules.py): nothing to fetch where
+            no projection or filter checked a decimal; a row that left
+            the 64-bit lane is the loud error, never a wrapped number."""
+            decimal_rules.clear_pending()
+            out = consume(p, ctx)
+            decimal_rules.settle_pending()
+            return out
+
         def _attempt(p):
             """One full run of the plan through the execution pipeline,
             with the speculative-sizing overflow retry inside (plans
@@ -943,7 +955,7 @@ class DataFrame:
             retry can never duplicate output files)."""
             try:
                 out = DeviceDumpHandler(self.session.conf).wrap(
-                    lambda: consume(p, ctx), p)
+                    lambda: _consume_checked(p), p)
                 ctx.check_speculations()
                 return out
             except SpeculativeOverflow:
@@ -951,7 +963,7 @@ class DataFrame:
                 ctx.speculations.clear()
                 ctx.metrics.clear()
                 return DeviceDumpHandler(self.session.conf).wrap(
-                    lambda: consume(p, ctx), p)
+                    lambda: _consume_checked(p), p)
 
         def _note_timeout():
             from ..metrics import registry as _mr

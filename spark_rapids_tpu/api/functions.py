@@ -111,7 +111,12 @@ _DTYPES = {"boolean": BOOL, "bool": BOOL, "tinyint": INT8, "byte": INT8,
 def _dtype_of(d) -> DataType:
     if isinstance(d, DataType):
         return d
-    return _DTYPES[str(d).lower()]
+    name = str(d).lower().replace(" ", "")
+    if name.startswith("decimal(") and name.endswith(")"):
+        from ..types import DecimalType
+        p, _, s = name[len("decimal("):-1].partition(",")
+        return DecimalType(int(p), int(s or 0))
+    return _DTYPES[name]
 
 
 def col(name: str) -> Col:

@@ -55,7 +55,8 @@ def _decimal_unscaled_int64(arr, valid: np.ndarray) -> np.ndarray:
     hi = words[2 * off + 1::2][:len(arr)]
     ok = (hi == np.where(lo < 0, -1, 0))
     if not ok[valid].all():
-        raise ValueError(
+        from ..exprs.decimal_rules import DecimalOverflow
+        raise DecimalOverflow(
             "decimal value exceeds the device's 64-bit unscaled range "
             "(|unscaled| >= 2^63); this magnitude needs host execution")
     return np.where(valid, lo, 0)

@@ -139,11 +139,17 @@ def arrow_from_numpy(d: np.ndarray, v: np.ndarray, dtype: DataType):
     if dtype == DATE:
         return pa.Array.from_pandas(d, mask=~v).cast(pa.int32()).cast(at)
     if isinstance(dtype, DecimalType):
-        import decimal as _dec
-        scale = dtype.scale
-        py = [None if not ok else _dec.Decimal(int(x)).scaleb(-scale)
-              for x, ok in zip(d.tolist(), v.tolist())]
-        return pa.array(py, type=at)
+        # int64 lanes -> decimal128 of the declared type: host work the
+        # float path does not have, so it is a span of its own
+        # (d2h.decimal.finish: billed as fetch time; docs/profiling.md)
+        from ..exprs.decimal_rules import unscaled_to_arrow
+        from ..trace import core as trace_core
+        tr = trace_core.TRACER
+        if tr is None:
+            return unscaled_to_arrow(d, v, dtype)
+        with tr.span("d2h.decimal.finish", cat="transfer",
+                     args={"rows": int(len(d))}):
+            return unscaled_to_arrow(d, v, dtype)
     return pa.Array.from_pandas(d, mask=~v, type=at)
 
 

@@ -37,6 +37,7 @@ from ..mem import SpillableBatch, with_retry_no_split
 from ..trace import core as trace_core
 from ..types import STRING, Schema, StructField
 from .base import ESSENTIAL, ExecContext, TpuExec
+from ..exprs import decimal_rules as D
 from .groupby_core import segmented_groupby
 
 __all__ = ["TpuHashAggregateExec", "CpuAggregateExec"]
@@ -115,18 +116,26 @@ def _build_groupby_kernel(key_exprs: Sequence[Expression],
                      for c, dt in zip(cols, dtypes)]
             ctx = EvalContext(schema, dvals, num_rows, padded_len,
                               scalars, slots)
-        keys = [e.eval_device(ctx) for e in key_exprs]
-        vals = [[e.eval_device(ctx) for e in exprs] for exprs in value_exprs]
+        with D.masked(jnp, lambda: ctx.row_mask() if keep is None else keep):
+            keys = [e.eval_device(ctx) for e in key_exprs]
+            vals = [[e.eval_device(ctx) for e in exprs]
+                    for exprs in value_exprs]
         return keys, vals, keep
 
-    @functools.partial(jax.jit, static_argnums=(2,))
-    def kernel(cols, num_rows, padded_len, scalars=()):
+    def raw(cols, num_rows, padded_len, scalars=()):
         keys, vals, keep = prep(cols, num_rows, padded_len, scalars)
         return segmented_groupby(keys, vals, aggs, mode, num_rows,
                                  padded_len, row_mask=keep)
 
+    @functools.partial(jax.jit, static_argnums=(2,))
+    def kernel(cols, num_rows, padded_len, scalars=()):
+        return raw(cols, num_rows, padded_len, scalars)
+
     kernel.n_param_slots = len(slots)
     kernel._prep = prep
+    #: the untraced body: what a kernel that collects decimal overflow
+    #: flags traces in its OWN scope (a nested jit would keep them)
+    kernel._raw = raw
     kernel._value_exprs = value_exprs
     kernel.n_dispatches = 1      # one fused module per batch
     return kernel
@@ -291,17 +300,21 @@ def _apply_pre_stages(stages, in_schema, base_dvals, num_rows, padded_len,
                       scalars, slots)
     keep = ctx.row_mask()
     for st in stages:
-        if st[0] == "filter":
-            pv = st[1].eval_device(ctx)
-            keep = jnp.logical_and(keep,
-                                   jnp.logical_and(pv.data, pv.validity))
-        else:
-            _, exprs, out_schema = st
-            dv = [e.eval_device(ctx)
-                  if e.fully_device_supported(ctx.schema) is None
-                  else None for e in exprs]
-            ctx = EvalContext(out_schema, dv, num_rows, padded_len,
-                              ctx.scalars, ctx.literal_slots)
+        # a checked decimal operation's overflow counts for the rows that
+        # reached the stage (exprs/decimal_rules.py; traces nothing where
+        # no kernel collects)
+        with D.masked(jnp, lambda k=keep: k):
+            if st[0] == "filter":
+                pv = st[1].eval_device(ctx)
+                keep = jnp.logical_and(
+                    keep, jnp.logical_and(pv.data, pv.validity))
+            else:
+                _, exprs, out_schema = st
+                dv = [e.eval_device(ctx)
+                      if e.fully_device_supported(ctx.schema) is None
+                      else None for e in exprs]
+                ctx = EvalContext(out_schema, dv, num_rows, padded_len,
+                                  ctx.scalars, ctx.literal_slots)
     return ctx, keep
 
 
@@ -509,6 +522,31 @@ class TpuHashAggregateExec(TpuExec):
             [StructField(f"_k{i}", e.data_type(cs), True)
              for i, e in enumerate(self.groupings)] + afields)
         self._rect_mode = False
+        #: checked decimal operations (exprs/decimal_rules.py) traced by
+        #: the update kernels (pre-stages, keys, aggregate inputs) and by
+        #: every aggregate's finalize: where there are any, the kernels
+        #: carry a count of overflowed rows to the operator's own fetch
+        self._input_checks = self._count_input_checks()
+        self._decimal_checks = self._input_checks + sum(
+            a.decimal_checks(cs) for a in self.aggs)
+
+    def _count_input_checks(self) -> int:
+        n = D.checked_ops_of_stages(
+            self.pre_stages, self.children[0].output_schema())[0] \
+            if self.pre_stages else 0
+        n += sum(D.checked_ops(g, self._kernel_schema)
+                 for g in self._kernel_groupings)
+        for a in self.aggs:
+            n += sum(D.checked_ops(e, self._kernel_schema)
+                     for e in a.input_exprs())
+        return n
+
+    def _settle_overflow(self, rows: int) -> None:
+        """The end of this execution's decimal checks: the counter, and
+        the loud error where a row left the lane."""
+        D.count_checked(self._decimal_checks, rows)
+        if rows:
+            raise D.overflow_error(rows, self.describe())
 
     def output_schema(self) -> Schema:
         return self._schema
@@ -521,6 +559,14 @@ class TpuHashAggregateExec(TpuExec):
         can overlap every batch's kernel and resolve all counts in ONE
         stacked fetch (a per-batch ``int(num_groups)`` costs a full device
         round trip each and serializes the pipeline)."""
+        cols = self._raw_cols(batch, extra_cols)
+        _check_scalar_slots(kernel, scalars)
+        key_outs, partial_outs, num_groups = kernel(
+            cols, jnp.int32(batch.num_rows_raw), batch.padded_len, scalars)
+        return list(key_outs) + list(partial_outs), num_groups
+
+    @staticmethod
+    def _raw_cols(batch: ColumnarBatch, extra_cols=()) -> list:
         from ..columnar.strrect import ByteRectColumn
         cols = []
         for c in batch.columns:
@@ -532,10 +578,35 @@ class TpuHashAggregateExec(TpuExec):
                 cols.append(None)
         for c in extra_cols:
             cols.append((c.data, c.validity))
-        _check_scalar_slots(kernel, scalars)
-        key_outs, partial_outs, num_groups = kernel(
-            cols, jnp.int32(batch.num_rows_raw), batch.padded_len, scalars)
-        return list(key_outs) + list(partial_outs), num_groups
+        return cols
+
+    def _probe_overflow(self, batch: ColumnarBatch, extra_cols) -> None:
+        """The sort path's update kernels have no channel for decimal
+        overflow flags (their lanes are NULL all the same): where the
+        aggregate's inputs hold checked operations, one more small
+        dispatch a batch traces the same prologue and counts the flagged
+        rows, for the sink to fetch."""
+        key = ("ovfprobe",) + self._kernel_key
+        probe = _AGG_KERNEL_CACHE.get(key)
+        if probe is None:
+            prep = _get_kernel(
+                self._kernel_groupings, self.aggs, self._kernel_schema,
+                "update",
+                in_schema=(self.children[0].output_schema()
+                           if self.pre_stages else None),
+                stages=self.pre_stages or None,
+                n_codes=len(self._dict_keys))._prep
+
+            @functools.partial(  # tpulint: disable=adhoc-jit
+                jax.jit, static_argnums=(2,))
+            def probe(cols, num_rows, padded_len, scalars=()):
+                with D.collecting() as col:
+                    prep(cols, num_rows, padded_len, scalars)
+                return col.rows(jnp)
+            _AGG_KERNEL_CACHE[key] = probe
+        D.defer(probe(self._raw_cols(batch, extra_cols),
+                      jnp.int32(batch.num_rows_raw), batch.padded_len,
+                      self._upd_scalars), self._input_checks)
 
     def _slice_to_count(self, outs, n, out_schema: Schema) -> ColumnarBatch:
         """Re-bucket raw kernel outputs once the group count is known:
@@ -728,22 +799,29 @@ class TpuHashAggregateExec(TpuExec):
         ptypes = [f.dtype for f in self._partial_schema.fields]
         OPT = self.OPTIMISTIC_GROUPS
 
+        checked = self._decimal_checks > 0
+
         @functools.partial(jax.jit, static_argnums=(2,))
         def fast(cols, num_rows, padded_len, scalars=()):
-            key_outs, partial_outs, num_groups = update_k(
-                cols, num_rows, padded_len, scalars)
-            outs = list(key_outs)
-            ord_ = 0
-            for ai, a in enumerate(aggs):
-                parts = [DVal(partial_outs[o][0], partial_outs[o][1],
-                              ptypes[nkeys + o])
-                         for o in range(ord_, ord_ + pcounts[ai])]
-                ord_ += pcounts[ai]
-                fin = a.finalize(parts)
-                outs.append((fin.data, fin.validity))
+            with D.collecting(checked) as col:
+                # collecting: the update kernel's body is traced in THIS
+                # scope, so that its overflow flags can leave with the
+                # packed result
+                key_outs, partial_outs, num_groups = (
+                    update_k._raw if checked else update_k)(
+                        cols, num_rows, padded_len, scalars)
+                outs = list(key_outs)
+                ord_ = 0
+                for ai, a in enumerate(aggs):
+                    parts = [DVal(partial_outs[o][0], partial_outs[o][1],
+                                  ptypes[nkeys + o])
+                             for o in range(ord_, ord_ + pcounts[ai])]
+                    ord_ += pcounts[ai]
+                    fin = a.finalize(parts)
+                    outs.append((fin.data, fin.validity))
             from ..columnar.packing import pack_traced
-            flat = [num_groups] + [x for d, v in outs
-                                   for x in (d[:OPT], v[:OPT])]
+            flat = [num_groups] + ([col.rows(jnp)] if checked else []) \
+                + [x for d, v in outs for x in (d[:OPT], v[:OPT])]
             spec_cell[padded_len] = [(np.dtype(x.dtype), tuple(x.shape))
                                      for x in flat]
             return pack_traced(flat)
@@ -777,8 +855,11 @@ class TpuHashAggregateExec(TpuExec):
         @functools.partial(jax.jit, static_argnums=(2,))
         def fast_direct(cols, num_rows, padded_len, cards, scalars,
                         code_pairs, remaps):
-            return finish(*core(cols, num_rows, padded_len, cards, scalars,
-                                code_pairs, remaps), padded_len)
+            key_outs, partial_outs, num_groups, overflow = core(
+                cols, num_rows, padded_len, cards, scalars, code_pairs,
+                remaps)
+            return finish(key_outs, partial_outs, num_groups, padded_len,
+                          overflow)
 
         fast_direct.out_specs = spec_cell
         fast_direct.n_param_slots = core.n_param_slots
@@ -796,23 +877,32 @@ class TpuHashAggregateExec(TpuExec):
         ptypes = [f.dtype for f in self._partial_schema.fields]
         OPT = self.OPTIMISTIC_GROUPS
         G = g_bucket
+        checked = self._decimal_checks > 0
 
-        def finish(key_outs, partial_outs, num_groups, spec_key):
+        def finish(key_outs, partial_outs, num_groups, spec_key,
+                   overflow=None):
+            """``overflow``: the rows the update kernels flagged (a
+            traced int scalar), where the aggregate checks decimals; the
+            finalizers' own flags are added and the sum is packed after
+            the group count."""
             outs = list(key_outs)
             live = jnp.arange(G, dtype=jnp.int32) < num_groups
             ord_ = 0
-            for ai, a in enumerate(aggs):
-                parts = []
-                for o in range(ord_, ord_ + pcounts[ai]):
-                    cd, cv = partial_outs[o]
-                    parts.append(DVal(cd, jnp.logical_and(cv, live),
-                                      ptypes[nkeys + o]))
-                ord_ += pcounts[ai]
-                fin = a.finalize(parts)
-                outs.append((fin.data, fin.validity))
+            with D.collecting(checked) as col:
+                for ai, a in enumerate(aggs):
+                    parts = []
+                    for o in range(ord_, ord_ + pcounts[ai]):
+                        cd, cv = partial_outs[o]
+                        parts.append(DVal(cd, jnp.logical_and(cv, live),
+                                          ptypes[nkeys + o]))
+                    ord_ += pcounts[ai]
+                    fin = a.finalize(parts)
+                    outs.append((fin.data, fin.validity))
             from ..columnar.packing import pack_traced
-            flat = [num_groups] + [x for d, v in outs
-                                   for x in (d[:OPT], v[:OPT])]
+            flat = [num_groups] + (
+                [overflow.astype(jnp.int32) + col.rows(jnp)]
+                if checked else []) \
+                + [x for d, v in outs for x in (d[:OPT], v[:OPT])]
             spec_cell[spec_key] = [(np.dtype(x.dtype), tuple(x.shape))
                                    for x in flat]
             return pack_traced(flat)
@@ -867,6 +957,12 @@ class TpuHashAggregateExec(TpuExec):
         # outputs 2 ms; inputs are nearly free — PERF.md, PR 26), which at
         # one array per partial is more than a batch's device work.
         np_dtypes = [np.dtype(f.dtype.np_dtype) for f in pfields]
+        checked = self._decimal_checks > 0
+        if checked:
+            # the rows the update kernels flagged so far (decimal
+            # overflow) ride as one more int64 row of the carry, the
+            # count in its slot 0: no array and no fetch of its own
+            np_dtypes.append(np.dtype(np.int64))
         blocks = sorted(set(np_dtypes), key=str)
         rows_of = {dt: [o for o, d in enumerate(np_dtypes) if d == dt]
                    for dt in blocks}
@@ -921,6 +1017,9 @@ class TpuHashAggregateExec(TpuExec):
                 ord_ += n
             occ = seg_sum(jnp.ones(gid.shape, jnp.int32), gid,
                           num_segments=G) > 0
+            if checked:
+                merged.append((c_parts[-1][0] + dense[-1][0],
+                               c_parts[-1][1]))
             return stack(merged, occ)
 
         @functools.cache
@@ -931,13 +1030,20 @@ class TpuHashAggregateExec(TpuExec):
                           for dt in blocks),
                     jnp.zeros((len(np_dtypes) + 1, G), jnp.bool_))
 
+        def unstack_checked(carry):
+            """(partials, occupancy, flagged rows or None)."""
+            parts, occ = unstack(carry)
+            return parts, occ, (parts.pop()[0][0] if checked else None)
+
         @jax.jit  # tpulint: disable=adhoc-jit
         def tail(carry, cards):
-            return finish(*core.compact(*unstack(carry), cards), None)
+            parts, occ, overflow = unstack_checked(carry)
+            return finish(*core.compact(parts, occ, cards), None, overflow)
 
         @jax.jit  # tpulint: disable=adhoc-jit
         def flush(carry, cards):
-            return core.compact(*unstack(carry), cards)
+            parts, occ, overflow = unstack_checked(carry)
+            return core.compact(parts, occ, cards) + (overflow,)
 
         fold.empty_carry = empty_carry
         fold.n_param_slots = core.n_param_slots
@@ -982,8 +1088,26 @@ class TpuHashAggregateExec(TpuExec):
         # distinct flag — whenever a non-appended key was present)
         dict_ords = tuple(self._dict_keys)
 
+        checked = self._decimal_checks > 0
+
         def dense(cols, num_rows, padded_len, cards, scalars,
                   code_pairs, remaps):
+            if not checked:
+                return dense_body(cols, num_rows, padded_len, cards,
+                                  scalars, code_pairs, remaps)
+            # decimal overflow: the flagged rows of this batch as one
+            # more per-slot "partial" (the count in slot 0), which the
+            # carry adds up and the finish packs into the one fetch
+            with D.collecting() as col:
+                partial_dense, occ = dense_body(
+                    cols, num_rows, padded_len, cards, scalars,
+                    code_pairs, remaps)
+            flagged = jnp.zeros(G, jnp.int64).at[0].set(
+                col.rows(jnp).astype(jnp.int64))
+            return partial_dense + [(flagged, jnp.zeros(G, jnp.bool_))], occ
+
+        def dense_body(cols, num_rows, padded_len, cards, scalars,
+                       code_pairs, remaps):
             from ..columnar.segmented import onehot_gather
             # dictionary remap FUSED into the kernel (a standalone remap
             # would be one more dispatch per key)
@@ -1019,8 +1143,9 @@ class TpuHashAggregateExec(TpuExec):
                 ceff = jnp.where(cv, cd, cards[i])
                 gid = gid + ceff * strides[i]
             gid = jnp.where(keep, gid, G)        # dead rows drop out
-            vals = [[e.eval_device(ectx) for e in exprs]
-                    for exprs in value_exprs]
+            with D.masked(jnp, lambda: keep):
+                vals = [[e.eval_device(ectx) for e in exprs]
+                        for exprs in value_exprs]
             partial_dense = []
             for a, vs in zip(aggs, vals):
                 partial_dense.extend(a.update(vs, gid, G, keep))
@@ -1053,7 +1178,8 @@ class TpuHashAggregateExec(TpuExec):
                  code_pairs, remaps):
             partial_dense, occ = dense(cols, num_rows, padded_len, cards,
                                        scalars, code_pairs, remaps)
-            return compact(partial_dense, occ, cards)
+            overflow = partial_dense.pop()[0][0] if checked else None
+            return compact(partial_dense, occ, cards) + (overflow,)
 
         core.dense = dense
         core.compact = compact
@@ -1273,11 +1399,17 @@ class TpuHashAggregateExec(TpuExec):
         if n > self.OPTIMISTIC_GROUPS:
             _FAST_GROUPS[self._kernel_key] = n
             return None
+        first = 1
+        if self._decimal_checks:
+            # the rows a checked decimal operation flagged, packed after
+            # the group count: the one fetch carries them
+            first = 2
+            self._settle_overflow(int(got[1]))
         out_cols = []
         dict_pos = {i: j for j, i in enumerate(self._dict_keys)}
         for o, f in enumerate(self._schema.fields):
-            d = np.asarray(got[1 + 2 * o])[:n]
-            v = np.asarray(got[2 + 2 * o])[:n]
+            d = np.asarray(got[first + 2 * o])[:n]
+            v = np.asarray(got[first + 1 + 2 * o])[:n]
             if o in dict_pos:
                 inv = self._inverse_dict(dict_pos[o])
                 vals = [inv[int(x)] if ok else None
@@ -1512,7 +1644,11 @@ class TpuHashAggregateExec(TpuExec):
             flushes += 1
 
             def dispatch():
-                key_outs, partial_outs, ng = flush_k(state, c_cards)
+                key_outs, partial_outs, ng, overflow = flush_k(state,
+                                                               c_cards)
+                if overflow is not None:
+                    # leaves with the sort path: the sink fetches it
+                    D.defer(overflow, self._input_checks)
                 return list(key_outs) + list(partial_outs), ng
             enqueue(dispatch, 0, 1)
 
@@ -1549,6 +1685,8 @@ class TpuHashAggregateExec(TpuExec):
                     if carry is not None:
                         flush_carry()
                     codes = [] if self._rect_mode else self._augment(batch)
+                    if self._input_checks and not self._rect_mode:
+                        self._probe_overflow(batch, codes)
 
                     def dispatch(b=batch, extra=codes):
                         return self._run_kernel_raw(
@@ -1805,15 +1943,23 @@ class TpuHashAggregateExec(TpuExec):
         out_cols: List[DeviceColumn] = self._decode_keys(
             list(merged.columns[:nkeys]), merged.num_rows_raw)
         ord_ = nkeys
-        for ai, a in enumerate(self.aggs):
-            n = self._partial_counts[ai]
-            parts = [DVal(merged.columns[o].data, merged.columns[o].validity,
-                          merged.columns[o].dtype)
-                     for o in range(ord_, ord_ + n)]
-            ord_ += n
-            final = a.finalize(parts)
-            out_cols.append(DeviceColumn(final.data, final.validity,
-                                         self._schema.fields[nkeys + ai].dtype))
+        checks = self._decimal_checks - self._input_checks
+        with D.collecting(checks > 0) as col:
+            for ai, a in enumerate(self.aggs):
+                n = self._partial_counts[ai]
+                parts = [DVal(merged.columns[o].data,
+                              merged.columns[o].validity,
+                              merged.columns[o].dtype)
+                         for o in range(ord_, ord_ + n)]
+                ord_ += n
+                final = a.finalize(parts)
+                out_cols.append(DeviceColumn(
+                    final.data, final.validity,
+                    self._schema.fields[nkeys + ai].dtype))
+        if checks:
+            # finalized eagerly, batch left on the device: the sink
+            # fetches the count of totals that left the lane
+            D.defer(col.rows(jnp), checks)
         return ColumnarBatch(out_cols, merged.num_rows_raw, self._schema)
 
     def describe(self):
@@ -1861,6 +2007,8 @@ class CpuAggregateExec(TpuExec):
                                         MaxBy, Min, MinBy, Percentile,
                                         StddevPop, StddevSamp, Sum,
                                         VariancePop, VarianceSamp)
+        from ..types import DecimalType
+        child_schema = self.children[0].output_schema()
         tables = [b.to_arrow() for b in self.children[0].execute(ctx)]
         at = (pa.concat_tables(tables) if tables
               else _empty_arrow(self.children[0].output_schema()))
@@ -1935,6 +2083,17 @@ class CpuAggregateExec(TpuExec):
                 return float(np.percentile(np.sort(fv),
                                            a.percentage * 100.0,
                                            method="linear"))
+            dec_in = a.child.data_type(child_schema)
+            if isinstance(dec_in, DecimalType) \
+                    and isinstance(a, (Sum, Average)):
+                # exact: Python ints of the unscaled values; Spark's
+                # result types and its two HALF_UP roundings of an average
+                total = sum(D.unscaled(x, dec_in) for x in vals)
+                if isinstance(a, Sum):
+                    return D.from_unscaled(total, D.sum_type(dec_in))
+                q = D.average_int(total, len(vals), dec_in)
+                return None if q is None else D.from_unscaled(
+                    q, D.avg_types(dec_in)[2])
             if isinstance(a, Sum):
                 return np.sum(vals)                 # NaN propagates
             if isinstance(a, Min):

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..types import (BOOL, DataType, DecimalType, FLOAT64, INT64, Schema,
                      TypeEnum, numeric, tpuNative)
+from . import decimal_rules as D
 from .base import DVal, Expression, Literal
 from ..columnar.segmented import SortedSegments, seg_max, seg_min, seg_sum
 
@@ -140,6 +141,11 @@ class AggregateExpression:
             return f"{self.name_hint}: input type {dt.name} is host-only"
         return None
 
+    def decimal_checks(self, schema: Schema) -> int:
+        """Checked decimal operations of this aggregate's ``finalize``
+        (a total or an average that may leave the lane)."""
+        return 0
+
     # ---- device pipeline -------------------------------------------------
     def input_exprs(self) -> List[Expression]:
         return [self.child] if self.child is not None else []
@@ -173,43 +179,208 @@ _DEC_LIMB = 10 ** 12
 _DEC_LIMB2 = _DEC_LIMB * _DEC_LIMB
 
 
+def _divmod_est(x, k, rounds: int = 2):
+    """Floor ``divmod(x, k)`` of int64 lanes, k > 0 (a Python int or
+    lanes), WITHOUT a 64-bit division. This backend emulates int64, and
+    one 64-bit division costs its compiler about a second
+    (described-v5e compile, PR 28: the decimal ``tail`` with some 240 of
+    them did not compile in 300 s) and its device a long emulated
+    sequence per lane; float32 is native. Each round estimates what is
+    left of the quotient in float32 (the remainder as a float32, times
+    the constant's reciprocal or over the lanes' divisor, floored) and
+    takes it off the remainder with an integer multiply and subtract
+    (wrapping arithmetic: the true remainder fits the lane, so it comes
+    out right); one whole step each way then finishes.
+
+    A round's estimate is off by at most |what is left| x 2^-21 (the
+    int64 -> float32 conversion, the constant, the multiply or divide:
+    five float32 roundings, with room for a divide that is a few ulps
+    off) plus the floor. So ``rounds=1`` is exact where |x / k| < 2^21
+    (the estimate is then within one of the quotient), and ``rounds=2``
+    where |x / k| < 2^42: every int64 x over a limb or a digit."""
+    inv = jnp.float32(1.0 / k) if isinstance(k, int) else None
+    q = jnp.zeros_like(x)
+    r = x
+    for _ in range(rounds):
+        rf = r.astype(jnp.float32)
+        est = jnp.floor(rf * inv if inv is not None
+                        else rf / k.astype(jnp.float32)).astype(jnp.int64)
+        q = q + est
+        r = r - est * k
+    low = r < 0
+    q = jnp.where(low, q - 1, q)
+    r = jnp.where(low, r + k, r)
+    high = r >= k
+    q = jnp.where(high, q + 1, q)
+    r = jnp.where(high, r - k, r)
+    return q, r
+
+
 def _dec_normalize(l0, l1, l2):
     """Carry-propagate limb sums back into canonical form
     (l0, l1 in [0, base); sign carried by l2)."""
-    l1 = l1 + l0 // _DEC_LIMB
-    l0 = l0 % _DEC_LIMB
-    l2 = l2 + l1 // _DEC_LIMB
-    l1 = l1 % _DEC_LIMB
-    return l0, l1, l2
+    c0, l0 = _divmod_est(l0, _DEC_LIMB)
+    c1, l1 = _divmod_est(l1 + c0, _DEC_LIMB)
+    return l0, l1, l2 + c1
+
+
+def _dec_limb_sums(v: DVal, gid, num_segments):
+    """Exact 128-bit-wide accumulation of a decimal column in 10^12-base
+    limbs: every per-segment limb sum fits int64 (ref DecimalUtils JNI
+    128-bit sums; TPU has no int128, limbs are the XLA shape). Returns the
+    normalized limbs and the count of valid rows."""
+    x = v.data.astype(jnp.int64)
+    # up to 18 digits the quotient is below 10^6: one round; any int64
+    # over 10^12 is below 2^24: two
+    xd, x0 = _divmod_est(x, _DEC_LIMB,
+                         1 if v.dtype.precision <= D.LANE_DIGITS else 2)
+    # |xd| < 2^63 / 10^12 < 10^12: its own split is a compare
+    neg = xd < 0
+    l0, c = _seg_sum(x0, v.validity, gid, num_segments)
+    l1, _ = _seg_sum(jnp.where(neg, xd + _DEC_LIMB, xd), v.validity, gid,
+                     num_segments)
+    l2, _ = _seg_sum(jnp.where(neg, -1, 0).astype(jnp.int64), v.validity,
+                     gid, num_segments)
+    return _dec_normalize(l0, l1, l2), c
+
+
+def _dec_limb_merge(partials, gid, num_segments):
+    sums = [_seg_sum(p.data, p.validity, gid, num_segments)[0]
+            for p in partials]
+    return _dec_normalize(*sums)
+
+
+def _dec_total(l0, l1, l2):
+    """(total, fits): the limbs as ONE int64 where the exact total fits
+    the lane. The f64 magnitude test is exact to ~1e3 at the boundary,
+    erring to "does not fit" inside the last few thousand ulps; the
+    nested form keeps every constant and (when it fits) every
+    intermediate inside int64."""
+    est = (l2.astype(jnp.float64) * float(_DEC_LIMB2)
+           + l1.astype(jnp.float64) * float(_DEC_LIMB)
+           + l0.astype(jnp.float64))
+    fits = jnp.abs(est) < 9.223372e18
+    total = (l2 * _DEC_LIMB + l1) * _DEC_LIMB + l0
+    return jnp.where(fits, total, 0), fits
+
+
+_DIGIT = 10 ** 6
+
+
+def _dec_collapse(digits):
+    """Base-10^6 digits (most significant first) as ONE int64, and the
+    flag of the lanes it does not fit."""
+    acc = jnp.zeros_like(digits[0])
+    over = jnp.zeros(digits[0].shape, jnp.bool_)
+    for d in digits:
+        over = jnp.logical_or(over, acc > (D.INT64_MAX - _DIGIT) // _DIGIT)
+        acc = jnp.where(over, 0, acc) * _DIGIT + d
+    return acc, over
+
+
+def _dec_average(l0, l1, l2, count, in_type: DecimalType):
+    """avg over ``in_type`` from the limb total and the count, on int64
+    lanes, as Spark evaluates it: ``sum / cast(count as decimal(20,0))``
+    HALF_UP at the divide's own scale, then cast HALF_UP to the
+    average's (two roundings; exprs/decimal_rules.py:avg_types). Neither
+    the numerator (total x 10^k, up to ~40 digits) nor the first quotient
+    (Q1's avg_price is 3.8e19 at the divide's scale 15) is ever formed:
+    schoolbook long division over base-10^6 digits keeps ``remainder x
+    10^6 + digit`` inside int64 for any count below 2^42, the first
+    rounding is a carry through the quotient's digits, and the second
+    splits them at the average's scale. Every quotient on the way is a
+    digit, so no 64-bit division is traced (_divmod_est). Returns
+    (value, overflow flag): the flag where the count or the final
+    quotient leaves the lane."""
+    st, dv, res = D.avg_types(in_type)
+    k = dv.scale - st.scale
+    B = _DEC_LIMB
+    # the magnitude, limb by limb (l0, l1 in [0, B): a borrow, no division)
+    neg = l2 < 0
+    b0 = l0 > 0
+    t1 = l1 + b0
+    a0 = jnp.where(neg, jnp.where(b0, B - l0, 0), l0)
+    a1 = jnp.where(neg, jnp.where(t1 > 0, B - t1, 0), l1)
+    a2 = jnp.where(neg, -l2 - (t1 > 0), l2)
+    # its digits, most significant first (a2 < 2^63 < 10^24)
+    d7, rest = _divmod_est(a2, _DIGIT ** 3)
+    d6, rest = _divmod_est(rest, _DIGIT ** 2)
+    d5, d4 = _divmod_est(rest, _DIGIT)
+    d3, d2 = _divmod_est(a1, _DIGIT)
+    d1, d0 = _divmod_est(a0, _DIGIT)
+    digits = [d7, d6, d5, d4, d3, d2, d1, d0]
+    k6, kr = divmod(max(k, 0), 6)
+    if kr:
+        carry = jnp.zeros_like(a0)
+        scaled = []
+        for d in reversed(digits):
+            carry, low = _divmod_est(d * 10 ** kr + carry, _DIGIT)
+            scaled.insert(0, low)
+        digits = [carry] + scaled
+    digits = digits + [jnp.zeros_like(a0)] * k6
+    c = jnp.where(count > 0, count, 1) * 10 ** max(-k, 0)
+    over = c >= 1 << 42
+    c = jnp.where(over, 1, c)
+    quot = []
+    r = jnp.zeros_like(a0)
+    for d in digits:
+        q, r = _divmod_est(r * _DIGIT + d, c)
+        quot.append(q)
+    # HALF_UP at the divide's scale: one more in the last digit, carried
+    carry = 2 * r >= c
+    for i in reversed(range(len(quot))):
+        t = quot[i] + carry
+        carry = t >= _DIGIT
+        quot[i] = jnp.where(carry, t - _DIGIT, t)
+    quot = [carry.astype(jnp.int64)] + quot
+    m = dv.scale - res.scale
+    if m < 0:
+        q, o1 = _dec_collapse(quot)
+        q, o2 = D.scale_up(jnp, q, -m)
+        return jnp.where(neg, -q, q), D.any_flag(jnp, over, o1, o2)
+    # HALF_UP again at the average's scale: the digits split at 10^m
+    # (m <= 17: the dropped part fits a lane); the kept part is formed
+    # at ITS scale, never at the divide's
+    m6, mr = divmod(m, 6)
+    cut = len(quot) - m6 - 1            # the digit the split runs through
+    high, o1 = _dec_collapse(quot[:cut])
+    high, o2 = D.scale_up(jnp, high, 6 - mr)
+    o3 = high > D.INT64_MAX - 2 * _DIGIT
+    kept, low = _divmod_est(quot[cut], 10 ** mr)
+    for d in quot[cut + 1:]:
+        low = low * _DIGIT + d
+    q = jnp.where(o3, 0, high) + kept + (2 * low >= 10 ** m)
+    return jnp.where(neg, -q, q), D.any_flag(jnp, over, o1, o2, o3)
 
 
 class Sum(AggregateExpression):
     pandas_agg = "sum"
     device_type_sig = tpuNative.with_psnote(
         TypeEnum.DECIMAL,
-        "totals whose |unscaled value| >= 2^63 finalize as NULL (device "
-        "decimals are int64-scaled; Spark non-ANSI would return up to "
-        "38 digits)")
+        "summed exactly in three 10^12-base limbs; a total whose |unscaled "
+        "value| >= 2^63 raises DecimalOverflow on the device (lanes are "
+        "int64; Spark non-ANSI holds up to 38 digits, and so does the "
+        "host engine)")
 
     def data_type(self, schema):
         dt = self.child.data_type(schema)
         if dt.name in ("tinyint", "smallint", "int", "bigint"):
             return INT64
         if isinstance(dt, DecimalType):
-            # Spark: sum(decimal(p,s)) -> decimal(min(p+10, 38), s).
-            # ENGINE LIMITATION (documented in docs/performance.md and
-            # supported_ops): device decimals are int64-scaled, so a
-            # finalized total whose |unscaled value| >= 2^63 returns
-            # NULL even when the declared result precision could hold it
-            # (Spark non-ANSI would return the value up to min(p+10,38)
-            # digits). The limb accumulation itself is exact; only the
-            # final materialization is capped. Same cap as ingest
-            # (types.py/_decimal-to-int64).
-            return DecimalType(min(dt.precision + 10, 38), dt.scale)
+            # Spark: sum(decimal(p,s)) -> decimal(min(p+10, 38), s). The
+            # limb accumulation is exact; only the finalized total has to
+            # fit a lane. One that does not is NULL in its lane AND
+            # flagged (exprs/decimal_rules.py), which the aggregate's own
+            # fetch turns into the loud error, as ingest does
+            # (columnar/batch.py).
+            return D.sum_type(dt)
         return FLOAT64 if dt.name in ("float", "double") else dt
 
     def _is_decimal(self, schema) -> bool:
         return isinstance(self.child.data_type(schema), DecimalType)
+
+    def decimal_checks(self, schema):
+        return int(self._is_decimal(schema))
 
     def partial_types(self, schema):
         if self._is_decimal(schema):
@@ -219,17 +390,7 @@ class Sum(AggregateExpression):
     def update(self, vals, gid, num_segments, row_mask):
         v = vals[0]
         if isinstance(v.dtype, DecimalType):
-            # exact 128-bit-wide accumulation in 10^12-base limbs: every
-            # per-segment limb sum fits int64 (ref DecimalUtils JNI
-            # 128-bit sums; TPU has no int128, limbs are the XLA shape)
-            x = v.data.astype(jnp.int64)
-            xd = x // _DEC_LIMB
-            l0, c = _seg_sum(x % _DEC_LIMB, v.validity, gid, num_segments)
-            l1, _ = _seg_sum(xd % _DEC_LIMB, v.validity,
-                             gid, num_segments)
-            l2, _ = _seg_sum(xd // _DEC_LIMB, v.validity, gid,
-                             num_segments)
-            l0, l1, l2 = _dec_normalize(l0, l1, l2)
+            (l0, l1, l2), c = _dec_limb_sums(v, gid, num_segments)
             ok = c > 0
             return [(l0, ok), (l1, ok), (l2, ok)]
         # promote to the accumulator type before summing
@@ -240,13 +401,9 @@ class Sum(AggregateExpression):
 
     def merge(self, partials, gid, num_segments):
         if len(partials) == 3:         # decimal limbs
-            sums = []
-            ok = None
-            for p in partials:
-                s, cnt = _seg_sum(p.data, p.validity, gid, num_segments)
-                sums.append(s)
-                ok = cnt > 0 if ok is None else ok
-            l0, l1, l2 = _dec_normalize(*sums)
+            l0, l1, l2 = _dec_limb_merge(partials, gid, num_segments)
+            ok = seg_sum(partials[0].validity.astype(jnp.int64), gid,
+                         num_segments=num_segments) > 0
             return [(l0, ok), (l1, ok), (l2, ok)]
         p = partials[0]
         s, cnt = _seg_sum(p.data, p.validity, gid, num_segments)
@@ -256,20 +413,11 @@ class Sum(AggregateExpression):
         if len(partials) == 3:
             l0, l1, l2 = (p.data for p in partials)
             ok = partials[0].validity
-            # representable on device iff the exact total fits int64;
-            # beyond that Spark's (non-ANSI) overflow answer is NULL —
-            # the f64 magnitude test is exact to ~1e3 at the boundary,
-            # erring to NULL inside the last few thousand ulps
-            est = (l2.astype(jnp.float64) * float(_DEC_LIMB2)
-                   + l1.astype(jnp.float64) * float(_DEC_LIMB)
-                   + l0.astype(jnp.float64))
-            fits = jnp.abs(est) < 9.223372e18
-            # nested form keeps every constant and (when fits) every
-            # intermediate inside int64: value = (l2*M + l1)*M + l0;
-            # non-fitting lanes wrap silently and are masked NULL
-            total = (l2 * _DEC_LIMB + l1) * _DEC_LIMB + l0
-            return DVal(jnp.where(fits, total, 0),
-                        jnp.logical_and(ok, fits), INT64)
+            # representable on device iff the exact total fits int64; a
+            # total that does not is NULL in its lane and flagged
+            total, fits = _dec_total(l0, l1, l2)
+            D.note_overflow(jnp.logical_and(ok, jnp.logical_not(fits)))
+            return DVal(total, jnp.logical_and(ok, fits), INT64)
         return partials[0]
 
 
@@ -364,22 +512,50 @@ class Max(AggregateExpression):
 
 
 class Average(AggregateExpression):
+    """avg: double for every input but a decimal; avg(decimal(p,s)) is
+    Spark's decimal(p+4, s+4): the sum in three limbs beside the count,
+    divided and rounded HALF_UP on the device (_dec_average)."""
     pandas_agg = "mean"
 
+    def _decimal_in(self, schema):
+        dt = self.child.data_type(schema)
+        #: finalize sees partials only: the input type is kept from the
+        #: typing calls every exec makes before it builds a kernel (same
+        #: key -> same type, so a cached kernel's copy agrees)
+        self._dec_in = dt if isinstance(dt, DecimalType) else None
+        return self._dec_in
+
     def data_type(self, schema):
-        return FLOAT64
+        dt = self._decimal_in(schema)
+        return FLOAT64 if dt is None else D.avg_types(dt)[2]
+
+    def decimal_checks(self, schema):
+        return int(self._decimal_in(schema) is not None)
 
     def partial_types(self, schema):
+        if self._decimal_in(schema) is not None:
+            return [INT64, INT64, INT64, INT64]  # three limbs, count
         return [FLOAT64, INT64]  # sum, count
 
     def update(self, vals, gid, num_segments, row_mask):
         v = vals[0]
+        if isinstance(v.dtype, DecimalType):
+            (l0, l1, l2), c = _dec_limb_sums(v, gid, num_segments)
+            ok = c > 0
+            return [(l0, ok), (l1, ok), (l2, ok), (c, jnp.ones_like(ok))]
         s, cnt = _seg_sum(v.data.astype(jnp.float64), v.validity, gid,
                           num_segments)
         ok = cnt > 0
         return [(s, ok), (cnt, jnp.ones_like(ok))]
 
     def merge(self, partials, gid, num_segments):
+        if len(partials) == 4:         # decimal limbs + count
+            l0, l1, l2 = _dec_limb_merge(partials[:3], gid, num_segments)
+            c, _ = _seg_sum(partials[3].data, partials[3].validity, gid,
+                            num_segments)
+            ok = c > 0
+            return [(l0, ok), (l1, ok), (l2, ok),
+                    (c, jnp.ones_like(c, dtype=jnp.bool_))]
         s, _ = _seg_sum(partials[0].data, partials[0].validity, gid,
                         num_segments)
         c, _ = _seg_sum(partials[1].data, partials[1].validity, gid,
@@ -387,6 +563,14 @@ class Average(AggregateExpression):
         return [(s, c > 0), (c, jnp.ones_like(c, dtype=jnp.bool_))]
 
     def finalize(self, partials):
+        if len(partials) == 4:
+            l0, l1, l2, c = (p.data for p in partials)
+            ok = jnp.logical_and(partials[0].validity, c > 0)
+            q, over = _dec_average(l0, l1, l2, c, self._dec_in)
+            over = jnp.logical_and(over, ok)
+            D.note_overflow(over)
+            ok = jnp.logical_and(ok, jnp.logical_not(over))
+            return DVal(jnp.where(ok, q, 0), ok, INT64)
         s, c = partials[0], partials[1]
         ok = jnp.logical_and(s.validity, c.data > 0)
         denom = jnp.where(c.data > 0, c.data, jnp.ones_like(c.data))

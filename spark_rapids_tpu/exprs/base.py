@@ -227,6 +227,11 @@ class Expression:
                 return r
         return None
 
+    def decimal_checks(self, schema: Schema) -> int:
+        """Checked decimal operations THIS node traces on the device
+        (exprs/decimal_rules.py): each notes one overflow flag."""
+        return 0
+
     # --- evaluation -------------------------------------------------------
     def eval_device(self, ctx: EvalContext) -> DVal:
         raise Unsupported(f"{type(self).__name__} has no device implementation")
@@ -243,6 +248,45 @@ class Expression:
 
     def __repr__(self):
         return self.key()
+
+
+def coerce_decimal_literals(e, schema: Schema):
+    """Spark's ``DecimalPrecision`` for literals, applied where a logical
+    node takes its expressions (plan/logical.py): in arithmetic and
+    comparisons, an integer literal or a SQL ``0.05`` that meets a decimal
+    operand becomes a decimal literal of its own digits. Returns ``e`` or
+    a copy with the literals replaced; never touches a tree without such
+    a pair, so plans over floats keep their keys."""
+    import copy
+    from .aggregates import AggregateExpression
+    if isinstance(e, AggregateExpression):
+        if e.child is None:
+            return e
+        child = coerce_decimal_literals(e.child, schema)
+        if child is e.child:
+            return e
+        e = copy.copy(e)
+        e.child = child
+        return e
+    kids = getattr(e, "children", None)
+    if not kids or not all(isinstance(c, Expression) for c in kids):
+        return e
+    new = [coerce_decimal_literals(c, schema) for c in kids]
+    if getattr(e, "decimal_literal_operands", False) and len(new) == 2:
+        for i in (0, 1):
+            if not isinstance(new[i], Literal):
+                continue
+            try:
+                other = new[1 - i].data_type(schema)
+            except Exception:  # noqa: BLE001 - not typeable here: leave
+                continue
+            if isinstance(other, DecimalType):
+                new[i] = new[i].as_decimal() or new[i]
+    if all(a is b for a, b in zip(new, kids)):
+        return e
+    e = copy.copy(e)
+    e.children = new
+    return e
 
 
 class ColumnRef(Expression):
@@ -306,8 +350,12 @@ class BoundReference(Expression):
 
 def _literal_type(value) -> DataType:
     import datetime
+    import decimal
     if value is None:
         return NULLTYPE
+    if isinstance(value, decimal.Decimal):
+        from .decimal_rules import literal_type
+        return literal_type(value)
     if isinstance(value, bool):
         return BOOL
     if isinstance(value, int):
@@ -339,6 +387,13 @@ def _canonical_literal(value, dtype: DataType):
         return int(np.datetime64(value, "D").astype(np.int64))
     if dtype == TIMESTAMP and not isinstance(value, (int, np.integer)):
         return int(np.datetime64(value, "us").astype(np.int64))
+    if isinstance(dtype, DecimalType):
+        # the value a decimal literal holds IS a decimal.Decimal at the
+        # type's scale (the device lane takes its unscaled int)
+        import decimal
+        return decimal.Decimal(value).quantize(
+            decimal.Decimal(1).scaleb(-dtype.scale),
+            context=decimal.Context(prec=76))
     return value
 
 
@@ -373,8 +428,30 @@ class Literal(Expression):
             v = ctx.scalars[slots[id(self)]]
             return DVal(jnp.broadcast_to(v, (p,)),
                         jnp.ones(p, dtype=jnp.bool_), self.dtype)
-        data = jnp.full((p,), self.value, dtype=self.dtype.np_dtype)
+        value = self.value
+        if isinstance(self.dtype, DecimalType):
+            from .decimal_rules import unscaled
+            value = unscaled(value, self.dtype)
+        data = jnp.full((p,), value, dtype=self.dtype.np_dtype)
         return DVal(data, jnp.ones(p, dtype=jnp.bool_), self.dtype)
+
+    def as_decimal(self) -> Optional["Literal"]:
+        """This literal as Spark types it beside a decimal operand: an
+        integer by its own digits (1 is decimal(1,0)), a SQL literal
+        written with a point and no exponent by its text (0.05 is
+        decimal(2,2), types.DecimalText); None for any other (a double
+        beside a decimal makes the operation a double one). The decimal
+        literal's value is part of its key, never a traced scalar: its
+        scale is a type."""
+        import decimal
+        from ..types import DecimalText
+        if isinstance(self.value, DecimalText):
+            return Literal(decimal.Decimal(self.value.text))
+        if (isinstance(self.value, (int, np.integer))
+                and not isinstance(self.value, (bool, np.bool_))
+                and self.dtype in (INT8, INT16, INT32, INT64)):
+            return Literal(decimal.Decimal(int(self.value)))
+        return None
 
     def eval_host(self, batch):
         import pyarrow as pa
@@ -435,7 +512,9 @@ class Alias(Expression):
 
 
 # ---------------------------------------------------------------------------
-# numeric type promotion (simplified Catalyst TypeCoercion)
+# numeric type promotion (Catalyst TypeCoercion's common type of two
+# operands; the result type of decimal ARITHMETIC is the operator's own,
+# exprs/decimal_rules.py)
 # ---------------------------------------------------------------------------
 
 _NUMERIC_ORDER = [INT8, INT16, INT32, INT64, FLOAT32, FLOAT64]
@@ -445,10 +524,16 @@ def promote_types(l: DataType, r: DataType) -> DataType:
     if l == r:
         return l
     if isinstance(l, DecimalType) or isinstance(r, DecimalType):
-        # simplified: decimal op decimal -> wider; decimal op int -> decimal
-        if isinstance(l, DecimalType) and isinstance(r, DecimalType):
-            return DecimalType(max(l.precision, r.precision), max(l.scale, r.scale))
-        return l if isinstance(l, DecimalType) else r
+        # two decimals, or a decimal and an integer (as decimal(3|5|10|
+        # 20, 0)): the wider decimal, which holds both; a float or double
+        # beside a decimal: double
+        from .decimal_rules import operand_type, wider_type
+        ld, rd = operand_type(l), operand_type(r)
+        if ld is not None and rd is not None:
+            return wider_type(ld, rd)
+        if l in (FLOAT32, FLOAT64) or r in (FLOAT32, FLOAT64):
+            return FLOAT64
+        raise TypeError(f"cannot promote {l} and {r}")
     try:
         li, ri = _NUMERIC_ORDER.index(l), _NUMERIC_ORDER.index(r)
     except ValueError:

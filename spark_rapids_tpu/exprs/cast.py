@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from ..types import (BOOL, DATE, DataType, DecimalType, Schema, STRING,
                      TIMESTAMP, all_types)
+from . import decimal_rules as D
 from .base import DVal, Expression
 from .arithmetic import arrow_to_masked_numpy, masked_numpy_to_arrow
 
@@ -42,6 +43,38 @@ def _float_to_int_java(d, np_dt, xp):
     return out.astype(np_dt)
 
 
+def _decimal_cast(xp, d, src: DataType, dst: DataType, wide: bool = False):
+    """A cast with a decimal on either side, on unscaled lanes: (values,
+    NULL flag or None, lane-overflow flag or None). Spark (non-ANSI): a
+    scale that is cut rounds HALF_UP; a value with more digits than the
+    target declares is NULL; decimal -> integral truncates toward zero.
+    ``wide``: Python ints in object arrays (the host's exact form)."""
+    if isinstance(dst, DecimalType):
+        if isinstance(src, DecimalType) or D.operand_type(src) is not None:
+            frm = src.scale if isinstance(src, DecimalType) else 0
+            if not wide:
+                d = d.astype(xp.int64)
+            out, over = D.rescale(xp, d, frm, dst.scale, wide)
+        else:                                   # float / double
+            x = d.astype(xp.float64) * float(10 ** dst.scale)
+            out = (xp.sign(x) * xp.floor(xp.abs(x) + 0.5))
+            over = xp.logical_or(xp.isnan(x), xp.abs(out) >= 2.0 ** 63)
+            out = xp.where(over, 0, out).astype(xp.int64)
+            # a double that leaves the lane has left decimal(38) or is
+            # unrepresentable alike: Spark's answer there is NULL
+            return out, over, None
+        return out, D.exceeds(xp, out, dst), over
+    # decimal -> numeric / boolean
+    if dst == BOOL:
+        return d != 0, None, None
+    if np.issubdtype(dst.np_dtype, np.floating):
+        return (d.astype(xp.float64) / float(10 ** src.scale)) \
+            .astype(dst.np_dtype), None, None
+    whole = xp.where(d < 0, -(abs(d) // 10 ** src.scale),
+                     abs(d) // 10 ** src.scale)
+    return whole.astype(dst.np_dtype), None, None
+
+
 class Cast(Expression):
     device_type_sig = all_types  # per-pair support decided in reason check
 
@@ -57,9 +90,13 @@ class Cast(Expression):
         if not src.device_backed or not self.dtype.device_backed:
             return (f"cast {src.name} -> {self.dtype.name} runs on host "
                     f"(string/nested path)")
-        if isinstance(src, DecimalType) or isinstance(self.dtype, DecimalType):
-            return "decimal cast not yet on device"
         return None
+
+    def decimal_checks(self, schema):
+        src = self.children[0].data_type(schema)
+        frm = D.operand_type(src)
+        return int(isinstance(self.dtype, DecimalType) and frm is not None
+                   and self.dtype.scale > frm.scale)
 
     def eval_device(self, ctx):
         src = self.children[0].data_type(ctx.schema)
@@ -68,6 +105,19 @@ class Cast(Expression):
         d = c.data
         if src == dst:
             return c
+        if isinstance(src, DecimalType) or isinstance(dst, DecimalType):
+            out, null, over = _decimal_cast(jnp, d, src, dst)
+            valid = c.validity
+            if over is not None:
+                # Spark would hold the number: flagged, the engine's
+                # loud error
+                over = jnp.logical_and(over, valid)
+                D.note_overflow(over)
+                null = over if null is None else jnp.logical_or(null, over)
+            if null is not None:
+                valid = jnp.logical_and(valid, jnp.logical_not(null))
+                out = jnp.where(null, jnp.zeros_like(out), out)
+            return DVal(out, valid, dst)
         if dst == BOOL:
             out = d != 0
         elif src == BOOL:
@@ -95,6 +145,18 @@ class Cast(Expression):
         if src.device_backed and dst.device_backed:
             # mirror the device semantics exactly with numpy
             v, ok = arrow_to_masked_numpy(arr)
+            if isinstance(src, DecimalType) or isinstance(dst, DecimalType):
+                wide = v.dtype == object
+                if not wide:
+                    out, null, over = _decimal_cast(np, v, src, dst)
+                    wide = over is not None and (over & ok).any()
+                if wide:
+                    out, null, _ = _decimal_cast(np, v.astype(object), src,
+                                                 dst, wide=True)
+                if null is not None:
+                    ok = ok & ~np.asarray(null, dtype=bool)
+                    out = np.where(ok, out, 0)
+                return masked_numpy_to_arrow(out, ok, dst)
             if dst == BOOL:
                 out = v != 0
             elif src == BOOL:

@@ -9,9 +9,12 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..types import BOOL, DataType, Schema, comparable, STRING
+from ..types import (BOOL, DataType, DecimalType, Schema, comparable,
+                     STRING)
+from . import decimal_rules as D
 from .base import DVal, EvalContext, Expression, null_and, promote_types
-from .arithmetic import arrow_to_masked_numpy, masked_numpy_to_arrow
+from .arithmetic import (arrow_to_masked_numpy, decimal_as_double,
+                         masked_numpy_to_arrow)
 
 __all__ = ["EqualTo", "EqualNullSafe", "NotEqual", "LessThan",
            "LessThanOrEqual", "GreaterThan", "GreaterThanOrEqual",
@@ -38,6 +41,9 @@ def _nan_lt(l, r):
 class BinaryComparison(Expression):
     device_type_sig = comparable
     symbol = "?"
+    #: an integer or SQL-decimal literal beside a decimal operand becomes
+    #: a decimal literal (exprs/base.py:coerce_decimal_literals)
+    decimal_literal_operands = True
 
     def __init__(self, left: Expression, right: Expression):
         self.children = [left, right]
@@ -45,14 +51,42 @@ class BinaryComparison(Expression):
     def data_type(self, schema: Schema) -> DataType:
         return BOOL
 
+    def _decimal_pair(self, schema: Schema):
+        """(left, right) decimal types where a decimal is compared with a
+        decimal or an integer: both sides go to the wider scale first."""
+        ldt = self.children[0].data_type(schema)
+        rdt = self.children[1].data_type(schema)
+        if not (isinstance(ldt, DecimalType) or isinstance(rdt, DecimalType)):
+            return None
+        l, r = D.operand_type(ldt), D.operand_type(rdt)
+        return None if l is None or r is None else (l, r)
+
+    def decimal_checks(self, schema):
+        dec = self._decimal_pair(schema)
+        return int(dec is not None and dec[0].scale != dec[1].scale
+                   and D.wider_type(*dec).precision > D.LANE_DIGITS)
+
     def _operands(self, ctx: EvalContext):
         l = self.children[0].eval_device(ctx)
         r = self.children[1].eval_device(ctx)
         ldt = self.children[0].data_type(ctx.schema)
         rdt = self.children[1].data_type(ctx.schema)
+        dec = self._decimal_pair(ctx.schema)
+        if dec is not None:
+            valid = null_and(l.validity, r.validity)
+            x, y, over = D.compare_values(jnp, l.data.astype(jnp.int64),
+                                          r.data.astype(jnp.int64), *dec)
+            if over is not None:
+                over = jnp.logical_and(over, valid)
+                D.note_overflow(over)
+                valid = jnp.logical_and(valid, jnp.logical_not(over))
+            return x, y, valid
+        # (operands first, validity after: the traced order is part of a
+        # float plan's executable-cache key)
         if ldt != rdt:
             wide = promote_types(ldt, rdt)
-            return (l.data.astype(wide.np_dtype), r.data.astype(wide.np_dtype),
+            return (decimal_as_double(jnp, l.data, ldt).astype(wide.np_dtype),
+                    decimal_as_double(jnp, r.data, rdt).astype(wide.np_dtype),
                     null_and(l.validity, r.validity))
         return l.data, r.data, null_and(l.validity, r.validity)
 
@@ -92,9 +126,23 @@ class BinaryComparison(Expression):
         r, rv = side(self.children[1], lit1)
         ldt = self.children[0].data_type(batch.schema)
         rdt = self.children[1].data_type(batch.schema)
+        dec = self._decimal_pair(batch.schema)
+        if dec is not None:
+            # unscaled lanes at the wider scale; Python ints where the
+            # lanes cannot hold that (exact)
+            wide_ints = l.dtype == object or r.dtype == object
+            if not wide_ints:
+                x, y, over = D.compare_values(np, l.astype(np.int64),
+                                              r.astype(np.int64), *dec)
+                wide_ints = over is not None and (over & lv & rv).any()
+            if wide_ints:
+                x, y, _ = D.compare_values(np, l.astype(object),
+                                           r.astype(object), *dec, wide=True)
+            return x, y, lv & rv
         if ldt != rdt and ldt.device_backed and rdt.device_backed:
             wide = promote_types(ldt, rdt).np_dtype
-            l, r = l.astype(wide), r.astype(wide)
+            l = decimal_as_double(np, l, ldt).astype(wide)
+            r = decimal_as_double(np, r, rdt).astype(wide)
         return l, r, lv & rv
 
     def key(self):

@@ -24,6 +24,7 @@ import numpy as np
 from ..columnar import ColumnarBatch, DeviceColumn, HostColumn
 from ..columnar.bucketing import bucket_for
 from ..types import Schema, StructField
+from . import decimal_rules as D
 from .base import (DVal, EvalContext, Expression, collect_param_literals,
                    literal_scalars, literal_slot_map, parameterized_keys)
 
@@ -75,6 +76,10 @@ class DeviceProjector:
         # projections/filters with different constants share ONE kernel
         self._lits = collect_param_literals(self.exprs)
         self._scalars = literal_scalars(self._lits)
+        #: checked decimal operations (decimal_rules.py): where there are
+        #: any, the kernel also returns the rows they flagged, and run()
+        #: leaves that count for the query's sink
+        self._checks = sum(D.checked_ops(e, schema) for e in self.exprs)
         # resolved through the process-wide executable cache (not a
         # per-exec dict): a repeat query's fresh exec objects reuse the
         # SAME callable, so jax serves every shape bucket it has traced
@@ -88,9 +93,9 @@ class DeviceProjector:
         exprs, schema = self.exprs, self.schema
         dtypes = [f.dtype for f in schema.fields]  # static, closed over
         slots = {id(l): i for i, l in enumerate(self._lits)}
+        checked = self._checks > 0
 
-        @functools.partial(jax.jit, static_argnums=(2,))
-        def kernel(cols, num_rows, padded_len, scalars=()):
+        def body(cols, num_rows, padded_len, scalars):
             dvals = []
             for c, dt in zip(cols, dtypes):
                 if c is None:
@@ -102,11 +107,20 @@ class DeviceProjector:
             ctx = EvalContext(schema, dvals, num_rows, padded_len,
                               scalars, slots)
             outs = []
-            for e in exprs:
-                v = e.eval_device(ctx)
-                # clamp validity so padding rows are always invalid
-                outs.append((v.data, jnp.logical_and(v.validity, ctx.row_mask())))
+            with D.masked(jnp, ctx.row_mask):
+                for e in exprs:
+                    v = e.eval_device(ctx)
+                    # clamp validity so padding rows are always invalid
+                    outs.append((v.data, jnp.logical_and(v.validity, ctx.row_mask())))
             return outs
+
+        @functools.partial(jax.jit, static_argnums=(2,))
+        def kernel(cols, num_rows, padded_len, scalars=()):
+            if not checked:
+                return body(cols, num_rows, padded_len, scalars)
+            with D.collecting() as col:
+                outs = body(cols, num_rows, padded_len, scalars)
+            return outs, col.rows(jnp)
 
         return kernel
 
@@ -127,6 +141,9 @@ class DeviceProjector:
                 cols.append(None)  # host column: device exprs must not touch it
         num_rows = jnp.int32(batch.num_rows_raw)
         outs = self._fn(cols, num_rows, p, self._scalars + extra_scalars)
+        if self._checks:
+            outs, flagged = outs
+            D.defer(flagged, self._checks)
         built = []
         for (d, v), dt in zip(outs, self.out_types):
             if isinstance(d, ListVal):
@@ -424,6 +441,8 @@ class FusedStageKernel:
                 for st in self.stages)
         self._lits = collect_param_literals(all_exprs)
         self._scalars = literal_scalars(self._lits)
+        #: checked decimal operations, stage by stage (decimal_rules.py)
+        self._checks = D.checked_ops_of_stages(self.stages, schema)[0]
         from ..plan import exec_cache
         self.digest = exec_cache.digest_of(stage_sig)
         schema_sig = tuple((f.name, f.dtype.name) for f in schema.fields)
@@ -435,9 +454,9 @@ class FusedStageKernel:
         stages, in_schema = self.stages, self.schema
         dtypes = [f.dtype for f in in_schema.fields]
         slots = {id(l): i for i, l in enumerate(self._lits)}
+        checked = self._checks > 0
 
-        @functools.partial(jax.jit, static_argnums=(2,))
-        def kernel(cols, num_rows, padded_len, scalars=()):
+        def body(cols, num_rows, padded_len, scalars):
             from ..columnar.segmented import compact_rows
             dvals = [DVal(c[0], c[1], dt) for c, dt in zip(cols, dtypes)]
             ctx = EvalContext(in_schema, dvals, num_rows, padded_len,
@@ -445,30 +464,45 @@ class FusedStageKernel:
             live = ctx.row_mask()
             counts = []
             for st in stages:
-                if st[0] == "filter":
-                    v = st[1].eval_device(ctx)
-                    live = jnp.logical_and(
-                        live, jnp.logical_and(v.data, v.validity))
-                    counts.append(jnp.sum(live).astype(jnp.int32))
-                else:
-                    outs = [e.eval_device(ctx) for e in st[1]]
-                    ctx = EvalContext(st[2], outs, num_rows, padded_len,
-                                      scalars, slots)
-                    counts.append(
-                        counts[-1] if counts
-                        else jnp.sum(live).astype(jnp.int32))
+                # a decimal overflow counts for the rows that reached the
+                # stage (traces nothing where nothing collects)
+                with D.masked(jnp, lambda k=live: k):
+                    if st[0] == "filter":
+                        v = st[1].eval_device(ctx)
+                        live = jnp.logical_and(
+                            live, jnp.logical_and(v.data, v.validity))
+                        counts.append(jnp.sum(live).astype(jnp.int32))
+                    else:
+                        outs = [e.eval_device(ctx) for e in st[1]]
+                        ctx = EvalContext(st[2], outs, num_rows,
+                                          padded_len, scalars, slots)
+                        counts.append(
+                            counts[-1] if counts
+                            else jnp.sum(live).astype(jnp.int32))
             arrays = [(c.data, jnp.logical_and(c.validity, live))
                       for c in ctx.columns]
             outs, count = compact_rows(arrays, live, padded_len)
             return outs, count, counts
+
+        @functools.partial(jax.jit, static_argnums=(2,))
+        def kernel(cols, num_rows, padded_len, scalars=()):
+            if not checked:
+                return body(cols, num_rows, padded_len, scalars)
+            with D.collecting() as col:
+                out = body(cols, num_rows, padded_len, scalars)
+            return out + (col.rows(jnp),)
 
         return kernel
 
     def run(self, batch: ColumnarBatch, extra_scalars: tuple = ()):
         cols = [(c.data, c.validity) for c in batch.columns]
         num_rows = jnp.int32(batch.num_rows_raw)
-        return self._fn(cols, num_rows, batch.padded_len,
-                        self._scalars + extra_scalars)
+        out = self._fn(cols, num_rows, batch.padded_len,
+                       self._scalars + extra_scalars)
+        if self._checks:
+            D.defer(out[3], self._checks)
+            out = out[:3]
+        return out
 
 
 def compile_fused_stages(stages, schema: Schema) -> FusedStageKernel:

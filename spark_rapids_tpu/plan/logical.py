@@ -9,7 +9,8 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence, Tuple
 
-from ..types import (BOOL, INT64, DataType, Schema, StructField)
+from ..types import (BOOL, INT64, DataType, DecimalType, Schema,
+                     StructField)
 from ..exprs.base import Alias, ColumnRef, Expression
 
 __all__ = ["LogicalPlan", "LogicalScan", "ParquetScan", "Project", "Filter",
@@ -91,9 +92,19 @@ class AvroScan(ParquetScan):
     """Avro file source (ref GpuAvroScan.scala)."""
 
 
+def _coerced(exprs, child: LogicalPlan) -> list:
+    """Expressions as a node takes them: literals that meet a decimal
+    operand typed as Spark types them (exprs/base.py)."""
+    from ..exprs.base import coerce_decimal_literals
+    schema = child.schema()
+    if not any(isinstance(f.dtype, DecimalType) for f in schema.fields):
+        return list(exprs)
+    return [coerce_decimal_literals(e, schema) for e in exprs]
+
+
 class Project(LogicalPlan):
     def __init__(self, exprs: Sequence[Expression], child: LogicalPlan):
-        self.exprs = list(exprs)
+        self.exprs = _coerced(exprs, child)
         self.children = [child]
 
     def schema(self) -> Schema:
@@ -107,7 +118,7 @@ class Project(LogicalPlan):
 
 class Filter(LogicalPlan):
     def __init__(self, condition: Expression, child: LogicalPlan):
-        self.condition = condition
+        self.condition = _coerced([condition], child)[0]
         self.children = [child]
 
     def schema(self) -> Schema:
@@ -124,8 +135,8 @@ class Aggregate(LogicalPlan):
     def __init__(self, groupings, aggs, child: LogicalPlan,
                  many_groups_hint: bool = False,
                  int_key_cards=None):
-        self.groupings = list(groupings)
-        self.aggs = list(aggs)
+        self.groupings = _coerced(groupings, child)
+        self.aggs = _coerced(aggs, child)
         #: planner knows this aggregate is high-cardinality (e.g. the
         #: inner dedup pass of a DISTINCT expansion groups by the distinct
         #: value): the exec skips its optimistic single-fetch fast path,

@@ -636,8 +636,13 @@ class _Parser:
         if t.kind == "num":
             self.next()
             v = t.val
-            if "." in v or "e" in v.lower():
+            if "e" in v.lower():
                 return ("lit", float(v))
+            if "." in v:
+                # Spark types 0.05 decimal(2,2): a double here, with its
+                # text kept for where it meets a decimal (types.py)
+                from ..types import DecimalText
+                return ("lit", DecimalText(float(v), v))
             return ("lit", int(v))
         if t.kind == "str":
             self.next()

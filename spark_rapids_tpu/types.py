@@ -20,7 +20,8 @@ import numpy as np
 __all__ = [
     "DataType", "IntegerType", "FractionalType", "BOOL", "INT8", "INT16",
     "INT32", "INT64", "FLOAT32", "FLOAT64", "STRING", "BINARY", "DATE",
-    "TIMESTAMP", "NULLTYPE", "DECIMAL64", "DecimalType", "ArrayType",
+    "TIMESTAMP", "NULLTYPE", "DECIMAL64", "DecimalType", "DecimalText",
+    "ArrayType",
     "StructType", "StructField", "MapType", "TypeSig", "TypeEnum",
     "from_arrow", "to_arrow", "from_numpy_dtype",
 ]
@@ -93,18 +94,35 @@ NULLTYPE = _Simple("void", None)
 
 
 class DecimalType(DataType):
-    """Decimal held as the SCALED UNSCALED-int64 value on device, for
-    every declared precision up to 38.
+    """Decimal held as its UNSCALED value in int64 lanes on the device,
+    for every declared precision up to 38.
 
     The reference's decimal128 path is cudf's 128-bit columns
     (DecimalUtils JNI, SURVEY.md 2.12). The TPU has no native int128, so
-    the engine stores the unscaled value in int64 lanes — exact for
+    the engine stores the unscaled value in int64 lanes: exact for
     magnitudes up to ~9.2e18 unscaled (19 significant digits; every TPC
-    money column fits) and a LOUD ingest error beyond that
-    (ColumnarBatch.from_arrow's checked cast). Aggregation does NOT rely
-    on int64 intermediates: SUM accumulates in three 10^12-base limbs
-    (exprs/aggregates.py Sum), so 38-digit-wide running totals stay
-    exact and only the final value must be representable."""
+    money column fits).
+
+    What runs on the lanes (exprs/decimal_rules.py has the rules; PR 28):
+    Spark's result type for every operator (``DecimalPrecision``, with
+    ``adjustPrecisionScale`` beyond 38 digits); add, subtract, multiply,
+    compare, ``between`` and casts with both operands brought to one
+    scale and HALF_UP wherever a scale is cut; an integer or SQL decimal
+    literal typed by its own digits beside a decimal operand; SUM and AVG
+    accumulated in three 10^12-base limbs, so 38-digit-wide running
+    totals stay exact, AVG divided and rounded on the device as Spark
+    rounds it (twice). Decimal divide, remainder and pmod run on the
+    host engine, on Python ints (Spark's scale of a quotient soon leaves
+    a lane).
+
+    What does not fit 63 bits is never a wrapped number: a value at
+    ingest (ColumnarBatch.from_arrow's checked cast), a product, a
+    rescale or a finalized total that Spark's decimal(38) would hold
+    raises ``DecimalOverflow``; a value with more digits than its
+    declared type is NULL, as in Spark (non-ANSI). The host engine
+    computes the same expressions on Python ints where int64 cannot, so
+    it returns Spark's number there. No int128 emulation, no ANSI
+    mode."""
 
     def __init__(self, precision: int = 10, scale: int = 0):
         if precision < 1 or precision > 38:
@@ -123,6 +141,23 @@ class DecimalType(DataType):
 
 
 DECIMAL64 = DecimalType(18, 2)
+
+
+class DecimalText(float):
+    """A SQL numeric literal written with a point and no exponent
+    (``0.05``): Spark types it ``decimal(2,2)``. It is kept as the double
+    it always was here, with its text beside it, and becomes a decimal
+    literal only where it meets a decimal operand
+    (exprs/base.py:coerce_decimal_literals): everywhere else it behaves,
+    prints and keys as the float."""
+
+    def __new__(cls, value, text=None):
+        self = float.__new__(cls, value)
+        self.text = str(value) if text is None else text
+        return self
+
+    def __reduce__(self):
+        return (DecimalText, (float(self), self.text))
 
 
 @dataclasses.dataclass(frozen=True)

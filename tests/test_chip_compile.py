@@ -161,13 +161,40 @@ def test_fused_groupby_kernel_q6(session, one_chip):
                           _update_scalars(agg, one_chip)), FAST_LIMIT_S)
 
 
+@pytest.fixture(scope="module")
+def decimal_session():
+    """``lineitem`` with decimal(15,2) money columns (the benchmark's
+    configuration tpch_sf10_decimal), tiny."""
+    import json
+    import os
+    import sys
+    from spark_rapids_tpu.api import TpuSession
+    perfbench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    import datagen
+    with open(os.path.join(perfbench, "configs",
+                           "tpch_sf10_decimal.json")) as f:
+        config = json.load(f)
+    tables = datagen.scaled_tables(config, 2048)
+    gen = datagen.load_module("generators", config["generator"])
+    s = TpuSession({"spark.rapids.tpu.sql.optimizer.enabled": False})
+    s.create_dataframe(gen.generate("lineitem", tables, 7, 0, 2048)) \
+        .create_or_replace_temp_view("lineitem")
+    with open(os.path.join(perfbench, "queries",
+                           "tpch_q1_decimal.sql")) as f:
+        s.q1_text = f.read()
+    return s
+
+
 def _q1_carry(session, one_chip):
     """Q1's aggregate, its carried kernels at G=16 ((3+1)x(2+1) key slots)
     and the carry's shapes on the described chip."""
     import jax
     from benchmarks import queries_sql as Q
     from spark_rapids_tpu.columnar.segmented import bucket_segments
-    agg = _agg_of(session, Q.TPCH_Q1)
+    agg = _agg_of(session, getattr(session, "q1_text", Q.TPCH_Q1))
     assert agg._dict_keys == [0, 1] and agg._direct_keys_ok()
     g = bucket_segments((3 + 1) * (2 + 1))
     fold, tail, _flush = agg._build_carry_kernels(g)
@@ -181,6 +208,21 @@ def test_direct_onehot_update_kernel_q1(session, one_chip):
     dispatch a batch: two dictionary-coded string keys grouped by direct
     one-hot addressing, eight aggregates, fused filter, and the batch's
     partials merged into the running ones slot onto slot."""
+    _compile_q1_fold(session, one_chip)
+
+
+def test_direct_onehot_update_kernel_q1_decimal(decimal_session, one_chip):
+    """The same ``fold`` over decimal(15,2) columns: int64 lanes, checked
+    products (leading-zero count + unsigned multiply), three-limb sums,
+    and the flagged-row count as one more int64 row of the carry."""
+    agg = _compile_q1_fold(decimal_session, one_chip)
+    assert agg._decimal_checks >= 2 + 7      # two products, seven finals
+    from spark_rapids_tpu.types import DecimalType
+    assert sum(isinstance(f.dtype, DecimalType)
+               for f in agg.output_schema().fields) == 7
+
+
+def _compile_q1_fold(session, one_chip):
     import jax
     agg, g, fold, _tail, carry = _q1_carry(session, one_chip)
     in_schema = agg.children[0].output_schema()
@@ -198,6 +240,7 @@ def test_direct_onehot_update_kernel_q1(session, one_chip):
                         sds((), np.int32), BATCH, cards,
                         _update_scalars(agg, one_chip), pairs, remaps),
              FAST_LIMIT_S)
+    return agg
 
 
 def test_direct_carry_tail_kernel_q1(session, one_chip):
@@ -206,6 +249,18 @@ def test_direct_carry_tail_kernel_q1(session, one_chip):
     fetch."""
     import jax
     _agg, _g, _fold, tail, carry = _q1_carry(session, one_chip)
+    _compile(tail.lower(carry, jax.ShapeDtypeStruct((2,), np.int32,
+                                                    sharding=one_chip)),
+             FAST_LIMIT_S)
+
+
+def test_direct_carry_tail_kernel_q1_decimal(decimal_session, one_chip):
+    """``tail`` over the decimal carry: the limb totals made int64, the
+    averages divided and rounded HALF_UP twice on the device (long
+    division over base-10^6 digits), the overflow count packed after the
+    group count."""
+    import jax
+    _agg, _g, _fold, tail, carry = _q1_carry(decimal_session, one_chip)
     _compile(tail.lower(carry, jax.ShapeDtypeStruct((2,), np.int32,
                                                     sharding=one_chip)),
              FAST_LIMIT_S)

@@ -310,13 +310,14 @@ def test_every_fetch_happens_inside_a_d2h_span(monkeypatch):
                 (name, span)
 
 
-def _agg_trace(table, key):
+def _agg_trace(table, key, value=None):
     """One traced run, in a session of its own, of a two-aggregate
     group-by of ``table`` (6 batches) by ``key``: (spans, counters, the
     aggregate's operator metrics)."""
     s = tpu_session(_OPERATOR_CONF)
+    v = F.col("v") if value is None else value
     df = s.create_dataframe(table, num_partitions=6).group_by(key).agg(
-        F.sum(F.col("v")).with_name("sv"), F.avg(F.col("v")).with_name("av"))
+        F.sum(v).with_name("sv"), F.avg(v).with_name("av"))
     tr = install_tracer(Tracer())
     try:
         assert df.collect_arrow().num_rows > 0
@@ -362,6 +363,51 @@ def test_direct_aggregate_is_one_fetch_a_query():
     assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
         [{"batches": 0, "flushes": 0}]
     assert agg_m["updateDispatches"] > 7, agg_m
+
+
+def test_decimal_aggregate_is_one_fetch_and_counts_its_checks():
+    """The same 6-batch direct aggregate over decimal lanes: still ONE
+    fetch a query and 6 + 1 dispatches (the flagged-row count rides in the
+    carry and in the packed result), the ``decimal.checked`` counter is
+    written once beside ``agg.carry`` with the operations checked (the
+    product, traced once for each of the two aggregates that read it, and
+    the two finalizers) and no overflowed row, and the int64 ->
+    decimal128 build of the result is a ``d2h.decimal.finish`` span, one
+    per decimal column, billed as fetch time."""
+    import decimal
+    n = 6000
+    money = pa.array([decimal.Decimal(int(x)).scaleb(-2)
+                      for x in np.arange(n) * 37 % 100003],
+                     pa.decimal128(15, 2))
+    t = pa.table({"k": pa.array(np.array(["a", "b", "c"], dtype=object)
+                                [np.arange(n) % 3]),
+                  "v": money, "w": money})
+    spans, counters, agg_m = _agg_trace(t, "k", F.col("v") * F.col("w"))
+    fetches = [e["name"] for e in spans if e["name"].startswith("d2h")
+               and e["name"].endswith(".transfer")]
+    assert fetches.count("d2h.agg.transfer") == 1
+    assert "d2h.decimal.transfer" not in fetches
+    assert not [e for e in spans if e["name"].startswith("d2h.groups")]
+    assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
+        [{"batches": 6, "flushes": 0}]
+    assert agg_m["updateDispatches"] == 7, agg_m
+    assert [e["args"] for e in counters if e["name"] == "decimal.checked"] \
+        == [{"ops": 4, "overflow_rows": 0}]
+    finish = [e for e in spans if e["name"] == "d2h.decimal.finish"]
+    assert len(finish) >= 2 and all(e["cat"] == "transfer" for e in finish)
+
+    # a projection's checks reach the sink: one small fetch of its own
+    s = tpu_session(_OPERATOR_CONF)
+    tr = install_tracer(Tracer())
+    try:
+        s.create_dataframe(t).select(
+            (F.col("v") * F.col("w")).alias("p")).collect_arrow()
+    finally:
+        install_tracer(None)
+    assert [e["args"] for e in tr.snapshot() if e["ph"] == "C"
+            and e["name"] == "decimal.checked"] == \
+        [{"ops": 1, "overflow_rows": 0}]
+    assert [e for e in _xs(tr) if e["name"] == "d2h.decimal.transfer"]
 
 
 def test_spans_of_a_query_share_its_ordinal():
