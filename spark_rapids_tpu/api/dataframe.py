@@ -68,7 +68,8 @@ class TpuSession:
         self._views: dict = {}
         from ..aux.profiler import Profiler
         self.profiler = Profiler(self.conf)
-        #: per-query runtime summary (ref GpuTaskMetrics accumulators)
+        #: the last query's runtime summary (ref GpuTaskMetrics
+        #: accumulators); "operators" holds that query's operators only
         self.last_query_metrics = None
         #: rotating query-history log (ref spark.eventLog.*), None when
         #: spark.rapids.tpu.eventLog.enabled is off
@@ -182,6 +183,12 @@ class TpuSession:
         return self
 
     def exec_context(self) -> ExecContext:
+        """The session's one context: it holds the semaphore and the
+        memory manager every query of the session shares. A query run
+        through a sink (``collect_arrow`` ...) gets a context of its own
+        under this one (``_execute_query``); a plan executed on THIS
+        context directly keeps its metrics and broadcast relations here
+        until ``close()``."""
         if self._ctx is None:
             self._ctx = ExecContext(self.conf)
         return self._ctx
@@ -829,14 +836,16 @@ class DataFrame:
         from ..aux.metrics import TaskMetrics
         from ..columnar.batch import SpeculativeOverflow
         physical = lore_wrap(physical, run_conf or self.session.conf)
-        ctx = self.session.exec_context()
-        if run_conf is not None:
-            # batch targets are consumed at EXEC time through ctx.conf
-            # (exec/basic.py), so a feedback overlay needs a context
-            # carrying it — sharing the session context's memory manager
-            # and semaphore so budgets/permits stay per-process
-            ctx = ExecContext(run_conf, semaphore=ctx.semaphore,
-                              memory=ctx.memory)
+        # the query's own context: its operator metrics, cleanups,
+        # broadcast relations, speculations and OOM bookkeeping start
+        # empty and die in the finally below, so a query costs the same
+        # on a session's first day and on its thousandth query and two
+        # threads on one session never see each other's. The session's
+        # context lends its semaphore and memory manager (budgets and
+        # permits stay per-process). A feedback overlay rides the same
+        # way: batch targets are consumed at EXEC time through ctx.conf
+        # (exec/basic.py)
+        ctx = ExecContext(run_conf, parent=self.session.exec_context())
         from ..metrics import registry as metrics_registry
         mreg0 = metrics_registry.REGISTRY   # installed by the ctx above
         if mreg0 is not None and placement_summary is not None:
@@ -847,9 +856,7 @@ class DataFrame:
                     mreg0.counter("srtpu_placement_fallback_total",
                                   code=code, op=op).inc(n)
         side_effects = isinstance(self.plan, L.WriteFile)
-        ctx.speculations.clear()
-        ctx.speculate = (ctx.conf.join_speculative_sizing
-                         and not side_effects)
+        ctx.speculate = ctx.speculate and not side_effects
         prof = self.session.profiler
         tm = TaskMetrics(ctx)
         prof.maybe_start()
@@ -932,8 +939,6 @@ class DataFrame:
         from ..mem.semaphore import QueryTimeout
         qt = float(self.session.conf.get(QUERY_TIMEOUT))
         ctx.set_query_deadline(_time.monotonic() + qt if qt > 0 else None)
-        ctx.take_oom_degradations()          # per-query reset
-        ctx.take_ladder_rung()               # per-query reset
         degs: List[dict] = []
 
         from ..exprs import decimal_rules
@@ -1057,13 +1062,19 @@ class DataFrame:
             ctx.set_query_deadline(None)
             degs = ctx.take_oom_degradations()
             ladder_rung = ctx.take_ladder_rung()
+            # the query's broadcast relations leave the memory manager
+            # and its cleanups run, whether it returned or raised; its
+            # metrics stay readable below and for EXPLAIN ANALYZE
+            ctx.close()
             prof.maybe_stop()
             self.session.last_query_metrics = tm.finish()
             if qargs is not None:
                 # the query span carries the placement verdict so the
                 # trace alone answers "did this query even touch the
-                # device"
+                # device", and how many operator ids the summary above
+                # walked: this plan's, whatever the session's age
                 qargs["ok"] = ok
+                qargs["metric_execs"] = len(ctx.metrics)
                 if report is not None:
                     qargs["placement"] = report.verdict
             if degs and report is not None:

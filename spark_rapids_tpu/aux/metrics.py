@@ -122,8 +122,9 @@ def metrics_summary(ctx):
     level = str(ctx.conf.get(METRICS_LEVEL)).upper()
     lvl_rank = {"ESSENTIAL": 0, "MODERATE": 1, "DEBUG": 2}
     keep = lvl_rank.get(level, 2)
-    # snapshot the raw VALUES of THIS query now — Metric objects live on
-    # the session-cached context and later queries mutate them
+    # ctx is the query's own (api/dataframe._execute_query): this walks
+    # the operators of ONE plan. Snapshot the raw VALUES all the same —
+    # a caller may hand in a context it goes on executing plans on
     snap = {}
     for exec_id, ms in ctx.metrics.items():
         kept = {name: m.value for name, m in ms.items()

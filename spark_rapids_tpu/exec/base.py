@@ -39,26 +39,81 @@ class Metric:
 
 
 class ExecContext:
-    """Per-query execution context: conf + shared runtime services.
+    """Execution context: conf + the runtime services + ONE query's state.
 
-    Reference analog: the executor-process singletons (GpuSemaphore,
-    RapidsBufferCatalog, GpuTaskMetrics) — scoped per query here since we are
-    a library, not a long-lived executor."""
+    The services (the ``DeviceSemaphore``, the ``MemoryManager``, the
+    installed tracer / metric registry / ops plane / admission / AQE log)
+    are process-wide and shared; reference analog: the executor-process
+    singletons (GpuSemaphore, RapidsBufferCatalog, GpuTaskMetrics). The
+    rest — operator metrics, cleanups, the broadcast cache, speculations,
+    OOM degradations, the ladder rung, the deadline — belongs to one
+    query. A session holds ONE context for the services' sake
+    (``TpuSession.exec_context()``; tests and the mesh path also execute
+    plans on it directly) and ``api/dataframe._execute_query`` runs each
+    query on a context of its own, ``ExecContext(conf, parent=session's)``,
+    which it closes when the query ends: a query's bookkeeping lives as
+    long as the query."""
 
     def __init__(self, conf: Optional[TpuConf] = None, semaphore=None,
-                 memory=None):
+                 memory=None, parent: Optional["ExecContext"] = None):
         from ..mem.semaphore import DeviceSemaphore
         from ..mem.manager import MemoryManager
+        if parent is not None:
+            # one query's context under a session's: the parent's
+            # services, and under the parent's own conf nothing to
+            # install again (the parent's constructor did)
+            conf = conf or parent.conf
+            semaphore = semaphore or parent.semaphore
+            memory = memory or parent.memory
         self.conf = conf or TpuConf()
-        # one conf lookup per query context, never per event: installs
-        # the process tracer iff spark.rapids.tpu.trace.enabled, and the
-        # metric registry (+ sampler) iff spark.rapids.tpu.metrics.enabled
+        if parent is None or self.conf is not parent.conf:
+            self._install_from_conf()
+        from ..config import SEMAPHORE_WEDGE_TIMEOUT_MS, TASK_TIMEOUT
+        self.memory = memory or MemoryManager.get(self.conf)
+        self.semaphore = semaphore or DeviceSemaphore(
+            self.conf.concurrent_tpu_tasks,
+            timeout_s=float(self.conf.get(TASK_TIMEOUT)),
+            wedge_timeout_ms=int(self.conf.get(SEMAPHORE_WEDGE_TIMEOUT_MS)),
+            memory=self.memory)
+        #: exec id -> {name: Metric}: the operators executed on THIS
+        #: context (one query's, where _execute_query made the context)
+        self.metrics: Dict[str, Dict[str, Metric]] = {}
+        self._cleanups = []
+        #: BroadcastExchangeExec id -> SpillableBatch: relations built
+        #: once and held until close() (shuffle/broadcast.py)
+        self._broadcast_cache: Dict[str, object] = {}
+        #: query-lifecycle cooperative deadline (time.monotonic instant,
+        #: None = no timeout); checked per produced batch and polled by
+        #: semaphore waits (api/dataframe.py sets it per query)
+        self.deadline: Optional[float] = None
+        self._oom_lock = threading.Lock()
+        #: runtime OOM_PRESSURE_HOST degradations recorded by the retry
+        #: ladder (mem/retry.py): [{"op", "detail"}, ...]; drained at the
+        #: query's end by api/dataframe._execute_query
+        self.oom_degradations: List[dict] = []  # tpulint: guarded-by _oom_lock
+        #: highest OOM-escalation rung any ladder reached this query
+        #: (1 retry / 2 split / 3 pressure spill / 4 host degradation);
+        #: drained per query next to oom_degradations — the queryEnd
+        #: record, /queries and the regression sentinel all read it
+        self.max_ladder_rung = 0  # tpulint: guarded-by _oom_lock
+        #: speculative output sizing (joins skip the count->host sync and
+        #: guess the bucket); the FINAL sink calls check_speculations() once
+        self.speculate = self.conf.join_speculative_sizing
+        #: [(device total, capacity, join stat key), ...]
+        self.speculations = []
+
+    def _install_from_conf(self) -> None:
+        """The process-wide installs a conf asks for, each install-once
+        and a conf lookup or two when already done."""
+        # installs the process tracer iff spark.rapids.tpu.trace.enabled,
+        # and the metric registry (+ sampler) iff
+        # spark.rapids.tpu.metrics.enabled
         trace_core.ensure_tracer_from_conf(self.conf)
         from ..metrics import registry as metrics_registry
         metrics_registry.ensure_metrics_from_conf(self.conf)
         # persistent executable tier: point jax's compilation cache at
-        # the conf'd dir + trim to budget (one lookup per query context,
-        # never per kernel — plan/exec_cache.py)
+        # the conf'd dir + trim to budget (never per kernel —
+        # plan/exec_cache.py)
         from ..plan import exec_cache
         exec_cache.configure_from_conf(self.conf)
         # live ops plane: HTTP endpoint, flight recorder, regression
@@ -77,34 +132,6 @@ class ExecContext:
         # off, every decision site is one module load + branch
         from ..aqe import ensure_aqe_from_conf
         ensure_aqe_from_conf(self.conf)
-        from ..config import SEMAPHORE_WEDGE_TIMEOUT_MS, TASK_TIMEOUT
-        self.memory = memory or MemoryManager.get(self.conf)
-        self.semaphore = semaphore or DeviceSemaphore(
-            self.conf.concurrent_tpu_tasks,
-            timeout_s=float(self.conf.get(TASK_TIMEOUT)),
-            wedge_timeout_ms=int(self.conf.get(SEMAPHORE_WEDGE_TIMEOUT_MS)),
-            memory=self.memory)
-        self.metrics: Dict[str, Dict[str, Metric]] = {}
-        self._cleanups = []
-        #: query-lifecycle cooperative deadline (time.monotonic instant,
-        #: None = no timeout); checked per produced batch and polled by
-        #: semaphore waits (api/dataframe.py sets it per query)
-        self.deadline: Optional[float] = None
-        self._oom_lock = threading.Lock()
-        #: runtime OOM_PRESSURE_HOST degradations recorded by the retry
-        #: ladder (mem/retry.py): [{"op", "detail"}, ...]; drained per
-        #: query by api/dataframe._execute_wrapped
-        self.oom_degradations: List[dict] = []  # tpulint: guarded-by _oom_lock
-        #: highest OOM-escalation rung any ladder reached this query
-        #: (1 retry / 2 split / 3 pressure spill / 4 host degradation);
-        #: drained per query next to oom_degradations — the queryEnd
-        #: record, /queries and the regression sentinel all read it
-        self.max_ladder_rung = 0  # tpulint: guarded-by _oom_lock
-        #: speculative output sizing (joins skip the count->host sync and
-        #: guess the bucket); the FINAL sink calls check_speculations() once
-        self.speculate = self.conf.join_speculative_sizing
-        #: [(device total, capacity, join stat key), ...]
-        self.speculations = []
 
     # --------------------------------------------- query-lifecycle control
     def set_query_deadline(self, deadline: Optional[float]) -> None:
@@ -206,14 +233,16 @@ class ExecContext:
         self._cleanups.append(fn)
 
     def close(self) -> None:
+        """Run the registered cleanups and drop the broadcast cache; the
+        operator metrics stay readable (EXPLAIN ANALYZE renders them
+        after the query). Idempotent."""
         fns, self._cleanups = self._cleanups, []
         for fn in fns:
             try:
                 fn()
             except Exception:  # pragma: no cover - best-effort teardown
                 pass
-        if getattr(self, "_broadcast_cache", None):
-            self._broadcast_cache.clear()
+        self._broadcast_cache.clear()
 
     def __del__(self):  # pragma: no cover - GC backstop
         try:

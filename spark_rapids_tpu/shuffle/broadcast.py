@@ -7,7 +7,8 @@ where GpuBroadcastHelper materializes it onto the device once.
 
 TPU-native shape: one process hosts the query, so "broadcast" = build the
 child's result exactly once per query, hold it as a single coalesced batch
-in a per-context cache, and hand the same device-resident batch to every
+in the query's ExecContext until that context closes (the end of the query,
+success or failure), and hand the same device-resident batch to every
 consumer (all stream batches of a broadcast join, multiple joins reusing
 the same exchange — the analog of Spark's reuseExchange). In the
 multi-chip path the batch is replicated across the mesh by the sharding
@@ -28,8 +29,8 @@ __all__ = ["BroadcastExchangeExec"]
 
 class BroadcastExchangeExec(TpuExec):
     """Build-once, consume-many exchange. ``broadcast(ctx)`` returns the
-    single coalesced batch, memoized per ExecContext (the per-query analog
-    of the executor-wide broadcast cache)."""
+    single coalesced batch, memoized in ``ctx`` (the per-query analog of
+    the executor-wide broadcast cache) under this exec's id."""
 
     def __init__(self, child: TpuExec):
         super().__init__([child])
@@ -42,11 +43,12 @@ class BroadcastExchangeExec(TpuExec):
         """The cached relation is held as a SpillableBatch (lowest spill
         priority — broadcast data is cheap to rebuild from host) so its HBM
         footprint stays visible to the memory manager; `get()` migrates it
-        back if it was spilled between consumers."""
+        back if it was spilled between consumers. It lives as long as
+        ``ctx``: ``ctx.close()`` closes it, and ``_execute_query`` closes a
+        query's context in its ``finally`` — a later query plans new exec
+        ids and builds its own relation, so nothing outlives its query."""
         from ..mem.spillable import SpillPriorities
-        cache = getattr(ctx, "_broadcast_cache", None)
-        if cache is None:
-            cache = ctx._broadcast_cache = {}
+        cache = ctx._broadcast_cache
         sb = cache.get(self._exec_id)
         if sb is None:
             size_m = ctx.metric(self._exec_id, "dataSize", ESSENTIAL)

@@ -20,6 +20,12 @@ from spark_rapids_tpu.config import TpuConf
 
 DEFAULT_CONF = {}
 
+#: the operator pipeline (exec/), not the one-program fragment of
+#: parallel/ nor a host placement: a broadcast join stays a broadcast join
+OPERATOR_CONF = {"spark.rapids.tpu.sql.optimizer.enabled": False,
+                 "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+                 "spark.rapids.tpu.distributed.enabled": False}
+
 
 def tpu_session(extra_conf=None, mesh=None) -> TpuSession:
     conf = TpuConf({**DEFAULT_CONF, **(extra_conf or {})})

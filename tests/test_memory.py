@@ -9,7 +9,8 @@ import threading
 import pandas as pd
 import pytest
 
-from harness import assert_tpu_and_cpu_equal, tpu_session
+from harness import (OPERATOR_CONF, assert_tpu_and_cpu_equal,
+                     tpu_session)
 from data_gen import DoubleGen, IntGen, gen_df
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.columnar import ColumnarBatch
@@ -449,6 +450,56 @@ class TestAggregateUnderOOM:
         got = dict(zip(out["k"], out["s"]))
         want = dict(zip(expect["k"], expect["v"]))
         assert got == want
+
+
+# ---------------------------------------------------------------------------
+# a query's broadcast relations live as long as the query (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+class TestBroadcastRelationsDieWithTheirQuery:
+    def _join(self, s):
+        fact = s.create_dataframe(
+            gen_df({"k": IntGen(lo=0, hi=10, nullable=False),
+                    "v": IntGen(nullable=False)}, n=4096))
+        dim = s.create_dataframe(pd.DataFrame(
+            {"k": range(10), "w": [float(i) for i in range(10)]}))
+        joined = fact.join(dim, on="k")
+        tree = joined._physical().tree_string()
+        assert "BroadcastExchange" in tree, tree
+        return joined
+
+    def test_five_join_queries_hold_no_more_than_one(self):
+        s = tpu_session(OPERATOR_CONF)
+        mm = s.exec_context().memory
+        q = self._join(s).group_by("k").agg(
+            F.sum(F.col("w")).with_name("sw"))
+        census = []
+        for _ in range(5):
+            assert q.collect_arrow().num_rows == 10
+            census.append((len(mm.audit_leaks()),
+                           mm.stats()["device_used"]))
+            assert not s.exec_context()._cleanups
+            assert not s.exec_context()._broadcast_cache
+        assert census[-1] <= census[0], census
+        assert census[0][0] == 0, census
+
+    def test_a_query_that_raises_releases_its_relation(self):
+        s = tpu_session(OPERATOR_CONF)
+        mm = s.exec_context().memory
+        held = []
+
+        def boom(pdf):
+            # by now the join has built and registered its relation
+            held.append(len(mm.audit_leaks()))
+            raise ValueError("boom")
+
+        from spark_rapids_tpu.types import INT64
+        q = self._join(s).map_in_pandas(boom, {"k": INT64})
+        with pytest.raises(Exception, match="boom"):
+            q.collect_arrow()
+        assert held and held[0] >= 1, held
+        assert mm.audit_leaks() == []
+        assert not s.exec_context()._cleanups
 
 
 # ---------------------------------------------------------------------------

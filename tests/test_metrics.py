@@ -15,7 +15,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
-from harness import tpu_session
+from harness import OPERATOR_CONF, tpu_session
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.metrics import (MetricRegistry, SAMPLER_THREAD_NAME,
                                       active_registry, install_metrics,
@@ -419,6 +419,138 @@ def test_failed_query_does_not_leave_stale_metrics():
     with pytest.raises(RuntimeError):
         df.collect_arrow()
     assert s.last_query_metrics is None
+
+
+# ---------------------------------------------------------------------------
+# a query's metrics are its own, whatever the session's age (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+def _dim_table(k=7):
+    return pa.table({"k": pa.array(np.arange(k)),
+                     "w": pa.array(np.arange(k, dtype=np.float64) + 1.0)})
+
+
+def _global_aggregate(s, n):
+    return (s.create_dataframe(_small_table(n)).filter(F.col("v") >= 100.0)
+            .agg(F.sum(F.col("v")).with_name("sv")))
+
+
+def _grouped_aggregate(s, n):
+    return (s.create_dataframe(_small_table(n)).group_by("k")
+            .agg(F.sum(F.col("v")).with_name("sv")))
+
+
+def _broadcast_join(s, n):
+    return (s.create_dataframe(_small_table(n))
+            .join(s.create_dataframe(_dim_table()), on="k")
+            .group_by("k")
+            .agg(F.sum(F.col("v") * F.col("w")).with_name("sv")))
+
+
+_SHAPES = {"global_aggregate": _global_aggregate,
+           "grouped_aggregate": _grouped_aggregate,
+           "broadcast_join": _broadcast_join}
+
+
+def _plan_execs(physical):
+    """Every operator of a physical plan, fused ones included."""
+    out = [physical, *getattr(physical, "fused_ops", ())]
+    for c in physical.children:
+        out.extend(_plan_execs(c))
+    return out
+
+
+def _traced_query(df):
+    """Run ``df`` under a tracer of its own; the ``query`` span's args."""
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+    tr = install_tracer(Tracer())
+    try:
+        out = df.collect_arrow()
+    finally:
+        install_tracer(None)
+    (query,) = [e for e in tr.snapshot()
+                if e["ph"] == "X" and e["name"] == "query"]
+    return out, query["args"]
+
+
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_query_metrics_do_not_grow_with_session_age(shape):
+    """300 queries in ONE session: the operators of
+    ``last_query_metrics`` and the ``query`` span's ``metric_execs`` are
+    the plan's own at query 1 and at query 300, and the last query's
+    row counts are its own (it runs over another table than the 299
+    before it)."""
+    s = tpu_session(OPERATOR_CONF)
+    make = _SHAPES[shape]
+    df = make(s, 2000)
+    plan_ids = [type(e).__name__ for e in _plan_execs(df._physical())]
+    if shape == "broadcast_join":
+        assert "BroadcastExchangeExec" in plan_ids, plan_ids
+
+    def check(frame, scan_rows):
+        out, qargs = _traced_query(frame)
+        ops = dict(s.last_query_metrics["operators"])
+        assert sorted(k.split("@")[0] for k in ops) == sorted(plan_ids)
+        assert qargs["metric_execs"] == len(plan_ids) == len(ops)
+        scans = sorted(v["numOutputRows"] for k, v in ops.items()
+                       if k.startswith("InMemoryScanExec"))
+        assert scans[-1] == scan_rows, ops
+        assert all(v["numOutputBatches"] >= 1 for v in ops.values()
+                   if "numOutputBatches" in v), ops
+        return out
+
+    first = check(df, 2000)
+    for _ in range(298):
+        df.collect_arrow()
+    assert len(dict(s.last_query_metrics["operators"])) == len(plan_ids)
+    # query 300 reads 3000 rows: a summary that still held an earlier
+    # query's operators would show a 2000-row scan
+    last = check(make(s, 3000), 3000)
+    assert first.num_rows == last.num_rows
+    # nothing of any query stayed behind on the session's own context
+    ctx = s.exec_context()
+    assert not ctx.metrics and not ctx._cleanups
+    assert not ctx._broadcast_cache
+    s.close()
+
+
+def test_concurrent_queries_keep_their_own_metrics():
+    """Two threads drive queries through ONE session: a query's start
+    must not wipe the metrics of the other thread's query in flight —
+    every finished query's operators carry non-zero batch counts."""
+    s = tpu_session(OPERATOR_CONF)
+    frames = [_grouped_aggregate(s, 2000), _broadcast_join(s, 3000)]
+    wants = [len(_plan_execs(f._physical())) for f in frames]
+    seen, errors = [[], []], []
+    start = threading.Barrier(2)
+
+    def drive(i):
+        try:
+            start.wait(timeout=60)
+            for _ in range(40):
+                frames[i].collect_arrow()
+                # last_query_metrics is the SESSION's, so the other
+                # thread may have replaced it: take whichever query it
+                # is and hold it to that query's own plan below
+                m = s.last_query_metrics
+                if m is not None:
+                    seen[i].append(dict(m["operators"]))
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=drive, args=(i,)) for i in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    assert not errors, errors
+    assert seen[0] and seen[1]
+    for ops in seen[0] + seen[1]:
+        assert len(ops) in wants, ops
+        batches = [v["numOutputBatches"] for v in ops.values()
+                   if "numOutputBatches" in v]
+        assert batches and all(b >= 1 for b in batches), ops
+    s.close()
 
 
 # ---------------------------------------------------------------------------
