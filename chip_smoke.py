@@ -215,8 +215,8 @@ def reference_q3(store_sales, date_dim, item):
 
 
 def gen_string_agg_table(rows: int, seed: int):
-    """The string-keyed aggregation input of the distributed rung
-    (benchmarks/distributed_rung.py): 500 distinct keys, one double."""
+    """The string-keyed aggregation input of the SPMD phase: 500
+    distinct keys, one double."""
     import pyarrow as pa
     rng = np.random.RandomState(seed + 2)
     keys = pa.array([f"k{i:03d}" for i in range(500)])
@@ -234,7 +234,7 @@ def reference_string_agg(table):
 
 
 # ---------------------------------------------------------------------------
-# comparisons (bench.py's tolerances: rtol 1e-9 on sums, counts exact)
+# comparisons (rtol 1e-9 on sums, counts exact)
 # ---------------------------------------------------------------------------
 
 RTOL = 1e-9

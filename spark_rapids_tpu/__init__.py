@@ -3,7 +3,7 @@
 A ground-up re-design of the capabilities of the RAPIDS Accelerator for Apache
 Spark (reference: /root/reference, spark-rapids 24.12) for TPU hardware:
 columnar batches are shape-bucketed jax.Arrays in HBM, operators compile to
-XLA computations (jax.numpy / Pallas), distribution rides jax.sharding meshes
+XLA computations (jax.numpy), distribution rides jax.sharding meshes
 with ICI/DCN collectives, and a tiered HBM->host->disk memory runtime provides
 spill + OOM-retry semantics.
 """

@@ -13,10 +13,11 @@ from typing import List, Optional, Sequence, Union
 
 from ..config import TpuConf
 from ..exec.base import ExecContext
+from ..exec.query import audit_leaks, plan_physical, run_query, tenant_of
 from ..exprs.aggregates import AggregateExpression
 from ..exprs.base import Alias, ColumnRef, Expression
 from ..plan import logical as L
-from ..plan.overrides import explain_potential_tpu_plan, plan_query
+from ..plan.overrides import explain_potential_tpu_plan
 from ..types import Schema, from_arrow
 from .functions import Col, _to_expr, col as _col
 
@@ -80,22 +81,20 @@ class TpuSession:
         #: tenant id this session's queries run as — the admission
         #: controller's priority/fairness unit and the memory manager's
         #: quota unit (sched/admission.py; empty conf = anonymous None)
-        from ..sched.admission import TENANT_ID
-        self.tenant = str(self.conf.get(TENANT_ID)) or None
+        self.tenant = tenant_of(self.conf)
         #: fault_stats of the last LocalCluster.execute on this session
         #: (the event log's queryEnd picks it up)
         self.last_fault_stats = None
         #: AqeDecision summaries of the last query (aqe/__init__.py):
         #: a list of {"kind", "detail", "parts", "shuffle"?} dicts for
         #: every adaptive re-planning decision the run recorded —
-        #: explain("analyze") renders them, bench.py counts them per
-        #: rung, queryEnd/clusterQuery records carry the kind->count
+        #: explain("analyze") renders them, queryEnd/clusterQuery
+        #: records carry the kind->count
         self.last_aqe_decisions = None
         #: engine that ran the last materialized query: "device"/"host"
         self.last_placement = None
         #: coded PlacementReport summary of the last planned query
-        #: ({"verdict", "codes", "ops", "estRows"} — plan/tags.py);
-        #: bench.py records it per rung as details[rung]["placement_reasons"]
+        #: ({"verdict", "codes", "ops", "estRows"} — plan/tags.py)
         self.last_placement_report = None
         #: device mesh for distributed execution: explicit, or built from
         #: spark.rapids.tpu.distributed.* conf (the planner lowers
@@ -149,8 +148,7 @@ class TpuSession:
             self._ctx = None
         from ..config import LEAK_DETECTION
         if self.conf.get(LEAK_DETECTION):
-            from ..mem.manager import MemoryManager
-            leaks = MemoryManager.audit_all_leaks()
+            leaks = audit_leaks()
             if leaks:
                 raise AssertionError(
                     f"{len(leaks)} leaked device buffer registration(s) "
@@ -178,17 +176,12 @@ class TpuSession:
         self.profiler = Profiler(self.conf)
         from ..metrics.events import EventLogWriter
         self.event_log = EventLogWriter.from_conf(self.conf)
-        from ..sched.admission import TENANT_ID
-        self.tenant = str(self.conf.get(TENANT_ID)) or None
+        self.tenant = tenant_of(self.conf)
         return self
 
     def exec_context(self) -> ExecContext:
-        """The session's one context: it holds the semaphore and the
-        memory manager every query of the session shares. A query run
-        through a sink (``collect_arrow`` ...) gets a context of its own
-        under this one (``_execute_query``); a plan executed on THIS
-        context directly keeps its metrics and broadcast relations here
-        until ``close()``."""
+        """The session's services (semaphore, memory manager). Plans run
+        on contexts of their own under it: ``exec/query.py``."""
         if self._ctx is None:
             self._ctx = ExecContext(self.conf)
         return self._ctx
@@ -701,55 +694,7 @@ class DataFrame:
         return self.plan.schema().names()
 
     def _physical(self, conf=None):
-        from ..trace import core as trace_core
-        tr = trace_core.TRACER       # single branch when tracing is off
-        if tr is None:
-            return self._plan_physical(conf)
-        with tr.span("plan.physical", cat="plan"):
-            return self._plan_physical(conf)
-
-    def _plan_physical(self, conf):
-        return plan_query(self.plan, conf or self.session.conf,
-                          mesh=getattr(self.session, "mesh", None),
-                          mesh_auto=getattr(self.session, "mesh_is_auto",
-                                            False))
-
-    def _aqe_feedback_conf(self, aqe_log):
-        """Sentinel-history feedback (ISSUE 19, aqe/feedback.py): a
-        digest whose baseline shows repeated rung>=3 escalation or
-        warm-slowdown flags is admitted with an overlay conf — smaller
-        target batches or host placement — BEFORE planning. Returns the
-        overlay conf, or None on the (common) clean-history path."""
-        if aqe_log is None:
-            return None
-        from .. import aqe as aqe_mod
-        conf = self.session.conf
-        if not bool(conf.get(aqe_mod.AQE_FEEDBACK_ENABLED)):
-            return None
-        from ..ops import sentinel as sentinel_mod
-        from ..ops import slo as slo_mod
-        sent = sentinel_mod.SENTINEL
-        if sent is None and slo_mod.TRACKER is None:
-            return None
-        from ..aqe.feedback import plan_feedback
-        from ..metrics.events import plan_digest
-        digest = plan_digest(self.plan)
-        fb = plan_feedback(
-            digest,
-            sent.baselines().get(digest) if sent is not None else None,
-            conf)
-        if fb is None:
-            return None
-        over = conf
-        for k, v in sorted(fb.settings.items()):
-            over = over.set(k, v)
-        try:  # tpulint: never-raise
-            aqe_log.record(aqe_mod.make_decision(
-                aqe_mod.FEEDBACK_REPLAN, detail=fb.reason,
-                parts=len(fb.settings)))
-        except Exception:  # noqa: BLE001 - observability only
-            pass
-        return over
+        return plan_physical(self.session, self.plan, conf)
 
     def _execute_wrapped(self, consume):
         """Every materializing sink goes through here: the query under
@@ -763,15 +708,15 @@ class DataFrame:
         from ..trace import core as trace_core
         tracer, mine = trace_core.query_tracer(self.session.conf)
         if tracer is None:
-            return self._execute_query(consume)
+            return run_query(self.session, self.plan, consume)
         q = next(self.session._query_seq)
         qargs = {}
         out_path = (str(self.session.conf.get(trace_core.TRACE_OUTPUT))
                     if tracer.recording else "")
         try:
             with tracer.span("query", cat="query", args=qargs, q=q):
-                return self._execute_query(consume, q, qargs,
-                                           out_path or None)
+                return run_query(self.session, self.plan, consume, q,
+                                 qargs, out_path or None)
         finally:
             if mine:
                 trace_core.release_query_tracer(tracer)
@@ -788,519 +733,6 @@ class DataFrame:
                     import logging
                     logging.getLogger(__name__).warning(
                         "could not write trace to %s: %s", out_path, e)
-
-    def _execute_query(self, consume, q=None, qargs=None, trace_path=None):
-        """Run the physical plan through the full execution pipeline
-        (explainOnly guard, LORE wrap, profiler, task metrics, fault
-        dumps). Speculative join sizing is reset per query, validated
-        after the consume, and transparently retried with exact sizing
-        on overflow; plans with side effects (file writes) run with
-        speculation OFF so a retry can never duplicate output files.
-        ``q`` / ``qargs``: the ordinal and the args of the open ``query``
-        span (None when tracing is off); ``trace_path``: where
-        ``_execute_wrapped`` writes this query's trace afterwards."""
-        # stale-telemetry guard: a query that RAISES must not leave the
-        # prior run's summary behind for callers to misattribute — and a
-        # non-distributed query must not inherit the last cluster run's
-        # fault stats. Cleared before anything (planning included) can
-        # fail.
-        self.session.last_query_metrics = None
-        self.session.last_fault_stats = None
-        self.session.last_placement_report = None
-        self.session.last_aqe_decisions = None
-        # closed-loop AQE (ISSUE 19): install the decision log up front
-        # and mark it, so the finally below can slice out exactly THIS
-        # query's decisions (thread-ident attribution); the feedback
-        # hook may hand back an overlay conf the whole run then uses
-        from .. import aqe as aqe_mod
-        import threading as _threading
-        aqe_log = aqe_mod.ensure_aqe_from_conf(self.session.conf)
-        aqe_mark = aqe_log.mark() if aqe_log is not None else 0
-        run_conf = self._aqe_feedback_conf(aqe_log)
-        physical = self._physical(run_conf)
-        report = getattr(physical, "placement_report", None)
-        # one summary, three consumers (session attribute, queryStart
-        # record, metric increments) — computed once
-        placement_summary = (report.summary() if report is not None
-                             else None)
-        self.session.last_placement_report = placement_summary
-        if self.session.conf.is_explain_only:
-            raise RuntimeError("session is in explainOnly mode")
-        # re-install this query's per-expression disables for the runtime
-        # device/host checks: planning by another session in between must
-        # not leak its conf into this execution (thread-local set)
-        from ..plan.op_confs import install_from_conf
-        install_from_conf(self.session.conf)
-        from ..aux.fault import DeviceDumpHandler
-        from ..aux.lore import lore_wrap
-        from ..aux.metrics import TaskMetrics
-        from ..columnar.batch import SpeculativeOverflow
-        physical = lore_wrap(physical, run_conf or self.session.conf)
-        # the query's own context: its operator metrics, cleanups,
-        # broadcast relations, speculations and OOM bookkeeping start
-        # empty and die in the finally below, so a query costs the same
-        # on a session's first day and on its thousandth query and two
-        # threads on one session never see each other's. The session's
-        # context lends its semaphore and memory manager (budgets and
-        # permits stay per-process). A feedback overlay rides the same
-        # way: batch targets are consumed at EXEC time through ctx.conf
-        # (exec/basic.py)
-        ctx = ExecContext(run_conf, parent=self.session.exec_context())
-        from ..metrics import registry as metrics_registry
-        mreg0 = metrics_registry.REGISTRY   # installed by the ctx above
-        if mreg0 is not None and placement_summary is not None:
-            # per-query fallback accounting (the qualification feed):
-            # one increment per (reason code, operator) tag occurrence
-            for op, codes in sorted(placement_summary["ops"].items()):
-                for code, n in sorted(codes.items()):
-                    mreg0.counter("srtpu_placement_fallback_total",
-                                  code=code, op=op).inc(n)
-        side_effects = isinstance(self.plan, L.WriteFile)
-        ctx.speculate = ctx.speculate and not side_effects
-        prof = self.session.profiler
-        tm = TaskMetrics(ctx)
-        prof.maybe_start()
-        elog = self.session.event_log
-        qid = digest = None
-
-        def _resolve_digest():
-            # the planner already hashed the pre-rewrite tree when the
-            # optimizer ran (overrides.plan_query attaches it) — re-hash
-            # only when it didn't. ONE resolution chain for both
-            # consumers (queryStart record, record_plan_compiled):
-            # lookup and record must agree on the digest.
-            d = getattr(physical, "plan_digest", None)
-            if d is None:
-                from ..metrics.events import plan_digest
-                d = plan_digest(self.plan)
-            return d
-
-        # live ops plane (ISSUE 15): one module-global load + branch per
-        # consumer when nothing is configured — the trace/metrics
-        # disabled-path contract
-        from ..ops import flight as flight_mod
-        from ..ops import sentinel as sentinel_mod
-        from ..ops import server as ops_server_mod
-        from ..ops import slo as slo_mod
-        frec = flight_mod.RECORDER
-        sentinel = sentinel_mod.SENTINEL
-        slo = slo_mod.TRACKER
-        _srv = ops_server_mod.SERVER
-        tracker = _srv.tracker if _srv is not None else None
-        if (elog is not None or tracker is not None or frec is not None
-                or sentinel is not None or slo is not None):
-            # a traced query's id is its span's ordinal
-            qid = q if q is not None else next(self.session._query_seq)
-            digest = _resolve_digest()
-        if elog is not None:
-            elog.write({"event": "queryStart", "queryId": qid,
-                        "planDigest": digest,
-                        "root": type(self.plan).__name__,
-                        # coded placement summary: what tools/qualify
-                        # mines across the history (docs/placement.md)
-                        "placement": placement_summary,
-                        "conf": {k: str(v) for k, v
-                                 in sorted(self.session.conf.raw.items())}})
-        track_tok = None
-        if tracker is not None:
-            track_tok = tracker.begin(
-                qid, digest, (placement_summary or {}).get("verdict"),
-                root=type(self.plan).__name__,
-                tenant=self.session.tenant)
-        if frec is not None:
-            # anomaly dumps fired from THIS thread (semaphore wedge, OOM
-            # ladder) carry the in-flight query's digest + coded report
-            frec.set_query({"queryId": qid, "planDigest": digest,
-                            "placement": placement_summary})
-        import time as _time
-        # executable-cache counters around the run: zero in-process
-        # misses AND zero backend-compile seconds = a COMPILE-FREE run,
-        # the only kind the cost model learns walls from (plan/cost.py
-        # record_engine_wall / record_op_wall exec-cache-hit keying)
-        from ..plan import exec_cache
-        cache_before = exec_cache.stats()
-        # warm-digest recompile detector (ops/flight.py): this digest's
-        # executables were vouched warm — any backend-compile seconds
-        # the run pays anyway is an anomaly worth a bundle
-        was_warm = (frec is not None and digest is not None
-                    and exec_cache.plan_digest_cached(digest))
-        # bundle census before the run: any bundle beyond this count was
-        # written DURING this query, so an SLO exemplar can link to it
-        bundles_before = (len(frec.stats()["bundles"])
-                          if frec is not None else 0)
-        # ---------------- query-lifecycle controller (ISSUE 14) --------
-        # cooperative deadline: every operator checks it per produced
-        # batch and the semaphore polls it, so a timed-out query unwinds
-        # through the normal exception path (permits released, batches
-        # closed — the zero-leak audit holds)
-        from ..config import QUERY_TIMEOUT
-        from ..mem.manager import (OutOfDeviceMemory, RetryOOM,
-                                   SplitAndRetryOOM)
-        from ..mem.semaphore import QueryTimeout
-        qt = float(self.session.conf.get(QUERY_TIMEOUT))
-        ctx.set_query_deadline(_time.monotonic() + qt if qt > 0 else None)
-        degs: List[dict] = []
-
-        from ..exprs import decimal_rules
-
-        def _consume_checked(p):
-            """The sink, then the decimal overflow counts its kernels
-            left for it (exprs/decimal_rules.py): nothing to fetch where
-            no projection or filter checked a decimal; a row that left
-            the 64-bit lane is the loud error, never a wrapped number."""
-            decimal_rules.clear_pending()
-            out = consume(p, ctx)
-            decimal_rules.settle_pending()
-            return out
-
-        def _attempt(p):
-            """One full run of the plan through the execution pipeline,
-            with the speculative-sizing overflow retry inside (plans
-            with side effects run with speculation off, so this inner
-            retry can never duplicate output files)."""
-            try:
-                out = DeviceDumpHandler(self.session.conf).wrap(
-                    lambda: _consume_checked(p), p)
-                ctx.check_speculations()
-                return out
-            except SpeculativeOverflow:
-                ctx.speculate = False
-                ctx.speculations.clear()
-                ctx.metrics.clear()
-                return DeviceDumpHandler(self.session.conf).wrap(
-                    lambda: _consume_checked(p), p)
-
-        def _note_timeout():
-            from ..metrics import registry as _mr
-            if _mr.REGISTRY is not None:
-                _mr.REGISTRY.counter("srtpu_query_timeout_total").inc()
-            if frec is not None:
-                frec.trigger(
-                    "query_timeout",
-                    detail=f"query {qid if qid is not None else '?'} "
-                           f"(digest {digest or '?'}) cancelled by "
-                           "spark.rapids.tpu.query.timeout")
-
-        # ------------- multi-tenant admission front door (ISSUE 18) ----
-        # one module-global load + branch when admission is off; with a
-        # controller installed the query queues HERE — before any device
-        # work — so an overloaded or pressure-degraded process refuses
-        # work with a structured AdmissionRejected (retry-after hint)
-        # instead of piling onto the semaphore
-        from ..sched import admission as adm_mod
-        adm = adm_mod.CONTROLLER
-        adm_ticket = None
-        queued_ms = None
-        admission_status = None
-        tenant = self.session.tenant
-        if tenant is not None:
-            # per-tenant HBM quota attribution for every buffer this
-            # query retains (mem/manager.py census; cleared in finally)
-            from ..sched.admission import TENANT_HBM_SHARE
-            share = float(self.session.conf.get(TENANT_HBM_SHARE))
-            ctx.memory.set_thread_tenant(
-                tenant, int(share * ctx.memory.budget)
-                if share > 0 else 0)
-        t0 = _time.perf_counter()
-        ok = False
-        fail_reason = None
-        try:
-            if adm is not None:
-                if tracker is not None and track_tok is not None:
-                    tracker.admission(track_tok, "queued")
-                from ..sched.admission import TENANT_PRIORITY
-                try:
-                    adm_ticket = adm.admit(
-                        tenant=tenant,
-                        priority=int(
-                            self.session.conf.get(TENANT_PRIORITY)),
-                        deadline=ctx.deadline)
-                except adm_mod.AdmissionRejected:
-                    admission_status = "shed"
-                    if tracker is not None and track_tok is not None:
-                        tracker.admission(track_tok, "shed")
-                    raise
-                admission_status = "admitted"
-                queued_ms = adm_ticket.queued_ms
-                if tracker is not None and track_tok is not None:
-                    tracker.admission(track_tok, "admitted", queued_ms)
-            try:
-                out = _attempt(physical)
-                ok = True
-                return out
-            except (RetryOOM, SplitAndRetryOOM, OutOfDeviceMemory) as e:
-                # an OOM escaped every operator-level retry frame (a
-                # reserve outside any with_retry scope, or a ladder with
-                # host fallback disabled). Side-effecting plans must not
-                # re-run — a retry could duplicate output files.
-                if side_effects:
-                    raise
-                try:
-                    out = self._oom_query_ladder(e, physical, ctx,
-                                                 _attempt, consume)
-                except QueryTimeout:
-                    # raised from inside this handler, so the sibling
-                    # except below never sees it — count it here
-                    _note_timeout()
-                    raise
-                ok = True
-                return out
-            except QueryTimeout:
-                _note_timeout()
-                raise
-        except BaseException as e:
-            # satellite fix (ISSUE 15): the event log only distinguished
-            # ok/exception — a cancelled or failed query now records WHY
-            # (tools/history renders the reason column)
-            fail_reason = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            if adm_ticket is not None:
-                adm.release(adm_ticket)   # idempotent; never raises
-            if tenant is not None:
-                ctx.memory.set_thread_tenant(None)
-            ctx.set_query_deadline(None)
-            degs = ctx.take_oom_degradations()
-            ladder_rung = ctx.take_ladder_rung()
-            # the query's broadcast relations leave the memory manager
-            # and its cleanups run, whether it returned or raised; its
-            # metrics stay readable below and for EXPLAIN ANALYZE
-            ctx.close()
-            prof.maybe_stop()
-            self.session.last_query_metrics = tm.finish()
-            if qargs is not None:
-                # the query span carries the placement verdict so the
-                # trace alone answers "did this query even touch the
-                # device", and how many operator ids the summary above
-                # walked: this plan's, whatever the session's age
-                qargs["ok"] = ok
-                qargs["metric_execs"] = len(ctx.metrics)
-                if report is not None:
-                    qargs["placement"] = report.verdict
-            if degs and report is not None:
-                # runtime pressure degradations join the query's coded
-                # placement report: explain-analyze renderers, the
-                # session summary and the event log all see the operator
-                # that fell back (the only tag recorded AFTER planning)
-                from ..plan.tags import OOM_PRESSURE_HOST, make_tag
-                for d in degs:
-                    report.plan_tags.append(make_tag(
-                        OOM_PRESSURE_HOST, d["detail"], node=d["op"]))
-                placement_summary = report.summary()
-                self.session.last_placement_report = placement_summary
-            from ..metrics import registry as metrics_registry
-            mreg = metrics_registry.REGISTRY
-            wall_s = _time.perf_counter() - t0
-            # PROCESS-global counter delta (the compile_free_since
-            # contract): a concurrent query's compile lands in this
-            # delta too. Both consumers err conservative with it — the
-            # sentinel treats the run as cold (skips, never
-            # false-flags) and warm_recompile is rate-limited — but a
-            # page's compileSeconds can over-attribute under mixed
-            # concurrent traffic, exactly like the learned-cost feeds.
-            compile_s_paid = round(
-                exec_cache.stats()["compile_s"]
-                - cache_before["compile_s"], 4)
-            # one reason for every consumer (event log, /queries): a
-            # failed query carries its exception, a rung-4 degraded one
-            # carries which operators fell back
-            if not ok:
-                reason = fail_reason
-            elif degs:
-                reason = ("degraded: " + "; ".join(
-                    f"{d['op']}: {d['detail']}" for d in degs))[:500]
-            else:
-                reason = None
-            if mreg is not None:
-                mreg.counter("srtpu_queries_total",
-                             status="ok" if ok else "failed").inc()
-                # per-tenant tail accounting (ISSUE 20): the wall lands
-                # in the tenant's histogram lane AND in two mergeable
-                # quantile sketches — per tenant for SLO burn math, per
-                # plan digest (bounded: overflow -> "other") so /slo can
-                # rank digests by tail contribution
-                mtenant = tenant or "default"
-                mreg.histogram("srtpu_query_seconds",
-                               tenant=mtenant).observe(wall_s)
-                mreg.summary("srtpu_query_latency_seconds",
-                             tenant=mtenant).observe(wall_s)
-                if digest is not None:
-                    mreg.summary(
-                        "srtpu_digest_latency_seconds",
-                        digest=mreg.bounded_label(
-                            "srtpu_digest_latency_seconds", "digest",
-                            digest)).observe(wall_s)
-            # one drain for every consumer (session attribute, queryEnd
-            # record, /queries): this thread drove every decision site
-            # of this query, so the thread filter is the attribution
-            aqe_decs = (aqe_log.since(aqe_mark,
-                                      thread=_threading.get_ident())
-                        if aqe_log is not None else [])
-            aqe_summary = (aqe_mod.summarize(aqe_decs)
-                           if aqe_decs else None)
-            self.session.last_aqe_decisions = \
-                [d.summary() for d in aqe_decs] if aqe_decs else None
-            if elog is not None:
-                from ..aux.metrics import metrics_to_json
-                end_rec = {"event": "queryEnd", "queryId": qid,
-                           "planDigest": digest, "ok": ok,
-                           "durationMs": round(wall_s * 1000.0, 3),
-                           # satellite (ISSUE 15): cancellation and
-                           # degradation are first-class outcomes, not
-                           # just "ok": false — the sentinel and
-                           # tools/history read these four directly
-                           "degraded": bool(degs),
-                           "ladderRung": ladder_rung,
-                           # multi-tenant serving fields (ISSUE 18):
-                           # which tenant ran it and the admission
-                           # wait it paid at the front door
-                           "tenant": tenant,
-                           "queuedMs": queued_ms,
-                           "compileSeconds": compile_s_paid,
-                           "placementVerdict": (placement_summary
-                                                or {}).get("verdict"),
-                           "metrics": metrics_to_json(
-                               self.session.last_query_metrics),
-                           "faultStats": self.session.last_fault_stats,
-                           "trace": trace_path}
-                if reason:
-                    end_rec["reason"] = reason
-                if admission_status:
-                    end_rec["admission"] = admission_status
-                if aqe_summary:
-                    # compact kind -> count map (ISSUE 19); the full
-                    # per-decision details ride the session attribute
-                    # and the trace, not every event record
-                    end_rec["aqe"] = aqe_summary
-                if degs:
-                    # queryStart already shipped the plan-time summary;
-                    # degradations are runtime facts, so the END record
-                    # carries them (and the refreshed placement summary
-                    # tools/qualify prefers when present)
-                    end_rec["oomDegradations"] = degs
-                    end_rec["placement"] = placement_summary
-                elog.write(end_rec)
-            if frec is not None:
-                if was_warm and compile_s_paid > 0:
-                    # warm-digest recompile: the compiled-plan set
-                    # vouched for this digest, yet the run paid real XLA
-                    # compile — a retrace cliff or an evicted tier
-                    frec.trigger(
-                        "warm_recompile",
-                        detail=f"digest {digest} is in the compiled-"
-                               f"plan set but paid {compile_s_paid}s "
-                               "of backend compile")
-                frec.set_query(None)
-            if sentinel is not None and digest is not None:
-                # fold AFTER the event record: the sentinel sees exactly
-                # what a tools/regress replay of this log would see
-                sentinel.fold({"digest": digest,
-                               "wallMs": round(wall_s * 1000.0, 3),
-                               "verdict": (placement_summary
-                                           or {}).get("verdict"),
-                               "rung": ladder_rung, "ok": ok,
-                               "compileS": compile_s_paid})
-            if slo is not None:
-                # SLO fold AFTER the trace write and any flight dump:
-                # an over-target exemplar links the artifacts this very
-                # query produced (the trace above; the newest bundle if
-                # one landed during the run)
-                flight_path = None
-                if frec is not None:
-                    _bundles = frec.stats()["bundles"]
-                    if len(_bundles) > bundles_before:
-                        flight_path = _bundles[-1]
-                slo.observe(tenant=tenant, wall_ms=wall_s * 1000.0,
-                            ok=ok, query_id=qid, digest=digest,
-                            trace_path=trace_path,
-                            flight_path=flight_path)
-            if tracker is not None and track_tok is not None:
-                tracker.end(track_tok, ok=ok,
-                            wall_ms=wall_s * 1000.0, rung=ladder_rung,
-                            reason=reason, degraded=bool(degs),
-                            aqe=aqe_summary)
-            if ok and not side_effects and not degs:
-                # (a degraded run's wall mixes failed attempts and the
-                # emergency host path — never feed it to the cost model)
-                # measured whole-query wall per (shape, engine placement):
-                # the cost optimizer prefers these over its model, so a
-                # mispriced engine choice self-corrects on the next
-                # planning of the same shape (plan/cost._ENGINE_WALLS)
-                from ..plan.cost import plan_signature, record_engine_wall
-
-                def _on_device(n):
-                    # scans and engine-neutral pass-throughs (union,
-                    # limit, branch-align) are shared by both engines;
-                    # any OTHER device exec means the query actually
-                    # touched the accelerator
-                    if n.is_tpu and not n.engine_neutral \
-                            and "Scan" not in type(n).__name__:
-                        return True
-                    return any(_on_device(c) for c in n.children)
-
-                placement = ("device" if _on_device(physical) else "host")
-                #: benchmark/diagnostic surface: which engine actually ran
-                #: the last materialized query on this session
-                self.session.last_placement = placement
-                compile_free = exec_cache.compile_free_since(cache_before)
-                # wall_s, not a fresh perf_counter diff: the elog write
-                # and metrics export above are observability overhead,
-                # not engine time — and a >=1-observation-trusted wall
-                # inflated by them could flip a close arbitration
-                record_engine_wall(plan_signature(self.plan), placement,
-                                   wall_s, compile_free=compile_free)
-                # per-operator self-times -> the learned cost table
-                # (device AND host row costs; metrics/analyze.py)
-                from ..metrics.analyze import record_learned_op_costs
-                record_learned_op_costs(physical, ctx, compile_free)
-                if placement == "device":
-                    # this plan's kernels now live in the executable
-                    # cache tiers: the planner's cache-aware floor
-                    # charges warm repeats dispatch-only (plan/cost.py).
-                    # Only the optimizer reads the digest set, and the
-                    # planner hashes the tree exactly when the optimizer
-                    # runs — with it off (and no event log) don't pay a
-                    # full-tree hash to record a digest nothing reads.
-                    if digest is None:
-                        digest = getattr(physical, "plan_digest", None)
-                    if digest is not None:
-                        exec_cache.record_plan_compiled(digest)
-
-    def _oom_query_ladder(self, err, physical, ctx, attempt, consume):
-        """Query-level OOM escalation — the controller's backstop for an
-        OOM that escaped every operator retry frame (a reserve outside
-        any with_retry scope). Rung A: spill EVERY live session's
-        spillables and re-run the plan once on the device. Rung B
-        (``spark.rapids.tpu.oom.hostFallback.enabled``): re-plan the
-        query onto the host engine and run it under an unbudgeted
-        pressure grant, recorded as a whole-query OOM_PRESSURE_HOST
-        degradation — pressure degrades *placement*, never results."""
-        from ..mem.manager import (MemoryManager, OutOfDeviceMemory,
-                                   RetryOOM, SplitAndRetryOOM)
-        ctx.note_ladder_rung(
-            3, f"query-level pressure spill after {type(err).__name__} "
-               "escaped every operator retry frame")
-        MemoryManager.spill_all_sessions()
-        ctx.memory.spill_everything()    # explicit managers too
-        ctx.metrics.clear()
-        ctx.speculations.clear()
-        try:
-            return attempt(physical)
-        except (RetryOOM, SplitAndRetryOOM, OutOfDeviceMemory) as e2:
-            from ..config import OOM_HOST_FALLBACK_ENABLED
-            if not bool(self.session.conf.get(OOM_HOST_FALLBACK_ENABLED)):
-                raise
-            ctx.record_oom_degradation(
-                "Query", "whole-query host degradation after "
-                f"{type(e2).__name__}: {e2}")
-            host_conf = self.session.conf.set(
-                "spark.rapids.tpu.sql.enabled", False)
-            host_physical = plan_query(self.plan, host_conf)
-            ctx.metrics.clear()
-            ctx.speculations.clear()
-            ctx.speculate = False
-            with ctx.memory.pressure_host_grant():
-                return consume(host_physical, ctx)
 
     def collect_arrow(self):
         return self._execute_wrapped(lambda p, ctx: p.collect(ctx))

@@ -20,7 +20,7 @@ once and executed blind. This package closes the loop:
   (shuffle/exchange.py, exec/joins.py, plan/overrides.py);
 * :mod:`~.feedback` consumes sentinel history so a digest that
   repeatedly hit OOM rung >= 3 — or kept flagging warm-slowdown — is
-  pre-emptively re-planned at admission (api/dataframe.py).
+  pre-emptively re-planned at admission (aqe/feedback.py overlay_conf).
 
 Every decision is an :class:`AqeDecision` with a kind from the CLOSED
 ``DECISION_KINDS`` registry (the plan/tags idiom: unknown kinds raise),

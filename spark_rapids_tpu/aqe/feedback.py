@@ -25,6 +25,11 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..metrics.events import plan_digest
+from ..ops import sentinel as sentinel_mod
+from ..ops import slo as slo_mod
+from . import AQE_FEEDBACK_ENABLED, FEEDBACK_REPLAN, make_decision
+
 #: how many rung>=3 folds / warm-slowdown flags a digest's baseline
 #: must accumulate before feedback re-plans it (2 = "repeatedly":
 #: one bad run can be noise, two is a pattern)
@@ -43,7 +48,8 @@ BATCH_SHRINK_FACTOR = 4
 MIN_BATCH_BYTES = 1 << 20
 MIN_BATCH_ROWS = 4096
 
-__all__ = ["FeedbackPlan", "plan_feedback", "HIGH_RUNG_REPEATS",
+__all__ = ["FeedbackPlan", "plan_feedback", "overlay_conf",
+           "HIGH_RUNG_REPEATS",
            "WARM_SLOWDOWN_REPEATS", "SLO_BREACH_REPEATS",
            "BATCH_SHRINK_FACTOR"]
 
@@ -105,7 +111,6 @@ def plan_feedback(digest: Optional[str], baseline: Optional[dict],
     # repeatedly attributed over-target walls to gets the same
     # pre-emptive batch shrink as a rung offender — smaller batches
     # shorten the longest device occupancy a single query can pin
-    from ..ops import slo as slo_mod
     slo = slo_mod.TRACKER
     if slo is not None:
         breaches = slo.digest_breaches(digest)
@@ -119,3 +124,29 @@ def plan_feedback(digest: Optional[str], baseline: Optional[dict],
                     f"{breaches}x — admitted with batchSizeBytes "
                     f"{cur_b}->{new_b}, batchSizeRows {cur_r}->{new_r}")
     return None
+
+
+def overlay_conf(conf, plan, aqe_log):
+    """The conf a query is admitted with BEFORE planning, where its
+    digest's history (sentinel baseline, SLO breaches) asks for smaller
+    target batches or host placement; None on the common clean-history
+    path. The overlay is recorded as a FEEDBACK_REPLAN decision."""
+    if aqe_log is None or not bool(conf.get(AQE_FEEDBACK_ENABLED)):
+        return None
+    sent = sentinel_mod.SENTINEL
+    if sent is None and slo_mod.TRACKER is None:
+        return None
+    digest = plan_digest(plan)
+    fb = plan_feedback(
+        digest, sent.baselines().get(digest) if sent is not None else None,
+        conf)
+    if fb is None:
+        return None
+    for k, v in sorted(fb.settings.items()):
+        conf = conf.set(k, v)
+    try:  # tpulint: never-raise
+        aqe_log.record(make_decision(FEEDBACK_REPLAN, detail=fb.reason,
+                                     parts=len(fb.settings)))
+    except Exception:  # noqa: BLE001 - observability only
+        pass
+    return conf

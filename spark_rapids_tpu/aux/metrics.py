@@ -122,7 +122,7 @@ def metrics_summary(ctx):
     level = str(ctx.conf.get(METRICS_LEVEL)).upper()
     lvl_rank = {"ESSENTIAL": 0, "MODERATE": 1, "DEBUG": 2}
     keep = lvl_rank.get(level, 2)
-    # ctx is the query's own (api/dataframe._execute_query): this walks
+    # ctx is the query's own (exec/query.run_query): this walks
     # the operators of ONE plan. Snapshot the raw VALUES all the same —
     # a caller may hand in a context it goes on executing plans on
     snap = {}

@@ -47,12 +47,9 @@ class ExecContext:
     singletons (GpuSemaphore, RapidsBufferCatalog, GpuTaskMetrics). The
     rest — operator metrics, cleanups, the broadcast cache, speculations,
     OOM degradations, the ladder rung, the deadline — belongs to one
-    query. A session holds ONE context for the services' sake
-    (``TpuSession.exec_context()``; tests and the mesh path also execute
-    plans on it directly) and ``api/dataframe._execute_query`` runs each
-    query on a context of its own, ``ExecContext(conf, parent=session's)``,
-    which it closes when the query ends: a query's bookkeeping lives as
-    long as the query."""
+    query. A session holds ONE context for the services' sake; product
+    code runs every plan on ``ExecContext(conf, parent=session's)`` and
+    closes it when the plan ends (``exec/query.py``)."""
 
     def __init__(self, conf: Optional[TpuConf] = None, semaphore=None,
                  memory=None, parent: Optional["ExecContext"] = None):
@@ -76,7 +73,7 @@ class ExecContext:
             wedge_timeout_ms=int(self.conf.get(SEMAPHORE_WEDGE_TIMEOUT_MS)),
             memory=self.memory)
         #: exec id -> {name: Metric}: the operators executed on THIS
-        #: context (one query's, where _execute_query made the context)
+        #: context (one query's, where exec/query.py made the context)
         self.metrics: Dict[str, Dict[str, Metric]] = {}
         self._cleanups = []
         #: BroadcastExchangeExec id -> SpillableBatch: relations built
@@ -84,12 +81,12 @@ class ExecContext:
         self._broadcast_cache: Dict[str, object] = {}
         #: query-lifecycle cooperative deadline (time.monotonic instant,
         #: None = no timeout); checked per produced batch and polled by
-        #: semaphore waits (api/dataframe.py sets it per query)
+        #: semaphore waits (exec/query.py sets it per query)
         self.deadline: Optional[float] = None
         self._oom_lock = threading.Lock()
         #: runtime OOM_PRESSURE_HOST degradations recorded by the retry
         #: ladder (mem/retry.py): [{"op", "detail"}, ...]; drained at the
-        #: query's end by api/dataframe._execute_query
+        #: query's end by exec/query.run_query
         self.oom_degradations: List[dict] = []  # tpulint: guarded-by _oom_lock
         #: highest OOM-escalation rung any ladder reached this query
         #: (1 retry / 2 split / 3 pressure spill / 4 host degradation);

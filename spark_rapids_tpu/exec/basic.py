@@ -274,8 +274,7 @@ class TpuProjectExec(TpuExec):
         return DictColumn(codes, col.validity, col.dtype,
                           np.asarray(uniq, dtype=object))
 
-    def _rect_eval(self, expr, col, ordinal: int, width_cap: int,
-                   use_pallas: bool = False):
+    def _rect_eval(self, expr, col, ordinal: int, width_cap: int):
         """One jitted kernel for a whole rect string chain (upper/trim/
         substring/... fused), resolved through the PROCESS-wide
         executable cache keyed on (expr, width, padded, cap): a
@@ -285,7 +284,7 @@ class TpuProjectExec(TpuExec):
         from ..exprs.base import StrVal
         from ..exprs.compiler import compile_rect_chain
         fn = compile_rect_chain(expr, col.width, col.padded_len,
-                                width_cap, use_pallas)
+                                width_cap)
         data, valid = fn(col.data, col.lengths, col.validity)
         if isinstance(data, StrVal):
             return ByteRectColumn(data.bytes_, valid, data.lengths,
@@ -365,13 +364,10 @@ class TpuProjectExec(TpuExec):
                     src = batch.column_by_name(leaf)
                     if isinstance(src, ByteRectColumn) and src.ascii_only:
                         from ..columnar.strrect import RECT_MAX_BYTES
-                        from ..exprs.pallas_rect import pallas_enabled
                         cap = int(ctx.conf.get(RECT_MAX_BYTES))
-                        pls = pallas_enabled(ctx.conf)
                         try:
                             with ctx.semaphore.held():
-                                out[i] = self._rect_eval(expr, src, i,
-                                                         cap, pls)
+                                out[i] = self._rect_eval(expr, src, i, cap)
                             continue
                         except RectUnsupported:
                             # the chain outgrows the width cap: host for

@@ -509,8 +509,7 @@ def compile_fused_stages(stages, schema: Schema) -> FusedStageKernel:
     return FusedStageKernel(stages, schema)
 
 
-def compile_rect_chain(expr, width: int, padded: int, width_cap: int,
-                       use_pallas: bool = False):
+def compile_rect_chain(expr, width: int, padded: int, width_cap: int):
     """Process-wide compiled kernel for a byte-rectangle string chain
     (upper/trim/substring/... fused over [rows, width]). Previously each
     TpuProjectExec held a private kernel dict, so every query — and
@@ -528,13 +527,13 @@ def compile_rect_chain(expr, width: int, padded: int, width_cap: int,
         def fn(bytes_, lengths, validity, e=expr):
             outv = eval_rect_chain(
                 e, DVal(StrVal(bytes_, lengths), validity, STRING),
-                width_cap=width_cap, use_pallas=use_pallas)
+                width_cap=width_cap)
             return outv.data, outv.validity
         return fn
 
     key = exec_cache.fused_key(
         exec_cache.digest_of("rect", expr.key()),
-        (width, padded, width_cap, use_pallas))
+        (width, padded, width_cap))
     return _resolve_cached(key, build, label="rect_chain")
 
 

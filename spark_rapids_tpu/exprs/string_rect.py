@@ -464,11 +464,8 @@ def rect_chain_leaf(e: Expression, schema: Schema) -> Optional[str]:
 
 
 def eval_rect_expr(e: Expression, child: DVal,
-                   width_cap: int = 1 << 20,
-                   use_pallas: bool = False) -> DVal:
-    """Evaluate one rect-supported op over a StrVal-typed DVal (traced).
-    ``use_pallas`` routes the sliding-pattern match family through the
-    hand-written Pallas kernels (exprs/pallas_rect.py)."""
+                   width_cap: int = 1 << 20) -> DVal:
+    """Evaluate one rect-supported op over a StrVal-typed DVal (traced)."""
     from .string_fns import (Contains, EndsWith, Length, Like, Lower, Lpad,
                              Reverse, RLike, Rpad, StartsWith, StringInstr,
                              StringLocate, StringReplace, StringTrim,
@@ -476,33 +473,6 @@ def eval_rect_expr(e: Expression, child: DVal,
                              SubstringIndex, Substring, Upper)
     sv: StrVal = child.data
     v = child.validity
-    if use_pallas:
-        from .pallas_rect import pallas_match
-        if isinstance(e, StartsWith):
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     e.pattern.encode(), "startswith"),
-                        v, BOOL)
-        if isinstance(e, EndsWith):
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     e.pattern.encode(), "endswith"),
-                        v, BOOL)
-        if isinstance(e, Contains):
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     e.pattern.encode(), "contains"),
-                        v, BOOL)
-        if isinstance(e, (Like, RLike)):
-            form, lit = (_like_parts(e.pattern) if isinstance(e, Like)
-                         else _rlike_literal_parts(e.pattern))
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     lit.encode(), form), v, BOOL)
-        if isinstance(e, StringLocate):
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     e.substr.encode(), "locate"),
-                        v, INT32)
-        if isinstance(e, StringInstr):
-            return DVal(pallas_match(sv.bytes_, sv.lengths,
-                                     e.children[1].value.encode(),
-                                     "locate"), v, INT32)
     if isinstance(e, Upper):
         return DVal(_upper(sv), v, STRING)
     if isinstance(e, Lower):
@@ -552,11 +522,9 @@ def eval_rect_expr(e: Expression, child: DVal,
 
 
 def eval_rect_chain(e: Expression, leaf_val: DVal,
-                    width_cap: int = 1 << 20,
-                    use_pallas: bool = False) -> DVal:
+                    width_cap: int = 1 << 20) -> DVal:
     """Evaluate a rect_chain (validated by rect_chain_leaf) bottom-up."""
     if isinstance(e, ColumnRef):
         return leaf_val
-    child = eval_rect_chain(e.children[0], leaf_val, width_cap,
-                            use_pallas)
-    return eval_rect_expr(e, child, width_cap, use_pallas)
+    child = eval_rect_chain(e.children[0], leaf_val, width_cap)
+    return eval_rect_expr(e, child, width_cap)

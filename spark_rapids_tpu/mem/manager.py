@@ -122,7 +122,7 @@ class MemoryManager:
         #: each thread reads/writes only its own slot)
         self._grant = threading.local()
         #: tenant the calling thread's reserves run as (threading.local:
-        #: _execute_wrapped sets it per query from
+        #: exec/query.run_query sets it per query from
         #: spark.rapids.tpu.tenant.*)
         self._tenant = threading.local()
         #: handle -> owning tenant for registered spillables: tenant

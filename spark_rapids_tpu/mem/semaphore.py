@@ -10,7 +10,7 @@ holder/waiter/held-bytes diagnostic, and force-releases permits whose
 holder THREAD is dead — a worker killed while holding the semaphore can
 no longer wedge every later query (counted by
 ``srtpu_semaphore_wedge_total``). Waits also poll the query-lifecycle
-``deadline`` (api/dataframe.py cooperative cancellation), so a timed-out
+``deadline`` (exec/query.py cooperative cancellation), so a timed-out
 query never sits out the full task timeout inside acquire().
 """
 from __future__ import annotations

@@ -153,6 +153,46 @@ class EventLogWriter:
             REGISTRY.counter("srtpu_event_log_records_total").inc()
         return True
 
+    def query_started(self, o) -> None:
+        """The ``queryStart`` record of one ``exec/query.QueryOutcome``."""
+        self.write({"event": "queryStart", "queryId": o.query_id,
+                    "planDigest": o.digest, "root": o.root,
+                    # coded placement summary: what tools/qualify mines
+                    # across the history (docs/placement.md)
+                    "placement": o.placement,
+                    "conf": {k: str(v)
+                             for k, v in sorted(o.conf.raw.items())}})
+
+    def query_ended(self, o) -> None:
+        """The ``queryEnd`` record; the sentinel and tools/history read
+        ``degraded`` / ``ladderRung`` / ``reason`` directly."""
+        from ..aux.metrics import metrics_to_json
+        rec = {"event": "queryEnd", "queryId": o.query_id,
+               "planDigest": o.digest, "ok": o.ok,
+               "durationMs": round(o.wall_ms, 3),
+               "degraded": bool(o.degradations),
+               "ladderRung": o.ladder_rung,
+               "tenant": o.tenant, "queuedMs": o.queued_ms,
+               "compileSeconds": o.compile_s,
+               "placementVerdict": o.verdict,
+               "metrics": metrics_to_json(o.metrics),
+               "faultStats": o.fault_stats, "trace": o.trace_path}
+        if o.reason:
+            rec["reason"] = o.reason
+        if o.admission:
+            rec["admission"] = o.admission
+        if o.aqe:
+            # compact kind -> count map; the per-decision details ride
+            # the session attribute and the trace, not every record
+            rec["aqe"] = o.aqe
+        if o.degradations:
+            # queryStart shipped the plan-time summary; degradations are
+            # runtime facts, so the END record carries them (and the
+            # refreshed summary tools/qualify prefers when present)
+            rec["oomDegradations"] = o.degradations
+            rec["placement"] = o.placement
+        self.write(rec)
+
     def _rotate(self) -> None:
         # re-scan at rotation time: another writer sharing the
         # directory (two sessions, two processes) may have rotated

@@ -267,6 +267,34 @@ class MetricRegistry:
                 return value
         return "other"
 
+    # ------------------------------------------ a query's own accounting
+    def query_started(self, o) -> None:
+        """Per-query fallback accounting (the qualification feed): one
+        increment per (reason code, operator) tag occurrence of the
+        plan-time placement summary."""
+        if o.placement is not None:
+            for op, codes in sorted(o.placement["ops"].items()):
+                for code, n in sorted(codes.items()):
+                    self.counter("srtpu_placement_fallback_total",
+                                 code=code, op=op).inc(n)
+
+    def query_ended(self, o) -> None:
+        """Status count, and the wall into the tenant's histogram lane
+        and two mergeable quantile sketches: per tenant for SLO burn
+        math, per plan digest (bounded: overflow -> "other") so /slo can
+        rank digests by tail contribution."""
+        self.counter("srtpu_queries_total",
+                     status="ok" if o.ok else "failed").inc()
+        tenant = o.tenant or "default"
+        self.histogram("srtpu_query_seconds",
+                       tenant=tenant).observe(o.wall_s)
+        self.summary("srtpu_query_latency_seconds",
+                     tenant=tenant).observe(o.wall_s)
+        if o.digest is not None:
+            name = "srtpu_digest_latency_seconds"
+            self.summary(name, digest=self.bounded_label(
+                name, "digest", o.digest)).observe(o.wall_s)
+
     # ------------------------------------------------------------- read
     def snapshot(self) -> dict:
         """JSON-able {name: {kind, series: [...]}} snapshot plus a

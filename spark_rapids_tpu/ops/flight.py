@@ -149,7 +149,7 @@ class FlightRecorder:
         self.suppressed: Dict[str, int] = {}  # tpulint: guarded-by _lock
         #: paths of every bundle written, oldest first
         self.bundles: List[str] = []         # tpulint: guarded-by _lock
-        #: the in-flight query on THIS thread (set by _execute_wrapped):
+        #: the in-flight query on THIS thread (set by query_started):
         #: {"queryId", "planDigest", "placement", "startedMonotonic"}
         self._query = threading.local()
 
@@ -177,6 +177,34 @@ class FlightRecorder:
 
     def query_context(self) -> Optional[dict]:
         return getattr(self._query, "info", None)
+
+    def query_started(self, o) -> None:
+        """Anomaly dumps fired from THIS thread (semaphore wedge, OOM
+        ladder) carry the in-flight query's digest and coded report."""
+        from ..plan import exec_cache
+        self.set_query({"queryId": o.query_id, "planDigest": o.digest,
+                        "placement": o.placement})
+        o.was_warm = (o.digest is not None
+                      and exec_cache.plan_digest_cached(o.digest))
+        o.bundles_before = len(self.stats()["bundles"])
+
+    def query_ended(self, o) -> None:
+        if o.was_warm and o.compile_s > 0:
+            # the compiled-plan set vouched for this digest, yet the run
+            # paid real XLA compile: a retrace cliff or an evicted tier
+            self.trigger(
+                "warm_recompile",
+                detail=f"digest {o.digest} is in the compiled-"
+                       f"plan set but paid {o.compile_s}s "
+                       "of backend compile")
+        self.set_query(None)
+
+    def bundle_since(self, count: Optional[int]) -> Optional[str]:
+        """The newest bundle, if one was written after the census stood
+        at ``count`` (a query's ``bundles_before``)."""
+        bundles = self.stats()["bundles"]
+        newer = count is not None and len(bundles) > count
+        return bundles[-1] if newer else None
 
     # ------------------------------------------------------------ stats
     def stats(self) -> dict:

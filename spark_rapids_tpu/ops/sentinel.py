@@ -336,6 +336,14 @@ class RegressionSentinel:
             self.save()
         return regs
 
+    def query_ended(self, o) -> None:
+        """Runs AFTER the event record: the sentinel sees exactly what a
+        tools/regress replay of this log would see."""
+        if o.digest is not None:
+            self.fold({"digest": o.digest, "wallMs": round(o.wall_ms, 3),
+                       "verdict": o.verdict, "rung": o.ladder_rung,
+                       "ok": o.ok, "compileS": o.compile_s})
+
     # ------------------------------------------------------------- reads
     def baselines(self) -> Dict[str, dict]:
         with self._lock:

@@ -143,6 +143,15 @@ class QueryTracker:
                 rec["aqe"] = dict(aqe)
             self._recent.append(rec)
 
+    def query_started(self, o) -> None:
+        o.tracker_token = self.begin(o.query_id, o.digest, o.verdict,
+                                     root=o.root, tenant=o.tenant)
+
+    def query_ended(self, o) -> None:
+        self.end(o.tracker_token, ok=o.ok, wall_ms=o.wall_ms,
+                 rung=o.ladder_rung, reason=o.reason,
+                 degraded=bool(o.degradations), aqe=o.aqe)
+
     def snapshot(self) -> dict:
         now = time.monotonic()
         with self._lock:

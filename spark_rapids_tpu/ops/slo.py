@@ -279,6 +279,17 @@ class SloTracker:
         if alert_tenant is not None:
             self._fire_alert(alert_tenant)
 
+    def query_ended(self, o) -> None:
+        """Runs AFTER the trace write and any flight dump: an over-target
+        exemplar links the artifacts this very query produced (its
+        trace; the newest bundle if one landed during the run)."""
+        from .flight import RECORDER as frec
+        self.observe(tenant=o.tenant, wall_ms=o.wall_ms, ok=o.ok,
+                     query_id=o.query_id, digest=o.digest,
+                     trace_path=o.trace_path,
+                     flight_path=(frec.bundle_since(o.bundles_before)
+                                  if frec is not None else None))
+
     def _fold(self, *, tenant: str, wall_ms: float, ok: bool, query_id,
               digest: Optional[str], trace_path: Optional[str],
               flight_path: Optional[str], ts: float) -> Optional[str]:

@@ -409,6 +409,50 @@ def learned_row_cost(kind: str, placement: str):
     return got[1] / got[0]
 
 
+def _on_device(node) -> bool:
+    # scans and engine-neutral pass-throughs (union, limit, branch-align)
+    # are shared by both engines; any OTHER device exec means the query
+    # actually touched the accelerator
+    if node.is_tpu and not node.engine_neutral \
+            and "Scan" not in type(node).__name__:
+        return True
+    return any(_on_device(c) for c in node.children)
+
+
+def learn_from_query(o, plan: L.LogicalPlan, physical, ctx,
+                     cache_before: dict) -> Optional[str]:
+    """Everything the cost model learns from one finished query
+    (``o``: its exec/query.QueryOutcome; ``cache_before``: the
+    ``exec_cache.stats()`` taken before the run). Returns the engine
+    that ran it, or None where nothing is learned: a failed query, a
+    write (never re-priced), or a degraded run, whose wall mixes failed
+    attempts and the emergency host path."""
+    if not o.ok or o.degradations or isinstance(plan, L.WriteFile):
+        return None
+    from . import exec_cache
+    from ..metrics.analyze import record_learned_op_costs
+    placement = "device" if _on_device(physical) else "host"
+    compile_free = exec_cache.compile_free_since(cache_before)
+    # measured whole-query wall per (shape, engine placement): the
+    # optimizer prefers these over its model, so a mispriced engine
+    # choice self-corrects on the next planning of the same shape. The
+    # outcome's wall: the observers that ran since are not engine time
+    record_engine_wall(plan_signature(plan), placement, o.wall_s,
+                       compile_free=compile_free)
+    # per-operator self-times -> the learned row costs (device AND host)
+    record_learned_op_costs(physical, ctx, compile_free)
+    if placement == "device":
+        # this plan's kernels now live in the executable cache tiers:
+        # the cache-aware floor charges warm repeats dispatch-only. Only
+        # the optimizer reads the digest set, and the planner hashes the
+        # tree exactly when the optimizer runs: with it off (and no
+        # observer that asked for a digest) nothing pays a full-tree hash
+        digest = o.digest or getattr(physical, "plan_digest", None)
+        if digest is not None:
+            exec_cache.record_plan_compiled(digest)
+    return placement
+
+
 class RowsAccum:
     """Per-exec output-row accumulator for measured-rows feedback.
 

@@ -269,8 +269,8 @@ def get_or_build_jit(name: str, fn: Callable, **jit_kwargs) -> Callable:
 
 
 def stats() -> Dict[str, float]:
-    """Copy of the process-lifetime cache counters (bench.py diffs
-    these around each rung for the cold/warm compile split)."""
+    """Copy of the process-lifetime cache counters (exec/query.py diffs
+    these around each query for its compile seconds)."""
     with _LOCK:
         return dict(_STATS)
 

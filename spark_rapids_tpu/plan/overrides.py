@@ -55,7 +55,7 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
     install_from_conf(conf)
     # signature of the plan AS THE USER BUILT IT: the execution sink
     # records measured walls under this same pre-rewrite signature
-    # (api/dataframe._execute_wrapped), so lookup and record must agree
+    # (exec/query.run_query), so lookup and record must agree
     wall_sig = plan_signature(plan)
     digest = None
     if conf.get(OPTIMIZER_ENABLED):

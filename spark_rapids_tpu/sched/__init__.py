@@ -1,6 +1,6 @@
 """Multi-tenant scheduling layer (ISSUE 18).
 
-The query-serving front door: every ``_execute_wrapped`` query passes
+The query-serving front door: every ``exec/query.run_query`` query passes
 through the :mod:`.admission` controller before it can touch the device
 semaphore. The subsystem rations *entry* the way the reference stack's
 ``GpuSemaphore`` rations concurrent device tasks — but one level up,

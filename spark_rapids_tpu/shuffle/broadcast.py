@@ -44,7 +44,7 @@ class BroadcastExchangeExec(TpuExec):
         priority — broadcast data is cheap to rebuild from host) so its HBM
         footprint stays visible to the memory manager; `get()` migrates it
         back if it was spilled between consumers. It lives as long as
-        ``ctx``: ``ctx.close()`` closes it, and ``_execute_query`` closes a
+        ``ctx``: ``ctx.close()`` closes it, and ``exec/query.py`` closes a
         query's context in its ``finally`` — a later query plans new exec
         ids and builds its own relation, so nothing outlives its query."""
         from ..mem.spillable import SpillPriorities

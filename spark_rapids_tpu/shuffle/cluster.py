@@ -72,6 +72,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from .. import aqe as aqe_mod
 from ..config import register
+from ..exec.query import run_plan
 from ..trace import core as trace_core
 from .heartbeat import ShuffleHeartbeatEndpoint, ShuffleHeartbeatManager
 from .transport import BlockClient, BlockServer, ShuffleFetchFailed
@@ -333,7 +334,7 @@ def _run_range_map_task(shuffle_id: int, plan_bytes: bytes,
     boundaries = pickle.loads(boundaries_bytes)
     session = TpuSession()
     physical = plan_query(plan, session.conf)
-    table = physical.collect(session.exec_context())
+    table = run_plan(session, physical)
     parts = _range_partition(table, key_name, ascending, nulls_first,
                              boundaries, len(owners))
     return _put_partitions(shuffle_id, parts, owners, map_id=map_id)
@@ -442,7 +443,7 @@ def _run_map_task(shuffle_id: int, plan_bytes: bytes, group_bytes: bytes,
     session = TpuSession()
     from ..plan.overrides import plan_query
     physical = plan_query(plan, session.conf)
-    table = physical.collect(session.exec_context())
+    table = run_plan(session, physical)
     parts = _hash_partition(table, groupings, len(owners))
     return _put_partitions(shuffle_id, parts, owners, map_id=map_id)
 
@@ -465,7 +466,7 @@ def _run_reduce_task(shuffle_id: int, parts: List[int], plan_bytes: bytes,
     reduce_plan.children = [_scan_of(t)]
     session = TpuSession()
     physical = plan_query(reduce_plan, session.conf)
-    out = physical.collect(session.exec_context())
+    out = run_plan(session, physical)
     return serialize_table(out, "lz4")
 
 
@@ -480,7 +481,7 @@ def _run_join_side_task(shuffle_id: int, plan_bytes: bytes,
     keys = pickle.loads(key_bytes)
     session = TpuSession()
     physical = plan_query(plan, session.conf)
-    table = physical.collect(session.exec_context())
+    table = run_plan(session, physical)
     parts = _hash_partition(table, keys, len(owners))
     return _put_partitions(shuffle_id, parts, owners, map_id=map_id)
 
@@ -512,7 +513,7 @@ def _run_join_local_task(shuffle_l: int, shuffle_r: int, parts: List[int],
                      L.LogicalScan([rt], rschema)]
     session = TpuSession()
     physical = plan_query(template, session.conf)
-    table = physical.collect(session.exec_context())
+    table = run_plan(session, physical)
     parts_out = _hash_partition(table, groupings, len(owners))
     return _put_partitions(out_shuffle, parts_out, owners, map_id=map_id)
 
@@ -1487,7 +1488,7 @@ class LocalCluster:
                 elog = getattr(session, "event_log", None)
                 if elog is not None:
                     # distributed queries do not route through the
-                    # session's _execute_wrapped, so they append their
+                    # session's run_query, so they append their
                     # own durable record carrying the fault counters
                     rec = {"event": "clusterQuery",
                            "faultStats": dict(self.fault_stats),
@@ -1610,7 +1611,7 @@ class LocalCluster:
             final = clone
         from ..plan.overrides import plan_query
         physical = plan_query(final, session.conf)
-        return physical.collect(session.exec_context())
+        return run_plan(session, physical)
 
     # -------------------------------------------------------------------
     def _shuffle_scope(self):
@@ -1655,7 +1656,7 @@ class LocalCluster:
             clone.children = [final]
             final = clone
         physical = plan_query(final, session.conf)
-        return physical.collect(session.exec_context())
+        return run_plan(session, physical)
 
     def _sliced_fragments(self, child, n: int):
         """Slice the largest in-memory scan of a fragment row-wise into
@@ -1755,8 +1756,7 @@ class LocalCluster:
         scan_s = L.LogicalScan([sample], fact._schema, columns=fact.columns)
         plan_s = _replace_node(child, fact, scan_s)
         session = getattr(df, "session", None) or TpuSession()
-        out = plan_query(plan_s, session.conf).collect(
-            session.exec_context())
+        out = run_plan(session, plan_query(plan_s, session.conf))
         batch = ColumnarBatch.from_arrow_host(out)
         arr = ColumnRef(order0.expr.name_hint).eval_host(batch)
         if isinstance(arr, pa.ChunkedArray):

@@ -10,8 +10,8 @@ over Spark event logs. Reads a log directory (rotated
 * the slowest queries (``--slowest N``),
 * a deterministic run-over-run regression diff between two logs
   (``--diff OTHER_DIR``), matching queries by plan digest,
-* a metrics-snapshot summary (``--metrics-file snap.json``) over the
-  JSON artifacts bench.py emits per rung.
+* a metrics-snapshot summary (``--metrics-file snap.json``) over a
+  JSON snapshot artifact (metrics/export.py).
 
 Crash tolerance: a crash-truncated (or otherwise undecodable) line is
 skipped and counted, never fatal — the log is written line-at-a-time
@@ -326,8 +326,7 @@ def format_slo(report: dict, source: str = "") -> str:
 
 
 def summarize_metrics_file(path: str) -> str:
-    """Render the KEY_METRICS series of a JSON snapshot artifact (the
-    ``details[rung]["metrics"]`` file bench.py emits)."""
+    """Render the KEY_METRICS series of a JSON snapshot artifact."""
     with open(path, encoding="utf-8") as f:
         doc = json.load(f)
     snap = doc.get("snapshot", doc)

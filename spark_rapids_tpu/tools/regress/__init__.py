@@ -10,10 +10,10 @@ code path with the live check:
   the live sentinel runs per queryEnd — into a deterministic report of
   warm-digest slowdowns, device->host verdict flips and new rung-3+
   escalations, plus the final per-digest baselines;
-* ``--bench BASE.json NEW.json`` diffs two ``BENCH_r*.json`` artifacts
-  into a one-line geomean/placement delta plus per-rung regressions —
-  the same differ bench.py auto-emits after each run, so ladder rounds
-  land with machine-checkable evidence instead of eyeballed geomeans.
+* ``--bench BASE.json NEW.json`` diffs two summary artifacts of the
+  user's own (a ``details`` map of rungs with ``speedup`` and
+  ``placement``) into a one-line geomean/placement delta plus per-rung
+  regressions. The repo's own speed record is ``perfbench/``.
 
 Stdlib-only and deterministic: identical inputs render identical
 bytes. Crash-truncated event-log lines are skipped and counted
@@ -29,8 +29,7 @@ from typing import Dict, List, Optional, Tuple
 __all__ = ["replay_events", "format_replay", "load_bench", "diff_bench",
            "format_bench_delta", "main"]
 
-#: per-rung speedup drop flagged by the bench differ (same threshold as
-#: bench.py's historical regression gate)
+#: per-rung speedup drop flagged by the bench differ
 BENCH_REGRESSION_RATIO = 0.8
 
 
@@ -131,7 +130,7 @@ def format_replay(result: dict, source: str = "",
 def load_bench(path: str) -> dict:
     """Normalize one BENCH artifact to ``{"geomean", "placement_counts",
     "details": {rung: {"speedup", "placement"}}}``. Accepts the raw
-    bench.py summary JSON, the driver-captured ``{"parsed": ..., "tail":
+    summary JSON, a driver-captured ``{"parsed": ..., "tail":
     ...}`` wrapper, and (tail-only) the emitted metric lines."""
     with open(path, encoding="utf-8") as f:
         doc = json.load(f)
@@ -224,7 +223,7 @@ def _fmt_counts(c: dict) -> str:
 
 
 def format_bench_delta(delta: dict, base_name: str = "base") -> str:
-    """The one-line summary bench.py logs after each run."""
+    """The one-line summary of a ``diff_bench`` delta."""
     g = delta["geomean"]
     pc = delta["placement_counts"]
     line = (f"delta vs {base_name}: geomean {_fmt_geo(g['base'])} -> "

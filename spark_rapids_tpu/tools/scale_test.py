@@ -9,8 +9,8 @@ CLI::
         --rows 10000000 --queries q1,q6,q3,q9,q28 --iters 2 \
         --report scale_report.json
 
-Differences from ``bench.py`` (the driver's fixed ladder): scale and query
-set are parameters, every query is verified against the host oracle (not
+Not the benchmark (that is ``perfbench/``): scale and query set are
+parameters, every query is verified against the host oracle (not
 pandas), and the report captures the engine placement the cost optimizer
 chose plus task metrics — the artifact a CI perf job diffs run-over-run.
 """

@@ -83,7 +83,6 @@ def _load_registries():
               "spark_rapids_tpu.plan.rewrites",
               "spark_rapids_tpu.sql.catalog",
               "spark_rapids_tpu.bootstrap",
-              "spark_rapids_tpu.exprs.pallas_rect",
               "spark_rapids_tpu.plan.cost",
               "spark_rapids_tpu.plan.exec_cache",
               "spark_rapids_tpu.plan.stats_store",

@@ -1,6 +1,6 @@
 """Test config: run on the host CPU backend with 8 virtual devices so
 multi-chip sharding tests work without TPU hardware (the driver separately
-dry-runs the multi-chip path; bench.py uses the real chip)."""
+dry-runs the multi-chip path; perfbench/ uses the real chip)."""
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")

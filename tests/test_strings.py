@@ -703,49 +703,6 @@ def test_rect_edgecases_empty_and_all_space():
     assert_tpu_and_cpu_equal(q)
 
 
-def test_pallas_conf_on_tpu_fails_with_the_compilers_words(monkeypatch):
-    """The chip's compiler refuses the match kernel (PR 21): enabling the
-    conf on a TPU backend must say so, not die in a raw lowering error.
-    On the CPU the kernels stay available in interpreter mode."""
-    from spark_rapids_tpu.config import TpuConf
-    from spark_rapids_tpu.exprs import pallas_rect
-    on = TpuConf({"spark.rapids.tpu.sql.pallas.enabled": True})
-    assert pallas_rect.pallas_enabled(on) is True
-    assert pallas_rect.pallas_enabled(TpuConf()) is False
-    monkeypatch.setattr(pallas_rect, "_interpret", lambda: False)  # "tpu"
-    assert pallas_rect.pallas_enabled(TpuConf()) is False
-    with pytest.raises(NotImplementedError) as ei:
-        pallas_rect.pallas_enabled(on)
-    assert "Target does not support this comparison" in str(ei.value)
-    assert "RecursionError" in str(ei.value)
-
-
-def test_pallas_rect_predicates_differential():
-    """r5: the Pallas sliding-match kernels (interpret mode on CPU) must
-    agree with both the XLA rect ops and the host engine."""
-    from spark_rapids_tpu.exprs.pallas_rect import pallas_available
-    if not pallas_available():
-        import pytest
-        pytest.skip("pallas not available")
-    t = _high_card_table(30000, 20000)
-    conf = {"spark.rapids.tpu.sql.pallas.enabled": True}
-
-    def q(s):
-        df = s.create_dataframe(t)
-        return df.select(F.col("s").contains("0123").alias("c"),
-                         F.startswith(F.col("s"), "  Item-0").alias("sw"),
-                         F.endswith(F.col("s"), "x  ").alias("ew"),
-                         F.locate("-00", F.col("s")).alias("lc"),
-                         F.col("s").like("%Item-1%").alias("lk"),
-                         F.col("v"))
-    assert_tpu_and_cpu_equal(q, conf=conf)
-    # and identical to the XLA rect path
-    import pandas as pd
-    a = q(tpu_session(conf)).to_pandas()
-    b = q(tpu_session()).to_pandas()
-    pd.testing.assert_frame_equal(a, b)
-
-
 def test_rect_rlike_literal_routing_differential():
     """r5: RLIKE patterns that are plain (optionally anchored) literals
     run on the rectangle device path; real regexes stay host."""
@@ -769,5 +726,3 @@ def test_rect_rlike_literal_routing_differential():
                          F.rlike(F.col("s"), "xx  $").alias("r3"),
                          F.col("v"))
     assert_tpu_and_cpu_equal(q)
-    assert_tpu_and_cpu_equal(
-        q, conf={"spark.rapids.tpu.sql.pallas.enabled": True})
