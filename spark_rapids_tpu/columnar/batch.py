@@ -516,21 +516,31 @@ class ColumnarBatch:
                 f"cols=[{kinds}], {self.schema})")
 
 
-def _device_concat_compact(counts, cols):
-    """Traced device concat of prefix-packed batches: per batch a liveness
-    mask from its (traced) count, one stable argsort moves live rows to the
-    front, every column gathers through the same permutation. Counts ride
-    as a traced vector so varying row counts never recompile."""
+def _device_concat_packed(counts, cols, out_len):
+    """Traced device concat of prefix-packed batches without a sort: the
+    live rows of batch ``i`` go to offset ``sum(counts[:i])`` by one
+    ``dynamic_update_slice`` a lane, in batch order, so the padding a
+    batch writes past its live rows is overwritten by the next batch's
+    rows. ``counts`` rides as a traced vector (varying row counts never
+    recompile); ``out_len`` is the static output bucket. The workspace is
+    as long as all inputs together, so no update is ever clamped."""
+    import jax
     import jax.numpy as jnp
-    live = jnp.concatenate([
-        jnp.arange(d.shape[0], dtype=jnp.int32) < counts[i]
-        for i, (d, _) in enumerate(cols[0])])
-    perm = jnp.argsort(jnp.logical_not(live), stable=True)
+    offs = jnp.cumsum(counts) - counts
+    work = max(out_len, sum(d.shape[0] for d, _ in cols[0]))
+    lives = [jnp.arange(d.shape[0], dtype=jnp.int32) < counts[i]
+             for i, (d, _) in enumerate(cols[0])]
     out = []
     for per_batch in cols:
-        d = jnp.concatenate([d for d, _ in per_batch])[perm]
-        v = jnp.concatenate([v for _, v in per_batch])[perm]
-        out.append((d, v))
+        d = jnp.zeros(work, per_batch[0][0].dtype)
+        v = jnp.zeros(work, jnp.bool_)
+        for i, (bd, bv) in enumerate(per_batch):
+            at = (offs[i],)
+            d = jax.lax.dynamic_update_slice(
+                d, jnp.where(lives[i], bd, jnp.zeros_like(bd)), at)
+            v = jax.lax.dynamic_update_slice(
+                v, jnp.logical_and(bv, lives[i]), at)
+        out.append((d[:out_len], v[:out_len]))
     return out
 
 
@@ -603,6 +613,7 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
                 return DeviceColumn(outs[0][0], outs[0][1], dt)
             rebuilds.append((1, rebuild))
     total = sum(counts)
+    target = bucket_for(total, buckets)
     if all(c == b.padded_len for c, b in
            zip(counts[:-1], batches[:-1])):
         # every batch but the last is full: plain concatenation is already
@@ -612,6 +623,9 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
                  jnp.concatenate([v for _, v in per]))
                 for per in lane_cols]
     else:
+        # counts known here, every lane 1-D: each batch's live rows land
+        # at a known offset — one small dispatch, no sort, and the output
+        # comes out of the kernel at its bucket
         global _DEVICE_CONCAT_JIT
         # bind to a local: a concurrent exec_cache.clear() may null the
         # memo between the check and the call
@@ -624,10 +638,10 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
             from ..plan import exec_cache
             exec_cache.register_clear_hook(_clear_device_concat)
             concat_fn = _DEVICE_CONCAT_JIT = exec_cache.get_or_build_jit(
-                "columnar.device_concat", _device_concat_compact)
+                "columnar.device_concat", _device_concat_packed,
+                static_argnums=(2,))
         outs = concat_fn(
-            jnp.asarray(np.asarray(counts, np.int32)), lane_cols)
-    target = bucket_for(total, buckets)
+            jnp.asarray(np.asarray(counts, np.int32)), lane_cols, target)
     sized = []
     for d, v in outs:
         if target < d.shape[0]:

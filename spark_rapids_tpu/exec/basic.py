@@ -4,6 +4,7 @@ GpuFilterExec:806, GpuRangeExec:1137; GpuCoalesceBatches.scala:112).
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, Iterator, List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -14,6 +15,7 @@ from ..columnar import (ColumnarBatch, DeviceColumn, HostColumn,
 from ..columnar.bucketing import bucket_for
 from ..exprs.base import Expression
 from ..exprs.compiler import compile_projection, filter_batch_device
+from ..trace import core as trace_core
 from ..types import INT64, Schema, StructField
 from .base import DEBUG, ESSENTIAL, ExecContext, TpuExec
 
@@ -644,7 +646,13 @@ class BranchAlignExec(TpuExec):
 class CoalesceBatchesExec(TpuExec):
     """Concatenate small batches up to a target size (ref
     GpuCoalesceBatches.scala CoalesceGoal/TargetSize; RequireSingleBatch via
-    target_rows=None meaning 'all')."""
+    target_rows=None meaning 'all'). A TargetSize goal is a ceiling: the
+    pending batches leave when the NEXT one would pass it, so the output's
+    bucket is ``bucket_for(target_rows)`` at most. A batch alone in its
+    group passes through as the same object. The merged batch carries the
+    first batch's ``meta`` (``plan/overrides.py`` plans this operator in
+    no plan that reads it). Tracer: a span ``coalesce.concat`` around each
+    concat, a counter ``coalesce.batches`` {in, out} once an execution."""
 
     def __init__(self, child: TpuExec, target_rows: Optional[int] = None,
                  target_bytes: Optional[int] = None):
@@ -656,23 +664,53 @@ class CoalesceBatchesExec(TpuExec):
         return self.children[0].output_schema()
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
-        conf_bytes = self.target_bytes or ctx.conf.batch_size_bytes
-        conf_rows = self.target_rows or ctx.conf.batch_size_rows
-        pending: List[ColumnarBatch] = []
-        rows = 0
-        nbytes = 0
+        single = self.target_rows is None and self.target_bytes is None
+        max_bytes = self.target_bytes or ctx.conf.batch_size_bytes
+        max_rows = self.target_rows or ctx.conf.batch_size_rows
         concat_m = ctx.metric(self._exec_id, "concatTime", DEBUG)
-        for batch in self.children[0].execute(ctx):
-            pending.append(batch)
-            rows += batch.num_rows
-            nbytes += batch.size_bytes()
-            if (self.target_rows is None and self.target_bytes is None):
-                continue  # single-batch goal: concat everything at the end
-            if rows >= conf_rows or nbytes >= conf_bytes:
-                yield concat_batches(pending)
-                pending, rows, nbytes = [], 0, 0
-        if pending:
-            yield concat_batches(pending)
+        pending: List[ColumnarBatch] = []
+        rows = nbytes = n_in = n_out = 0
+
+        def concat() -> ColumnarBatch:
+            with ctx.semaphore.held():
+                return concat_batches(pending)
+
+        def merged() -> ColumnarBatch:
+            if len(pending) == 1:
+                return pending[0]
+            t0 = time.perf_counter()
+            tr = trace_core.TRACER       # single branch when tracing is off
+            if tr is None:
+                out = concat()
+            else:
+                with tr.span("coalesce.concat", cat="exec",
+                             args={"exec": self._exec_id,
+                                   "n": len(pending)}):
+                    out = concat()
+            out.meta = pending[0].meta
+            concat_m.add(time.perf_counter() - t0)
+            return out
+
+        try:
+            for batch in self.children[0].execute(ctx):
+                n_in += 1
+                b_rows, b_bytes = batch.num_rows, batch.size_bytes()
+                if pending and not single and (rows + b_rows > max_rows
+                                               or nbytes + b_bytes > max_bytes):
+                    n_out += 1
+                    yield merged()
+                    pending, rows, nbytes = [], 0, 0
+                pending.append(batch)
+                rows += b_rows
+                nbytes += b_bytes
+            if pending:
+                n_out += 1
+                yield merged()
+        finally:
+            tr = trace_core.TRACER
+            if tr is not None:
+                tr.counter("coalesce.batches", {"in": n_in, "out": n_out},
+                           cat="exec")
 
     def describe(self):
         goal = "RequireSingleBatch" if (self.target_rows is None and

@@ -39,6 +39,7 @@ from ..exprs.compiler import (_compact_kernel, eval_predicate_device,
                               filter_batch_device, gather_batch_device)
 from ..mem import (SpillableBatch, with_retry_no_split,
                    wrap_spillable_sides)
+from ..trace import core as trace_core
 from ..types import BOOL, Schema, StructField
 from .base import ESSENTIAL, ExecContext, TpuExec
 from .encoding import grouping_operands, operands_equal
@@ -51,6 +52,48 @@ _FUSED_CACHE: Dict[Tuple, object] = {}
 _GATHER_CACHE: Dict[Tuple, object] = {}
 #: last observed output total per join shape (feeds speculative sizing)
 _TOTAL_STATS: Dict[Tuple, int] = {}
+
+
+class _OutBound:
+    """What ONE execution of a streaming broadcast join knows about its
+    output size. ``mult`` is the largest multiplicity of a build-side key,
+    None until the first stream batch's count kernel has been read (one
+    small fetch a join a query). For inner joins and the outer join that
+    null-extends the build side, ``total <= n_stream * max(mult, 1)``: at
+    ``mult <= 1`` the stream batch's own bucket is a HARD bound, so the
+    output keeps it, nothing is speculated and nothing validated at the
+    sink (as for semi/anti joins). Above 1 the speculation on the last
+    observed total stays."""
+
+    __slots__ = ("stream_left", "mult")
+
+    def __init__(self, stream_left: bool):
+        self.stream_left = stream_left
+        self.mult: Optional[int] = None
+
+    @property
+    def hard(self) -> bool:
+        return self.mult is not None and self.mult <= 1
+
+
+def _max_count(cnt):
+    """Largest per-group row count of one side, from the count kernel's
+    output as it is: a separate small program, so the sort-bearing
+    kernels' compiled bodies (and their cache entries) stay as they are."""
+    from ..plan import exec_cache
+    return exec_cache.get_or_build_jit(
+        "joins.max_count", lambda c: jnp.max(c).astype(jnp.int32))(cnt)
+
+
+def _count_out_bound(hard: bool) -> None:
+    """Tracer counter ``join.out_bound``, once per streaming broadcast
+    join execution: whether its output buckets were bound hard by the
+    build side's unique keys or sized by speculation."""
+    tr = trace_core.TRACER
+    if tr is not None:
+        tr.counter("join.out_bound", {"hard": int(hard),
+                                      "speculative": int(not hard)},
+                   cat="exec")
 
 
 def _build_count_kernel(lkey_exprs, rkey_exprs, lschema, rschema, join_type):
@@ -592,7 +635,8 @@ class TpuHashJoinExec(TpuExec):
 
     # ------------------------------------------------------------------
     def _join(self, lb: ColumnarBatch, rb: ColumnarBatch,
-              ctx: Optional[ExecContext] = None) -> ColumnarBatch:
+              ctx: Optional[ExecContext] = None,
+              bound: Optional[_OutBound] = None) -> ColumnarBatch:
         if self.join_type == "cross" or not self.left_keys:
             return self._cross(lb, rb)
         if (self.condition is not None and
@@ -627,10 +671,19 @@ class TpuHashJoinExec(TpuExec):
         spec0 = (ctx is not None and ctx.speculate)
         stat0 = _TOTAL_STATS.get(ck)
         all_dev = lb.all_device and rb.all_device
-        if all_dev and spec0 and self.condition is None \
-                and (semi_like or stat0 is not None):
+        # the streaming broadcast path's bound (None elsewhere): its first
+        # batch runs the count kernel on its own, to read the build
+        # side's largest key multiplicity beside the total
+        measure = bound is not None and bound.mult is None
+        stream_p = hard_p = None
+        if bound is not None:
+            stream_p = bucket_for(max(
+                (lb if bound.stream_left else rb).padded_len, 1))
+            hard_p = stream_p if bound.hard else None
+        if all_dev and spec0 and self.condition is None and not measure \
+                and (semi_like or hard_p or stat0 is not None):
             return self._join_fused(ctx, lb, rb, lcols, rcols, ck,
-                                    kern, semi_like, stat0)
+                                    kern, semi_like, stat0, hard_p)
 
         (s_orig, cnt_l, cnt_r, start_l, start_r, pairs, offsets, total,
          num_groups) = kern(lcols, rcols, jnp.int32(lb.num_rows_raw),
@@ -650,6 +703,17 @@ class TpuHashJoinExec(TpuExec):
             # lazy sizing needs no validation at all
             n_out = total
             out_p = bucket_for(max(lb.padded_len, 1))
+        elif hard_p and spec:
+            n_out, out_p = total, hard_p
+        elif measure:
+            n_out, mult = (int(x) for x in traced_device_get(
+                (total, _max_count(cnt_r if bound.stream_left else cnt_l)),
+                "d2h.join_count"))
+            bound.mult = mult
+            _TOTAL_STATS[ck] = n_out
+            # unique build keys: the stream's bucket from the first batch
+            # on, so every batch of the query leaves in one shape
+            out_p = stream_p if bound.hard else bucket_for(max(n_out, 1))
         elif spec and stat is not None:
             # adaptive guess from this join shape's last observed total
             # (x1.5 headroom); validated at the sink, exact re-run on
@@ -682,13 +746,15 @@ class TpuHashJoinExec(TpuExec):
 
     def _join_fused(self, ctx, lb: ColumnarBatch, rb: ColumnarBatch,
                     lcols, rcols, ck, count_kern, semi_like: bool,
-                    stat) -> ColumnarBatch:
+                    stat, hard_p: Optional[int] = None) -> ColumnarBatch:
         fk = _FUSED_CACHE.get(ck)
         if fk is None:
             fk = _build_fused_join_kernel(count_kern, semi_like)
             _FUSED_CACHE[ck] = fk
         if semi_like:
             out_p = bucket_for(max(lb.padded_len, 1))
+        elif hard_p:
+            out_p = hard_p
         else:
             out_p = bucket_for(max(int(stat * 1.5), 1))
         left_nullable = 1 if self.join_type in ("right", "full") else 0
@@ -698,7 +764,7 @@ class TpuHashJoinExec(TpuExec):
         total, louts, routs = fk(lcols, rcols, jnp.int32(lb.num_rows_raw),
                                  jnp.int32(rb.num_rows_raw),
                                  lb.padded_len, rb.padded_len, out_p, cfg)
-        if not semi_like:
+        if not semi_like and not hard_p:
             ctx.speculations.append((total, out_p, ck,
                                      getattr(self, 'plan_sig', None)))
         new_cols = [c.with_arrays(d, v)
@@ -860,10 +926,13 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
     """Equi-join against a broadcast build side (ref
     GpuBroadcastHashJoinExecBase): the build child is a
     BroadcastExchangeExec whose single cached batch is reused across every
-    stream batch — the stream side is NOT coalesced, each incoming batch
-    joins independently. Only join types needing no null-extension (or
-    per-row marks) of the BUILD side across stream batches may stream; the
-    rest take the coalesced whole-sides path."""
+    stream batch — each incoming batch joins independently. The planner
+    fills those batches first where a scan hands them over under-filled
+    (``plan/overrides.py:insert_coalesce``), and a build side with unique
+    keys keeps each output in its stream batch's bucket (:class:`_OutBound`).
+    Only join types needing no null-extension (or per-row marks) of the
+    BUILD side across stream batches may stream; the rest take the
+    coalesced whole-sides path."""
 
     #: join types streamable per build side
     STREAMABLE = {
@@ -932,29 +1001,41 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
                                         op=self._exec_id)
         else:
             bloom = None
+        # a stream row meets at most the build side's largest key
+        # multiplicity of rows where the join emits it once per match or
+        # once null-extended: there the output can be bound by the input
+        bound = None
+        if ctx.speculate and self.join_type in (
+                ("inner", "left") if bi == 1 else ("inner", "right")) \
+                and (self.condition is None or self.join_type == "inner"):
+            bound = _OutBound(stream_left=(bi == 1))
         produced = False
-        for sb in self.children[1 - bi].execute(ctx):
-            sb = sb.ensure_device().with_lists_on_host()
-            def run(sb=sb):
-                with ctx.semaphore.held():
-                    if bloom is not None and sb.num_rows > 0:
-                        sb2 = self._apply_bloom(ctx, bloom, sb)
-                    else:
-                        sb2 = sb
-                    return (self._join(sb2, bb, ctx) if bi == 1
-                            else self._join(bb, sb2, ctx))
-            out = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
-            rows_m.add(out.num_rows_raw)
-            produced = True
-            yield out
-        if not produced:
-            empty = _empty_batch(self.children[1 - bi].output_schema())
+        try:
+            for sb in self.children[1 - bi].execute(ctx):
+                sb = sb.ensure_device().with_lists_on_host()
+                def run(sb=sb):
+                    with ctx.semaphore.held():
+                        if bloom is not None and sb.num_rows > 0:
+                            sb2 = self._apply_bloom(ctx, bloom, sb)
+                        else:
+                            sb2 = sb
+                        return (self._join(sb2, bb, ctx, bound) if bi == 1
+                                else self._join(bb, sb2, ctx, bound))
+                out = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
+                rows_m.add(out.num_rows_raw)
+                produced = True
+                yield out
+            if not produced:
+                empty = _empty_batch(self.children[1 - bi].output_schema())
 
-            def run_empty():
-                with ctx.semaphore.held():
-                    return (self._join(empty, bb, ctx) if bi == 1
-                            else self._join(bb, empty, ctx))
-            yield with_retry_no_split(run_empty, ctx=ctx, op=self._exec_id)
+                def run_empty():
+                    with ctx.semaphore.held():
+                        return (self._join(empty, bb, ctx, bound) if bi == 1
+                                else self._join(bb, empty, ctx, bound))
+                yield with_retry_no_split(run_empty, ctx=ctx,
+                                          op=self._exec_id)
+        finally:
+            _count_out_bound(bound is not None and bound.hard)
 
     def describe(self):
         return "Broadcast" + super().describe()[:-1] + \

@@ -787,10 +787,11 @@ class _Planner:
         from ..shuffle.broadcast import BroadcastExchangeExec
         from ..shuffle.exchange import ShuffleExchangeExec
 
-        if isinstance(node, ShuffleExchangeExec):
+        if isinstance(node, (ShuffleExchangeExec, B.CoalesceBatchesExec)):
             # the SPMD program IS the exchange: shuffles lower to the
             # routing inside joins/aggs; a bare repartition is an identity
-            # on the mesh
+            # on the mesh, and so is a coalesce (a fragment is a
+            # single-batch program)
             return self.lower(node.children[0], replicated)
 
         if isinstance(node, B.TpuFilterExec):

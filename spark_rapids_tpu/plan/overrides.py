@@ -141,6 +141,7 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
             only_not_on_device=(pexplain == "NOT_ON_DEVICE")))
     physical = meta.convert()
     if conf.sql_enabled:
+        physical = insert_coalesce(physical, conf)
         from ..parallel.planner import (FUSED_PIPELINE, distribution_gate,
                                         maybe_fuse_single_chip,
                                         try_distribute)
@@ -173,6 +174,116 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
     #: (exec_cache.record_plan_compiled) instead of re-hashing the tree
     physical.plan_digest = digest
     return physical
+
+
+# ---------------------------------------------------------------------------
+# post-conversion: full buckets for the per-batch operators (ref
+# GpuTransitionOverrides.insertCoalesce)
+# ---------------------------------------------------------------------------
+
+def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
+    """Put ``CoalesceBatches[TargetSize]`` above every in-memory scan whose
+    batches reach a per-batch operator under-filled: the stream side of a
+    streaming broadcast join or the input of an aggregate, through
+    device-only filters and projections. Those operators pay their host
+    and device work per BATCH at the padded shape, so two half-empty
+    buckets cost twice what one full one does. Not a sort: a
+    partition-local one answers per partition, which a merge would show,
+    and a global one concatenates everything itself. Everything is read off the plan: a scan whose neighbouring
+    batches do not fit the target together (``batchSizeRows``) gets no
+    operator and the plan is the one it was. No plan that reads
+    ``batch.meta`` (``spark_partition_id`` and its kin) gets one either:
+    the merged batch carries its first batch's ``meta``."""
+    from ..exec.joins import TpuBroadcastHashJoinExec
+    from ..exec.wholestage import _fusible
+    from ..shuffle.broadcast import BroadcastExchangeExec
+    target_rows = conf.batch_size_rows
+    sites = []      # (parent, child index) of each scan to wrap
+
+    def visit(node: TpuExec) -> None:
+        fed = None  # index of the child this node consumes batch by batch
+        if isinstance(node, TpuBroadcastHashJoinExec):
+            bi = 1 if node.build_side == "right" else 0
+            if (node.join_type in node.STREAMABLE[node.build_side]
+                    and isinstance(node.children[bi],
+                                   BroadcastExchangeExec)):
+                fed = 1 - bi
+        elif isinstance(node, A.TpuHashAggregateExec):
+            fed = 0
+        if fed is not None:
+            parent, i = node, fed
+            while _fusible(parent.children[i]):
+                parent, i = parent.children[i], 0
+            scan = parent.children[i]
+            # plain device columns only: a dictionary, rectangle or host
+            # column would send the concat through Arrow and back
+            if (isinstance(scan, B.InMemoryScanExec)
+                    and all(f.dtype.device_backed
+                            for f in scan.output_schema())
+                    and _scan_underfilled(scan, target_rows)):
+                sites.append((parent, i))
+        for c in node.children:
+            visit(c)
+
+    visit(physical)
+    if not sites or _reads_task_context(physical):
+        return physical
+    for parent, i in sites:
+        parent.children[i] = B.CoalesceBatchesExec(
+            parent.children[i], target_rows=target_rows,
+            target_bytes=conf.batch_size_bytes)
+    return physical
+
+
+def _scan_underfilled(scan, target_rows: int) -> bool:
+    """Whether two neighbouring batches of the scan fit ``target_rows``
+    together: each table yields ``batch_rows`` rows a batch and then the
+    rest, so the first and last batch of each table say it all."""
+    b = max(int(scan.batch_rows), 1)
+    last = None
+    for t in scan.tables:
+        full, rest = divmod(t.num_rows, b)
+        first = b if full else rest
+        if last is not None and last + first <= target_rows:
+            return True
+        if full and ((full > 1 and 2 * b <= target_rows)
+                     or (rest and b + rest <= target_rows)):
+            return True
+        last = rest if rest or not full else b
+    return False
+
+
+def _reads_task_context(plan: TpuExec) -> bool:
+    """Whether anything the plan holds reads the task context off
+    ``batch.meta`` (exprs/nondeterministic.py), by the family's one marker,
+    ``reset_task_state`` (exec/wholestage.py:_nondeterministic reads the
+    same). Fails closed: the walk goes through EVERY attribute of every
+    operator and of whatever they hold, containers and objects of any
+    class alike, so an expression kept in a holder nobody listed here is
+    still found; only what cannot hold an expression is not entered
+    (batches, tables and arrays, the conf, modules, classes, functions)."""
+    import types
+    from ..columnar.batch import ColumnarBatch
+    opaque = (ColumnarBatch, TpuConf, type, types.ModuleType,
+              types.FunctionType, types.BuiltinFunctionType,
+              types.MethodType)
+    seen = set()
+    stack = [plan]
+    while stack:
+        v = stack.pop()
+        if isinstance(v, opaque) or id(v) in seen:
+            continue
+        seen.add(id(v))
+        if getattr(v, "reset_task_state", None) is not None:
+            return True
+        if isinstance(v, dict):
+            stack.extend(v.values())
+        elif isinstance(v, (list, tuple, set, frozenset)):
+            stack.extend(v)
+        stack.extend(getattr(v, "__dict__", {}).values())
+        stack.extend(getattr(v, n, None) for c in type(v).__mro__
+                     for n in vars(c).get("__slots__", ()))
+    return False
 
 
 #: logical nodes whose execs are engine-shared pass-throughs: their

@@ -1,0 +1,120 @@
+"""Device concat of batches (columnar/batch.py): where every batch's row
+count is a host int and every column a plain device column or a byte
+rectangle (which rides as 1-D lanes), the live rows of each batch go to a
+known offset in ONE jitted call with no sort."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from spark_rapids_tpu.columnar import ColumnarBatch
+from spark_rapids_tpu.columnar import batch as batch_mod
+from spark_rapids_tpu.columnar.batch import (_device_concat_packed,
+                                             concat_batches_device)
+from spark_rapids_tpu.columnar.strrect import ByteRectColumn
+
+
+def _batch(n: int, seed: int) -> ColumnarBatch:
+    rng = np.random.RandomState(seed)
+    return ColumnarBatch.from_arrow(pa.table({
+        "i": pa.array(rng.randint(-50, 50, n), mask=rng.rand(n) < 0.2),
+        "f": pa.array(rng.rand(n), mask=rng.rand(n) < 0.2),
+        "b": pa.array(rng.rand(n) < 0.5, mask=rng.rand(n) < 0.2),
+        "d": pa.array(rng.randint(0, 20000, n).astype("int32"),
+                      pa.date32())}))
+
+
+def _strings(n: int, seed: int, long: bool = False) -> ColumnarBatch:
+    rng = np.random.RandomState(seed)
+    tail = "-a-longer-tail-that-widens-the-rectangle" if long else ""
+    vals = [f"row-{seed}-{i}-{rng.randint(1 << 30)}{tail}" for i in range(n)]
+    return ColumnarBatch.from_arrow(pa.table({
+        "s": pa.array(vals, pa.string(), mask=rng.rand(n) < 0.1),
+        "v": pa.array(rng.rand(n))}))
+
+
+def _lanes(batches):
+    return [[(b.columns[i].data, b.columns[i].validity) for b in batches]
+            for i in range(len(batches[0].columns))]
+
+
+@pytest.fixture
+def concat_kernel_ran():
+    """Reads whether the jitted concat was resolved since the fixture
+    cleared its memo: the only kernel the device concat has."""
+    batch_mod._clear_device_concat()
+    return lambda: batch_mod._DEVICE_CONCAT_JIT is not None
+
+
+# row counts of the batches: under-filled pairs (the coalesce's case), a
+# zero-row batch first / in the middle / last, a full batch in the middle,
+# more rows than one bucket, one row
+@pytest.mark.parametrize("counts", [
+    (4092, 4092), (4096, 3728), (0, 700), (700, 0, 5), (5, 0),
+    (100, 1024, 3), (1000, 1000, 1000, 1000, 1000, 1000, 1000, 1000, 1000),
+    (1, 1)])
+def test_packed_concat_equals_the_arrow_concat(counts, concat_kernel_ran):
+    batches = [_batch(n, seed) for seed, n in enumerate(counts)]
+    got = concat_batches_device(batches)
+    assert concat_kernel_ran()
+    total = sum(counts)
+    assert got.num_rows == total and isinstance(got.num_rows_raw, int)
+    assert got.padded_len == batch_mod.bucket_for(total)
+    want = pa.concat_tables([b.to_arrow() for b in batches])
+    assert got.to_arrow().equals(want)
+    # beyond the live prefix padding stays padding: no validity, zero data
+    for col in got.columns:
+        assert not np.asarray(col.validity)[total:].any()
+        assert not np.asarray(col.data)[total:].any()
+
+
+# byte-rectangle strings ride the same kernel as packed word + length
+# lanes: under-filled, a zero-row batch, rectangles of different widths
+@pytest.mark.parametrize("shapes", [
+    ((300, False), (200, False)), ((0, False), (50, False), (7, False)),
+    ((300, False), (200, True), (1100, False))])
+def test_packed_concat_takes_byte_rectangles(shapes, concat_kernel_ran):
+    rect = [_strings(n, seed, long)
+            for seed, (n, long) in enumerate(shapes)]
+    assert all(isinstance(b.columns[0], ByteRectColumn) for b in rect)
+    out = concat_batches_device(rect)
+    assert concat_kernel_ran()
+    assert isinstance(out.columns[0], ByteRectColumn)
+    total = sum(n for n, _ in shapes)
+    assert out.num_rows_raw == total
+    assert out.padded_len == batch_mod.bucket_for(total)
+    assert out.to_arrow().equals(
+        pa.concat_tables([b.to_arrow() for b in rect]))
+
+
+def test_packed_concat_has_no_sort_and_fetches_nothing(monkeypatch,
+                                                       concat_kernel_ran):
+    batches = [_batch(4092, 0), _batch(4092, 1)]
+    hlo = jax.jit(_device_concat_packed, static_argnums=(2,)).lower(
+        jnp.zeros(2, jnp.int32), _lanes(batches), 8192).as_text()
+    assert "sort" not in hlo and "gather" not in hlo
+    assert "dynamic_update_slice" in hlo or "dynamic-update-slice" in hlo
+    # what the text looks for, were it there
+    assert "sort" in jax.jit(jnp.argsort).lower(
+        jnp.zeros(8, jnp.int32)).as_text()
+
+    def no_fetch(*a, **k):
+        raise AssertionError("the concat fetched")
+    monkeypatch.setattr(jax, "device_get", no_fetch)
+    out = concat_batches_device(batches)
+    assert concat_kernel_ran()
+    assert out.padded_len == 8192 and out.num_rows_raw == 8184
+
+
+def test_full_batches_and_foreign_columns_keep_their_paths(
+        concat_kernel_ran):
+    # every batch but the last full: plain concatenation, no kernel of ours
+    full = [_batch(1024, 0), _batch(1024, 1), _batch(10, 2)]
+    out = concat_batches_device(full)
+    assert not concat_kernel_ran()
+    assert out.to_arrow().equals(
+        pa.concat_tables([b.to_arrow() for b in full]))
+    # a dictionary column or a lazy count is not this function's to merge
+    dic = ColumnarBatch.from_arrow(pa.table({"s": pa.array(["a", "b"] * 8)}))
+    assert concat_batches_device([dic, dic]) is None

@@ -292,6 +292,23 @@ def test_ingest_decode_kernel(one_chip):
     assert "f64" in compiled.as_text()      # the emulated-f64 decode
 
 
+def test_packed_concat_kernel_q3_pair(one_chip):
+    """columnar/batch.py:_device_concat_packed — two of q3's 4,096-row
+    fact partitions (each padded to 8,192) filled into one 8,192-row
+    batch: int64 keys, a float64 price, one dispatch, and no sort in what
+    the chip's compiler is handed."""
+    import jax
+    from spark_rapids_tpu.columnar.batch import _device_concat_packed
+    pair = [[(jax.ShapeDtypeStruct((8192,), dt, sharding=one_chip),
+              jax.ShapeDtypeStruct((8192,), np.bool_, sharding=one_chip))
+             for _ in range(2)] for dt in (np.int64, np.int64, np.float64)]
+    counts = jax.ShapeDtypeStruct((2,), np.int32, sharding=one_chip)
+    compiled = _compile(
+        jax.jit(_device_concat_packed, static_argnums=(2,)).lower(
+            counts, pair, 8192), FAST_LIMIT_S)
+    assert "sort" not in compiled.as_text()
+
+
 def _q3_join(session, build_left: bool):
     from benchmarks import queries_sql as Q
     from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec

@@ -367,3 +367,139 @@ def test_using_join_single_key_column():
     jf = (l.join(r2, on="k", how="full")
           .order_by(F.col("k").asc()).to_pandas())
     assert list(jf["k"]) == [1, 2, 3], jf
+
+
+# -- the streaming broadcast join's output bound (exec/joins.py _OutBound):
+# a build side with unique keys keeps each output in its stream batch's
+# bucket; a duplicated build key leaves the speculation as it was ---------
+
+#: the operator pipeline, a stream of three 1024-row batches the planner
+#: leaves apart (two of them do not fit the target together)
+_BOUND_CONF = {"spark.rapids.tpu.sql.optimizer.enabled": False,
+               "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+               "spark.rapids.tpu.distributed.enabled": False,
+               "spark.rapids.tpu.sql.batchSizeRows": 1024}
+
+
+def _stream_and_build(s, stream_keys, build_keys):
+    import numpy as np
+    import pyarrow as pa
+    n = len(stream_keys)
+    stream = s.create_dataframe(
+        pa.table({"sk": stream_keys,
+                  "sv": pa.array(np.arange(n, dtype=np.float64))}),
+        num_partitions=n // 1024)
+    build = s.create_dataframe(
+        pa.table({"bk": build_keys,
+                  "bv": pa.array(np.arange(len(build_keys)))}))
+    return stream, build
+
+
+def _run_join(s, df):
+    """Execute the plan's one broadcast join on a context of the test's
+    own, under the engine's tracer: (output batches, what was left in
+    ``ctx.speculations``, the ``join.out_bound`` counters)."""
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+
+    def find(node):
+        if isinstance(node, TpuBroadcastHashJoinExec):
+            return node
+        return next(filter(None, map(find, node.children)), None)
+
+    join = find(df._physical())
+    assert join is not None, df._physical().tree_string()
+    ctx = ExecContext(parent=s.exec_context())
+    tr = install_tracer(Tracer())
+    try:
+        outs = list(join.execute(ctx))
+        left = list(ctx.speculations)
+        ctx.check_speculations()
+    finally:
+        install_tracer(None)
+        ctx.close()
+    bounds = [e["args"] for e in tr.snapshot()
+              if e["ph"] == "C" and e["name"] == "join.out_bound"]
+    return outs, left, bounds
+
+
+@pytest.mark.parametrize("how,stream_first", [
+    ("inner", True), ("left", True), ("inner", False), ("right", False)])
+def test_unique_build_keys_bound_the_output_hard(how, stream_first):
+    """Unique build keys: every output batch sits in its stream batch's
+    bucket (1024), first batch included, although 3 of 4 stream rows
+    match and a guess of 1.5 times the last total would take the next
+    bucket; nothing is registered for the sink to validate; the counter
+    says hard. NULL and absent keys null-extend in the outer joins and
+    still fit."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.RandomState(3)
+    keys = rng.randint(0, 400, 3 * 1024)       # 0..299 match, the rest not
+    sk = pa.array(keys, mask=rng.rand(len(keys)) < 0.05)
+    bk = pa.array(np.arange(300), mask=np.arange(300) == 7)   # a NULL key
+
+    def q(s):
+        stream, build = _stream_and_build(s, sk, bk)
+        if stream_first:
+            return stream.join(build, on=[("sk", "bk")], how=how)
+        return build.join(stream, on=[("bk", "sk")], how=how)
+
+    s = tpu_session(_BOUND_CONF)
+    df = q(s)
+    assert "CoalesceBatches" not in df._physical().tree_string()
+    outs, left, bounds = _run_join(s, df)
+    assert len(outs) == 3
+    assert [b.padded_len for b in outs] == [1024] * 3
+    assert left == []
+    assert bounds == [{"hard": 1, "speculative": 0}]
+    n_out = sum(b.num_rows for b in outs)
+    t = assert_tpu_and_cpu_equal(q, conf=_BOUND_CONF)
+    assert len(t) == n_out
+    if how == "inner":
+        assert 0 < n_out < len(keys)
+    else:
+        assert n_out == len(keys)
+
+
+def test_duplicated_build_key_keeps_the_speculation_and_its_rerun():
+    """One duplicated build key: the first batch is sized exactly and
+    measures the multiplicity, the rest are sized from the last total
+    with 1.5x headroom and registered for the sink, as before; when the
+    second batch then emits 50 rows a row the sink's check overflows and
+    the exact re-run gives every row."""
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.columnar.batch import SpeculativeOverflow
+    # batch 0 meets the unique key 0, batches 1 and 2 the key 1 x 50
+    sk = pa.array(np.repeat([0, 1, 1], 1024))
+    bk = pa.array(np.array([0] + [1] * 50))
+
+    def q(s):
+        stream, build = _stream_and_build(s, sk, bk)
+        return stream.join(build, on=[("sk", "bk")], how="inner")
+
+    s = tpu_session(_BOUND_CONF)
+    with pytest.raises(SpeculativeOverflow):
+        _run_join(s, q(s))
+    s = tpu_session(_BOUND_CONF)
+    df = q(s)
+    got = df.collect_arrow()
+    assert got.num_rows == 1024 + 2 * 1024 * 50
+    assert_tpu_and_cpu_equal(q, conf=_BOUND_CONF)
+
+    # duplicates that stay inside the guess: speculative, validated, kept
+    sk2 = pa.array(np.tile(np.arange(8), 3 * 128))
+    bk2 = pa.array(np.array([0, 0, 1, 2, 3]))
+
+    def q2(s):
+        stream, build = _stream_and_build(s, sk2, bk2)
+        return stream.join(build, on=[("sk", "bk")], how="inner")
+
+    s = tpu_session(_BOUND_CONF)
+    outs, left, bounds = _run_join(s, q2(s))
+    assert len(left) == 2                      # all but the measured first
+    assert bounds == [{"hard": 0, "speculative": 1}]
+    assert sum(b.num_rows for b in outs) == 3 * 128 * 5
+    assert_tpu_and_cpu_equal(q2, conf=_BOUND_CONF)

@@ -249,6 +249,87 @@ def test_profiler_session_gets_engine_spans(tmp_path):
                 or (c <= a and b <= d), (a, b, c, d)
 
 
+#: four 1000-row partitions against a target of 2000: the planner fills
+#: them into two batches (plan/overrides.insert_coalesce)
+_COALESCE_CONF = {**_OPERATOR_CONF, "spark.rapids.tpu.sql.batchSizeRows": 2000}
+
+
+def _coalesced(s):
+    t = pa.table({"k": pa.array(np.arange(4000) % 7),
+                  "v": pa.array(np.arange(4000, dtype=np.float64))})
+    return (s.create_dataframe(t, num_partitions=4).group_by("k")
+            .agg(F.sum(F.col("v")).with_name("sv")))
+
+
+def test_coalesce_span_and_counter_in_the_ring_buffer():
+    """Each concat is a ``coalesce.concat`` span, a ``cat="exec"`` child of
+    its operator's span (so ``exec_host_s`` bills it once), and
+    ``coalesce.batches`` says once an execution how many batches came and
+    went."""
+    s = tpu_session(_COALESCE_CONF)
+    df = _coalesced(s)
+    assert "CoalesceBatches[TargetSize(rows=2000" in \
+        df._physical().tree_string()
+    tr = install_tracer(Tracer())
+    try:
+        assert df.collect_arrow().num_rows == 7
+    finally:
+        install_tracer(None)
+    counters = [e["args"] for e in tr.snapshot()
+                if e["ph"] == "C" and e["name"] == "coalesce.batches"]
+    assert counters == [{"in": 4, "out": 2}]
+    spans = _xs(tr)
+    by_id = {e["id"]: e for e in spans}
+    concats = [e for e in spans if e["name"] == "coalesce.concat"]
+    assert len(concats) == 2
+    for e in concats:
+        assert e["cat"] == "exec" and e["args"]["n"] == 2
+        parent = by_id[e["parent"]]
+        assert parent["name"] == "CoalesceBatchesExec"
+        assert parent["args"]["exec"] == e["args"]["exec"]
+        assert parent["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    # no fetch inside the operator: the concat stays on the device
+    assert not [e for e in spans if e["name"].startswith("d2h")
+                and by_id.get(e["parent"], {}).get("name")
+                in ("coalesce.concat", "CoalesceBatchesExec")]
+
+
+def test_coalesce_span_under_the_profilers_tracer(tmp_path):
+    """Under a ``jax.profiler`` session the same span is an annotation
+    ``srtpu/exec/coalesce.concat`` carrying its operator's id, inside that
+    operator's annotation; the counter, which cannot be an annotation, is
+    skipped and nothing fails."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    s = tpu_session(_COALESCE_CONF)
+    df = _coalesced(s)
+    assert df.collect_arrow().num_rows == 7          # compiles outside
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert df.collect_arrow().num_rows == 7
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("srtpu/exec/")]
+    concats = [sp for sp in spans if sp[0] == "srtpu/exec/coalesce.concat"]
+    ops = [sp for sp in spans if sp[0] == "srtpu/exec/CoalesceBatchesExec"]
+    assert len(concats) == 2 and len(ops) == 3       # 2 batches + the end
+    for _, a, b, stats in concats:
+        assert stats["exec"].startswith("CoalesceBatchesExec@")
+        assert any(c <= a and b <= d and st["exec"] == stats["exec"]
+                   for _, c, d, st in ops)
+
+
 def test_traced_upload_never_blocks(monkeypatch):
     """With a tracer installed an upload stays an asynchronous enqueue:
     no block_until_ready (a tracer must not change what it measures),
@@ -310,11 +391,11 @@ def test_every_fetch_happens_inside_a_d2h_span(monkeypatch):
                 (name, span)
 
 
-def _agg_trace(table, key, value=None):
+def _agg_trace(table, key, value=None, conf=None):
     """One traced run, in a session of its own, of a two-aggregate
     group-by of ``table`` (6 batches) by ``key``: (spans, counters, the
     aggregate's operator metrics)."""
-    s = tpu_session(_OPERATOR_CONF)
+    s = tpu_session({**_OPERATOR_CONF, **(conf or {})})
     v = F.col("v") if value is None else value
     df = s.create_dataframe(table, num_partitions=6).group_by(key).agg(
         F.sum(v).with_name("sv"), F.avg(v).with_name("av"))
@@ -359,7 +440,10 @@ def test_direct_aggregate_is_one_fetch_a_query():
         [{"batches": 6, "flushes": 0}]
     assert agg_m["updateDispatches"] == 7, agg_m
 
-    _, counters, agg_m = _agg_trace(t, "m")
+    # an all-numeric scan: at the default target the planner would fill
+    # its six 1000-row batches into one (plan/overrides.insert_coalesce)
+    _, counters, agg_m = _agg_trace(
+        t, "m", conf={"spark.rapids.tpu.sql.batchSizeRows": 1000})
     assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
         [{"batches": 0, "flushes": 0}]
     assert agg_m["updateDispatches"] > 7, agg_m
