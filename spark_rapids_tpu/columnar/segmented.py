@@ -323,21 +323,59 @@ def _neutral_min(dtype):
     return jnp.array(jnp.iinfo(dtype).min, dtype=dtype)
 
 
+def front_sort(first, rank, lanes, padded_len: int):
+    """Rows where ``first`` to the front in the order of ``rank``, the
+    others behind them in the order of theirs, every lane (1-D, one entry
+    a row) carried along: ONE variadic sort, the idiom that replaces
+    cumsum+scatter (per-column 1M-row scatters serialize on the scalar
+    core, the sort network is bandwidth-bound, ~5 ms).
+
+    ``rank`` is below ``padded_len`` and unique among the ``first`` rows
+    and among the others. So the key is ONE uint32 a row and unique (bit
+    31: not first; then the rank), an UNSTABLE sort orders by it as a
+    stable one would, and the bool lanes ride in the bits below the rank
+    (what does not fit there, 32 lanes a word). On this backend a sort's
+    compile time grows with every operand and doubles with ``is_stable``:
+    at 1,048,576 rows three 64-bit columns with their validity compile in
+    a third of the time they took as (u8 key, stable, a bool lane a
+    column) (PERF.md, PR 32)."""
+    flags = [i for i, l in enumerate(lanes) if l.dtype == jnp.bool_]
+    spare = 31 - max(1, (padded_len - 1).bit_length())
+    in_key, rest = flags[:spare], flags[spare:]
+
+    def bits(ids):
+        word = jnp.zeros((padded_len,), jnp.uint32)
+        for b, i in enumerate(ids):
+            word = word | (lanes[i].astype(jnp.uint32) << b)
+        return word
+
+    key = jnp.where(first, jnp.uint32(0), jnp.uint32(1 << 31)) \
+        | (rank.astype(jnp.uint32) << len(in_key)) | bits(in_key)
+    plain = [i for i in range(len(lanes)) if i not in flags]
+    words = [rest[i:i + 32] for i in range(0, len(rest), 32)]
+    packed = jax.lax.sort(
+        tuple([key] + [lanes[i] for i in plain] + [bits(w) for w in words]),
+        num_keys=1, is_stable=False)
+    out = [None] * len(lanes)
+    for i, lane in zip(plain, packed[1:]):
+        out[i] = lane
+    for word, ids in zip((packed[0],) + packed[1 + len(plain):],
+                         [in_key] + words):
+        for b, i in enumerate(ids):
+            out[i] = (word >> b) & 1 == 1
+    return out
+
+
 def compact_rows(arrays, keep, padded_len: int):
-    """Move keep-rows to the front preserving order: ONE stable variadic
-    sort on (!keep) carrying every column as payload. Replaces the
-    cumsum+scatter idiom — per-column 1M-row scatters serialize on the
-    scalar core, while the sort network is bandwidth-bound (~5 ms).
+    """Move keep-rows to the front preserving order (``front_sort`` by the
+    row's index), validity cleared behind them.
 
     arrays: [(data, validity), ...]; returns (compacted pairs, count)."""
     count = jnp.sum(keep).astype(jnp.int32)
     live = jnp.arange(padded_len, dtype=jnp.int32) < count
-    key = jnp.where(keep, jnp.uint8(0), jnp.uint8(1))
-    flat = []
-    for d, v in arrays:
-        flat.extend((d, v))
-    packed = jax.lax.sort(tuple([key] + flat), num_keys=1, is_stable=True)
-    it = iter(packed[1:])
+    it = iter(front_sort(keep, jnp.arange(padded_len, dtype=jnp.int32),
+                         [lane for pair in arrays for lane in pair],
+                         padded_len))
     outs = [(next(it), jnp.logical_and(next(it), live)) for _ in arrays]
     return outs, count
 

@@ -126,7 +126,9 @@ AGG_WIDE_BATCH_ROWS = register(
 
 AUTO_BROADCAST_THRESHOLD = register(
     "spark.rapids.tpu.sql.autoBroadcastJoinThreshold", 10 * 1024 * 1024,
-    "Equi-joins broadcast a side whose plan-time size estimate is at or "
+    "Equi-joins broadcast a side whose plan-time size estimate (a tenth "
+    "of the input for each col = literal conjunct of a filter on it; the "
+    "measured size from the second planning on) is at or "
     "below this many bytes (build once, probe per shard — ref Spark's "
     "autoBroadcastJoinThreshold + the reference's AQE join-strategy "
     "switching, GpuOverrides.scala:4681). <=0 disables auto selection.",
@@ -140,7 +142,8 @@ JOIN_BLOOM_FILTER = register(
 
 JOIN_SUBPARTITION_SIZE = register(
     "spark.rapids.tpu.sql.join.subPartitionSizeBytes", 256 * 1024 * 1024,
-    "When the combined input of an equi-join exceeds this many bytes the join "
+    "When the build side of an equi-join (the smaller input, which every "
+    "batch of the other side is joined against whole) exceeds this many bytes the join "
     "hash-partitions both sides and runs N independent sub-joins "
     "(ref GpuSubPartitionHashJoin.scala / GpuShuffledSizedHashJoinExec.scala:1255). "
     "<= 0 disables sub-partitioning.")

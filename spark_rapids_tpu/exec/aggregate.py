@@ -172,12 +172,12 @@ def _build_groupby_kernel_split(key_exprs, aggs, schema, mode,
     # compile time, so the split path carries the MINIMUM. Keys whose
     # grouping encoding is the standard (null_rank, key) pair are NOT
     # duplicated as payload — k_scan reconstructs (data, validity) from
-    # the sorted operands themselves (validity = rank==0; data =
+    # the sorted operands themselves (validity = its flag bit; data =
     # operand cast back, canonicalized for floats — the
     # NormalizeFloatingNumbers semantics grouping already applies). The
-    # original-row-index payload rides only when an order-dependent
-    # aggregate (First/Last) needs it.
-    from ..exprs.aggregates import First, Last
+    # original row index rides behind the keys as the LAST key: the keys
+    # are then unique, and an unstable sort, which compiles in about half
+    # a stable one's time, gives the stable order (PERF.md, PR 32).
     from ..exprs.base import StrVal
 
     def _reconstructible(dt):
@@ -193,7 +193,8 @@ def _build_groupby_kernel_split(key_exprs, aggs, schema, mode,
         return len(shapes) == 2
 
     recon = [_reconstructible(dt) for dt in key_dtypes]
-    needs_rank = any(isinstance(a, (First, Last)) for a in aggs)
+    #: keys whose null rank fits the flag operand below the padding's bit
+    _FLAG_KEYS = 31
 
     @functools.partial(jax.jit, static_argnums=(2,))
     def k_prep(cols, num_rows, padded_len, scalars=()):
@@ -202,15 +203,25 @@ def _build_groupby_kernel_split(key_exprs, aggs, schema, mode,
         filter/CASE prologue pushed the q28 update sort past 15 minutes),
         so the sort gets a module to itself with raw operands. Key ops
         come back as a NESTED per-key tuple (arities vary: scalar keys
-        two operands, byte-rectangle strings 2 + W/8)."""
+        one operand, byte-rectangle strings 1 + W/8). Grouping asks
+        equal keys to meet, not Spark's order, so the keys' null ranks
+        (a uint8 operand a key) ride as bits of the ONE leading flag
+        operand, below the padding's bit: each key operand taken out of
+        the comparator is compile time (three keys at 524,288 rows: 357 s
+        with the ranks as operands; PERF.md, PR 32)."""
         keys, vals, keep = prep(cols, num_rows, padded_len, scalars)
         if keep is None:
             keep = jnp.arange(padded_len, dtype=jnp.int32) < num_rows
-        pad_flag = jnp.where(keep, jnp.uint8(0), jnp.uint8(1))
-        key_ops = tuple(tuple(grouping_operands(k)) for k in keys)
-        payload = []
-        if needs_rank:
-            payload.append(jnp.arange(padded_len, dtype=jnp.int32))
+        ops = [grouping_operands(k) for k in keys]
+        n_flag = min(len(keys), _FLAG_KEYS)
+        flag_t = jnp.uint8 if n_flag < 8 else jnp.uint32
+        # the padding's bit on top: dropped rows sort behind every live one
+        pad_flag = jnp.where(keep, flag_t(0), flag_t(1 << n_flag))
+        for i, o in enumerate(ops[:n_flag]):
+            pad_flag = pad_flag | (o[0].astype(flag_t) << i)
+        key_ops = tuple(tuple(o[1:] if i < _FLAG_KEYS else o)
+                        for i, o in enumerate(ops))
+        payload = [jnp.arange(padded_len, dtype=jnp.int32)]
         for k, r in zip(keys, recon):
             if not r:
                 payload.extend((k.data, k.validity))
@@ -223,12 +234,13 @@ def _build_groupby_kernel_split(key_exprs, aggs, schema, mode,
     _sort_jits = {}
 
     def k_sort(flat, nk):
-        """The bare variadic sort — nothing else in the module."""
+        """The bare variadic sort — nothing else in the module; the row
+        index (the first payload) is its last key."""
         fn = _sort_jits.get(nk)
         if fn is None:
             def mk(flat, nk=nk):
-                return jax.lax.sort(tuple(flat), num_keys=nk,
-                                    is_stable=True)
+                return jax.lax.sort(tuple(flat), num_keys=nk + 1,
+                                    is_stable=False)
             fn = _sort_jits[nk] = jax.jit(mk)
         return fn(flat)
 
@@ -236,29 +248,34 @@ def _build_groupby_kernel_split(key_exprs, aggs, schema, mode,
     def k_scan(flat, arities, padded_len, live):
         it = iter(flat)
         s_ops = [next(it) for _ in range(1 + sum(arities))]
-        perm = next(it) if needs_rank else None
+        perm = next(it)
         s_keys = []
         pos = 1
+        i = 0                       # the key's bit in the flag operand
         for ar, dt, r in zip(arities, key_dtypes, recon):
             ops = s_ops[pos:pos + ar]
             pos += ar
+            if i < _FLAG_KEYS:
+                valid = (s_ops[0] >> i) & 1 == 0
+            else:
+                valid, ops = ops[0] == 0, ops[1:]
+            i += 1
             if not r:
                 s_keys.append(DVal(next(it), next(it), dt))
             elif dt == STRING:
                 from ..columnar.strrect import unpack_words
-                rank, words, ln = ops[0], ops[1:-1], ops[-1]
+                words, ln = ops[:-1], ops[-1]
                 s_keys.append(DVal(
                     StrVal(unpack_words(list(words), 8 * len(words)),
                            ln.astype(jnp.int32)),
-                    rank == 0, dt))
+                    valid, dt))
             else:
-                rank, keyop = ops
-                s_keys.append(DVal(keyop.astype(dt.np_dtype), rank == 0,
-                                   dt))
+                s_keys.append(DVal(ops[0].astype(dt.np_dtype), valid, dt))
         sorted_vals = [[DVal(next(it), next(it), dt) for dt in dts]
                        for dts in val_dtypes]
+        # a group ends where the flags (a key's nullness) or a key differ
         ckey, carry, num_groups = stage_scan(
-            aggs, mode, s_ops, perm, s_keys, sorted_vals, live,
+            aggs, mode, s_ops[:1] + s_ops, perm, s_keys, sorted_vals, live,
             padded_len)
         return ckey, carry, num_groups
 

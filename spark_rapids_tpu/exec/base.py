@@ -98,6 +98,9 @@ class ExecContext:
         self.speculate = self.conf.join_speculative_sizing
         #: [(device total, capacity, join stat key), ...]
         self.speculations = []
+        #: join stat key -> the largest output total this context's query
+        #: has read of that join shape (exec/joins.py:_note_total)
+        self.join_totals: Dict[tuple, int] = {}
 
     def _install_from_conf(self) -> None:
         """The process-wide installs a conf asks for, each install-once
@@ -203,20 +206,23 @@ class ExecContext:
             return
         from ..columnar.batch import SpeculativeOverflow
         from ..columnar.packing import fetch_packed
-        from .joins import _TOTAL_STATS
+        from .joins import _note_total
         pending, self.speculations = self.speculations, []
         totals = fetch_packed([t for t, _, _, _ in pending])
+        over = None
         for n, (_, cap, stat_key, plan_sig) in zip(totals, pending):
             n = int(n)
             if stat_key is not None:
-                _TOTAL_STATS[stat_key] = n     # keep the statistic fresh
+                _note_total(self, stat_key, n)  # keep the statistic fresh
             if plan_sig is not None:
                 # measured join-output rows -> the cost model (the crudest
                 # estimate it has); rides the same batched totals fetch
                 from ..plan.cost import record_runtime_rows
                 record_runtime_rows(plan_sig, n)
-            if n > cap:
-                raise SpeculativeOverflow(n, cap)
+            if n > cap and over is None:
+                over = SpeculativeOverflow(n, cap)
+        if over is not None:
+            raise over
 
     def metric(self, exec_id: str, name: str, level: str = MODERATE) -> Metric:
         m = self.metrics.setdefault(exec_id, {})

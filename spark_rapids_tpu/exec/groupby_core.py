@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.segmented import (GlobalSegments, SortedSegments,
-                                  prefix_sum)
+                                  front_sort, prefix_sum)
 from ..exprs.base import DVal
 from .encoding import grouping_operands, operands_equal
 
@@ -108,8 +108,10 @@ def stage_sort(keys: List[DVal], vals: List[List[DVal]], num_rows,
     for vs in vals:
         for v in vs:
             payload.extend((v.data, v.validity))
+    # the row index (the first payload) as the last key: unique keys, so
+    # the unstable sort gives the stable order at half the compile time
     sorted_all = jax.lax.sort(tuple(operands + payload),
-                              num_keys=n_key_ops, is_stable=True)
+                              num_keys=n_key_ops + 1, is_stable=False)
     s_ops = sorted_all[:n_key_ops]
     rest = sorted_all[n_key_ops:]
     perm = rest[0]
@@ -179,9 +181,9 @@ def stage_pack(ckey, carry, num_groups, key_dtypes, padded_len: int):
     for d, v in partial_pairs:
         flat.extend((d, v))
     idx = jnp.arange(padded_len, dtype=jnp.int32)
-    packed = jax.lax.sort(tuple([ckey] + flat), num_keys=1,
-                          is_stable=True)
-    it = iter(packed[1:])
+    ends = ckey < padded_len
+    it = iter(front_sort(ends, jnp.where(ends, ckey, idx), flat,
+                         padded_len))
     group_live = idx < num_groups
     key_outs = []
     for g, dt in zip(key_groups, key_dtypes):

@@ -22,17 +22,18 @@ post-filter for inner/cross and tagged fallback otherwise.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..columnar.segmented import prefix_sum
+from ..columnar.segmented import last_valid_scan, prefix_sum
 import numpy as np
 
 from ..columnar import ColumnarBatch, DeviceColumn, concat_batches
-from ..columnar.bucketing import bucket_for
+from ..columnar.bucketing import BUILD_BUCKETS, bucket_for
 from ..columnar.transfer import traced_device_get
 from ..exprs.base import DVal, EvalContext, Expression
 from ..exprs.compiler import (_compact_kernel, eval_predicate_device,
@@ -40,7 +41,7 @@ from ..exprs.compiler import (_compact_kernel, eval_predicate_device,
 from ..mem import (SpillableBatch, with_retry_no_split,
                    wrap_spillable_sides)
 from ..trace import core as trace_core
-from ..types import BOOL, Schema, StructField
+from ..types import BOOL, DecimalType, Schema, StructField
 from .base import ESSENTIAL, ExecContext, TpuExec
 from .encoding import grouping_operands, operands_equal
 
@@ -50,8 +51,20 @@ __all__ = ["TpuHashJoinExec", "TpuNestedLoopJoinExec",
 _COUNT_CACHE: Dict[Tuple, object] = {}
 _FUSED_CACHE: Dict[Tuple, object] = {}
 _GATHER_CACHE: Dict[Tuple, object] = {}
+_PROBE_CACHE: Dict[Tuple, object] = {}
 #: last observed output total per join shape (feeds speculative sizing)
 _TOTAL_STATS: Dict[Tuple, int] = {}
+
+
+def _note_total(ctx: Optional[ExecContext], ck, n: int) -> None:
+    """``n`` rows left one execution of join shape ``ck`` BEFORE any
+    residual condition: the statistic the next run sizes its outputs from
+    is the LARGEST such total of the query (``ctx.join_totals``). A
+    streaming join has one a stream batch and its last batch is a partial
+    one: sized from that, the next run's full batches overflow."""
+    if ctx is not None:
+        n = ctx.join_totals[ck] = max(n, ctx.join_totals.get(ck, 0))
+    _TOTAL_STATS[ck] = n
 
 
 class _OutBound:
@@ -65,11 +78,15 @@ class _OutBound:
     sink (as for semi/anti joins). Above 1 the speculation on the last
     observed total stays."""
 
-    __slots__ = ("stream_left", "mult")
+    __slots__ = ("stream_left", "mult", "probe")
 
     def __init__(self, stream_left: bool):
         self.stream_left = stream_left
         self.mult: Optional[int] = None
+        #: the build side sorted by its unique integer key (_SortedBuild),
+        #: where the join has such a key: stream batches are then probed
+        #: against it by the sort-and-scan kernel, not the general one
+        self.probe: Optional[_SortedBuild] = None
 
     @property
     def hard(self) -> bool:
@@ -94,6 +111,193 @@ def _count_out_bound(hard: bool) -> None:
         tr.counter("join.out_bound", {"hard": int(hard),
                                       "speculative": int(not hard)},
                    cat="exec")
+
+
+def _count_join_rows(exec_id: str, build, stream, out, parts: int) -> None:
+    """Tracer counter ``join.rows``, once per equi-join execution: the rows
+    of the build side, of the stream side, of the output, the stream
+    batches (or sub-partitions) the join ran as, and ``op``, the number in
+    the operator's id (its ``join.build`` / ``join.probe`` spans carry the
+    id as ``exec``). Counts still on the device (a filter's survivors, a
+    speculated total) are fetched here in ONE packed transfer, and only
+    while a tracer is installed."""
+    tr = trace_core.TRACER
+    if tr is None or not tr.recording:
+        return
+    from ..columnar.packing import fetch_packed
+    groups = [list(g) if isinstance(g, (list, tuple)) else [g]
+              for g in (build, stream, out)]
+    lazy = [c for g in groups for c in g
+            if not isinstance(c, (int, np.integer))]
+    got = iter(fetch_packed(lazy)) if lazy else iter(())
+    b, st, o = (sum(int(c) if isinstance(c, (int, np.integer))
+                    else int(next(got)) for c in g) for g in groups)
+    tr.counter("join.rows", {"build": b, "stream": st, "out": o,
+                             "parts": int(parts),
+                             "op": int(exec_id.rsplit("@", 1)[-1])},
+               cat="exec")
+
+
+def _span(name: str, exec_id: str, **args):
+    """A ``with`` span of the installed tracer (so that it reaches the
+    profiler's clock, nested under the operator's own span), or nothing."""
+    tr = trace_core.TRACER
+    if tr is None:
+        return contextlib.nullcontext()
+    return tr.span(name, cat="exec", args=dict(args, exec=exec_id))
+
+
+def _resolve_counts(spillables) -> None:
+    """Install every row count that is still on the device (a filter's
+    survivors) among ``spillables``, all in ONE packed transfer.
+    SpillableBatch mirrors the lazy count: read WITHOUT get(), which
+    would unspill whole batches just for a row count."""
+    from ..columnar.packing import fetch_packed
+    lazy = [s for s in spillables
+            if not isinstance(s._num_rows, (int, np.integer))]
+    if lazy:
+        for s, v in zip(lazy, fetch_packed([s._num_rows for s in lazy])):
+            s._num_rows = int(v)
+
+
+def _counted(s: SpillableBatch) -> ColumnarBatch:
+    """The batch of a spillable whose count ``_resolve_counts`` installed,
+    knowing it too: a device concat needs host counts."""
+    b = s.get()
+    if not isinstance(b.num_rows_raw, int):
+        b._resolve_count(s.num_rows)
+    return b
+
+
+# ---------------------------------------------------------------------------
+# the unique-key probe: an inner join on ONE integer key whose build side
+# holds each key once (a primary key: the usual build side). The build
+# side is sorted by the key once a join a query; a stream batch then costs
+# two single-key sorts and two carry-forward scans over build + stream
+# rows and NO row-sized scatter or gather: the general kernel's four
+# segment reductions and its group-table lookups are what a batch costs
+# there (PERF.md, PR 32: 2.8 ns a row for such a sort on the chip, 10-16 ns
+# for a gathered one).
+# ---------------------------------------------------------------------------
+
+#: sort key of a row that cannot match: padding, a NULL key, a row the
+#: count leaves out. A LIVE build key of this value keeps the general path.
+_DEAD_KEY = np.iinfo(np.int64).max
+
+
+class _SortedBuild:
+    """A build side ready for ``_probe_kernel``: ``batch`` holds its rows
+    in key order, the ``rows`` that can match first; ``keys`` their keys
+    as int64, ``_DEAD_KEY`` past ``rows``."""
+
+    __slots__ = ("keys", "batch", "rows")
+
+    def __init__(self, keys, batch: ColumnarBatch, rows: int):
+        self.keys, self.batch, self.rows = keys, batch, rows
+
+
+def _key_lane(key_expr, schema, dtypes, cols, n, p):
+    """(int64 sort key, live) of one side's rows inside a kernel."""
+    dv = [None if c is None else DVal(c[0], c[1], dt)
+          for c, dt in zip(cols, dtypes)]
+    ctx = EvalContext(schema, dv, n, p)
+    k = key_expr.eval_device(ctx)
+    live = jnp.logical_and(ctx.row_mask(), k.validity)
+    return jnp.where(live, k.data.astype(jnp.int64), _DEAD_KEY), live
+
+
+def _build_sort_kernel(key_expr, schema):
+    """Build side -> (sorted keys, the permutation, rows that can match,
+    whether the probe must not be used: a key twice, or a live key equal
+    to ``_DEAD_KEY``)."""
+    dtypes = [f.dtype for f in schema.fields]
+
+    def join_build(cols, n, p):
+        key, live = _key_lane(key_expr, schema, dtypes, cols, n, p)
+        # the row index as a second key: unique keys, so the unstable
+        # sort (half the stable one's compile time) gives the stable order
+        skey, order = jax.lax.sort(
+            (key, jnp.arange(p, dtype=jnp.int32)), num_keys=2,
+            is_stable=False)
+        rows = jnp.sum(live).astype(jnp.int32)
+        again = jnp.logical_and(
+            skey == jnp.roll(skey, 1),
+            jnp.logical_and(jnp.arange(p, dtype=jnp.int32) < rows,
+                            jnp.arange(p) > 0))
+        unusable = jnp.logical_or(
+            jnp.any(again),
+            jnp.any(jnp.logical_and(live, key == _DEAD_KEY)))
+        return skey, order, rows, unusable
+
+    return join_build
+
+
+def _build_probe_kernel(key_expr, schema):
+    """Stream batch x sorted build keys -> the matching (stream row, build
+    row) pairs packed to the front, and their count. The output's size is
+    no part of this module: the sorts are what takes minutes to compile,
+    and they compile once a pair of input shapes (``_pairs_gather`` cuts
+    the pairs to the output bucket and gathers the columns).
+
+    Build keys first, then the stream's: a sort by (key, position) keeps
+    the build rows in their own (sorted) order, so the j-th build row met
+    is row j of the sorted build side and needs no rank. Each row then
+    learns the last live build row at or before it (two carry-forward
+    scans: its key and its row); a stream row matches where that key is
+    its own. A second single-key sort (matches first, then the position:
+    unique, so unstable) packs the matches."""
+    dtypes = [f.dtype for f in schema.fields]
+
+    def join_probe(bkeys, b_rows, scols, n_s, p_s):
+        p_b = bkeys.shape[0]
+        skey, _ = _key_lane(key_expr, schema, dtypes, scols, n_s, p_s)
+        keys, pos = jax.lax.sort(
+            (jnp.concatenate([bkeys, skey]),
+             jnp.arange(p_b + p_s, dtype=jnp.int32)),
+            num_keys=2, is_stable=False)
+        live_b = pos < b_rows          # build rows come first: pos = row
+        near_key, met = last_valid_scan(keys, live_b)
+        near_row, _ = last_valid_scan(pos, live_b)
+        match = jnp.logical_and(
+            jnp.logical_and(pos >= p_b, keys != _DEAD_KEY),
+            jnp.logical_and(met, near_key == keys))
+        total = jnp.sum(match).astype(jnp.int32)
+        _, s_row, b_row = jax.lax.sort(
+            (jnp.where(match, jnp.uint32(0), jnp.uint32(1 << 31))
+             | jnp.arange(p_b + p_s, dtype=jnp.uint32), pos - p_b,
+             near_row), num_keys=1, is_stable=False)
+        return total, s_row, b_row
+
+    return join_probe
+
+
+def _pairs_gather(total, s_row, b_row, scols, bcols, out_p):
+    """The first ``out_p`` (stream row, build row) pairs of a probe, -1
+    past ``total``, and both sides' columns gathered by them."""
+    short = max(0, out_p - s_row.shape[0])
+    live = jnp.arange(out_p, dtype=jnp.int32) < total
+    s_row = jnp.where(live, jnp.pad(s_row, (0, short))[:out_p], -1)
+    b_row = jnp.where(live, jnp.pad(b_row, (0, short))[:out_p], -1)
+    return (_packed_gather(scols, s_row, out_p),
+            _packed_gather(bcols, b_row, out_p))
+
+
+def _probe_kernel(kind: str, key_expr, schema):
+    """``join_build`` / ``join_probe`` for one key over one schema, each
+    ONE callable a process (the executable cache's, so its compiles are
+    counted), behind a lock-free memo for the per-batch path."""
+    pk = (kind, key_expr.key(),
+          tuple((f.name, f.dtype.name) for f in schema.fields))
+    fn = _PROBE_CACHE.get(pk)
+    if fn is None:
+        from ..plan import exec_cache
+        exec_cache.register_clear_hook(_PROBE_CACHE.clear)
+        make, static = ((_build_sort_kernel, (2,)) if kind == "build"
+                        else (_build_probe_kernel, (4,)))
+        fn = _PROBE_CACHE[pk] = exec_cache.get_or_build_jit(
+            f"joins.{kind}:{pk[1:]}", make(key_expr, schema),
+            static_argnums=static)
+    return fn
 
 
 def _build_count_kernel(lkey_exprs, rkey_exprs, lschema, rschema, join_type):
@@ -240,6 +444,12 @@ def _gather_index_kernel(s_orig, cnt_l, cnt_r, start_l, start_r, offsets,
     r_row = jnp.where(rpos >= 0, jnp.take(s_orig, jnp.maximum(rpos, 0),
                                           mode="clip"), -1)
     return l_row.astype(jnp.int32), r_row.astype(jnp.int32)
+
+
+def _lanes(batch: ColumnarBatch) -> list:
+    """A batch's device columns as the kernels take them."""
+    return [(c.data, c.validity) if isinstance(c, DeviceColumn) else None
+            for c in batch.columns]
 
 
 def _packed_gather(cols, idx_rows, out_p):
@@ -397,19 +607,9 @@ def _record_sides(sides) -> None:
     representation); lazy device row counts from BOTH sides fetch in
     ONE packed transfer (only the big-sides shuffled join pays this
     round trip — the broadcast path's counts are already host ints)."""
-    from ..columnar.packing import fetch_packed
     from ..plan.cost import record_runtime_size
-    # SpillableBatch mirrors the lazy count — read it WITHOUT get(),
-    # which would unspill whole batches just for a row count
-    lazy = []
-    for _sig, spillables, _schema in sides:
-        for s in spillables:
-            if not isinstance(s._num_rows, (int, np.integer)):
-                lazy.append(s)
-    if lazy:
-        vals = fetch_packed([s._num_rows for s in lazy])
-        for s, v in zip(lazy, vals):
-            s._num_rows = int(v)
+    _resolve_counts([s for _sig, spillables, _schema in sides
+                     for s in spillables])
     for sig, spillables, schema in sides:
         total = 0.0
         for s in spillables:
@@ -435,10 +635,20 @@ class TpuHashJoinExec(TpuExec):
     def output_schema(self) -> Schema:
         return self._schema
 
+    #: join types whose result is the union, over the batches of the
+    #: OTHER side, of that batch joined with the whole build side (no
+    #: null-extension or per-row mark of the build side across batches),
+    #: per build side
+    STREAMABLE = {
+        "right": ("inner", "left", "leftsemi", "leftanti", "existence",
+                  "cross"),
+        "left": ("inner", "right", "cross"),
+    }
+
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         rows_m = ctx.metric(self._exec_id, "numOutputRows", ESSENTIAL)
-        # build side: coalesce right entirely; stream left batches
-        # (ref GpuShuffledHashJoinExec build-side semantics)
+        # both sides materialize (spillable) before anything is joined:
+        # which of them is the build side is decided by what they hold.
         # list payloads materialize host-side: the join gather kernels move
         # 1D lanes only (columnar/nested.py with_lists_on_host)
         right_batches, left_batches = wrap_spillable_sides(
@@ -449,38 +659,192 @@ class TpuHashJoinExec(TpuExec):
              for b in self.children[0].execute(ctx)))
         ls, rs = (self.children[0].output_schema(),
                   self.children[1].output_schema())
-        total_bytes = sum(s.device_bytes() for s in right_batches +
-                          left_batches)
+        sides = {"left": left_batches, "right": right_batches}
+        try:
+            # ONE fetch a join: the row counts a filter below left on the
+            # device (sizes the build side, lets it concatenate on the
+            # device, and is what _record_sides reads afterwards)
+            _resolve_counts(left_batches + right_batches)
+            rows = {k: sum(s.num_rows for s in v) for k, v in sides.items()}
+        except BaseException:
+            for s in right_batches + left_batches:
+                s.close()
+            raise
+        build = self._build_side(rows)
         threshold = ctx.conf.join_subpartition_size_bytes
-        if (threshold > 0 and total_bytes > threshold and self.left_keys
+        # what every stream batch would be joined against, whole
+        against = (sum(s.device_bytes() for s in sides[build]) if build
+                   else sum(s.device_bytes()
+                            for s in left_batches + right_batches))
+        if (threshold > 0 and against > threshold and self.left_keys
                 and self.join_type != "cross" and self.condition is None
                 and self._subpartitionable(ls, rs)):
             yield from self._subpartitioned(ctx, left_batches, right_batches,
-                                            ls, rs, rows_m, total_bytes)
+                                            ls, rs, rows_m, against, rows)
+            return
+        if build is not None:
+            yield from self._streamed(ctx, build, sides, rows, rows_m)
             return
 
         def run():
             with ctx.semaphore.held():
-                lb = concat_batches([s.get() for s in left_batches]) \
+                lb = concat_batches([_counted(s) for s in left_batches]) \
                     if left_batches else _empty_batch(ls)
-                rb = concat_batches([s.get() for s in right_batches]) \
+                rb = concat_batches([_counted(s) for s in right_batches]) \
                     if right_batches else _empty_batch(rs)
                 lb = self._maybe_bloom_filter(ctx, lb, rb)
                 return self._join(lb, rb, ctx)
 
         try:
             out = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
-            sigs = getattr(self, "side_sigs", None)
-            if sigs is not None:
-                # AQE stage stats (ref GpuCustomShuffleReaderExec): record
-                # LOGICAL side sizes for the next planning of this shape
-                _record_sides([(sigs[0], left_batches, ls),
-                               (sigs[1], right_batches, rs)])
+            self._record(left_batches, right_batches, ls, rs)
         finally:
             for s in right_batches + left_batches:
                 s.close()
+        _count_join_rows(self._exec_id, rows["right"], rows["left"],
+                         out.num_rows_raw, 1)
         rows_m.add(out.num_rows_raw)
         yield out
+
+    def _record(self, left_batches, right_batches, ls, rs) -> None:
+        sigs = getattr(self, "side_sigs", None)
+        if sigs is not None:
+            # AQE stage stats (ref GpuCustomShuffleReaderExec): record
+            # LOGICAL side sizes for the next planning of this shape
+            _record_sides([(sigs[0], left_batches, ls),
+                           (sigs[1], right_batches, rs)])
+
+    def _build_side(self, rows: Dict[str, int]) -> Optional[str]:
+        """The side to make ready once and join every batch of the other
+        side against: the one with fewer rows among those the join type
+        lets stream (None: neither, both sides are joined whole)."""
+        if not self.left_keys:
+            return None
+        ok = [side for side in ("right", "left")
+              if self.join_type in self.STREAMABLE[side]]
+        return min(ok, key=lambda side: rows[side]) if ok else None
+
+    # -- a build side made ready once, probed by the stream side's batches
+    # (ref GpuShuffledHashJoinExec: build side coalesced to one batch, the
+    # stream side joined batch by batch) -----------------------------------
+    def _streamed(self, ctx, build: str, sides, rows, rows_m
+                  ) -> Iterator[ColumnarBatch]:
+        """The stream side never leaves its batches: each is sorted with
+        the build side alone, and its output is sized from the last
+        observed output of this join shape, not from its own bucket (a
+        selective join of a 1,048,576-row batch leaves a few thousand
+        rows). The outputs' totals are read in ONE fetch at the end,
+        checked against what was guessed, and the outputs leave as one
+        batch."""
+        bi = 1 if build == "right" else 0
+        build_batches, stream_batches = sides[build], \
+            sides["left" if bi else "right"]
+        lsch, rsch = (self.children[0].output_schema(),
+                      self.children[1].output_schema())
+        outs: List[ColumnarBatch] = []
+        try:
+            def make_build():
+                with ctx.semaphore.held():
+                    if not build_batches:
+                        return _empty_batch(rsch if bi else lsch)
+                    return concat_batches(
+                        [_counted(s) for s in build_batches], BUILD_BUCKETS)
+
+            def build_bloom_run():
+                with ctx.semaphore.held():
+                    return self._build_bloom(ctx, lsch, bb)
+            bound = self._out_bound(ctx, bi)
+            with _span("join.build", self._exec_id, rows=rows[build],
+                       cols=(rsch if bi else lsch).names()):
+                bb = with_retry_no_split(make_build, ctx=ctx,
+                                         op=self._exec_id)
+                bloom = with_retry_no_split(build_bloom_run, ctx=ctx,
+                                            op=self._exec_id) if bi else None
+                self._prepare_probe(ctx, bb, bound)
+            self._record(sides["left"], sides["right"], lsch, rsch)
+            for s in build_batches:
+                s.close()
+            n_spec = len(ctx.speculations)
+            for s in stream_batches or [None]:
+                def run(s=s):
+                    with ctx.semaphore.held():
+                        sb = _counted(s) if s is not None else \
+                            _empty_batch(lsch if bi else rsch)
+                        if bloom is not None and sb.num_rows > 0:
+                            sb = self._apply_bloom(ctx, bloom, sb)
+                        return (self._join(sb, bb, ctx, bound) if bi
+                                else self._join(bb, sb, ctx, bound))
+                with _span("join.probe", self._exec_id,
+                           cols=(lsch if bi else rsch).names()):
+                    outs.append(with_retry_no_split(run, ctx=ctx,
+                                                    op=self._exec_id))
+                if s is not None:
+                    s.close()
+            _count_out_bound(bound is not None and bound.hard)
+            # the totals this join registered for the sink are read and
+            # checked here, with the outputs' counts
+            mine = ctx.speculations[n_spec:]
+            del ctx.speculations[n_spec:]
+            out = self._coalesced(ctx, outs, mine)
+        finally:
+            for s in build_batches + stream_batches:
+                s.close()
+        _count_join_rows(self._exec_id, rows[build],
+                         rows["left" if bi else "right"], out.num_rows_raw,
+                         len(outs))
+        rows_m.add(out.num_rows_raw)
+        yield out
+
+    def _out_bound(self, ctx, bi: int) -> Optional[_OutBound]:
+        """A stream row meets at most the build side's largest key
+        multiplicity of rows where the join emits it once per match or
+        once null-extended: there the output can be bound by the input."""
+        if ctx.speculate and self.join_type in (
+                ("inner", "left") if bi == 1 else ("inner", "right")) \
+                and (self.condition is None or self.join_type == "inner"):
+            return _OutBound(stream_left=(bi == 1))
+        return None
+
+    def _coalesced(self, ctx, outs: List[ColumnarBatch],
+                   speculated) -> ColumnarBatch:
+        """The per-batch outputs as ONE batch. Their counts and the
+        totals the probes registered (``speculated``, the context's
+        records: each the pairs a batch matched BEFORE any residual
+        condition, beside the bucket that was guessed for them) are read
+        together, one transfer. A total over its bucket is the
+        speculation's overflow: pairs were cut, whatever the condition
+        kept of the rest, and the plan re-runs with exact sizing. The
+        largest total is what the next run of this join shape sizes its
+        outputs from, and the sum of the counts what the cost model learns
+        as the join's output."""
+        from ..columnar.batch import SpeculativeOverflow
+        from ..columnar.packing import fetch_packed
+        lazy = [b for b in outs if not isinstance(b.num_rows_raw, int)]
+        if lazy or speculated:
+            got = [int(n) for n in fetch_packed(
+                [b.num_rows_raw for b in lazy]
+                + [t for t, _, _, _ in speculated])]
+            over = [(n, b.padded_len) for b, n in zip(lazy, got)
+                    if n > b.padded_len]
+            for n, (_, cap, ck, _) in zip(got[len(lazy):], speculated):
+                _note_total(ctx, ck, n)
+                if n > cap:
+                    over.append((n, cap))
+            if over:
+                raise SpeculativeOverflow(*over[0])
+            for b, n in zip(lazy, got):
+                b._resolve_count(n)
+        plan_sig = getattr(self, "plan_sig", None)
+        if plan_sig is not None:
+            from ..plan.cost import record_runtime_rows
+            record_runtime_rows(plan_sig, sum(b.num_rows for b in outs))
+        if len(outs) == 1:
+            return outs[0]
+
+        def run():
+            with ctx.semaphore.held():
+                return concat_batches(outs, BUILD_BUCKETS)
+        return with_retry_no_split(run, ctx=ctx, op=self._exec_id)
 
     # -- runtime bloom filter (ref InjectRuntimeFilter + jni BloomFilter):
     # inner/semi equi-joins may drop stream rows whose keys cannot be in
@@ -505,7 +869,7 @@ class TpuHashJoinExec(TpuExec):
         from ..exprs.hash_fns import device_hashable
         from ..types import from_numpy_dtype
         rs = rb.schema
-        self._bloom_key_dtypes = []
+        key_dtypes = []
         for lk, rk in zip(self.left_keys, self.right_keys):
             ldt, rdt = lk.data_type(ls), rk.data_type(rs)
             if (device_hashable.reason_not_supported(ldt)
@@ -521,15 +885,18 @@ class TpuHashJoinExec(TpuExec):
                     return None
                 if device_hashable.reason_not_supported(cdt):
                     return None
-                self._bloom_key_dtypes.append(cdt)
+                key_dtypes.append(cdt)
             else:
-                self._bloom_key_dtypes.append(ldt)
+                key_dtypes.append(ldt)
         from ..exprs.bloom_filter import build_bloom
         from ..exprs.compiler import compile_projection
         rvals = [self._cast_key(DVal(c.data, c.validity, c.dtype), dt)
                  for c, dt in zip(compile_projection(
-                     self.right_keys, rs).run(rb), self._bloom_key_dtypes)]
-        return build_bloom(rvals, rb.num_rows)
+                     self.right_keys, rs).run(rb), key_dtypes)]
+        bloom = build_bloom(rvals, rb.num_rows)
+        #: the dtype each key pair was promoted to: probes cast to it
+        bloom.key_dtypes = key_dtypes
+        return bloom
 
     @staticmethod
     def _cast_key(v: DVal, dt) -> DVal:
@@ -543,7 +910,7 @@ class TpuHashJoinExec(TpuExec):
         ls = lb.schema
         lvals = [self._cast_key(DVal(c.data, c.validity, c.dtype), dt)
                  for c, dt in zip(compile_projection(
-                     self.left_keys, ls).run(lb), self._bloom_key_dtypes)]
+                     self.left_keys, ls).run(lb), bloom.key_dtypes)]
         live = jnp.arange(lb.padded_len, dtype=jnp.int32) < lb.num_rows
         keep = jnp.logical_and(bloom.might_contain_mask(lvals), live)
         out = filter_batch_by_mask(lb, keep)
@@ -576,7 +943,7 @@ class TpuHashJoinExec(TpuExec):
     SUBPARTITION_SEED = 1610612741
 
     def _subpartitioned(self, ctx, left_batches, right_batches, ls, rs,
-                        rows_m, total_bytes) -> Iterator[ColumnarBatch]:
+                        rows_m, total_bytes, rows) -> Iterator[ColumnarBatch]:
         """Hash both sides into N sub-partitions on the same key hash and run
         N independent joins — matching keys (and null keys, which never match
         anyway) co-locate, so every equi-join type distributes over the
@@ -593,9 +960,9 @@ class TpuHashJoinExec(TpuExec):
             outs = []
             try:
                 with ctx.semaphore.held():
-                    lb = concat_batches([s.get() for s in left_batches]) \
+                    lb = concat_batches([_counted(s) for s in left_batches]) \
                         if left_batches else _empty_batch(ls)
-                    rb = concat_batches([s.get() for s in right_batches]) \
+                    rb = concat_batches([_counted(s) for s in right_batches]) \
                         if right_batches else _empty_batch(rs)
                     lp = partition_batch(lb, self.left_keys, n_parts,
                                          seed=self.SUBPARTITION_SEED)
@@ -620,6 +987,8 @@ class TpuHashJoinExec(TpuExec):
         finally:
             for s in left_batches + right_batches:
                 s.close()
+        _count_join_rows(self._exec_id, rows["right"], rows["left"],
+                         [s._num_rows for s in outs], n_parts)
         try:
             for s in outs:
                 b = s.get()
@@ -653,15 +1022,17 @@ class TpuHashJoinExec(TpuExec):
               tuple((f.name, f.dtype.name) for f in ls.fields),
               tuple((f.name, f.dtype.name) for f in rs.fields),
               self.join_type)
+        if bound is not None and bound.probe is not None \
+                and ctx.speculate and lb.all_device and rb.all_device:
+            return self._join_probe(ctx, lb if bound.stream_left else rb,
+                                    bound, ck)
         kern = _COUNT_CACHE.get(ck)
         if kern is None:
             kern = _build_count_kernel(self.left_keys, self.right_keys,
                                        ls, rs, self.join_type)
             _COUNT_CACHE[ck] = kern
-        lcols = [(c.data, c.validity) if isinstance(c, DeviceColumn)
-                 else None for c in lb.columns]
-        rcols = [(c.data, c.validity) if isinstance(c, DeviceColumn)
-                 else None for c in rb.columns]
+        lcols = _lanes(lb)
+        rcols = _lanes(rb)
         semi_like = self.join_type in ("leftsemi", "leftanti")
 
         # ONE-dispatch fused path: with speculative sizing the output
@@ -703,14 +1074,15 @@ class TpuHashJoinExec(TpuExec):
             # lazy sizing needs no validation at all
             n_out = total
             out_p = bucket_for(max(lb.padded_len, 1))
-        elif hard_p and spec:
+        elif hard_p and spec and (stat is None or bucket_for(
+                max(int(stat * 1.5), 1)) >= hard_p):
             n_out, out_p = total, hard_p
         elif measure:
             n_out, mult = (int(x) for x in traced_device_get(
                 (total, _max_count(cnt_r if bound.stream_left else cnt_l)),
                 "d2h.join_count"))
             bound.mult = mult
-            _TOTAL_STATS[ck] = n_out
+            _note_total(ctx, ck, n_out)
             # unique build keys: the stream's bucket from the first batch
             # on, so every batch of the query leaves in one shape
             out_p = stream_p if bound.hard else bucket_for(max(n_out, 1))
@@ -724,7 +1096,7 @@ class TpuHashJoinExec(TpuExec):
                                      getattr(self, 'plan_sig', None)))
         else:
             n_out = int(traced_device_get(total, "d2h.join_count"))
-            _TOTAL_STATS[ck] = n_out
+            _note_total(ctx, ck, n_out)
             out_p = bucket_for(max(n_out, 1))
         left_nullable = 1 if self.join_type in ("right", "full") else 0
         right_nullable = 1 if self.join_type in ("left", "full") else 0
@@ -744,6 +1116,93 @@ class TpuHashJoinExec(TpuExec):
             out = filter_batch_device(self.condition, out)
         return out
 
+    # -- the unique-key probe (kernels above _build_count_kernel) ----------
+    def _probe_key(self, build_left: bool, ls: Schema, rs: Schema):
+        """(build side's key, its schema) where the probe applies: an
+        inner join on ONE key that is an integer lane on both sides
+        (integers, dates, timestamps; a decimal's lane means another
+        number at another scale)."""
+        if self.join_type != "inner" or len(self.left_keys) != 1:
+            return None
+        for k, sch in ((self.left_keys[0], ls), (self.right_keys[0], rs)):
+            dt = k.data_type(sch)
+            if isinstance(dt, DecimalType) or dt.np_dtype is None \
+                    or dt.np_dtype.kind != "i":
+                return None
+        return (self.left_keys[0], ls) if build_left \
+            else (self.right_keys[0], rs)
+
+    def _prepare_probe(self, ctx, bb: ColumnarBatch,
+                       bound: Optional[_OutBound]) -> None:
+        """Sort the build side by its key, once a join a query, and read
+        (ONE small fetch) how many of its rows can match and whether each
+        key is there once: then ``bound`` carries it and every stream
+        batch is probed against it. Otherwise ``bound`` stays as it was
+        and the first stream batch measures the key multiplicity."""
+        if bound is None or not bb.all_device:
+            return
+        found = self._probe_key(not bound.stream_left,
+                                self.children[0].output_schema(),
+                                self.children[1].output_schema())
+        if found is None:
+            return
+        kern = _probe_kernel("build", *found)
+
+        def run():
+            with ctx.semaphore.held():
+                keys, order, rows, unusable = kern(
+                    _lanes(bb), jnp.int32(bb.num_rows_raw), bb.padded_len)
+                rows, unusable = traced_device_get((rows, unusable),
+                                                   "d2h.join_count")
+                if unusable:
+                    return None
+                return _SortedBuild(keys, gather_batch_device(
+                    bb, order, int(rows), bb.padded_len), int(rows))
+        bound.probe = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
+        if bound.probe is not None:
+            bound.mult = min(bound.probe.rows, 1)
+
+    def _join_probe(self, ctx, sb: ColumnarBatch, bound: _OutBound,
+                    ck) -> ColumnarBatch:
+        """One stream batch against the sorted build side: the probe,
+        then the gather of the matched pairs. The output is sized from the last observed total of this join
+        shape where that takes a smaller bucket than the stream batch's
+        own (the hard bound: each stream row matches at most once); the
+        first batch ever of a shape reads its total."""
+        build = bound.probe
+        key_expr = (self.left_keys if bound.stream_left
+                    else self.right_keys)[0]
+        kern = _probe_kernel("probe", key_expr, sb.schema)
+        out_p = bucket_for(max(sb.padded_len, 1))
+        stat = _TOTAL_STATS.get(ck)
+        guess = out_p if stat is None \
+            else bucket_for(max(int(stat * 1.5), 1))
+        speculative = guess < out_p
+        out_p = min(out_p, guess)
+        from ..plan import exec_cache
+        scols = _lanes(sb)
+        total, s_row, b_row = kern(
+            build.keys, jnp.int32(build.rows), scols,
+            jnp.int32(sb.num_rows_raw), sb.padded_len)
+        souts, bouts = exec_cache.get_or_build_jit(
+            "joins.pairs_gather", _pairs_gather, static_argnums=(5,))(
+                total, s_row, b_row, scols, _lanes(build.batch), out_p)
+        if speculative:
+            ctx.speculations.append((total, out_p, ck,
+                                     getattr(self, 'plan_sig', None)))
+        elif stat is None:
+            total = int(traced_device_get(total, "d2h.join_count"))
+            _note_total(ctx, ck, total)
+        s_out = [c.with_arrays(d, v)
+                 for c, (d, v) in zip(sb.columns, souts)]
+        b_out = [c.with_arrays(d, v)
+                 for c, (d, v) in zip(build.batch.columns, bouts)]
+        out = ColumnarBatch(s_out + b_out if bound.stream_left
+                            else b_out + s_out, total, self._schema)
+        if self.condition is not None:
+            out = filter_batch_device(self.condition, out)
+        return out
+
     def _join_fused(self, ctx, lb: ColumnarBatch, rb: ColumnarBatch,
                     lcols, rcols, ck, count_kern, semi_like: bool,
                     stat, hard_p: Optional[int] = None) -> ColumnarBatch:
@@ -753,10 +1212,16 @@ class TpuHashJoinExec(TpuExec):
             _FUSED_CACHE[ck] = fk
         if semi_like:
             out_p = bucket_for(max(lb.padded_len, 1))
-        elif hard_p:
-            out_p = hard_p
         else:
-            out_p = bucket_for(max(int(stat * 1.5), 1))
+            # the last observed total (x1.5 headroom) where it is known
+            # and sizes the output under the hard bound: a selective join
+            # leaves a small share of its stream batch's bucket
+            out_p = bucket_for(max(int(stat * 1.5), 1)) \
+                if stat is not None else hard_p
+            if hard_p and out_p >= hard_p:
+                out_p = hard_p
+            else:
+                hard_p = None
         left_nullable = 1 if self.join_type in ("right", "full") else 0
         right_nullable = 1 if self.join_type in ("left", "full") else 0
         cfg = jnp.array([left_nullable, right_nullable,
@@ -802,10 +1267,7 @@ class TpuHashJoinExec(TpuExec):
             kern = _build_count_kernel(self.left_keys, self.right_keys,
                                        ls, rs, "inner")
             _COUNT_CACHE[ck] = kern
-        lcols = [(c.data, c.validity) if isinstance(c, DeviceColumn)
-                 else None for c in lb.columns]
-        rcols = [(c.data, c.validity) if isinstance(c, DeviceColumn)
-                 else None for c in rb.columns]
+        lcols, rcols = _lanes(lb), _lanes(rb)
         (s_orig, cnt_l, cnt_r, start_l, start_r, _pairs, offsets, total,
          _ng) = kern(lcols, rcols, jnp.int32(lb.num_rows),
                      jnp.int32(rb.num_rows), lb.padded_len, rb.padded_len)
@@ -934,13 +1396,6 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
     BUILD side across stream batches may stream; the rest take the
     coalesced whole-sides path."""
 
-    #: join types streamable per build side
-    STREAMABLE = {
-        "right": ("inner", "left", "leftsemi", "leftanti", "existence",
-                  "cross"),
-        "left": ("inner", "right", "cross"),
-    }
-
     def __init__(self, left, right, join_type, left_keys, right_keys,
                  condition=None, build_side: str = "right"):
         super().__init__(left, right, join_type, left_keys, right_keys,
@@ -957,7 +1412,12 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
             yield from super().do_execute(ctx)
             return
         rows_m = ctx.metric(self._exec_id, "numOutputRows", ESSENTIAL)
-        bb = build.broadcast(ctx)
+        bound = self._out_bound(ctx, bi)
+        with _span("join.build", self._exec_id,
+                   cols=build.output_schema().names()):
+            bb = build.broadcast(ctx)
+            if bb is not None:
+                self._prepare_probe(ctx, bb.with_lists_on_host(), bound)
         if bb is not None:
             # list payloads demote like every other join intake: the
             # gather path moves 1D lanes only
@@ -1001,15 +1461,8 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
                                         op=self._exec_id)
         else:
             bloom = None
-        # a stream row meets at most the build side's largest key
-        # multiplicity of rows where the join emits it once per match or
-        # once null-extended: there the output can be bound by the input
-        bound = None
-        if ctx.speculate and self.join_type in (
-                ("inner", "left") if bi == 1 else ("inner", "right")) \
-                and (self.condition is None or self.join_type == "inner"):
-            bound = _OutBound(stream_left=(bi == 1))
         produced = False
+        n_stream, n_out = [], []   # counts as they are: no read a batch
         try:
             for sb in self.children[1 - bi].execute(ctx):
                 sb = sb.ensure_device().with_lists_on_host()
@@ -1021,8 +1474,13 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
                             sb2 = sb
                         return (self._join(sb2, bb, ctx, bound) if bi == 1
                                 else self._join(bb, sb2, ctx, bound))
-                out = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
+                with _span("join.probe", self._exec_id,
+                           cols=sb.schema.names()):
+                    out = with_retry_no_split(run, ctx=ctx,
+                                              op=self._exec_id)
                 rows_m.add(out.num_rows_raw)
+                n_stream.append(sb.num_rows_raw)
+                n_out.append(out.num_rows_raw)
                 produced = True
                 yield out
             if not produced:
@@ -1036,6 +1494,9 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
                                           op=self._exec_id)
         finally:
             _count_out_bound(bound is not None and bound.hard)
+        _count_join_rows(self._exec_id,
+                         bb.num_rows_raw if bb is not None else 0,
+                         n_stream, n_out, len(n_out))
 
     def describe(self):
         return "Broadcast" + super().describe()[:-1] + \

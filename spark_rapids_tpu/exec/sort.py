@@ -94,11 +94,13 @@ def _build_sort_kernel(orders: List[SortOrder], schema: Schema,
         for o in orders:
             v = o.expr.eval_device(ctx)
             operands.extend(order_key_operands(v, o.ascending, o.nulls_first))
-        # sort (keys, row-index) then gather columns — payload-free sort
+        # sort (keys, row-index) then gather columns — payload-free sort;
+        # the row index is the last KEY: unique keys, so the unstable sort
+        # gives the stable order (and compiles in less time)
         perm0 = jnp.arange(padded_len, dtype=jnp.int32)
         n_ops = len(operands)
-        out = jax.lax.sort(tuple(operands + [perm0]), num_keys=n_ops,
-                           is_stable=True)
+        out = jax.lax.sort(tuple(operands + [perm0]), num_keys=n_ops + 1,
+                           is_stable=False)
         perm = out[n_ops]
         sorted_cols = [(jnp.take(dv.data, perm), jnp.take(dv.validity, perm))
                        for dv in dvals]
@@ -127,6 +129,80 @@ def sort_batch_device(orders: List[SortOrder], batch: ColumnarBatch,
                 for (d, v), c in zip(outs, batch.columns)]
     out = ColumnarBatch(new_cols, batch.num_rows, batch.schema)
     return (out, ops) if with_keys else out
+
+
+#: the largest LIMIT a sort above which takes the selection kernel (TPC-H's
+#: and TPC-DS's own are 10 to 100): its loop runs once a kept row
+TOP_N_MAX = 128
+
+
+def _build_topn_kernel(orders: List[SortOrder], schema: Schema, n: int):
+    """The first ``n`` rows of the sorted batch WITHOUT the sort: ``n``
+    rounds of a lexicographic minimum over the encoded key operands (a
+    masked min-reduction an operand, the candidates narrowed to the rows
+    that hold it), the lowest row index among equals, which is the stable
+    sort's order. A full sort of the batch is what a LIMIT above it pays
+    for and throws away; and its module is the one a float64 key makes
+    slowest to compile (262,144 rows, ``revenue DESC, o_orderdate``: 231 s
+    on the chip's host, PERF.md, PR 32)."""
+    from ..columnar.bucketing import bucket_for
+    from ..columnar.segmented import _neutral_max
+    dtypes = [f.dtype for f in schema.fields]
+    out_p = bucket_for(n)
+
+    def topn(cols, num_rows, padded_len):
+        dvals = [None if c is None else DVal(c[0], c[1], dt)
+                 for c, dt in zip(cols, dtypes)]
+        ctx = EvalContext(schema, dvals, num_rows, padded_len)
+        operands = []
+        for o in orders:
+            v = o.expr.eval_device(ctx)
+            for op in order_key_operands(v, o.ascending, o.nulls_first):
+                if jnp.issubdtype(op.dtype, jnp.floating):
+                    # the sort's total order has NaN above +inf (below
+                    # -inf negated, for DESC); a min-reduction has not
+                    nan = jnp.isnan(op)
+                    low = jnp.uint8(1 if o.ascending else 0)
+                    operands.append(jnp.where(nan, low, jnp.uint8(1) - low))
+                    op = jnp.where(nan, jnp.zeros_like(op), op)
+                operands.append(op)
+
+        def pick(t, state):
+            alive, picks = state
+            cand = alive
+            for op in operands:
+                least = jnp.min(jnp.where(cand, op, _neutral_max(op.dtype)))
+                cand = jnp.logical_and(cand, op == least)
+            row = jnp.argmax(cand).astype(jnp.int32)
+            return alive.at[row].set(False), picks.at[t].set(row)
+
+        _, picks = jax.lax.fori_loop(
+            0, n, pick, (ctx.row_mask(), jnp.zeros(out_p, jnp.int32)))
+        count = jnp.minimum(jnp.sum(ctx.row_mask()), n).astype(jnp.int32)
+        live = jnp.arange(out_p, dtype=jnp.int32) < count
+        return [(jnp.take(dv.data, picks, axis=0),
+                 jnp.logical_and(jnp.take(dv.validity, picks), live))
+                for dv in dvals], count
+
+    # ONE callable a process (the executable cache's, so its compiles are
+    # counted), as the joins' probe kernels
+    from ..plan import exec_cache
+    return exec_cache.get_or_build_jit(
+        f"sort.topn:{_kernel_cache_key(orders, schema)}:{n}", topn,
+        static_argnums=(2,))
+
+
+def topn_batch_device(orders: List[SortOrder], batch: ColumnarBatch,
+                      n: int) -> ColumnarBatch:
+    """``sort_batch_device`` cut to its first ``n`` rows, in their order."""
+    kernel = _build_topn_kernel(orders, batch.schema, n)
+    cols = [(c.data, c.validity) for c in batch.columns]
+    outs, count = kernel(cols, jnp.int32(batch.num_rows_raw),
+                         batch.padded_len)
+    rows = batch.num_rows_raw
+    return ColumnarBatch(
+        [c.with_arrays(d, v) for (d, v), c in zip(outs, batch.columns)],
+        min(rows, n) if isinstance(rows, int) else count, batch.schema)
 
 
 _KEYENC_CACHE: Dict[Tuple, object] = {}
@@ -189,15 +265,36 @@ class TpuSortExec(TpuExec):
     OVERSAMPLE = 8
 
     def __init__(self, orders: List[SortOrder], child: TpuExec,
-                 global_sort: bool = True):
+                 global_sort: bool = True, limit: int = None):
         super().__init__([child])
         self.orders = orders
         self.global_sort = global_sort
+        #: rows a LIMIT above keeps (plan/rewrites.py); None: all
+        self.limit = limit
 
     def output_schema(self) -> Schema:
         return self.children[0].output_schema()
 
+    def _top_n(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+        """Each input batch cut to its own first ``limit`` rows as it
+        arrives, then those cut once more: nothing is held but
+        ``limit`` rows a batch, and no batch is sorted."""
+        def top(batch):
+            with ctx.semaphore.held():
+                return topn_batch_device(self.orders, batch, self.limit)
+        tops = [with_retry_no_split(
+                    lambda b=b: top(b.ensure_device().with_lists_on_host()),
+                    ctx=ctx, op=self._exec_id)
+                for b in self.children[0].execute(ctx)]
+        if len(tops) > 1:
+            tops = [with_retry_no_split(lambda: top(concat_batches(tops)),
+                                        ctx=ctx, op=self._exec_id)]
+        yield from tops
+
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+        if self.limit is not None:
+            yield from self._top_n(ctx)
+            return
         if not self.global_sort:
             for batch in self.children[0].execute(ctx):
                 with ctx.semaphore.held():
@@ -328,7 +425,8 @@ class TpuSortExec(TpuExec):
             raise
 
     def describe(self):
-        return "Sort[" + ", ".join(map(repr, self.orders)) + "]"
+        top = "" if self.limit is None else f"; first {self.limit}"
+        return "Sort[" + ", ".join(map(repr, self.orders)) + top + "]"
 
 
 class CpuSortExec(TpuExec):

@@ -9,8 +9,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..types import (BOOL, DataType, DecimalType, Schema, comparable,
-                     STRING)
+from ..types import (BOOL, DataType, DecimalType, Schema, TypeEnum,
+                     comparable, tpuNative, STRING)
 from . import decimal_rules as D
 from .base import DVal, EvalContext, Expression, null_and, promote_types
 from .arithmetic import (arrow_to_masked_numpy, decimal_as_double,
@@ -38,9 +38,51 @@ def _nan_lt(l, r):
     return l < r
 
 
+#: supported_ops.md's note on STRING for =, <>, IN
+_DICT_NOTE = ("a plain STRING column against string literal(s) in a filter "
+              "is evaluated over the column's sorted dictionary, once per "
+              "distinct value, and the batch stays on the device (placement "
+              "code EXPR_DICT_EVAL); an IN list holding NULL, and a "
+              "comparison of two string columns or of a computed string, "
+              "run on the host")
+
+
+def _plain_string_column(e, schema: Schema):
+    """``e`` if it is a bare reference to a STRING column of ``schema``."""
+    from .base import ColumnRef
+    if isinstance(e, ColumnRef) and e.name in schema.names() \
+            and schema[e.name].dtype == STRING:
+        return e
+    return None
+
+
 class BinaryComparison(Expression):
     device_type_sig = comparable
     symbol = "?"
+    #: how a comparison of a STRING column with a string literal is
+    #: evaluated over the column's dictionary (exprs/compiler.py
+    #: build_dict_filter): None = not at all; "range" = the matching codes
+    #: of a sorted dictionary are one contiguous span; "mask" = any set
+    dict_form = None
+
+    def dict_column(self, schema: Schema):
+        """The plain STRING column this predicate compares with a non-NULL
+        string literal (either order), or None: then the match can be
+        computed ONCE per distinct value (``host_mask``) and broadcast
+        through the codes on the device."""
+        from .base import Literal
+        if self.dict_form is None:
+            return None
+        for col, lit in (self.children, self.children[::-1]):
+            if isinstance(lit, Literal) and isinstance(lit.value, str):
+                return _plain_string_column(col, schema)
+        return None
+
+    def _dict_literal(self):
+        import pyarrow as pa
+        from .base import Literal
+        lit = next(c for c in self.children if isinstance(c, Literal))
+        return pa.scalar(lit.value, type=pa.string())
     #: an integer or SQL-decimal literal beside a decimal operand becomes
     #: a decimal literal (exprs/base.py:coerce_decimal_literals)
     decimal_literal_operands = True
@@ -156,6 +198,12 @@ class BinaryComparison(Expression):
 
 class EqualTo(BinaryComparison):
     symbol = "="
+    device_type_sig = comparable.with_psnote(TypeEnum.STRING, _DICT_NOTE)
+    dict_form = "range"     # one value = one code of a sorted dictionary
+
+    def host_mask(self, arr):
+        import pyarrow.compute as pc
+        return pc.equal(arr, self._dict_literal())
 
     def eval_device(self, ctx):
         l, r, v = self._operands(ctx)
@@ -194,6 +242,12 @@ class EqualNullSafe(BinaryComparison):
 
 class NotEqual(BinaryComparison):
     symbol = "!="
+    device_type_sig = comparable.with_psnote(TypeEnum.STRING, _DICT_NOTE)
+    dict_form = "mask"
+
+    def host_mask(self, arr):
+        import pyarrow.compute as pc
+        return pc.not_equal(arr, self._dict_literal())
 
     def eval_device(self, ctx):
         l, r, v = self._operands(ctx)
@@ -349,12 +403,28 @@ class IsNaN(Expression):
 class In(Expression):
     """value IN (literals...) (ref GpuInSet)."""
 
+    device_type_sig = tpuNative.with_psnote(TypeEnum.STRING, _DICT_NOTE)
+    dict_form = "mask"
+
     def __init__(self, child: Expression, values):
         self.children = [child]
         self.values = tuple(values)
 
     def data_type(self, schema):
         return BOOL
+
+    def dict_column(self, schema: Schema):
+        """As ``BinaryComparison.dict_column``: a plain STRING column IN a
+        list of non-NULL string literals. (A NULL in the list makes a
+        non-match NULL, not false, which a mask cannot say.)"""
+        if self.values and all(isinstance(v, str) for v in self.values):
+            return _plain_string_column(self.children[0], schema)
+        return None
+
+    def host_mask(self, arr):
+        import pyarrow as pa
+        import pyarrow.compute as pc
+        return pc.is_in(arr, value_set=pa.array(self.values, pa.string()))
 
     def eval_device(self, ctx):
         c = self.children[0].eval_device(ctx)

@@ -282,25 +282,25 @@ class DictFilterEvaluator:
 def build_dict_filter(cond: Expression,
                       schema: Schema) -> Optional[DictFilterEvaluator]:
     """Rewrite ``cond`` replacing string predicates over plain STRING
-    column refs with _DictSlot placeholders; returns an evaluator when
-    the remainder is fully device-supported, else None."""
+    column refs (the pattern predicates, and ``=``, ``<>``, ``IN``
+    against string literals: whatever answers ``dict_column``) with
+    _DictSlot placeholders; returns an evaluator when the remainder is
+    fully device-supported, else None."""
     import copy as _copy
-    from ..types import STRING
-    from .base import ColumnRef
     from .string_fns import _PatternPredicate
     names = schema.names()
     preds: list = []
     n_lits = len(collect_param_literals([cond]))
 
     def rewrite(e):
+        dict_column = getattr(e, "dict_column", None)
+        col = dict_column(schema) if dict_column is not None else None
+        if col is not None:
+            ordinal = names.index(col.name)
+            slot = n_lits + len(preds)
+            preds.append((e, ordinal, e.dict_form))
+            return _DictSlot(slot, ordinal, e.dict_form)
         if isinstance(e, _PatternPredicate):
-            child = e.children[0]
-            if isinstance(child, ColumnRef) and child.name in names \
-                    and schema[child.name].dtype == STRING:
-                ordinal = names.index(child.name)
-                slot = n_lits + len(preds)
-                preds.append((e, ordinal, e.dict_form))
-                return _DictSlot(slot, ordinal, e.dict_form)
             return None
         if not getattr(e, "children", None):
             return e

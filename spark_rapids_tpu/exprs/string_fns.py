@@ -193,6 +193,12 @@ class _PatternPredicate(_HostStringExpr):
     def data_type(self, schema):
         return BOOL
 
+    def dict_column(self, schema):
+        """The plain STRING column the pattern is matched against, or
+        None (exprs/compiler.py build_dict_filter)."""
+        from .comparison import _plain_string_column
+        return _plain_string_column(self.children[0], schema)
+
     def host_mask(self, arr):
         raise NotImplementedError
 

@@ -795,6 +795,13 @@ class _Planner:
             return self.lower(node.children[0], replicated)
 
         if isinstance(node, B.TpuFilterExec):
+            if node.condition.fully_device_supported(
+                    node.children[0].output_schema()) is not None:
+                # a string predicate evaluated over the dictionary
+                # (exprs/compiler.py build_dict_filter) has no fragment
+                # form: the operator runs ahead of the fragment, as a
+                # source, where its host twin used to
+                return self.source(node, replicated)
             child = self.lower(node.children[0], replicated)
             if not self._expr_ok(node.condition, child):
                 raise _NotLowerable(f"filter {node.condition.name_hint}")
@@ -1745,6 +1752,16 @@ def _try_replace(node, conf: TpuConf, mesh, require_join: bool = False,
     return node if changed else None
 
 
+def _oversized_scan(sources) -> bool:
+    """Whether an in-memory scan among the fragment's sources holds more
+    rows than the largest shape bucket, read off the plan."""
+    from ..columnar.bucketing import DEFAULT_BUCKETS
+    from ..exec.basic import InMemoryScanExec
+    return any(isinstance(s, InMemoryScanExec)
+               and sum(t.num_rows for t in s.tables) > max(DEFAULT_BUCKETS)
+               for s, _ in sources)
+
+
 def _lower_node(node, conf: TpuConf, mesh, require_join: bool = False,
                 keep_fallback: bool = False):
     planner = _Planner(conf, fused_mode=require_join)
@@ -1756,6 +1773,12 @@ def _lower_node(node, conf: TpuConf, mesh, require_join: bool = False,
     if not planner.has_comm:
         return None                 # no join/agg: the mesh gains nothing
     if require_join and not planner.has_join:
+        return None
+    if keep_fallback and _oversized_scan(planner.sources):
+        # the fragment is a single-batch program and would hand such a
+        # source to its fallback before running anything
+        # (DistributedPipelineExec.do_execute): plan the operator pipeline
+        # as what it is, so that explain shows the operators that run
         return None
     ex = DistributedPipelineExec(frag, planner.sources, mesh, conf,
                                  node.output_schema(),

@@ -182,16 +182,19 @@ class SortOrder:
 
 class Sort(LogicalPlan):
     def __init__(self, orders: Sequence[SortOrder], child: LogicalPlan,
-                 global_sort: bool = True):
+                 global_sort: bool = True, limit: Optional[int] = None):
         self.orders = list(orders)
         self.global_sort = global_sort
+        #: the rows a GlobalLimit above keeps (rewrites.limit_into_sort)
+        self.limit = limit
         self.children = [child]
 
     def schema(self) -> Schema:
         return self.children[0].schema()
 
     def describe(self):
-        return f"Sort[{', '.join(map(repr, self.orders))}]"
+        top = "" if self.limit is None else f"; first {self.limit}"
+        return f"Sort[{', '.join(map(repr, self.orders))}{top}]"
 
 
 class GlobalLimit(LogicalPlan):

@@ -17,6 +17,7 @@ from ..exec import basic as B
 from ..exec import aggregate as A
 from ..exec import sort as S
 from ..exec.base import TpuExec
+from ..trace import core as trace_core
 from . import logical as L
 from . import tags as T
 from .meta import PlanMeta
@@ -77,7 +78,16 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
         # distinct applies only off-mesh: the distributed fragment
         # compiler lowers the two-level Aggregate form, not the
         # stateful DistinctFlag operator.
-        from .rewrites import HASH_DISTINCT_ENABLED, rewrite_plan
+        from .rewrites import (HASH_DISTINCT_ENABLED,
+                               push_filters_below_joins, rewrite_plan)
+        # one-input conjuncts of a filter above a join go below it first:
+        # both engines run the plan in that shape, so a whole-plan host
+        # reversion below keeps it
+        plan, pushed, above = push_filters_below_joins(plan)
+        tr = trace_core.TRACER
+        if tr is not None:
+            tr.counter("plan.pushdown", {"pushed": pushed,
+                                         "above_joins": above}, cat="plan")
         plan0 = plan               # the user's shape, pre-rewrite
         plan = rewrite_plan(
             plan, hash_distinct=(mesh is None
@@ -625,7 +635,7 @@ class SortMeta(PlanMeta):
 
     def convert_to_tpu(self, children):
         return S.TpuSortExec(self.plan.orders, children[0],
-                             self.plan.global_sort)
+                             self.plan.global_sort, self.plan.limit)
 
     def convert_to_cpu(self, children):
         return S.CpuSortExec(self.plan.orders, children[0],

@@ -31,7 +31,8 @@ from ..exprs.conditional import Coalesce
 from ..types import FLOAT64, INT64
 from . import logical as L
 
-__all__ = ["rewrite_plan", "prune_columns", "HASH_DISTINCT_ENABLED"]
+__all__ = ["rewrite_plan", "prune_columns", "push_filters_below_joins",
+           "HASH_DISTINCT_ENABLED"]
 
 from ..config import register
 
@@ -71,6 +72,20 @@ def _agg_refs(a, out: set):
     # input_exprs() covers multi-input aggregates (min_by's ordering)
     for e in a.input_exprs():
         _expr_refs(e, out)
+
+
+def _narrowed(child: L.LogicalPlan, required: Optional[set]):
+    """A join input under a projection onto what the join and the plan
+    above it read, where the input still carries more (a column only its
+    own filter reads, a key of a join below): the join sorts, gathers and
+    concatenates every column it is handed."""
+    if required is None:
+        return child
+    names = child.schema().names()
+    keep = [n for n in names if n in required] or names[:1]
+    if len(keep) == len(names):
+        return child
+    return L.Project([ColumnRef(n) for n in keep], child)
 
 
 def prune_columns(plan: L.LogicalPlan,
@@ -172,19 +187,123 @@ def prune_columns(plan: L.LogicalPlan,
             _expr_refs(plan.condition, cond_refs)
             lreq |= cond_refs & lnames
             rreq |= cond_refs & rnames
-        return rebuilt(plan, [prune_columns(plan.children[0], lreq),
-                              prune_columns(plan.children[1], rreq)])
+        return rebuilt(plan, [_narrowed(prune_columns(c, req), req)
+                              for c, req in zip(plan.children,
+                                                (lreq, rreq))])
     # Window/Generate/Expand/WriteFile/unknown: conservative — children
     # keep everything
     return rebuilt(plan, [prune_columns(c, None) for c in plan.children])
+
+
+# ---------------------------------------------------------------------------
+# predicate pushdown below joins (ref Spark PushPredicateThroughJoin)
+# ---------------------------------------------------------------------------
+# The SQL lowering claims the join-key conjuncts of an implicit join and
+# leaves every other WHERE conjunct in ONE filter above the last join, and
+# the DataFrame API lets a user write the same shape. A conjunct that names
+# columns of one join input alone filters that input just as well, and
+# there it runs before the join pays for the rows it drops.
+
+#: join type -> the inputs a filter ABOVE the join may move onto: both of
+#: an inner or cross join; the preserved side only of an outer, semi or
+#: anti join (below the null-producing side it would keep NULL-extended
+#: rows the filter above drops); none of a full outer or existence join
+_PUSH_SIDES = {"inner": (0, 1), "cross": (0, 1), "left": (0,),
+               "right": (1,), "leftsemi": (0,), "leftanti": (0,),
+               "full": (), "existence": ()}
+
+
+def _home(cond, sides) -> Optional[int]:
+    """The one join input (0 left, 1 right) whose columns alone the
+    conjunct names, by the inputs' column-name sets; None for a conjunct
+    over both, over neither, or over a name both inputs have."""
+    refs: set = set()
+    _expr_refs(cond, refs)
+    for i in (0, 1):
+        if refs and refs <= sides[i] and not refs & sides[1 - i]:
+            return i
+    return None
+
+
+def _and_all(conds):
+    from ..exprs.logical import And
+    out = None
+    for c in conds:
+        out = c if out is None else And(out, c)
+    return out
+
+
+def _filtered(conds, plan: L.LogicalPlan) -> L.LogicalPlan:
+    """``plan`` under the conjuncts: each moved below the join it can
+    cross, what is left in ONE filter directly above ``plan``."""
+    if not conds:
+        return plan
+    if isinstance(plan, L.Filter):
+        inner: list = []
+        _conjuncts(plan.condition, inner)
+        return _filtered(inner + list(conds), plan.children[0])
+    if isinstance(plan, L.Join):
+        from ..exec.wholestage import _nondeterministic
+        sides = [set(c.schema().names()) for c in plan.children]
+        moved = ([], [])
+        kept = []
+        for c in conds:
+            i = _home(c, sides)
+            # a value with per-task state (exec/wholestage.py's marker)
+            # depends on which rows reach it: it stays where it was written
+            if i in _PUSH_SIDES[plan.join_type] \
+                    and not _nondeterministic([c]):
+                moved[i].append(c)
+            else:
+                kept.append(c)
+        if moved[0] or moved[1]:
+            plan = copy.copy(plan)
+            plan.children = [_filtered(moved[i], plan.children[i])
+                             for i in (0, 1)]
+        conds = kept
+    return L.Filter(_and_all(conds), plan) if conds else plan
+
+
+def push_filters_below_joins(plan: L.LogicalPlan):
+    """(plan', pushed, above_joins): every filter directly above a join
+    split into its conjuncts, each deterministic conjunct that names
+    columns of ONE join input moved onto that input (recursively: below
+    the next join too), the others kept above. ``pushed`` counts the
+    conjuncts that crossed a join; ``above_joins`` the one-input conjuncts
+    that could not (the null-producing side of an outer join, a value
+    with per-task state). The plan comes back as the same object when
+    nothing moves."""
+    counts = [0, 0]
+
+    def walk(node):
+        kids = [walk(c) for c in node.children]
+        if any(n is not o for n, o in zip(kids, node.children)):
+            node = copy.copy(node)
+            node.children = kids
+        if not (isinstance(node, L.Filter)
+                and isinstance(node.children[0], L.Join)):
+            return node
+        join = node.children[0]
+        conds: list = []
+        _conjuncts(node.condition, conds)
+        new = _filtered(conds, join)
+        stay = []
+        if isinstance(new, L.Filter):
+            _conjuncts(new.condition, stay)
+        counts[0] += len(conds) - len(stay)
+        sides = [set(c.schema().names()) for c in join.children]
+        counts[1] += sum(_home(c, sides) is not None for c in stay)
+        return node if len(stay) == len(conds) else new
+
+    return walk(plan), counts[0], counts[1]
 
 
 def estimated_size_bytes(plan: L.LogicalPlan) -> Optional[int]:
     """Plan-time size estimate (ref Spark SizeInBytesOnlyStatsPlan /
     the reference's AQE stage statistics): known for in-memory and file
     scans, propagated through size-preserving unary nodes, None where
-    unknowable. Filters keep the child estimate (conservative — Spark's
-    default without column stats)."""
+    unknowable. A filter keeps of the child's estimate what
+    :func:`_kept_share` guesses."""
     own = getattr(plan, "estimated_size_bytes", None)
     if own is not None:                # LogicalScan, CachedRelation, ...
         return own()
@@ -194,10 +313,37 @@ def estimated_size_bytes(plan: L.LogicalPlan) -> Optional[int]:
             return sum(os.path.getsize(p) for p in plan.paths)
         except OSError:
             return None
-    if isinstance(plan, (L.Filter, L.Sort, L.Repartition, L.Sample,
+    if isinstance(plan, L.Filter):
+        est = estimated_size_bytes(plan.children[0])
+        return est if est is None else int(est * _kept_share(plan.condition))
+    if isinstance(plan, (L.Sort, L.Repartition, L.Sample,
                          L.LocalLimit, L.GlobalLimit, L.Project)):
         return estimated_size_bytes(plan.children[0])
     return None
+
+
+def _kept_share(cond) -> float:
+    """The share of its input a filter is taken to keep before anything
+    was measured: a tenth for each conjunct that holds a column to ONE
+    literal, k tenths for a list of k (System R's guess without
+    statistics: such a predicate names a few values of a domain); every
+    other conjunct keeps it all (Spark's default without column
+    statistics). A dimension cut to one segment or one month is then
+    planned as the broadcast side it will measure as, in its FIRST query
+    and not from the second on; a side that measures over the threshold
+    is demoted at the next planning (plan/overrides.py:_auto_broadcast)."""
+    from ..exprs.base import ColumnRef, Literal
+    from ..exprs.comparison import EqualTo, In
+    conds: list = []
+    _conjuncts(cond, conds)
+    share = 1.0
+    for c in conds:
+        if isinstance(c, EqualTo) and {type(k) for k in c.children} == {
+                ColumnRef, Literal}:
+            share *= 0.1
+        elif isinstance(c, In) and isinstance(c.children[0], ColumnRef):
+            share *= min(1.0, 0.1 * len(c.values))
+    return share
 
 
 def rewrite_plan(plan: L.LogicalPlan,
@@ -225,7 +371,31 @@ def rewrite_plan(plan: L.LogicalPlan,
             new = _rewrite_distinct(plan)
         if new is not None:
             plan = new
+    if type(plan) is L.GlobalLimit:
+        plan = _limit_into_sort(plan)
     return plan
+
+
+def _limit_into_sort(limit: L.GlobalLimit) -> L.LogicalPlan:
+    """``LIMIT n`` above a global sort, with nothing between them but
+    projections (a row in, a row out): the sort learns that only its first
+    ``n`` rows are read, and selects them without sorting the rest
+    (exec/sort.py:TOP_N_MAX bounds ``n``). The limit stays where it is."""
+    from ..exec.sort import TOP_N_MAX
+    chain = [limit]
+    while isinstance(chain[-1].children[0], L.Project):
+        chain.append(chain[-1].children[0])
+    sort = chain[-1].children[0]
+    if not (isinstance(sort, L.Sort) and sort.global_sort
+            and sort.limit is None and 0 < limit.n <= TOP_N_MAX):
+        return limit
+    node = copy.copy(sort)
+    node.limit = limit.n
+    for parent in reversed(chain):
+        parent = copy.copy(parent)
+        parent.children = [node]
+        node = parent
+    return node
 
 
 _DECOMPOSABLE = (AG.Sum, AG.Count, AG.CountStar, AG.Min, AG.Max, AG.Average)

@@ -57,8 +57,14 @@ class BroadcastExchangeExec(TpuExec):
                                     ctx.memory)
             try:
                 with ctx.semaphore.held():
-                    if spill:
-                        out = concat_batches([s.get() for s in spill])
+                    if len(spill) > 1:
+                        # counts a filter left on the device, read once:
+                        # with them the batches concatenate on the device
+                        from ..exec.joins import _counted, _resolve_counts
+                        _resolve_counts(spill)
+                        out = concat_batches([_counted(s) for s in spill])
+                    elif spill:
+                        out = spill[0].get()
                     else:
                         from ..exec.joins import _empty_batch
                         out = _empty_batch(self._schema)

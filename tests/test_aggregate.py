@@ -488,3 +488,43 @@ def test_wide_batch_auto_ceiling_byte_gated():
     wide = scans_of(tpu_session())   # derived budget: plenty for 18 MB
     assert any(s.batch_rows >= n for s in wide), \
         [s.batch_rows for s in wide]
+
+
+@pytest.mark.parametrize("n_keys", [1, 3, 9])
+def test_sorted_groupby_with_fused_filter_and_null_keys(n_keys):
+    """The sort-based group-by over several batches with its filter fused
+    in: the keys' null ranks and the dropped rows' bit share ONE flag
+    operand (exec/aggregate.py:k_prep), and a dropped row must sort behind
+    every live one whatever its keys' nullness: the groups are pandas'."""
+    import numpy as np
+    import pandas as pd
+    import pyarrow as pa
+    from harness import OPERATOR_CONF, tpu_session
+    rng = np.random.default_rng(n_keys)
+    n = 30_000
+    cols = {f"k{i}": pa.array(rng.integers(0, 4, n).astype(
+        np.int64 if i % 2 else np.int32), mask=rng.random(n) < 0.25)
+        for i in range(n_keys)}
+    cols["v"] = pa.array(rng.integers(-1000, 1000, n))
+    t = pa.table(cols)
+    s = tpu_session({**OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.batchSizeRows": 8192})
+    s.create_dataframe(t, num_partitions=4).create_or_replace_temp_view("t")
+    keys = ", ".join(list(cols)[:n_keys])
+    df = s.sql(f"select {keys}, sum(v) as sv, count(*) as c from t "
+               f"where v > -500 group by {keys}")
+    plan = df._physical().tree_string()
+    assert "HashAggregate" in plan and "fused=[filter]" in plan \
+        and "Cpu" not in plan, plan
+    got = df.collect_arrow().to_pandas()
+    pdf = t.to_pandas()
+    ks = list(cols)[:n_keys]
+    want = pdf[pdf.v > -500].groupby(ks, dropna=False).agg(
+        sv=("v", "sum"), c=("v", "size")).reset_index()
+    got = got.sort_values(ks, na_position="first").reset_index(drop=True)
+    want = want.sort_values(ks, na_position="first").reset_index(drop=True)
+    assert len(got) == len(want)
+    assert (got.sv.to_numpy() == want.sv.to_numpy()).all()
+    assert (got.c.to_numpy() == want.c.to_numpy()).all()
+    for k in ks:
+        assert (got[k].isna().to_numpy() == want[k].isna().to_numpy()).all()

@@ -118,3 +118,52 @@ def test_full_batches_and_foreign_columns_keep_their_paths(
     # a dictionary column or a lazy count is not this function's to merge
     dic = ColumnarBatch.from_arrow(pa.table({"s": pa.array(["a", "b"] * 8)}))
     assert concat_batches_device([dic, dic]) is None
+
+
+@pytest.mark.parametrize("rows,bucket", [
+    (300_000, 524_288), (524_289, 1_048_576), (1_460_000, 1_572_864),
+    (1_572_865, 2_097_152), (2_097_153, 3_145_728), (4_194_305, 8_388_608)])
+def test_build_ladder_is_finer_above_a_quarter_million_rows(rows, bucket):
+    """A join's build side and coalesced output: x2 steps above 262,144
+    rows, x1.5 / x1.33 from 1,048,576 on, DEFAULT_BUCKETS' own below, and
+    a bounded number of shapes (multiples of the top beyond it)."""
+    from spark_rapids_tpu.columnar.bucketing import (BUILD_BUCKETS,
+                                                     DEFAULT_BUCKETS,
+                                                     bucket_for)
+    assert bucket_for(rows, BUILD_BUCKETS) == bucket
+    assert BUILD_BUCKETS[:4] == DEFAULT_BUCKETS[:4]
+    assert set(DEFAULT_BUCKETS) <= set(BUILD_BUCKETS) and len(BUILD_BUCKETS) == 10
+
+
+# rows of the bucket, columns: the validity bits ride in the key's bits below
+# the row index (3 columns), beside it in 32-lane words (40 and 90 columns:
+# 18 fit the key at 8,192 rows), a 1,048,576-row batch (11 spare bits), the
+# smallest shapes
+@pytest.mark.parametrize("rows,ncol", [
+    (1024, 3), (8192, 40), (8192, 90), (1 << 20, 12), (16, 1), (1, 2)])
+def test_compaction_keeps_rows_in_order_with_their_validity(rows, ncol):
+    """``compact_rows`` (one unstable sort by a unique key): kept rows to the
+    front in their order with their validity, the dropped ones behind them
+    in theirs, validity cleared past the count; and the sort it traces has
+    ONE key and no bool operand."""
+    from spark_rapids_tpu.columnar.segmented import compact_rows
+    rng = np.random.default_rng(rows + ncol)
+    keep = rng.random(rows) < 0.4
+    cols = [(rng.integers(0, 1 << 40, rows) if i % 3 == 0 else
+             rng.random(rows) if i % 3 == 1 else rng.random(rows) < 0.5,
+             rng.random(rows) < 0.8) for i in range(ncol)]
+    args = ([(jnp.asarray(d), jnp.asarray(v)) for d, v in cols],
+            jnp.asarray(keep), rows)
+    outs, count = jax.jit(compact_rows, static_argnums=2)(*args)
+    n = int(count)
+    assert n == keep.sum()
+    for (d, v), (od, ov) in zip(cols, outs):
+        assert (np.asarray(od)[:n] == d[keep]).all()
+        assert (np.asarray(od)[n:] == d[~keep]).all()
+        assert (np.asarray(ov)[:n] == v[keep]).all()
+        assert not np.asarray(ov)[n:].any()
+    sorts = [e for e in jax.make_jaxpr(compact_rows, static_argnums=2)(
+        *args).jaxpr.eqns if e.primitive.name == "sort"]
+    assert len(sorts) == 1
+    assert sorts[0].params["num_keys"] == 1 and not sorts[0].params["is_stable"]
+    assert all(v.aval.dtype != np.bool_ for v in sorts[0].invars)

@@ -1,4 +1,5 @@
 """Differential join tests (ref join_test.py)."""
+import contextlib
 import pandas as pd
 import pytest
 
@@ -503,3 +504,280 @@ def test_duplicated_build_key_keeps_the_speculation_and_its_rerun():
     assert bounds == [{"hard": 0, "speculative": 1}]
     assert sum(b.num_rows for b in outs) == 3 * 128 * 5
     assert_tpu_and_cpu_equal(q2, conf=_BOUND_CONF)
+
+
+# ---------------------------------------------------------------------------
+# The join of two sides that are both too large to broadcast (PR 32): the
+# smaller side made ready once, the other side's batches joined against it,
+# their outputs leaving as one batch; sub-partitions where the build side
+# alone passes join.subPartitionSizeBytes. Against CpuJoinExec.
+# ---------------------------------------------------------------------------
+
+_SHUFFLED = {"spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 0,
+             "spark.rapids.tpu.sql.fusedPipeline.enabled": False}
+_HOW = ["inner", "left", "right", "full", "leftsemi", "leftanti"]
+
+
+def _big_sides(s, unique_build=False, key_hi=60, parts=4):
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(11)
+    n = 3000
+    l = s.create_dataframe(pa.table({
+        "lk": pa.array(rng.integers(0, key_hi, n), mask=rng.random(n) < .05),
+        "lv": pa.array(rng.integers(0, 1000, n))}), num_partitions=parts)
+    rk = (rng.permutation(key_hi * 2)[:key_hi] if unique_build
+          else rng.integers(0, key_hi, 700))
+    r = s.create_dataframe(pa.table({
+        "rk": pa.array(rk, mask=rng.random(len(rk)) < .05),
+        "rv": pa.array(rng.integers(0, 1000, len(rk)))}), num_partitions=2)
+    return l, r
+
+
+@pytest.mark.parametrize("unique_build", [False, True])
+@pytest.mark.parametrize("how", _HOW)
+def test_shuffled_join_streams_the_larger_side(how, unique_build):
+    def q(s):
+        l, r = _big_sides(s, unique_build)
+        return l.filter(F.col("lv") > 100).join(r, on=[("lk", "rk")],
+                                                 how=how)
+    from harness import tpu_session
+    tree = q(tpu_session(_SHUFFLED))._physical().tree_string()
+    assert "* HashJoin[" in tree and "Broadcast" not in tree, tree
+    assert_tpu_and_cpu_equal(q, conf=_SHUFFLED)
+
+
+@pytest.mark.parametrize("how", _HOW)
+def test_shuffled_join_sub_partitions_a_large_build_side(how):
+    def q(s):
+        l, r = _big_sides(s)
+        return l.join(r, on=[("lk", "rk")], how=how)
+    assert_tpu_and_cpu_equal(q, conf=dict(_SHUFFLED, **_SUBPART_CONF))
+
+
+def test_shuffled_join_build_side_is_the_smaller_one_either_way_round():
+    """The same join written both ways round gives the same rows, and a
+    second run (its outputs now sized from the first's totals) too."""
+    from harness import tpu_session
+
+    def q(s, flip):
+        l, r = _big_sides(s, unique_build=True)
+        j = r.join(l, on=[("rk", "lk")], how="inner") if flip \
+            else l.join(r, on=[("lk", "rk")], how="inner")
+        return j.select("lk", "lv", "rk", "rv")
+    s = tpu_session(_SHUFFLED)
+    frames = [q(s, flip).to_pandas() for flip in (False, True, False)]
+    key = ["lk", "lv", "rk", "rv"]
+    want = frames[0].sort_values(key).reset_index(drop=True)
+    assert len(want)
+    for f in frames[1:]:
+        pd.testing.assert_frame_equal(
+            f.sort_values(key).reset_index(drop=True), want)
+
+
+@pytest.mark.parametrize("build", ["dates", "max_key", "duplicate",
+                                   "all_null", "empty"])
+def test_unique_key_probe_edge_cases(build):
+    """The sort-and-scan probe takes an inner join on one integer-lane key
+    whose build side holds each key once; anything else (a key twice, a
+    live key equal to its padding value) keeps the general kernel. Either
+    way the rows are CpuJoinExec's."""
+    import numpy as np
+    import pyarrow as pa
+    big = np.iinfo(np.int64).max
+    keys = {"dates": pa.array(np.arange(40).astype("datetime64[D]")),
+            "max_key": pa.array([big, 5, 7, big - 1]),
+            "duplicate": pa.array([1, 2, 2, 3]),
+            "all_null": pa.array([None, None], pa.int64()),
+            "empty": pa.array([], pa.int64())}[build]
+    stream = {"dates": pa.array((np.arange(300) % 60)
+                                .astype("datetime64[D]")),
+              }.get(build, pa.array([big, 1, 2, 3, 5, None, big - 1] * 40))
+
+    def q(s):
+        l = s.create_dataframe(pa.table({
+            "lk": stream, "lv": pa.array(np.arange(len(stream)))}),
+            num_partitions=3)
+        r = s.create_dataframe(pa.table({
+            "rk": keys, "rv": pa.array(np.arange(len(keys)))}))
+        return l.join(r, on=[("lk", "rk")], how="inner")
+    for conf in (_SHUFFLED, {"spark.rapids.tpu.sql.fusedPipeline.enabled":
+                             False}):
+        assert_tpu_and_cpu_equal(q, conf=conf)
+
+
+def test_speculation_statistic_is_the_largest_total_of_the_query():
+    """A streaming join registers one speculated total a stream batch and
+    its last batch is a partial one: the next run sizes its outputs from
+    the LARGEST total seen, or its full batches would overflow and the
+    plan re-run with exact sizing (and the general kernel) every time."""
+    import jax.numpy as jnp
+    from harness import tpu_session
+    from spark_rapids_tpu.exec import joins
+    ctx = tpu_session().exec_context()
+    key = ("test", "speculation", "key")
+    try:
+        ctx.speculations.extend(
+            (jnp.int32(n), 1024, key, None) for n in (700, 900, 30))
+        ctx.check_speculations()
+        assert joins._TOTAL_STATS[key] == 900 and not ctx.speculations
+    finally:
+        joins._TOTAL_STATS.pop(key, None)
+
+
+def test_a_repeated_join_query_settles_after_its_second_run():
+    """Run 0 joins two big sides; its measured sizes make the planner
+    broadcast the small one from run 1 on (AQE); from then on a repeat
+    re-plans the same operators, registers no speculation that overflows
+    and compiles nothing new."""
+    import numpy as np
+    import pyarrow as pa
+    from harness import tpu_session
+    from spark_rapids_tpu.plan import exec_cache
+    rng = np.random.default_rng(2)
+    dim = pa.table({"dk": pa.array(np.arange(40_000)),
+                    "tag": pa.array(rng.integers(0, 50, 40_000))})
+    fact = pa.table({"k": pa.array(rng.integers(0, 40_000, 9_000)),
+                     "v": pa.array(rng.random(9_000))})
+    s = tpu_session({"spark.rapids.tpu.sql.optimizer.enabled": False,
+                     "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+                     "spark.rapids.tpu.sql.batchSizeRows": 4096,
+                     # the filtered dim is guessed at a tenth of its
+                     # 640,000 bytes: over this, so run 0 joins big sides
+                     "spark.rapids.tpu.sql.autoBroadcastJoinThreshold":
+                     50_000})
+
+    def q():
+        return (s.create_dataframe(fact, num_partitions=3)
+                .join(s.create_dataframe(dim).filter(F.col("tag") == 7),
+                      on=[("k", "dk")], how="inner")
+                .group_by("tag").agg(F.sum(F.col("v")).with_name("sv")))
+    plans, answers, misses = [], [], []
+    for _ in range(4):
+        df = q()
+        plans.append(df._physical().tree_string())
+        before = exec_cache.stats()["misses"]
+        answers.append(df.to_pandas())
+        misses.append(exec_cache.stats()["misses"] - before)
+    assert "* HashJoin[" in plans[0] and "BroadcastHashJoin" in plans[1]
+    assert plans[1] == plans[2] == plans[3]
+    assert misses[2:] == [0, 0], misses
+    for a in answers[1:]:
+        pd.testing.assert_frame_equal(a, answers[0])
+
+
+def _conditional_big_sides(s, matches=(5_000, 5_000, 500)):
+    """Unique build keys; stream batches of 20,000 rows of which
+    ``matches`` meet a build key; a condition over both sides that keeps
+    about one pair in twenty."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(5)
+    n_build, per = 20_000, 20_000
+    lk = np.concatenate([
+        np.concatenate([rng.permutation(n_build)[:m],
+                        n_build + rng.integers(0, 1000, per - m)])
+        for m in matches])
+    l = s.create_dataframe(pa.table({
+        "lk": pa.array(lk), "lv": pa.array(rng.integers(0, 1000, len(lk)))}),
+        num_partitions=len(matches))
+    r = s.create_dataframe(pa.table({
+        "rk": pa.array(rng.permutation(n_build)),
+        "rv": pa.array(rng.integers(0, 50, n_build))}))
+    return l, r
+
+
+@contextlib.contextmanager
+def _fresh_total_stats():
+    """The joins' output statistics as a new process has them: empty."""
+    from spark_rapids_tpu.exec import joins
+    known = dict(joins._TOTAL_STATS)
+    joins._TOTAL_STATS.clear()
+    try:
+        yield
+    finally:
+        joins._TOTAL_STATS.clear()
+        joins._TOTAL_STATS.update(known)
+
+
+def test_conditional_join_of_big_sides_checks_the_pairs_before_its_condition():
+    """5,000 pairs a batch (guessed into the 8,192-row bucket, under the
+    stream batch's own 65,536), a condition that keeps one in forty: the
+    speculation is on the PAIRS (what the gather cut to its guessed
+    bucket), not on what the condition left, and so is the statistic the
+    second run sizes from (from 125 rows it would guess 1,024 and lose
+    four pairs in five). Both runs give CpuJoinExec's rows."""
+    from spark_rapids_tpu.exec import joins
+
+    def q(s):
+        l, r = _conditional_big_sides(s)
+        return l.join(r, on=[("lk", "rk")], how="inner",
+                      condition=F.col("lv") < F.col("rv"))
+    with _fresh_total_stats():
+        first = assert_tpu_and_cpu_equal(q, conf=_SHUFFLED)
+        assert 0 < len(first) < 1024
+        assert list(joins._TOTAL_STATS.values()) == [5_000]
+        second = assert_tpu_and_cpu_equal(q, conf=_SHUFFLED)
+        assert len(second) == len(first)
+
+
+def test_exact_rerun_after_an_overflow_keeps_the_largest_total():
+    """A guess that was too small re-runs the plan with exact sizing, a
+    read a stream batch; the statistic it leaves is the largest of them,
+    not the last (partial) batch's, so the next run does not overflow
+    again: it compiles nothing and gives the same rows."""
+    from harness import tpu_session
+    from spark_rapids_tpu.exec import joins
+    from spark_rapids_tpu.plan import exec_cache
+    s = tpu_session(_SHUFFLED)
+
+    def run():
+        l, r = _conditional_big_sides(s)
+        df = l.join(r, on=[("lk", "rk")], how="inner")
+        before = exec_cache.stats()["misses"]
+        got = df.to_pandas()
+        return got, exec_cache.stats()["misses"] - before
+    with _fresh_total_stats():
+        want, _ = run()
+        (key,) = joins._TOTAL_STATS
+        assert joins._TOTAL_STATS[key] == 5_000 and len(want) == 10_500
+        joins._TOTAL_STATS[key] = 10       # the next guess: 1,024 rows
+        forced, _ = run()
+        assert joins._TOTAL_STATS[key] == 5_000
+        settled, misses = run()
+        assert misses == 0 and joins._TOTAL_STATS[key] == 5_000
+    cols = ["lk", "lv", "rk", "rv"]
+    for got in (forced, settled):
+        pd.testing.assert_frame_equal(
+            got.sort_values(cols).reset_index(drop=True),
+            want.sort_values(cols).reset_index(drop=True))
+
+
+@pytest.mark.parametrize("pred,share", [
+    (lambda: F.col("tag") == 7, 0.1),
+    (lambda: F.lit(7) == F.col("tag"), 0.1),
+    (lambda: F.col("tag").isin(1, 2, 3), 0.3),
+    (lambda: (F.col("tag") == 7) & (F.col("dk") > 10), 0.1),
+    (lambda: F.col("dk") > 10, 1.0),
+    (lambda: F.col("tag") == F.col("dk"), 1.0)])
+def test_filtered_side_is_sized_by_what_its_filter_is_taken_to_keep(
+        pred, share):
+    """The plan-time size of a join side under a filter: a tenth for each
+    conjunct that holds a column to a literal, k tenths for a list of k,
+    all of it otherwise; it decides the broadcast in the FIRST planning
+    (a measured size decides from the second on)."""
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.plan.rewrites import estimated_size_bytes
+    dim = pa.table({"dk": pa.array(np.arange(5_000)),
+                    "tag": pa.array(np.arange(5_000) % 50)})
+    fact = pa.table({"k": pa.array(np.arange(20_000) % 5_000)})
+    assert dim.nbytes == 80_000
+    s = tpu_session({
+        "spark.rapids.tpu.sql.fusedPipeline.enabled": False,
+        "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 30_000})
+    side = s.create_dataframe(dim).filter(pred())
+    assert estimated_size_bytes(side.plan) == int(80_000 * share)
+    tree = s.create_dataframe(fact, num_partitions=2).join(
+        side, on=[("k", "dk")], how="inner")._physical().tree_string()
+    assert ("BroadcastHashJoin" in tree) == (80_000 * share <= 30_000), tree

@@ -614,7 +614,15 @@ def test_memory_manager_names_its_state_machine():
     mm = MemoryManager(1 << 20, 1 << 20, "/tmp/srtpu_spill_t")
     assert mm.state_machine == "python"      # native is opt-in per ctor
     from spark_rapids_tpu.mem.native import load
-    native_mm = MemoryManager(1 << 20, 1 << 20, "/tmp/srtpu_spill_t",
-                              use_native=True)
-    assert native_mm.state_machine == ("native" if load() is not None
-                                       else "python")
+    try:
+        native_mm = MemoryManager(1 << 20, 1 << 20, "/tmp/srtpu_spill_t",
+                                  use_native=True)
+        assert native_mm.state_machine == ("native" if load() is not None
+                                           else "python")
+    finally:
+        # the native machine is ONE a process: hand it back to the
+        # session manager at that one's budget, or every query a later
+        # test of this worker runs meets a 1 MiB device
+        for mm in MemoryManager._instances.values():
+            if mm._native is not None:
+                mm._native.lib.oom_init(mm.budget)

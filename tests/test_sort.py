@@ -111,3 +111,70 @@ def test_out_of_core_skewed_keys():
             num_partitions=4)
         return df.order_by(F.col("a").asc(), F.col("b").asc())
     assert_tpu_and_cpu_equal(q, ignore_order=False, conf=_OOC_CONF)
+
+
+def _topn_table(parts_seed=3, n=20_000):
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(parts_seed)
+    f = rng.integers(0, 50, n) / 7.0
+    for value, share in ((np.nan, 0.05), (np.inf, 0.02), (-np.inf, 0.02),
+                         (-0.0, 0.02)):
+        f[rng.random(n) < share] = value
+    return pa.table({
+        "f": pa.array(f, mask=rng.random(n) < 0.1),
+        "i": pa.array(rng.integers(-3, 3, n), mask=rng.random(n) < 0.1),
+        "r": np.arange(n)})
+
+
+# a float key with NaN, both infinities, -0.0 and NULLs in both directions,
+# ties broken by the rows' order (20,000 rows over 300 distinct keys), a
+# projection between the limit and the sort, fewer rows than the limit, the
+# largest limit taken and the first one that is not
+@pytest.mark.parametrize("parts", [1, 5])
+@pytest.mark.parametrize("query,top", [
+    ("select * from t order by f desc, i limit 10", 10),
+    ("select * from t order by f asc nulls last, i desc nulls first limit 37",
+     37),
+    ("select r, f from t order by i, f desc nulls last limit 128", 128),
+    ("select * from t where r < 5 order by f limit 10", 10),
+    ("select * from t order by f limit 129", None)])
+def test_limit_above_a_sort_selects_its_rows_without_sorting(query, top,
+                                                            parts):
+    """``LIMIT n`` over ``ORDER BY`` (n <= 128): the sort operator selects
+    its first n rows (exec/sort.py:_build_topn_kernel, no ``lax.sort``) and
+    hands on what the host engine's stable sort and limit give, row for
+    row; above 128 it sorts as before."""
+    import pandas as pd
+    from harness import OPERATOR_CONF, cpu_session, tpu_session
+    got = []
+    for make in (tpu_session, cpu_session):
+        s = make({**OPERATOR_CONF,
+                  "spark.rapids.tpu.sql.batchSizeRows": 8192})
+        s.create_dataframe(_topn_table(), num_partitions=parts) \
+            .create_or_replace_temp_view("t")
+        df = s.sql(query)
+        if make is tpu_session:
+            plan = df._physical().tree_string()
+            assert "Cpu" not in plan, plan
+            assert ("; first %s]" % top in plan) == (top is not None), plan
+        got.append(df.collect_arrow().to_pandas())
+    pd.testing.assert_frame_equal(got[0], got[1], check_dtype=False)
+
+
+def test_the_selection_kernel_traces_no_sort():
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    from spark_rapids_tpu.exec.sort import _build_topn_kernel
+    from spark_rapids_tpu.plan.logical import SortOrder
+    from spark_rapids_tpu.exprs.base import ColumnRef
+    b = ColumnarBatch.from_arrow(_topn_table(n=2000))
+    orders = [SortOrder(ColumnRef("f"), False, False),
+              SortOrder(ColumnRef("i"), True, True)]
+    cols = [(c.data, c.validity) for c in b.columns]
+    text = str(jax.make_jaxpr(
+        _build_topn_kernel(orders, b.schema, 10), static_argnums=2)(
+            cols, jnp.int32(b.num_rows), b.padded_len))
+    assert " sort[" not in text
+    assert "while[" in text or "scan[" in text      # the n rounds, one loop

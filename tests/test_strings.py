@@ -726,3 +726,67 @@ def test_rect_rlike_literal_routing_differential():
                          F.rlike(F.col("s"), "xx  $").alias("r3"),
                          F.col("v"))
     assert_tpu_and_cpu_equal(q)
+
+
+# ---------------------------------------------------------------------------
+# =, <>, IN against string literals over a dictionary (PR 32): the match is
+# computed once per distinct value, the batch stays on the device
+# ---------------------------------------------------------------------------
+
+_LITERAL_PREDICATES = {
+    "eq": lambda c: c == "banana_002",
+    "eq_flipped": lambda c: F.lit("banana_002") == c,
+    "eq_absent": lambda c: c == "no such value",
+    "ne": lambda c: c != "banana_002",
+    "ne_absent": lambda c: c != "no such value",
+    "in": lambda c: c.isin(["banana_002", "date_004", "no such value"]),
+    "not_in": lambda c: ~c.isin(["banana_002", "date_004"]),
+    "not_eq": lambda c: ~(c == "banana_002"),
+    "mixed": lambda c: (c == "banana_002") | ((c != "date_004")
+                                              & (F.col("v") < F.lit(20))),
+}
+
+
+@pytest.mark.parametrize("pred", sorted(_LITERAL_PREDICATES))
+def test_dict_filter_literal_comparisons(pred):
+    """NULL strings match nothing, under NOT as well; a literal the
+    dictionary does not hold matches no row (=, IN) or every non-NULL one
+    (<>)."""
+    t = _str_table()
+
+    def q(s):
+        return (s.create_dataframe(t)
+                .filter(_LITERAL_PREDICATES[pred](F.col("s")))
+                .agg(F.count_star().with_name("c"),
+                     F.sum(F.col("v")).with_name("sv")))
+    s = tpu_session()
+    tree = q(s)._physical().tree_string()
+    assert not [l for l in tree.splitlines()
+                if "Cpu" in l or l.strip().startswith("!")], tree
+    got = assert_tpu_and_cpu_equal(q)
+    if pred == "eq_absent":
+        assert got["c"].tolist() == [0]
+    if pred == "ne_absent":
+        assert got["c"].tolist() == [t.column("s").drop_null().__len__()]
+
+
+def test_dict_filter_literal_comparison_is_tagged_and_placed_on_device():
+    t = _str_table()
+    s = tpu_session({"spark.rapids.tpu.sql.optimizer.enabled": False})
+    df = s.create_dataframe(t).filter(F.col("s") == "banana_002")
+    out = df.collect_arrow()
+    assert set(out.column("s").to_pylist()) == {"banana_002"}
+    assert s.last_placement == "device"
+    assert s.last_placement_report["codes"] == {"EXPR_DICT_EVAL": 1}
+
+
+def test_in_list_with_a_null_keeps_the_host_path():
+    """x IN ('a', NULL) is NULL, not false, where x is not 'a': a mask
+    over the dictionary cannot say that, so the predicate is not taken."""
+    t = _str_table()
+
+    def q(s):
+        return (s.create_dataframe(t)
+                .filter(~F.col("s").isin(["banana_002", None])))
+    assert "CpuFilter" in q(tpu_session())._physical().tree_string()
+    assert len(assert_tpu_and_cpu_equal(q)) == 0

@@ -330,6 +330,92 @@ def test_coalesce_span_under_the_profilers_tracer(tmp_path):
                    for _, c, d, st in ops)
 
 
+def _joined(s, shuffled: bool):
+    rng = np.random.default_rng(5)
+    fact = pa.table({"k": pa.array(rng.integers(0, 400, 6000)),
+                     "v": pa.array(rng.random(6000))})
+    dim = pa.table({"dk": pa.array(np.arange(300)),
+                    "w": pa.array(np.arange(300) % 9)})
+    return (s.create_dataframe(fact, num_partitions=3)
+            .join(s.create_dataframe(dim), on=[("k", "dk")], how="inner")
+            .filter((F.col("v") > 0.25) & (F.col("w") < 7)))
+
+
+@pytest.mark.parametrize("shuffled", [False, True])
+def test_join_spans_and_counters_in_the_ring_buffer(shuffled):
+    """``join.build`` (once a join a query) and ``join.probe`` (a stream
+    batch each) are ``cat="exec"`` children of the join operator's span
+    carrying its id and the query's ordinal; ``join.rows`` is written once
+    an execution and ``plan.pushdown`` once a planned query."""
+    conf = dict(_OPERATOR_CONF)
+    if shuffled:
+        conf["spark.rapids.tpu.sql.autoBroadcastJoinThreshold"] = 0
+    s = tpu_session(conf)
+    df = _joined(s, shuffled)
+    want = df.collect_arrow().num_rows      # sizes the join's outputs
+    tr = install_tracer(Tracer())
+    try:
+        assert df.collect_arrow().num_rows == want
+    finally:
+        install_tracer(None)
+    ev = tr.snapshot()
+    counters = {n: [e["args"] for e in ev if e["ph"] == "C"
+                    and e["name"] == n]
+                for n in ("join.rows", "plan.pushdown")}
+    assert counters["plan.pushdown"] == [{"pushed": 2, "above_joins": 0}]
+    (rows,) = counters["join.rows"]
+    # the planner fills the broadcast join's three stream batches into one
+    # (insert_coalesce); the join of two big sides takes them as they come
+    parts = 3 if shuffled else 1
+    assert (rows["build"], rows["out"], rows["parts"]) == (234, want, parts)
+    assert 4000 < rows["stream"] < 5000         # v > 0.25 of 6000
+    spans = _xs(tr)
+    by_id = {e["id"]: e for e in spans}
+    op = "TpuHashJoinExec" if shuffled else "TpuBroadcastHashJoinExec"
+    (build,) = [e for e in spans if e["name"] == "join.build"]
+    probes = [e for e in spans if e["name"] == "join.probe"]
+    assert len(probes) == parts
+    for e in [build] + probes:
+        parent = by_id[e["parent"]]
+        assert e["cat"] == "exec" and parent["name"] == op
+        assert e["args"]["exec"] == parent["args"]["exec"]
+        assert int(e["args"]["exec"].rsplit("@", 1)[1]) == rows["op"]
+        assert e["q"] == parent["q"] and e["q"] is not None
+        assert parent["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    assert build["args"]["cols"] == ["dk", "w"]
+    assert probes[0]["args"]["cols"] == ["k", "v"]
+    # a probe enqueues and returns: whatever is read, is read once a join
+    assert not [e for e in spans if e["name"].startswith("d2h")
+                and by_id.get(e["parent"], {}).get("name") == "join.probe"]
+
+
+def test_join_counters_cost_nothing_with_tracing_off(monkeypatch):
+    """No tracer, no work: the counter's one packed fetch of counts that
+    are still on the device is not made, and a span is no object at all."""
+    import contextlib
+    from spark_rapids_tpu.columnar import packing
+    from spark_rapids_tpu.exec import joins
+    from spark_rapids_tpu.trace import core as trace_core
+    assert trace_core.TRACER is None
+
+    def no_fetch(*a, **kw):
+        raise AssertionError("a fetch for a counter nobody records")
+    monkeypatch.setattr(packing, "fetch_packed", no_fetch)
+    joins._count_join_rows("TpuHashJoinExec@7", object(), [object()],
+                           object(), 1)
+    assert isinstance(joins._span("join.probe", "TpuHashJoinExec@7"),
+                      contextlib.nullcontext)
+    # an annotate-only tracer (a profiler session) records no counter
+    # either: spans reach the profiler, counters have nowhere to go
+    install_tracer(Tracer(recording=False))
+    try:
+        joins._count_join_rows("TpuHashJoinExec@7", object(), [object()],
+                               object(), 1)
+    finally:
+        install_tracer(None)
+
+
 def test_traced_upload_never_blocks(monkeypatch):
     """With a tracer installed an upload stays an asynchronous enqueue:
     no block_until_ready (a tracer must not change what it measures),
