@@ -120,14 +120,21 @@ class ColumnarBatch:
         if not isinstance(nr, int):
             from .transfer import traced_device_get
             nr = int(traced_device_get(nr, "d2h.num_rows"))   # device sync
-            cap = next((c.padded_len for c in self.columns
-                        if isinstance(c, DeviceColumn)), None)
-            if cap is not None and nr > cap:
-                # a speculatively-sized producer (join) guessed too small:
-                # rows beyond the padded capacity were truncated
-                raise SpeculativeOverflow(nr, cap)
-            self._resolve_count(nr)
+            self.install_count(nr)
         return nr
+
+    def install_count(self, nr: int) -> None:
+        """A row count that was still on the device, now read: checked
+        against what the columns can hold, then installed. Every site
+        that reads counts goes through here (``num_rows``,
+        :func:`resolve_counts`)."""
+        cap = next((c.padded_len for c in self.columns
+                    if isinstance(c, DeviceColumn)), None)
+        if cap is not None and nr > cap:
+            # a speculatively-sized producer (join) guessed too small:
+            # rows beyond the padded capacity were truncated
+            raise SpeculativeOverflow(nr, cap)
+        self._resolve_count(nr)
 
     def _resolve_count(self, nr: int) -> None:
         """Install a now-known row count; feeds the cost model's measured
@@ -516,6 +523,26 @@ class ColumnarBatch:
                 f"cols=[{kinds}], {self.schema})")
 
 
+def resolve_counts(batches: Sequence[ColumnarBatch], also=(),
+                   label: str = "d2h") -> List[int]:
+    """Read every row count still on the device among ``batches``, and the
+    device scalars ``also`` with them, in ONE packed transfer (span
+    ``<label>.transfer``); the counts are installed, ``also``'s values
+    returned. A count over its batch's capacity raises
+    :class:`SpeculativeOverflow`, as reading ``num_rows`` does. No
+    transfer where nothing is on the device."""
+    lazy = [b for b in batches if not isinstance(b.num_rows_raw, int)]
+    also = list(also)
+    if not lazy and not also:
+        return []
+    from .packing import fetch_packed
+    got = [int(n) for n in fetch_packed(
+        [b.num_rows_raw for b in lazy] + also, label)]
+    for b, n in zip(lazy, got):
+        b.install_count(n)
+    return got[len(lazy):]
+
+
 def _device_concat_packed(counts, cols, out_len):
     """Traced device concat of prefix-packed batches without a sort: the
     live rows of batch ``i`` go to offset ``sum(counts[:i])`` by one
@@ -554,10 +581,13 @@ def _clear_device_concat() -> None:
 
 def concat_batches_device(batches: Sequence[ColumnarBatch],
                           buckets: Sequence[int] = DEFAULT_BUCKETS):
-    """Device-resident concat: no D2H. Requires every column of every batch
-    to be a plain DeviceColumn and every row count to be a host int (the
-    aggregate merge path qualifies). Returns None when not applicable —
-    callers fall back to the host-staged concat_batches."""
+    """Device-resident concat: no D2H. Requires every row count to be a
+    host int (the aggregate merge path qualifies) and every column of
+    every batch to be a plain DeviceColumn, a byte rectangle, or a
+    DictColumn over the SAME dictionary object in every batch (the outputs
+    of one broadcast join share their build side's): its codes concatenate
+    as they are. Returns None when not applicable — callers fall back to
+    the host-staged concat_batches."""
     import jax
     import jax.numpy as jnp
     from .strrect import ByteRectColumn
@@ -566,8 +596,12 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
         if not isinstance(b.num_rows_raw, int):
             return None
         counts.append(b.num_rows_raw)
-        for c in b.columns:
-            if type(c) is not DeviceColumn \
+        for c, c0 in zip(b.columns, batches[0].columns):
+            if type(c) is DictColumn or type(c0) is DictColumn:
+                if type(c) is not type(c0) \
+                        or c.dictionary is not c0.dictionary:
+                    return None      # codes of another dictionary
+            elif type(c) is not DeviceColumn \
                     and type(c) is not ByteRectColumn:
                 return None
     schema = batches[0].schema
@@ -608,10 +642,9 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
             rebuilds.append((n_lanes, rebuild))
         else:
             lane_cols.append([(c.data, c.validity) for c in per_batch])
-
-            def rebuild(outs, dt=f.dtype):
-                return DeviceColumn(outs[0][0], outs[0][1], dt)
-            rebuilds.append((1, rebuild))
+            # a DictColumn rebuilds around the one dictionary all share
+            rebuilds.append((1, lambda outs, c0=per_batch[0]:
+                             c0.with_arrays(outs[0][0], outs[0][1])))
     total = sum(counts)
     target = bucket_for(total, buckets)
     if all(c == b.padded_len for c, b in

@@ -128,9 +128,10 @@ def unpack_streams(u32, f64, specs):
     return out
 
 
-def fetch_packed(arrays):
-    """Fetch a list of device arrays in at most two transfers; returns
-    numpy arrays with the original dtypes/shapes."""
+def fetch_packed(arrays, label: str = "d2h"):
+    """Fetch a list of device arrays in at most two transfers (span
+    ``<label>.transfer``); returns numpy arrays with the original
+    dtypes/shapes."""
     from ..trace import core as trace_core
     from .transfer import traced_device_get
     flat = tuple(arrays)
@@ -141,5 +142,5 @@ def fetch_packed(arrays):
     else:
         with tr.span("d2h.dispatch", cat="transfer"):
             packed = _pack(flat)     # pack-kernel dispatch (async)
-    u32, f64 = traced_device_get(packed)
+    u32, f64 = traced_device_get(packed, label)
     return unpack_streams(u32, f64, specs)

@@ -110,7 +110,8 @@ BATCH_SIZE_ROWS = register(
     "Target max rows per columnar batch (shape-bucket ceiling; TPU-specific: "
     "bounds XLA recompilation via the bucket ladder). Also the TargetSize "
     "goal of the CoalesceBatches the planner puts above an in-memory scan "
-    "whose under-filled batches reach a per-batch operator.")
+    "or a streaming broadcast join whose under-filled batches reach a "
+    "per-batch operator.")
 
 AGG_WIDE_BATCH_ROWS = register(
     "spark.rapids.tpu.sql.agg.wideBatchRows", 0,

@@ -12,6 +12,7 @@ import numpy as np
 
 from ..columnar import (ColumnarBatch, DeviceColumn, HostColumn,
                         concat_batches)
+from ..columnar.batch import resolve_counts
 from ..columnar.bucketing import bucket_for
 from ..exprs.base import Expression
 from ..exprs.compiler import compile_projection, filter_batch_device
@@ -651,8 +652,26 @@ class CoalesceBatchesExec(TpuExec):
     bucket is ``bucket_for(target_rows)`` at most. A batch alone in its
     group passes through as the same object. The merged batch carries the
     first batch's ``meta`` (``plan/overrides.py`` plans this operator in
-    no plan that reads it). Tracer: a span ``coalesce.concat`` around each
-    concat, a counter ``coalesce.batches`` {in, out} once an execution."""
+    no plan that reads it).
+
+    The goal is applied to TRUE row counts. Where the input's counts are
+    host ints (above a scan) each batch is decided as it arrives and
+    nothing is read. Where they are on the device (above a streaming
+    broadcast join) the input is taken ``COUNT_WINDOW`` batches at a time
+    and a window's counts are read in ONE packed transfer (span
+    ``d2h.coalesce_count.transfer``), each checked against its batch's
+    capacity as ``num_rows`` would (``SpeculativeOverflow``: the plan
+    re-runs with exact sizing); so at most ``batchSizeBytes`` of padded
+    bytes and one window are held. A group still open after a window with
+    ``COUNT_WINDOW`` batches or more is folded into one, so a concat takes
+    fewer than twice that many whatever the join let through (the kernel
+    compiles per arity). Tracer: a span ``coalesce.concat`` around each
+    concat, a counter ``coalesce.batches`` {in, out, fetches, op} once an
+    execution (``fetches``: count transfers; ``op``: the number in the
+    operator's id, which the spans carry as ``exec``)."""
+
+    #: input batches whose device counts are read in one transfer
+    COUNT_WINDOW = 8
 
     def __init__(self, child: TpuExec, target_rows: Optional[int] = None,
                  target_bytes: Optional[int] = None):
@@ -669,7 +688,9 @@ class CoalesceBatchesExec(TpuExec):
         max_rows = self.target_rows or ctx.conf.batch_size_rows
         concat_m = ctx.metric(self._exec_id, "concatTime", DEBUG)
         pending: List[ColumnarBatch] = []
-        rows = nbytes = n_in = n_out = 0
+        #: input whose counts are still on the device, in arrival order
+        window: List[ColumnarBatch] = []
+        rows = nbytes = n_in = n_out = fetches = 0
 
         def concat() -> ColumnarBatch:
             with ctx.semaphore.held():
@@ -691,25 +712,52 @@ class CoalesceBatchesExec(TpuExec):
             concat_m.add(time.perf_counter() - t0)
             return out
 
+        def admit(batch: ColumnarBatch) -> Iterator[ColumnarBatch]:
+            """The goal, on a batch whose count is on the host."""
+            nonlocal pending, rows, nbytes, n_out
+            b_rows, b_bytes = batch.num_rows, batch.size_bytes()
+            if pending and not single and (rows + b_rows > max_rows
+                                           or nbytes + b_bytes > max_bytes):
+                n_out += 1
+                yield merged()
+                pending, rows, nbytes = [], 0, 0
+            pending.append(batch)
+            rows += b_rows
+            nbytes += b_bytes
+
+        def admit_window() -> Iterator[ColumnarBatch]:
+            """The window's counts in one transfer, checked; then the goal
+            on each of its batches."""
+            nonlocal pending, nbytes, fetches
+            if window:       # opened by a count that is on the device
+                fetches += 1
+                resolve_counts(window, label="d2h.coalesce_count")
+            for b in window:
+                yield from admit(b)
+            window.clear()
+            if len(pending) >= self.COUNT_WINDOW:
+                pending = [merged()]
+                nbytes = pending[0].size_bytes()
+
         try:
             for batch in self.children[0].execute(ctx):
                 n_in += 1
-                b_rows, b_bytes = batch.num_rows, batch.size_bytes()
-                if pending and not single and (rows + b_rows > max_rows
-                                               or nbytes + b_bytes > max_bytes):
-                    n_out += 1
-                    yield merged()
-                    pending, rows, nbytes = [], 0, 0
-                pending.append(batch)
-                rows += b_rows
-                nbytes += b_bytes
+                if not window and isinstance(batch.num_rows_raw, int):
+                    yield from admit(batch)
+                    continue
+                window.append(batch)
+                if len(window) == self.COUNT_WINDOW:
+                    yield from admit_window()
+            yield from admit_window()
             if pending:
                 n_out += 1
                 yield merged()
         finally:
             tr = trace_core.TRACER
             if tr is not None:
-                tr.counter("coalesce.batches", {"in": n_in, "out": n_out},
+                tr.counter("coalesce.batches",
+                           {"in": n_in, "out": n_out, "fetches": fetches,
+                            "op": int(self._exec_id.rsplit("@", 1)[-1])},
                            cat="exec")
 
     def describe(self):

@@ -817,23 +817,15 @@ class TpuHashJoinExec(TpuExec):
         largest total is what the next run of this join shape sizes its
         outputs from, and the sum of the counts what the cost model learns
         as the join's output."""
-        from ..columnar.batch import SpeculativeOverflow
-        from ..columnar.packing import fetch_packed
-        lazy = [b for b in outs if not isinstance(b.num_rows_raw, int)]
-        if lazy or speculated:
-            got = [int(n) for n in fetch_packed(
-                [b.num_rows_raw for b in lazy]
-                + [t for t, _, _, _ in speculated])]
-            over = [(n, b.padded_len) for b, n in zip(lazy, got)
-                    if n > b.padded_len]
-            for n, (_, cap, ck, _) in zip(got[len(lazy):], speculated):
-                _note_total(ctx, ck, n)
-                if n > cap:
-                    over.append((n, cap))
-            if over:
-                raise SpeculativeOverflow(*over[0])
-            for b, n in zip(lazy, got):
-                b._resolve_count(n)
+        from ..columnar.batch import SpeculativeOverflow, resolve_counts
+        totals = resolve_counts(outs, [t for t, _, _, _ in speculated])
+        over = None
+        for n, (_, cap, ck, _) in zip(totals, speculated):
+            _note_total(ctx, ck, n)
+            if n > cap and over is None:
+                over = (n, cap)
+        if over:
+            raise SpeculativeOverflow(*over)
         plan_sig = getattr(self, "plan_sig", None)
         if plan_sig is not None:
             from ..plan.cost import record_runtime_rows
@@ -1388,10 +1380,17 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
     """Equi-join against a broadcast build side (ref
     GpuBroadcastHashJoinExecBase): the build child is a
     BroadcastExchangeExec whose single cached batch is reused across every
-    stream batch — each incoming batch joins independently. The planner
-    fills those batches first where a scan hands them over under-filled
-    (``plan/overrides.py:insert_coalesce``), and a build side with unique
-    keys keeps each output in its stream batch's bucket (:class:`_OutBound`).
+    stream batch — each incoming batch joins independently and leaves as
+    one batch, its count still on the device. The planner fills the
+    stream batches first where a scan hands them over under-filled, and
+    this join's outputs where they reach another per-batch operator (a
+    selective join hands on buckets of padding:
+    ``plan/overrides.py:insert_coalesce`` puts ``CoalesceBatchesExec``
+    above it, which reads the counts a window at a time); a build side
+    with unique keys keeps each output in its stream batch's bucket
+    (:class:`_OutBound`), and every output's strings are codes of the
+    build side's ONE dictionary, which is what lets them concatenate on
+    the device.
     Only join types needing no null-extension (or per-row marks) of the
     BUILD side across stream batches may stream; the rest take the
     coalesced whole-sides path."""
@@ -1403,14 +1402,22 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
         assert build_side in ("left", "right")
         self.build_side = build_side
 
-    def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+    @property
+    def streams(self) -> bool:
+        """Whether the stream side is joined batch by batch against the
+        broadcast relation (the planner's coalesce rule asks too)."""
         from ..shuffle.broadcast import BroadcastExchangeExec
-        bi = 1 if self.build_side == "right" else 0
-        build = self.children[bi]
-        if (self.join_type not in self.STREAMABLE[self.build_side]
-                or not isinstance(build, BroadcastExchangeExec)):
+        return (self.join_type in self.STREAMABLE[self.build_side]
+                and isinstance(
+                    self.children[1 if self.build_side == "right" else 0],
+                    BroadcastExchangeExec))
+
+    def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+        if not self.streams:
             yield from super().do_execute(ctx)
             return
+        bi = 1 if self.build_side == "right" else 0
+        build = self.children[bi]
         rows_m = ctx.metric(self._exec_id, "numOutputRows", ESSENTIAL)
         bound = self._out_bound(ctx, bi)
         with _span("join.build", self._exec_id,

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import copy
 import logging
-from typing import Callable, Dict, Type
+from typing import Callable, Dict, Optional, Type
 
 from ..config import TpuConf
 from ..types import Schema
@@ -192,45 +192,42 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
 # ---------------------------------------------------------------------------
 
 def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
-    """Put ``CoalesceBatches[TargetSize]`` above every in-memory scan whose
-    batches reach a per-batch operator under-filled: the stream side of a
-    streaming broadcast join or the input of an aggregate, through
-    device-only filters and projections. Those operators pay their host
-    and device work per BATCH at the padded shape, so two half-empty
-    buckets cost twice what one full one does. Not a sort: a
-    partition-local one answers per partition, which a merge would show,
-    and a global one concatenates everything itself. Everything is read off the plan: a scan whose neighbouring
-    batches do not fit the target together (``batchSizeRows``) gets no
-    operator and the plan is the one it was. No plan that reads
-    ``batch.meta`` (``spark_partition_id`` and its kin) gets one either:
-    the merged batch carries its first batch's ``meta``."""
-    from ..exec.joins import TpuBroadcastHashJoinExec
+    """Put ``CoalesceBatches[TargetSize]`` where a per-batch operator (the
+    stream side of a streaming broadcast join, the input of an aggregate)
+    would be handed under-filled batches, through device-only filters and
+    projections, by
+
+    - an in-memory scan whose neighbouring batches fit the target
+      (``batchSizeRows``) together: the operator goes above the scan;
+    - a streaming broadcast join: it hands on a batch a stream batch with
+      whatever matched, so a selective one hands on buckets of padding.
+      The operator goes above the join and reads the counts, which are
+      on the device, a window at a time (exec/basic.py).
+
+    Those operators pay their host and device work per BATCH at the padded
+    shape, so two half-empty buckets cost twice what one full one does.
+    Not below a sort (a partition-local one answers per partition, which a
+    merge would show, and a global one concatenates everything itself),
+    and not below whatever else materializes its input itself (the join
+    of two big sides, an exchange, the sink). Everything is read off the
+    plan: where nothing qualifies the plan is the one it was. No plan
+    that reads ``batch.meta`` (``spark_partition_id`` and its kin) gets
+    one either: the merged batch carries its first batch's ``meta``."""
     from ..exec.wholestage import _fusible
-    from ..shuffle.broadcast import BroadcastExchangeExec
     target_rows = conf.batch_size_rows
-    sites = []      # (parent, child index) of each scan to wrap
+    sites = []      # (parent, child index) of each scan or join to wrap
 
     def visit(node: TpuExec) -> None:
         fed = None  # index of the child this node consumes batch by batch
-        if isinstance(node, TpuBroadcastHashJoinExec):
-            bi = 1 if node.build_side == "right" else 0
-            if (node.join_type in node.STREAMABLE[node.build_side]
-                    and isinstance(node.children[bi],
-                                   BroadcastExchangeExec)):
-                fed = 1 - bi
+        if _streams(node):
+            fed = 0 if node.build_side == "right" else 1
         elif isinstance(node, A.TpuHashAggregateExec):
             fed = 0
         if fed is not None:
             parent, i = node, fed
             while _fusible(parent.children[i]):
                 parent, i = parent.children[i], 0
-            scan = parent.children[i]
-            # plain device columns only: a dictionary, rectangle or host
-            # column would send the concat through Arrow and back
-            if (isinstance(scan, B.InMemoryScanExec)
-                    and all(f.dtype.device_backed
-                            for f in scan.output_schema())
-                    and _scan_underfilled(scan, target_rows)):
+            if _fills(parent.children[i], target_rows):
                 sites.append((parent, i))
         for c in node.children:
             visit(c)
@@ -243,6 +240,46 @@ def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
             parent.children[i], target_rows=target_rows,
             target_bytes=conf.batch_size_bytes)
     return physical
+
+
+def _streams(node: TpuExec) -> bool:
+    """Whether ``node`` is a broadcast join that joins its stream side
+    batch by batch."""
+    from ..exec.joins import TpuBroadcastHashJoinExec
+    return isinstance(node, TpuBroadcastHashJoinExec) and node.streams
+
+
+def _fills(source: TpuExec, target_rows: int) -> bool:
+    """Whether ``source``'s batches are worth filling and concatenate on
+    the device: a dictionary, rectangle or host column from a scan would
+    send the concat through Arrow and back (each partition has a
+    dictionary of its own); a join's build side hands every output ONE
+    dictionary, so its strings concatenate as codes."""
+    if isinstance(source, B.InMemoryScanExec):
+        return (all(f.dtype.device_backed for f in source.output_schema())
+                and _scan_underfilled(source, target_rows))
+    if not _streams(source) or _stream_batches(source) == 1:
+        return False
+    from ..types import STRING
+    n_left = len(source.children[0].output_schema())
+    build = (range(n_left, len(source.output_schema()))
+             if source.build_side == "right" else range(n_left))
+    return all(f.dtype.device_backed or (f.dtype == STRING and i in build)
+               for i, f in enumerate(source.output_schema()))
+
+
+def _stream_batches(join: TpuExec) -> Optional[int]:
+    """The most batches a streaming broadcast join hands on, where the
+    plan says: one a batch of its stream side, which filters, projections
+    and further such joins pass on one for one from an in-memory scan."""
+    from ..exec.wholestage import _fusible
+    node = join.children[0 if join.build_side == "right" else 1]
+    while _fusible(node):
+        node = node.children[0]
+    if isinstance(node, B.InMemoryScanExec):
+        b = max(int(node.batch_rows), 1)
+        return sum(max(-(-t.num_rows // b), 1) for t in node.tables)
+    return _stream_batches(node) if _streams(node) else None
 
 
 def _scan_underfilled(scan, target_rows: int) -> bool:

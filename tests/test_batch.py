@@ -1,7 +1,8 @@
 """Device concat of batches (columnar/batch.py): where every batch's row
-count is a host int and every column a plain device column or a byte
-rectangle (which rides as 1-D lanes), the live rows of each batch go to a
-known offset in ONE jitted call with no sort."""
+count is a host int and every column a plain device column, a byte
+rectangle (which rides as 1-D lanes) or dictionary codes over ONE
+dictionary, the live rows of each batch go to a known offset in ONE
+jitted call with no sort."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -115,9 +116,75 @@ def test_full_batches_and_foreign_columns_keep_their_paths(
     assert not concat_kernel_ran()
     assert out.to_arrow().equals(
         pa.concat_tables([b.to_arrow() for b in full]))
-    # a dictionary column or a lazy count is not this function's to merge
-    dic = ColumnarBatch.from_arrow(pa.table({"s": pa.array(["a", "b"] * 8)}))
-    assert concat_batches_device([dic, dic]) is None
+    # a lazy count is not this function's to merge
+    lazy = _batch(10, 3)
+    lazy._num_rows = jnp.int32(10)
+    assert concat_batches_device([_batch(10, 4), lazy]) is None
+
+
+def _dict_batches(counts, dictionary=None):
+    """Batches of (codes over one dictionary object, a float), as one
+    broadcast join's outputs carry its build side's strings."""
+    from spark_rapids_tpu.columnar.column import DictColumn
+    from spark_rapids_tpu.types import STRING
+    if dictionary is None:
+        dictionary = np.array([f"brand #{i}" for i in range(9)], object)
+    out = []
+    for seed, n in enumerate(counts):
+        b = _batch(n, seed)
+        rng = np.random.RandomState(100 + seed)
+        p = b.padded_len
+        codes = np.zeros(p, np.int32)
+        valid = np.zeros(p, bool)
+        codes[:n] = rng.randint(0, len(dictionary), n)
+        valid[:n] = rng.rand(n) > 0.2
+        col = DictColumn(jnp.asarray(codes), jnp.asarray(valid), STRING,
+                         dictionary)
+        out.append(ColumnarBatch(
+            [col, b.columns[1]], n,
+            type(b.schema)([type(b.schema.fields[0])("s", STRING, True),
+                            b.schema.fields[1]])))
+    return out
+
+
+@pytest.mark.parametrize("counts", [(300, 200), (0, 50, 7), (1, 1, 1, 1024)])
+def test_one_dictionarys_codes_concat_on_the_device(counts, monkeypatch,
+                                                    concat_kernel_ran):
+    """DictColumns that share ONE dictionary object concatenate as their
+    code lanes, nothing leaves the device, the result is a DictColumn over
+    that dictionary and equals the host-staged concat."""
+    from spark_rapids_tpu.columnar.column import DictColumn
+    batches = _dict_batches(counts)
+    want = pa.concat_tables([b.to_arrow() for b in batches])
+
+    def no_arrow(self):
+        raise AssertionError("the concat went through Arrow")
+    with monkeypatch.context() as m:
+        m.setattr(ColumnarBatch, "to_arrow", no_arrow)
+        m.setattr(jax, "device_get", no_arrow)
+        got = batch_mod.concat_batches(batches)
+    assert concat_kernel_ran()
+    assert type(got.columns[0]) is DictColumn
+    assert got.columns[0].dictionary is batches[0].columns[0].dictionary
+    assert got.num_rows_raw == sum(counts)
+    assert got.to_arrow().equals(want)
+
+
+def test_differing_dictionaries_take_the_host_path(concat_kernel_ran):
+    """Codes of two dictionaries (equal or not) mean nothing side by side:
+    the device concat declines and the host-staged path decodes both."""
+    a = _dict_batches((30,))[0]
+    b = _dict_batches((20,), np.array([f"other #{i}" for i in range(9)],
+                                      object))[0]
+    twin = _dict_batches((20,))[0]      # an equal dictionary, another object
+    for pair in ([a, b], [a, twin]):
+        assert concat_batches_device(pair) is None
+        got = batch_mod.concat_batches(pair)
+        assert got.to_arrow().equals(
+            pa.concat_tables([x.to_arrow() for x in pair]))
+    assert not concat_kernel_ran()
+    # a dictionary column beside a plain one of another batch: declined too
+    assert concat_batches_device([a, _batch(5, 0)]) is None
 
 
 @pytest.mark.parametrize("rows,bucket", [

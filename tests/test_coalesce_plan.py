@@ -1,16 +1,18 @@
 """The post-conversion coalesce rule (plan/overrides.py:insert_coalesce):
-where a scan hands under-filled batches to a per-batch operator the plan
-gets ``CoalesceBatches[TargetSize]`` above that scan, and where it does
-not the plan is the one it was."""
+where a scan or a streaming broadcast join hands under-filled batches to a
+per-batch operator the plan gets ``CoalesceBatches[TargetSize]`` above it,
+and where it does not the plan is the one it was."""
 import numpy as np
 import pyarrow as pa
 import pytest
 
 from harness import OPERATOR_CONF, tpu_session
 from spark_rapids_tpu.api import functions as F
+from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
 from spark_rapids_tpu.exec.base import ExecContext
-from spark_rapids_tpu.exec.basic import CoalesceBatchesExec
+from spark_rapids_tpu.exec.basic import CoalesceBatchesExec, TpuProjectExec
 from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec
+from spark_rapids_tpu.exec.wholestage import WholeStageExec
 from spark_rapids_tpu.plan import overrides
 
 #: q3's pins (perfbench/configs/tpcds_sf1_star.json): the operator
@@ -68,13 +70,12 @@ def test_star_plan_fills_the_lowest_joins_stream(rows, parts):
     or reordered."""
     s = tpu_session(Q3_CONF)
     physical = _star(s, rows, parts)._physical()
-    (co,) = _nodes(physical, CoalesceBatchesExec)
-    assert co.describe() == \
-        "CoalesceBatches[TargetSize(rows=8192, bytes=536870912)]"
     joins = _nodes(physical, TpuBroadcastHashJoinExec)
     lowest = joins[-1]
-    assert co in lowest.children and len(_nodes(lowest, CoalesceBatchesExec)) \
-        == len(_nodes(joins[0], CoalesceBatchesExec)) == 1
+    (co,) = _nodes(lowest, CoalesceBatchesExec)
+    assert co.describe() == \
+        "CoalesceBatches[TargetSize(rows=8192, bytes=536870912)]"
+    assert co in lowest.children
     (scan,) = co.children
     assert scan.describe() == f"InMemoryScan[{parts} partitions]"
     ctx = ExecContext(parent=s.exec_context())
@@ -93,6 +94,115 @@ def test_star_plan_fills_the_lowest_joins_stream(rows, parts):
     # an odd one out passes through as the object the scan made
     if parts % 2:
         assert merged[-1] is scanned[-1]
+
+
+def test_full_partitions_fill_the_joins_and_not_the_scan():
+    """Two partitions of exactly the target: nothing to merge above the
+    scan, and each join still hands on what it kept of a batch."""
+    physical = _star(tpu_session(Q3_CONF), 2 * 8192, 2)._physical()
+    filled = [co.children[0] for co in _nodes(physical, CoalesceBatchesExec)]
+    assert filled == _nodes(physical, TpuBroadcastHashJoinExec)
+
+
+def test_star_plan_fills_above_both_joins():
+    """Each streaming broadcast join of the star hands its output to
+    another per-batch operator (the next join's stream side through a
+    projection, the aggregate), so each gets the operator directly above
+    it, and ``explain`` shows all three."""
+    physical = _star(tpu_session(Q3_CONF), 4 * PART, 4)._physical()
+    upper, lowest = _nodes(physical, TpuBroadcastHashJoinExec)
+    agg = _nodes(physical, TpuHashAggregateExec)[-1]
+    above_upper = agg.children[0]
+    assert isinstance(above_upper, CoalesceBatchesExec) \
+        and above_upper.children == [upper]
+    # through the projection between the joins, on the stream side
+    fed = upper.children[0]
+    while not isinstance(fed, CoalesceBatchesExec):
+        assert isinstance(fed, (TpuProjectExec, WholeStageExec)), fed
+        (fed,) = fed.children
+    assert fed.children == [lowest]
+    assert len(_nodes(physical, CoalesceBatchesExec)) == 3
+    assert physical.tree_string().count(
+        "CoalesceBatches[TargetSize(rows=8192, bytes=536870912)]") == 3
+
+
+def _join_into(s, above: str):
+    """A streaming broadcast join whose output reaches ``above``."""
+    rng = np.random.RandomState(3)
+    fact = pa.table({"f_k": pa.array(rng.randint(0, 50, 6000)),
+                     "f_v": pa.array(rng.rand(6000))})
+    dim = pa.table({"d_k": pa.array(np.arange(50)),
+                    "d_g": pa.array((np.arange(50) % 5).astype(np.int32))})
+    big = pa.table({"b_g": pa.array(rng.randint(0, 5, 7000).astype(np.int32)),
+                    "b_v": pa.array(rng.rand(7000))})
+    joined = s.create_dataframe(fact, num_partitions=3).join(
+        F.broadcast(s.create_dataframe(dim)), on=[("f_k", "d_k")])
+    if above == "sink":
+        return joined
+    if above == "sort":
+        return joined.sort("f_v")
+    # a join of two big sides: the threshold keeps both off the broadcast
+    return joined.join(s.create_dataframe(big, num_partitions=2),
+                       on=[("d_g", "b_g")])
+
+
+@pytest.mark.parametrize("above", ["hash-join", "sort", "sink"])
+def test_a_join_feeding_a_materializing_operator_gets_none(above,
+                                                           monkeypatch):
+    """``HashJoin``, a sort and the sink take their whole input themselves
+    (the join reads its sides' counts in one fetch): no operator above the
+    broadcast join, and the plan is the one the rule's absence gives."""
+    # two of the scan's 2,000-row batches do not fit 3,000: nothing to
+    # fill below the join either
+    conf = {**Q3_CONF, "spark.rapids.tpu.sql.batchSizeRows": 3000,
+            "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 0}
+    physical = _join_into(tpu_session(conf), above)._physical()
+    tree = physical.tree_string()
+    assert "BroadcastHashJoin" in tree and "CoalesceBatches" not in tree
+    if above == "hash-join":
+        assert "\n* HashJoin[" in "\n" + tree.replace("  ", "")
+    _without_rule(monkeypatch)
+    assert _join_into(tpu_session(conf), above)._physical().tree_string() \
+        == tree
+
+
+def test_strings_from_the_stream_side_stop_the_join_site():
+    """A string column of the stream side has a dictionary per stream
+    batch: its concat would go through Arrow, so no operator; the same
+    column from the build side shares ONE dictionary and gets it."""
+    s = tpu_session(Q3_CONF)
+    fact = pa.table({"f_k": pa.array(np.arange(6000) % 50),
+                     "f_s": pa.array([f"s{i % 9}" for i in range(6000)])})
+    dim = pa.table({"d_k": pa.array(np.arange(50)),
+                    "d_s": pa.array([f"d{i % 4}" for i in range(50)])})
+    f = s.create_dataframe(fact, num_partitions=3)
+    d = F.broadcast(s.create_dataframe(dim))
+    joined = f.join(d, on=[("f_k", "d_k")])
+    stream_s = joined.group_by("f_s").agg(F.count(F.col("f_k")).with_name("n"))
+    assert "CoalesceBatches" not in stream_s._physical().tree_string()
+    build_s = joined.select("f_k", "d_s").group_by("d_s").agg(
+        F.count(F.col("f_k")).with_name("n"))
+    tree = build_s._physical().tree_string()
+    assert "CoalesceBatches[TargetSize" in tree, tree
+    assert sorted((r["d_s"], r["n"]) for r in build_s.collect()) == \
+        [(f"d{i}", sum(1 for k in np.arange(6000) % 50 if k % 4 == i))
+         for i in range(4)]
+
+
+def test_star_answers_equal_the_host_engines(monkeypatch):
+    """With the three operators, without the rule, and on the host engine:
+    the same keys in the same order, the sums to float64's rounding."""
+    rows, parts = 5 * PART - 900, 5
+    got = _star(tpu_session(Q3_CONF), rows, parts).collect_arrow()
+    host = _star(tpu_session({"spark.rapids.tpu.sql.enabled": False}),
+                 rows, parts).collect_arrow()
+    _without_rule(monkeypatch)
+    plain = _star(tpu_session(Q3_CONF), rows, parts).collect_arrow()
+    assert got.num_rows == host.num_rows == plain.num_rows > 0
+    for want in (host, plain):
+        assert got.drop(["total"]).equals(want.drop(["total"]))
+        np.testing.assert_allclose(got["total"].to_numpy(),
+                                   want["total"].to_numpy(), rtol=1e-12)
 
 
 @pytest.mark.parametrize("conf", [
@@ -122,8 +232,12 @@ def test_answers_equal_with_and_without_the_operator(conf, monkeypatch):
 
 
 def _full_partitions(s):
-    # 2 partitions of exactly the target: nothing to merge
-    return _star(s, 2 * 8192, 2)
+    # 2 partitions of exactly the target feeding an aggregate: nothing to
+    # merge
+    t = pa.table({"k": pa.array(np.arange(2 * 8192) % 5),
+                  "v": pa.array(np.arange(2 * 8192, dtype=np.float64))})
+    return s.create_dataframe(t, num_partitions=2).group_by("k").agg(
+        F.sum(F.col("v")).with_name("sv"))
 
 
 def _global_aggregate(s):
@@ -133,6 +247,7 @@ def _global_aggregate(s):
 
 
 def _one_partition_join(s):
+    # one stream batch: each join hands on one batch, whatever it keeps
     return _star(s, 3000, 1)
 
 

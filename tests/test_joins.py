@@ -506,6 +506,114 @@ def test_duplicated_build_key_keeps_the_speculation_and_its_rerun():
     assert_tpu_and_cpu_equal(q2, conf=_BOUND_CONF)
 
 
+# -- the streaming broadcast join under CoalesceBatches (PR 33): its outputs
+# are filled before the next per-batch operator, their counts read a window
+# at a time (exec/basic.py CoalesceBatchesExec) -----------------------------
+
+def _coalesce_above_join(physical):
+    from spark_rapids_tpu.exec.basic import CoalesceBatchesExec
+    from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec
+    if isinstance(physical, CoalesceBatchesExec) and isinstance(
+            physical.children[0], TpuBroadcastHashJoinExec):
+        return physical
+    return next(filter(None, map(_coalesce_above_join, physical.children)),
+                None)
+
+
+def test_overflow_inside_a_coalesce_window_still_raises_and_reruns():
+    """A duplicated build key: batch 0 measures, batches 1 and 2 are sized
+    from its total and then emit 50 rows a row. The operator above the
+    join reads their counts in its window, finds them over their buckets
+    and raises what ``num_rows`` would; the query re-runs with exact
+    sizing and gives every row."""
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.columnar.batch import SpeculativeOverflow
+    from spark_rapids_tpu.exec.base import ExecContext
+    sk = pa.array(np.repeat([0, 1, 1], 1024))
+    bk = pa.array(np.array([0] + [1] * 50))
+
+    def q(s):
+        stream, build = _stream_and_build(s, sk, bk)
+        return (stream.join(build, on=[("sk", "bk")], how="inner")
+                .group_by("bv").agg(F.count(F.col("sv")).with_name("n"),
+                                    F.sum(F.col("sv")).with_name("t")))
+
+    s = tpu_session(_BOUND_CONF)
+    with _fresh_total_stats():
+        op = _coalesce_above_join(q(s)._physical())
+        assert op is not None, q(s)._physical().tree_string()
+        ctx = ExecContext(parent=s.exec_context())
+        try:
+            with pytest.raises(SpeculativeOverflow) as e:
+                list(op.execute(ctx))
+            assert e.value.needed == 1024 * 50
+            assert e.value.needed > e.value.capacity
+        finally:
+            ctx.close()
+    with _fresh_total_stats():
+        s = tpu_session(_BOUND_CONF)
+        got = q(s).to_pandas().sort_values("bv").reset_index(drop=True)
+    assert list(got["n"]) == [1024] + [2048] * 50
+    assert_tpu_and_cpu_equal(q, conf=_BOUND_CONF)
+
+
+def test_a_repeated_star_query_compiles_nothing_from_its_third_run_on():
+    """Two selective broadcast joins under an aggregate, each filled by
+    its own operator: run 0 measures (a first batch ever leaves in its
+    stream batch's bucket), run 1 may still meet a new shape, and from
+    run 2 on the concats' arities and buckets repeat: no executable-cache
+    miss and no backend compile, the same rows every time."""
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.plan import exec_cache
+    rng = np.random.default_rng(8)
+    n, parts = 24 * 512, 24
+    fact = pa.table({"f_d": pa.array(rng.integers(0, 120, n)),
+                     "f_i": pa.array(rng.integers(0, 300, n)),
+                     "f_v": pa.array(np.round(rng.random(n) * 100, 2))})
+    dates = pa.table({"d_k": pa.array(np.arange(120)),
+                      "d_y": pa.array((1998 + np.arange(120) // 12)
+                                      .astype(np.int32)),
+                      "d_m": pa.array((np.arange(120) % 12 + 1)
+                                      .astype(np.int32))})
+    items = pa.table({"i_k": pa.array(np.arange(300)),
+                      "i_m": pa.array((np.arange(300) % 10).astype(np.int32)),
+                      "i_b": pa.array([f"brand #{i % 13}"
+                                       for i in range(300)])})
+    conf = {**_BOUND_CONF}
+
+    def q(s):
+        for name, t, p in (("fact", fact, parts), ("dates", dates, 1),
+                           ("items", items, 1)):
+            s.create_dataframe(t, num_partitions=p) \
+                .create_or_replace_temp_view(name)
+        return s.sql(
+            "SELECT d_y, i_b, sum(f_v) AS t FROM dates, fact, items "
+            "WHERE d_k = f_d AND f_i = i_k AND d_m = 11 AND i_m = 3 "
+            "GROUP BY d_y, i_b ORDER BY d_y, i_b")
+
+    s = tpu_session(conf)
+    with _fresh_total_stats():
+        tree = q(s)._physical().tree_string()
+        assert tree.count("CoalesceBatches[TargetSize(rows=1024") == 3, tree
+        answers, misses, compile_s = [], [], []
+        for _ in range(4):
+            before = exec_cache.stats()
+            answers.append(q(s).to_pandas())
+            after = exec_cache.stats()
+            misses.append(after["misses"] - before["misses"])
+            compile_s.append(after["compile_s"] - before["compile_s"])
+    assert misses[2:] == [0, 0] and compile_s[2:] == [0.0, 0.0], \
+        (misses, compile_s)
+    assert len(answers[0]) > 5
+    for a in answers[1:]:
+        pd.testing.assert_frame_equal(a, answers[0])
+    host = q(tpu_session({"spark.rapids.tpu.sql.enabled": False})).to_pandas()
+    pd.testing.assert_frame_equal(answers[0], host, check_exact=False,
+                                  rtol=1e-12)
+
+
 # ---------------------------------------------------------------------------
 # The join of two sides that are both too large to broadcast (PR 32): the
 # smaller side made ready once, the other side's batches joined against it,

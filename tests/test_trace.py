@@ -277,7 +277,10 @@ def test_coalesce_span_and_counter_in_the_ring_buffer():
         install_tracer(None)
     counters = [e["args"] for e in tr.snapshot()
                 if e["ph"] == "C" and e["name"] == "coalesce.batches"]
-    assert counters == [{"in": 4, "out": 2}]
+    (counter,) = counters
+    # the scan's counts are host ints: no count is ever transferred
+    assert {k: counter[k] for k in ("in", "out", "fetches")} == \
+        {"in": 4, "out": 2, "fetches": 0}
     spans = _xs(tr)
     by_id = {e["id"]: e for e in spans}
     concats = [e for e in spans if e["name"] == "coalesce.concat"]
@@ -289,10 +292,72 @@ def test_coalesce_span_and_counter_in_the_ring_buffer():
         assert parent["args"]["exec"] == e["args"]["exec"]
         assert parent["ts"] <= e["ts"] and \
             e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    assert int(concats[0]["args"]["exec"].rsplit("@", 1)[1]) == counter["op"]
     # no fetch inside the operator: the concat stays on the device
     assert not [e for e in spans if e["name"].startswith("d2h")
                 and by_id.get(e["parent"], {}).get("name")
                 in ("coalesce.concat", "CoalesceBatchesExec")]
+
+
+def test_coalesce_above_a_join_reads_its_counts_a_window_at_a_time():
+    """Above a streaming broadcast join the counts are on the device: the
+    operator reads them in ``d2h.coalesce_count`` transfers of its own
+    span, at most one per 8 input batches, and ``coalesce.batches`` {in,
+    out, fetches, op} tells the plan's operators apart; the scan-site
+    operator of the same plan transfers nothing."""
+    rng = np.random.RandomState(11)
+    n, parts = 21 * 500, 42
+    fact = pa.table({"fk": pa.array(rng.randint(0, 400, n)),
+                     "v": pa.array(rng.rand(n))})
+    dim = pa.table({"dk": pa.array(np.arange(0, 400, 8)),     # keeps 1/8
+                    "g": pa.array((np.arange(50) % 3).astype(np.int32))})
+    s = tpu_session({**_OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.batchSizeRows": 500})
+    df = (s.create_dataframe(fact, num_partitions=parts)
+          .join(F.broadcast(s.create_dataframe(dim)), on=[("fk", "dk")])
+          .group_by("g").agg(F.sum(F.col("v")).with_name("sv")))
+    tree = df._physical().tree_string()
+    assert tree.count("CoalesceBatches[TargetSize(rows=500") == 2, tree
+    want = df.collect_arrow()               # sizes the join's outputs
+    tr = install_tracer(Tracer())
+    try:
+        got = df.collect_arrow()
+    finally:
+        install_tracer(None)
+    assert got.sort_by("g").equals(want.sort_by("g"))
+    spans = _xs(tr)
+    by_id = {e["id"]: e for e in spans}
+    ops = {int(e["args"]["exec"].rsplit("@", 1)[1]): e["name"]
+           for e in spans if "exec" in (e.get("args") or {})}
+    counters = {c["op"]: c for c in (
+        e["args"] for e in tr.snapshot()
+        if e["ph"] == "C" and e["name"] == "coalesce.batches")}
+    assert len(counters) == 2 and all(
+        ops[op] in ("CoalesceBatchesExec", "coalesce.concat")
+        for op in counters)
+    scan_site, join_site = sorted(counters.values(),
+                                  key=lambda c: c["fetches"])
+    assert (scan_site["in"], scan_site["out"], scan_site["fetches"]) == \
+        (parts, parts // 2, 0)
+    # 21 stream batches of 500 rows, an eighth of each kept: 3 windows
+    assert join_site["in"] == parts // 2 and join_site["fetches"] == 3
+    assert 8 * join_site["fetches"] <= join_site["in"] + 7
+    # the goal on TRUE rows: a group leaves when the next batch would pass
+    kept = (fact["fk"].to_numpy() % 8 == 0).reshape(21, 500).sum(axis=1)
+    groups, rows = 1, 0
+    for k in kept:
+        if rows and rows + k > 500:
+            groups, rows = groups + 1, 0
+        rows += k
+    assert join_site["out"] == groups < 5
+    gets = [e for e in spans if e["name"] == "d2h.coalesce_count.transfer"]
+    assert len(gets) == join_site["fetches"]
+    for e in gets:
+        owner = by_id[e["parent"]]
+        assert owner["name"] == "CoalesceBatchesExec" and int(
+            owner["args"]["exec"].rsplit("@", 1)[1]) == join_site["op"]
+    # whatever else is fetched, no batch's count is fetched on its own
+    assert not [e for e in spans if e["name"] == "d2h.num_rows.transfer"]
 
 
 def test_coalesce_span_under_the_profilers_tracer(tmp_path):
