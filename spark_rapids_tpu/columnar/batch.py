@@ -479,9 +479,9 @@ class ColumnarBatch:
         out.meta = self.meta
         return out
 
-    def with_lists_on_host(self) -> "ColumnarBatch":
-        """Demote 2-D device layouts (list rectangles AND string byte
-        rectangles) to HostColumns.
+    def with_lists_on_host(self, strings: bool = True) -> "ColumnarBatch":
+        """Demote 2-D device layouts (list rectangles AND, unless
+        ``strings`` is False, string byte rectangles) to HostColumns.
 
         Row-rearranging execs that own their kernels (joins, sorts, aggs,
         windows, partitioning) move 1D (data, validity) pairs; rectangle
@@ -491,7 +491,8 @@ class ColumnarBatch:
         mirrored in supported_ops docs."""
         from .nested import ListColumn
         from .strrect import ByteRectColumn
-        rect_types = (ListColumn, ByteRectColumn)
+        rect_types = (ListColumn, ByteRectColumn) if strings \
+            else (ListColumn,)
         if not any(isinstance(c, rect_types) for c in self.columns):
             return self
         n = self.num_rows
@@ -590,7 +591,7 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
     the host-staged concat_batches."""
     import jax
     import jax.numpy as jnp
-    from .strrect import ByteRectColumn
+    from .strrect import ByteRectColumn, one_width
     counts = []
     for b in batches:
         if not isinstance(b.num_rows_raw, int):
@@ -620,26 +621,12 @@ def concat_batches_device(batches: Sequence[ColumnarBatch],
             if not all(type(c) is ByteRectColumn for c in per_batch):
                 return None      # mixed rect/dict (spill round trip):
                                  # host-staged concat handles it
-            max_w = max(c.width for c in per_batch)
-            normed = []
-            for c in per_batch:
-                if c.width < max_w:
-                    c = ByteRectColumn(
-                        jnp.pad(c.data, ((0, 0), (0, max_w - c.width))),
-                        c.validity, c.lengths, ascii_only=c.ascii_only)
-                normed.append(c)
+            normed = one_width(per_batch)
             lane_lists = [c.kernel_lanes() for c in normed]
             n_lanes = len(lane_lists[0])
             for li in range(n_lanes):
                 lane_cols.append([ll[li] for ll in lane_lists])
-            template = normed[0]
-            asc = all(c.ascii_only for c in per_batch)
-
-            def rebuild(outs, template=template, asc=asc):
-                col = template.from_lanes(outs)
-                col.ascii_only = asc
-                return col
-            rebuilds.append((n_lanes, rebuild))
+            rebuilds.append((n_lanes, normed[0].from_lanes))
         else:
             lane_cols.append([(c.data, c.validity) for c in per_batch])
             # a DictColumn rebuilds around the one dictionary all share

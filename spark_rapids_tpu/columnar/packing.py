@@ -128,6 +128,20 @@ def unpack_streams(u32, f64, specs):
     return out
 
 
+def sum_counts(groups, label: str = "d2h"):
+    """The sum of each group of row counts, a count a host int or a scalar
+    still on the device: the device's all come in ONE packed transfer (none
+    where every count is on the host). What a tracer counter that reports
+    rows is built from."""
+    groups = [list(g) if isinstance(g, (list, tuple)) else [g]
+              for g in groups]
+    lazy = [c for g in groups for c in g
+            if not isinstance(c, (int, np.integer))]
+    got = iter(fetch_packed(lazy, label)) if lazy else iter(())
+    return [sum(int(c) if isinstance(c, (int, np.integer))
+                else int(next(got)) for c in g) for g in groups]
+
+
 def fetch_packed(arrays, label: str = "d2h"):
     """Fetch a list of device arrays in at most two transfers (span
     ``<label>.transfer``); returns numpy arrays with the original

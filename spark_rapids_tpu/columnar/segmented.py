@@ -323,14 +323,16 @@ def _neutral_min(dtype):
     return jnp.array(jnp.iinfo(dtype).min, dtype=dtype)
 
 
-def front_sort(first, rank, lanes, padded_len: int):
+def front_sort(first, rank, lanes, padded_len: int, rank_bound: int = 0):
     """Rows where ``first`` to the front in the order of ``rank``, the
     others behind them in the order of theirs, every lane (1-D, one entry
     a row) carried along: ONE variadic sort, the idiom that replaces
     cumsum+scatter (per-column 1M-row scatters serialize on the scalar
     core, the sort network is bandwidth-bound, ~5 ms).
 
-    ``rank`` is below ``padded_len`` and unique among the ``first`` rows
+    ``rank`` is below ``rank_bound`` (``padded_len`` where none is
+    given; a rank that leads with a partition number has a wider one) and
+    unique among the ``first`` rows
     and among the others. So the key is ONE uint32 a row and unique (bit
     31: not first; then the rank), an UNSTABLE sort orders by it as a
     stable one would, and the bool lanes ride in the bits below the rank
@@ -340,7 +342,8 @@ def front_sort(first, rank, lanes, padded_len: int):
     a third of the time they took as (u8 key, stable, a bool lane a
     column) (PERF.md, PR 32)."""
     flags = [i for i, l in enumerate(lanes) if l.dtype == jnp.bool_]
-    spare = 31 - max(1, (padded_len - 1).bit_length())
+    spare = 31 - max(1, (max(rank_bound, padded_len) - 1).bit_length())
+    assert spare >= 0, (rank_bound, padded_len)
     in_key, rest = flags[:spare], flags[spare:]
 
     def bits(ids):

@@ -157,6 +157,18 @@ def unpack_words(words, width: int):
     return jnp.stack(cols[:width], axis=1)
 
 
+def one_width(cols):
+    """One column's rectangles out of several batches, each padded to the
+    widest's width and all marked ASCII only where every one is: they then
+    have the same word lanes, and any of them rebuilds the others' rows."""
+    import jax.numpy as jnp
+    width = max(c.width for c in cols)
+    asc = all(c.ascii_only for c in cols)
+    return [ByteRectColumn(
+        jnp.pad(c.data, ((0, 0), (0, width - c.width))), c.validity,
+        c.lengths, ascii_only=asc) for c in cols]
+
+
 class ByteRectColumn(DeviceColumn):
     """STRING column living in HBM as a byte rectangle (module doc)."""
 

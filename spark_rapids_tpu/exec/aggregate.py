@@ -30,12 +30,14 @@ from ..columnar import ColumnarBatch, DeviceColumn, HostColumn, concat_batches
 from ..columnar.bucketing import bucket_for
 from ..columnar.transfer import traced_device_get
 from ..exprs.aggregates import AggregateExpression
+from ..exprs.compiler import _lane_pairs, _lane_rebuild
 from ..exprs.base import (BoundReference, DVal, EvalContext, Expression,
                           collect_param_literals, literal_scalars,
                           literal_slot_map, parameterized_keys)
 from ..mem import SpillableBatch, with_retry_no_split
 from ..trace import core as trace_core
-from ..types import STRING, Schema, StructField
+from ..types import (BOOL, FLOAT32, INT32, INT64, STRING, Schema,
+                     StructField)
 from .base import ESSENTIAL, ExecContext, TpuExec
 from ..exprs import decimal_rules as D
 from .groupby_core import segmented_groupby
@@ -445,6 +447,141 @@ def _direct_strides(cards, nkeys: int):
         strides.insert(0, stride)
         stride = stride * (cards[i] + 1)
     return strides
+
+
+#: the hash buckets ONE division of the partials sorts their rows into: a
+#: SHAPE of the partition kernel (its counts, and the bits the bucket
+#: number takes in the sort key above the row index), not a threshold. The
+#: partitions are runs of neighbouring buckets, packed under the cap from
+#: the counts the operator reads, so a bucket is the grain a partition is
+#: filled by (1/64 of the rows); a bucket that alone passes the cap (more
+#: than 64 caps' worth of rows, or a skewed hash) is divided again. The
+#: kernel's dense count over them is part of ``jit_agg_partition``'s
+#: device time (PERF.md section 5)
+_HASH_BUCKETS = 64
+
+
+def _hash_operands(d, v) -> List[DVal]:
+    """One lane of a group key as what the partition's hash folds. The
+    partition needs a function of the key's VALUE, not Spark's hash of it:
+    equal keys share it, and that is all. So a DOUBLE, which this chip
+    cannot hash bit for bit (it holds one as two float32, and no bitcast
+    reaches them), gives the float32 nearest it and the float32 nearest
+    what is left, after the group-by's own normalization (-0.0 is 0.0, one
+    NaN); every other lane hashes as its Spark type of the same width."""
+    if d.dtype == jnp.float64:
+        x = jnp.where(d == 0.0, 0.0, d)
+        head = x.astype(jnp.float32)
+        rest = jnp.where(jnp.isfinite(head),
+                         (x - head.astype(jnp.float64)).astype(jnp.float32),
+                         jnp.float32(0.0))
+        return [DVal(head, v, FLOAT32), DVal(rest, v, FLOAT32)]
+    if d.dtype == jnp.float32:
+        return [DVal(d, v, FLOAT32)]
+    if d.dtype == jnp.bool_:
+        return [DVal(d, v, BOOL)]
+    return [DVal(d, v, INT64 if d.dtype.itemsize == 8 else INT32)]
+
+
+def _partition_kernel(key_lanes: Tuple[int, ...], seed: int, buckets: int):
+    """A partial batch's lanes (1-D, a (data, validity) pair each; a
+    string rectangle as its word lanes) -> (the lanes with the live rows
+    in the order of their key's hash bucket, rows a bucket). The bucket is
+    a hash of the key's lanes (``key_lanes``: which they are) modulo
+    ``buckets``; the rows move in ONE single-key unstable sort that
+    carries every lane (``front_sort``: the bucket number above the row
+    index in a unique uint32 key, the validity lanes in its low bits), and
+    the counts are a dense compare-and-sum, no scatter."""
+    from ..columnar.segmented import front_sort
+    from ..exprs.hash_fns import murmur3_fold_device
+    from ..plan import exec_cache
+
+    def agg_partition(lanes, num_rows, padded_len):
+        h = murmur3_fold_device(
+            [w for i in key_lanes for w in _hash_operands(*lanes[i])], seed)
+        pid = h % buckets
+        pid = jnp.where(pid < 0, pid + buckets, pid)
+        live = jnp.arange(padded_len, dtype=jnp.int32) < num_rows
+        pid = jnp.where(live, pid, jnp.int32(buckets))
+        counts = jnp.sum(
+            pid[None, :] == jax.lax.broadcasted_iota(
+                jnp.int32, (buckets, padded_len), 0),
+            axis=1, dtype=jnp.int32)
+        rank = pid * jnp.int32(padded_len) \
+            + jnp.arange(padded_len, dtype=jnp.int32)
+        it = iter(front_sort(live, rank,
+                             [lane for pair in lanes for lane in pair],
+                             padded_len, (buckets + 1) * padded_len))
+        return [(next(it), jnp.logical_and(next(it), live))
+                for _ in lanes], counts
+
+    return exec_cache.get_or_build_jit(
+        f"agg.partition:{key_lanes, seed, buckets}", agg_partition,
+        static_argnums=(2,))
+
+
+def _pack_buckets(rows, cap: int) -> List[Tuple[int, int]]:
+    """Runs [lo, hi) of neighbouring hash buckets (``rows``: the rows in
+    each, over all partials) that hold at most ``cap`` rows together, as
+    few as taking them in order gives; a bucket that alone passes the cap
+    is a run of its own, and empty runs are left out."""
+    parts, lo, acc = [], 0, 0
+    for b, n in enumerate(rows):
+        if acc and acc + n > cap:
+            parts.append((lo, b))
+            lo, acc = b, 0
+        acc += int(n)
+    if acc:
+        parts.append((lo, len(rows)))
+    return parts
+
+
+def _one_width(group, nkeys: int):
+    """The (batch, start, count) sources of one collect with their string
+    key rectangles at one width: the same lanes in every source."""
+    from ..columnar.strrect import ByteRectColumn, one_width
+    cols = [list(b.columns) for b, _, _ in group]
+    for k in range(nkeys):
+        if isinstance(cols[0][k], ByteRectColumn):
+            for per, c in zip(cols, one_width([per[k] for per in cols])):
+                per[k] = c
+    return [(ColumnarBatch(per, b.num_rows_raw, b.schema), at, n)
+            for per, (b, at, n) in zip(cols, group)]
+
+
+def _collect_kernel():
+    """Runs of rows out of several batches -> one batch, the runs one
+    after another: each source gives ``piece`` rows from its start (what
+    lies past its count is overwritten by the next source's run, or lies
+    past the total), copied with dynamic slices: no gather, no sort."""
+    from ..plan import exec_cache
+
+    def agg_collect(sources, starts, counts, piece, out_p):
+        offs = jnp.cumsum(counts) - counts
+        total = jnp.sum(counts)
+        outs = None
+        for i, cols in enumerate(sources):
+            lanes = [lane for pair in cols for lane in pair]
+            if outs is None:
+                outs = [jnp.zeros((out_p + piece,), l.dtype) for l in lanes]
+            for j, lane in enumerate(lanes):
+                short = piece - lane.shape[0]
+                if short > 0:
+                    lane = jnp.pad(lane, (0, short))
+                # a slice that would pass the end starts earlier (the
+                # runtime clamps it so anyway) and is turned back
+                at = jnp.minimum(starts[i], lane.shape[0] - piece)
+                run = jnp.roll(jax.lax.dynamic_slice(lane, (at,), (piece,)),
+                               at - starts[i])
+                outs[j] = jax.lax.dynamic_update_slice(outs[j], run,
+                                                       (offs[i],))
+        live = jnp.arange(out_p, dtype=jnp.int32) < total
+        it = iter(o[:out_p] for o in outs)
+        return [(next(it), jnp.logical_and(next(it), live))
+                for _ in sources[0]]
+
+    return exec_cache.get_or_build_jit("agg.collect", agg_collect,
+                                       static_argnums=(3, 4))
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -1583,6 +1720,9 @@ class TpuHashAggregateExec(TpuExec):
         #: the direct path holds; G-sized: never sliced, fetched or spilled
         carry = None
         carried = flushes = 0
+        #: each input batch's row count, as it came (a host int, or still
+        #: on the device below a filter): the ``agg.highcard`` counter's
+        rows_in: list = []
 
         def flush_window():
             if not window:
@@ -1672,6 +1812,7 @@ class TpuHashAggregateExec(TpuExec):
         try:
             for batch in itertools.chain(pending, it):
                 batch = batch.ensure_device()
+                rows_in.append(batch.num_rows_raw)
                 if self._rect_mode:
                     batch = self._ensure_rect_cols(
                         batch, self._rect_key_ordinals_for(batch))
@@ -1740,11 +1881,14 @@ class TpuHashAggregateExec(TpuExec):
             yield out
             return
 
-        total = sum(sb.device_bytes() for sb in partials)
-        if (self.groupings and partials
-                and total > ctx.conf.batch_size_bytes
-                and self._repartitionable()):
-            yield from self._repartitioned_merge(ctx, partials, total, rows_m)
+        # partials that do not fit ONE bucket together (their group
+        # counts, which the windows' fetches brought, say so): no merge
+        # kernel is built over all of them; they finish in partitions
+        if (self.groupings and len(partials) > 1
+                and sum(sb.num_rows for sb in partials)
+                > self._merge_cap(ctx, partials)):
+            yield from self._repartitioned_merge(ctx, partials, rows_m,
+                                                 rows_in)
             return
 
         if len(partials) == 1:
@@ -1772,80 +1916,217 @@ class TpuHashAggregateExec(TpuExec):
             final.meta["count_cb"] = (_on_groups, weakref.ref(final))
         yield final
 
-    # -- re-partition fallback (ref GpuAggregateExec.scala:718-780: when the
-    # merge target cannot fit, hash re-partition the partial batches by key
-    # and merge each partition independently — group keys are disjoint
-    # across partitions, so per-partition merge+finalize is exact) ---------
+    # -- the partitioned finish (ref GpuAggregateExec.scala:718-780: when
+    # the merge target cannot fit, hash re-partition the partial batches by
+    # key and merge each partition independently — group keys are disjoint
+    # across partitions, so per-partition merge+finalize is exact). The
+    # reference also skips its merge passes where the first pass barely
+    # reduces (skipAggPassReductionRatio); here nothing is merged before
+    # the partials are divided, so a partition's merge is the only one ----
     #: distinct seed from shuffle partitioning (42) so a key-partitioned
     #: shuffle stage does not collapse all rows into one sub-partition
     REPARTITION_SEED = 1879048201
 
-    def _repartitionable(self) -> bool:
-        from ..exprs.hash_fns import device_hashable
-        return not any(
-            device_hashable.reason_not_supported(f.dtype)
-            for f in self._partial_schema.fields[:len(self.groupings)])
-
     def _merge_kernel(self):
         merge_keys = [BoundReference(i, f.dtype) for i, f in
                       enumerate(self._partial_schema.fields[:len(self.groupings)])]
-        merge_k = _get_kernel(merge_keys, self.aggs, self._partial_schema,
-                              "merge", self._partial_counts, split=True)
-        return merge_keys, merge_k
+        return _get_kernel(merge_keys, self.aggs, self._partial_schema,
+                           "merge", self._partial_counts, split=True)
 
-    def _repartitioned_merge(self, ctx: ExecContext, partials, total, rows_m
+    def _merge_cap(self, ctx: ExecContext, partials) -> int:
+        """The most rows ONE merge kernel is built for: ``batchSizeRows``,
+        or as many rows of these partials as ``batchSizeBytes`` holds
+        where that is fewer (wide partials: a string rectangle, many
+        aggregates), and never under the largest single partial (whose
+        update kernel ran at that shape already)."""
+        row_bytes = max(1, sum(sb.device_bytes() for sb in partials)
+                        // max(1, sum(sb.padded_len for sb in partials)))
+        return max(min(ctx.conf.batch_size_rows,
+                       ctx.conf.batch_size_bytes // row_bytes),
+                   max(sb.num_rows for sb in partials))
+
+    def _partial(self, sb: SpillableBatch) -> ColumnarBatch:
+        """A partial as the kernels take it (the caller holds the
+        semaphore): back on the device, string keys as rectangles where
+        the aggregate groups on them."""
+        b = sb.get()
+        return self._ensure_rect_cols(b, range(len(self.groupings))) \
+            if self._rect_mode else b
+
+    def _repartitioned_merge(self, ctx: ExecContext, partials, rows_m,
+                             rows_in, depth: int = 0, seen=None
                              ) -> Iterator[ColumnarBatch]:
-        from ..shuffle.partitioning import partition_batch, scatter_spillables
-        merge_keys, merge_k = self._merge_kernel()
-        n_parts = min(1 << max(1, (int(total) // ctx.conf.batch_size_bytes
-                                   ).bit_length()), 64)
-        ctx.metric(self._exec_id, "aggRepartitions").set(n_parts)
-        slices = scatter_spillables(
-            ctx, partials,
-            lambda b: partition_batch(b, merge_keys, n_parts,
-                                      seed=self.REPARTITION_SEED),
-            n_parts)
-        try:
-            for p in range(n_parts):
-                parts = slices[p]
-                if not parts:
-                    continue
+        """The partitioned finish: every partial's rows are put in the
+        order of their key's hash bucket (``agg_partition``: ONE
+        single-key sort a partial carrying its columns), the buckets'
+        row counts of all partials come in one fetch, and neighbouring
+        buckets are packed into partitions of at most the cap from those
+        counts (what the operator observed, not a guess at how a hash
+        spreads). Each partition is then collected (``agg_collect``: a run
+        of every partial copied, nothing gathered), merged by the ordinary
+        merge kernel at a shape of the bucket ladder, finalized and handed
+        on: group keys are disjoint across partitions, so that is exact. A
+        bucket that alone passes the cap is divided again under another
+        seed, unless the division that made it moved nothing (every row in
+        one bucket: keys no hash tells apart)."""
+        merge_k = self._merge_kernel()
+        nkeys = len(self.groupings)
+        cap = self._merge_cap(ctx, partials)
+        total = sum(sb.num_rows for sb in partials)
+        largest_partial = max(sb.num_rows for sb in partials)
+        seen = seen if seen is not None else {
+            "partials": len(partials), "partitions": 0, "largest": 0,
+            "groups": []}
+        cols = list(self._highcard_cols())
+        placed: List[SpillableBatch] = []
 
-                def merge_part(parts=parts):
-                    with ctx.semaphore.held():
-                        big = concat_batches([s.get() for s in parts])
-                        return self._run_kernel(merge_k, big,
-                                                self._partial_schema)
-                try:
-                    merged = with_retry_no_split(merge_part, ctx=ctx,
-                                                 op=self._exec_id)
-                finally:
-                    for s in parts:
-                        s.close()
-                final = self._finalize(ctx, merged)
-                rows_m.add(final.num_rows)
+        def collect(took, at, n):
+            """The runs (from ``at``, ``n`` rows: one entry a placed
+            partial) of the placed partials ``took``."""
+            with ctx.semaphore.held():
+                return self._collect(placed, took, at, n)
+        try:
+            with self.child_span("agg.partition", cols=cols,
+                                 partials=len(partials)):
+                counts = []
+                for sb in partials:
+                    def place(sb=sb):
+                        with ctx.semaphore.held():
+                            b = self._partial(sb)
+                            lanes, spans = _lane_pairs(
+                                list(enumerate(b.columns)))
+                            outs, cnt = _partition_kernel(
+                                tuple(range(spans[nkeys - 1][2])),
+                                self.REPARTITION_SEED + depth,
+                                _HASH_BUCKETS)(
+                                    lanes, jnp.int32(b.num_rows),
+                                    b.padded_len)
+                            moved = [None] * len(b.columns)
+                            _lane_rebuild(b, spans, outs, moved)
+                            return ColumnarBatch(moved, b.num_rows,
+                                                 b.schema), cnt
+                    pb, cnt = with_retry_no_split(place, ctx=ctx,
+                                                  op=self._exec_id)
+                    sb.close()
+                    placed.append(SpillableBatch(pb, ctx.memory))
+                    counts.append(cnt)
+                counts = np.asarray(traced_device_get(
+                    jnp.stack(counts), "d2h.agg_parts"), np.int64)
+            starts = np.cumsum(counts, axis=1) - counts
+            parts = _pack_buckets(counts.sum(axis=0), cap)
+            ctx.metric(self._exec_id, "aggRepartitions").set(
+                seen["partitions"] + len(parts))
+            # every placed partial is a source of every partition, one
+            # that holds no row of it too: the collect kernel unrolls over
+            # its sources, and their number is then the same whatever the
+            # counts (one module a division, not one a partition)
+            took = list(range(len(placed)))
+            for lo, hi in parts:
+                at, n = starts[:, lo], counts[:, lo:hi].sum(axis=1)
+                rows_p = int(n.sum())
+                if cap < rows_p < total:
+                    # ONE bucket over the cap: its runs leave as batches
+                    # of at most the cap each and are divided again
+                    groups, acc = [[]], 0
+                    for i in took:
+                        if groups[-1] and acc + n[i] > cap:
+                            groups.append([])
+                            acc = 0
+                        groups[-1].append(i)
+                        acc += int(n[i])
+                    pieces = [SpillableBatch(with_retry_no_split(
+                        lambda g=g: collect(g, at, n), ctx=ctx,
+                        op=self._exec_id), ctx.memory) for g in groups]
+                    yield from self._repartitioned_merge(
+                        ctx, pieces, rows_m, rows_in, depth + 1, seen)
+                    continue
+                seen["partitions"] += 1
+                seen["largest"] = max(seen["largest"], rows_p)
+                with self.child_span("agg.merge_part", cols=cols,
+                                     rows=rows_p):
+                    def merge_part(at=at, n=n):
+                        with ctx.semaphore.held():
+                            return self._run_kernel(
+                                merge_k, collect(took, at, n),
+                                self._partial_schema, lazy=True)
+                    final = self._finalize(ctx, with_retry_no_split(
+                        merge_part, ctx=ctx, op=self._exec_id))
+                seen["groups"].append(final.num_rows_raw)
+                rows_m.add(final.num_rows_raw)
                 yield final
-        except BaseException:
-            # fatal merge or abandoned consumer: LATER partitions' slices
-            # still pin pool budget (close() is idempotent)
-            for slot in slices:
-                for s in slot:
-                    s.close()
-            raise
+        finally:
+            # consumed, failed or abandoned: the placed partials pin pool
+            # budget no longer (close() is idempotent)
+            for sb in partials + placed:
+                sb.close()
+        if not depth:
+            # the next run's partials keep the bucket of this run's largest
+            _FAST_GROUPS[self._kernel_key] = largest_partial
+            self._count_highcard(seen, rows_in)
+
+    def _count_highcard(self, seen: dict, rows_in: list) -> None:
+        """Tracer counter ``agg.highcard``, once per execution that
+        finished in partitions. Counts still on the device (the
+        partitions' groups, input rows below a filter) come in ONE packed
+        transfer, and only while a tracer records."""
+        tr = trace_core.TRACER
+        if tr is None or not tr.recording:
+            return
+        from ..columnar.packing import sum_counts
+        groups, rows = sum_counts((seen["groups"], rows_in),
+                                  "d2h.agg_highcard")
+        tr.counter("agg.highcard", {
+            "partials": seen["partials"], "rows_in": rows,
+            "partitions": seen["partitions"], "groups": groups,
+            "largest_partition_rows": seen["largest"],
+            "op": int(self._exec_id.rsplit("@", 1)[-1])}, cat="exec")
+
+    def _highcard_cols(self):
+        """The input columns the aggregate reads (its keys' and its
+        aggregates' references), by name: the ``cols`` of its spans."""
+        from ..plan.rewrites import _agg_refs, _expr_refs
+        refs: set = set()
+        for g in self.groupings:
+            _expr_refs(g, refs)
+        for a in self.aggs:
+            _agg_refs(a, refs)
+        names = self.children[0].output_schema().names()
+        return [n for n in names if n in refs] or names
+
+    def _collect(self, placed, took, starts, counts) -> ColumnarBatch:
+        """One partition's rows out of the placed partials ``took`` (each
+        holds them in one run from ``starts``), as one batch in the bucket
+        of their sum. The caller holds the semaphore."""
+        group = [(self._partial(placed[i]), int(starts[i]), int(counts[i]))
+                 for i in took]
+        if self._rect_mode:
+            group = _one_width(group, len(self.groupings))
+        rows = sum(n for _, _, n in group)
+        # a run is copied as a slice of a static length: the power of two
+        # that holds the longest
+        piece = 1 << (max(n for _, _, n in group) - 1).bit_length()
+        lanes = [_lane_pairs(list(enumerate(b.columns)))
+                 for b, _, _ in group]
+        got = _collect_kernel()(
+            [pairs for pairs, _ in lanes],
+            np.asarray([a for _, a, _ in group], np.int32),
+            np.asarray([n for _, _, n in group], np.int32),
+            piece, bucket_for(rows))
+        first = group[0][0]
+        cols = [None] * len(first.columns)
+        _lane_rebuild(first, lanes[0][1], got, cols)
+        return ColumnarBatch(cols, rows, first.schema)
 
     # ------------------------------------------------------------------
     def _merge(self, ctx: ExecContext,
                partials: List[SpillableBatch]) -> ColumnarBatch:
-        """Merge partial batches. Small totals concat once and run ONE
-        lazy merge kernel. Totals whose concat would exceed batchSizeRows
-        merge as a bounded-fan-in TREE instead: chunks of partials whose
-        padded sum fits the cap merge in parallel (counts resolved in one
-        stacked fetch per level), so no merge kernel is ever compiled
-        above the bucket the cap implies. Before this, 10 high-cardinality
-        partials at the 262144 bucket concatenated to a 4.19M-row shape
-        whose variadic-sort merge kernel did not compile in useful time
-        (TPC-DS q28 at 10M rows)."""
-        _, merge_k = self._merge_kernel()
+        """Merge partial batches that fit one bucket together (the caller
+        saw to that: what passes the cap finishes in partitions,
+        ``_repartitioned_merge``): one concat, ONE lazy merge kernel. The
+        inputs materialize via ``sb.get()`` INSIDE the retried closure, so
+        a RetryOOM spill actually frees HBM and the retry re-materializes
+        from the host."""
+        merge_k = self._merge_kernel()
         if not partials:
             # empty input: still one row for global agg, zero rows for grouped
             empty = ColumnarBatch.from_arrow(
@@ -1853,90 +2134,9 @@ class TpuHashAggregateExec(TpuExec):
             with ctx.semaphore.held():
                 return self._run_kernel(merge_k, empty, self._partial_schema)
 
-        # the tree operates on SPILLABLES end to end: every level's inputs
-        # materialize via sb.get() INSIDE the retried closure, so a
-        # RetryOOM spill actually frees HBM and the retry re-materializes
-        # from host (holding raw jax arrays across the retry would pin
-        # the memory the spill claims to have released).
-        # the cap never sits below the largest single partial (a chunk of
-        # one merges nothing and would loop forever)
-        cap = max(ctx.conf.batch_size_rows,
-                  max(sb.padded_len for sb in partials))
-        level: List[SpillableBatch] = list(partials)
-
-        merged_level: List = []
-        try:
-            while len(level) > 1 and \
-                    sum(sb.padded_len for sb in level) > cap:
-                # greedy chunking by padded length
-                chunks, cur, acc = [], [], 0
-                for sb in level:
-                    if cur and acc + sb.padded_len > cap:
-                        chunks.append(cur)
-                        cur, acc = [], 0
-                    cur.append(sb)
-                    acc += sb.padded_len
-                chunks.append(cur)
-                raws = []
-                for chunk in chunks:
-                    if len(chunk) == 1:
-                        raws.append(chunk[0])    # spillable passthrough
-                        continue
-
-                    def level_merge(c=chunk):
-                        with ctx.semaphore.held():
-                            big = concat_batches([s.get() for s in c])
-                            if self._rect_mode:
-                                big = self._ensure_rect_cols(
-                                    big, range(len(self.groupings)))
-                            return self._run_kernel_raw(merge_k, big)
-                    raws.append(with_retry_no_split(level_merge, ctx=ctx,
-                                                    op=self._exec_id))
-                ngs = [r[1] for r in raws if isinstance(r, tuple)]
-                if len(ngs) > 1:
-                    def resolve():
-                        return [int(x) for x in traced_device_get(
-                            jnp.stack(ngs), "d2h.groups")]
-                    counts = iter(with_retry_no_split(resolve, ctx=ctx,
-                                                      op=self._exec_id))
-                else:
-                    counts = iter([int(traced_device_get(
-                        ngs[0], "d2h.groups"))] if ngs else [])
-                merged_level = []
-                for r in raws:
-                    if not isinstance(r, tuple):
-                        merged_level.append(r)
-                        continue
-                    pb = self._slice_to_count(r[0], next(counts),
-                                              self._partial_schema)
-                    merged_level.append(SpillableBatch(pb, ctx.memory))
-                # consumed chunk inputs can release now (their content lives
-                # on in the level outputs)
-                for sb in level:
-                    if sb not in merged_level:
-                        sb.close()
-                if len(merged_level) >= len(level):
-                    # no progress (every chunk was a singleton — all partials
-                    # at cap size): fall through to one oversized merge rather
-                    # than loop forever
-                    level = merged_level
-                    break
-                level = merged_level
-        except BaseException:
-            # fatal error (or QueryTimeout) mid-tree: the current
-            # level's inputs AND any outputs already merged at this
-            # level must release (close() is idempotent — items that
-            # moved between the lists close once)
-            for sb in level:
-                sb.close()
-            for sb in merged_level:
-                if isinstance(sb, SpillableBatch):
-                    sb.close()
-            raise
-
         def do_merge() -> ColumnarBatch:
             with ctx.semaphore.held():
-                big = concat_batches([s.get() for s in level])
+                big = concat_batches([s.get() for s in partials])
                 if self._rect_mode:
                     big = self._ensure_rect_cols(
                         big, range(len(self.groupings)))
@@ -1947,11 +2147,9 @@ class TpuHashAggregateExec(TpuExec):
                                         lazy=True)
 
         try:
-            if len(level) == 1:
-                return level[0].get()
             return with_retry_no_split(do_merge, ctx=ctx, op=self._exec_id)
         finally:
-            for sb in level:
+            for sb in partials:
                 sb.close()
 
     # ------------------------------------------------------------------

@@ -6,6 +6,7 @@ the device semaphore gates concurrent device work (GpuSemaphore.scala:51).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -300,6 +301,16 @@ class TpuExec:
         if tr is not None:
             it = self._traced_iter(it, tr)
         return it
+
+    def child_span(self, name: str, **args):
+        """A ``with`` span of the installed tracer for one step inside this
+        operator (a join's build, an aggregate's partition), nested under
+        the operator's own span and carrying its id as ``exec`` (so that it
+        reaches the profiler's clock: trace/core.py), or nothing."""
+        tr = trace_core.TRACER
+        if tr is None:
+            return contextlib.nullcontext()
+        return tr.span(name, cat="exec", args=dict(args, exec=self._exec_id))
 
     @staticmethod
     def _metered_iter(it, m_time: Metric, m_batches: Metric):

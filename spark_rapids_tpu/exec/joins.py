@@ -22,7 +22,6 @@ post-filter for inner/cross and tagged fallback otherwise.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -36,8 +35,9 @@ from ..columnar import ColumnarBatch, DeviceColumn, concat_batches
 from ..columnar.bucketing import BUILD_BUCKETS, bucket_for
 from ..columnar.transfer import traced_device_get
 from ..exprs.base import DVal, EvalContext, Expression
-from ..exprs.compiler import (_compact_kernel, eval_predicate_device,
-                              filter_batch_device, gather_batch_device)
+from ..exprs.compiler import (_compact_kernel, _lane_pairs, _lane_rebuild,
+                              eval_predicate_device, filter_batch_device,
+                              gather_batch_device)
 from ..mem import (SpillableBatch, with_retry_no_split,
                    wrap_spillable_sides)
 from ..trace import core as trace_core
@@ -78,15 +78,25 @@ class _OutBound:
     sink (as for semi/anti joins). Above 1 the speculation on the last
     observed total stays."""
 
-    __slots__ = ("stream_left", "mult", "probe")
+    __slots__ = ("stream_left", "mult", "probe", "swap")
 
     def __init__(self, stream_left: bool):
         self.stream_left = stream_left
         self.mult: Optional[int] = None
-        #: the build side sorted by its unique integer key (_SortedBuild),
-        #: where the join has such a key: stream batches are then probed
+        #: the build side's keys sorted (_SortedBuild), where the join has
+        #: one integer key that the build side holds once a value (for a
+        #: semi join: however often): stream batches are then probed
         #: against it by the sort-and-scan kernel, not the general one
         self.probe: Optional[_SortedBuild] = None
+        #: an inner join whose BUILD side holds a key twice: the probe
+        #: needs one side with unique keys, and each stream batch is
+        #: tried as that side (``_join_swapped``)
+        self.swap = False
+
+    @property
+    def probing(self) -> bool:
+        """Whether stream batches go through the sort-and-scan probe."""
+        return self.probe is not None or self.swap
 
     @property
     def hard(self) -> bool:
@@ -124,27 +134,20 @@ def _count_join_rows(exec_id: str, build, stream, out, parts: int) -> None:
     tr = trace_core.TRACER
     if tr is None or not tr.recording:
         return
-    from ..columnar.packing import fetch_packed
-    groups = [list(g) if isinstance(g, (list, tuple)) else [g]
-              for g in (build, stream, out)]
-    lazy = [c for g in groups for c in g
-            if not isinstance(c, (int, np.integer))]
-    got = iter(fetch_packed(lazy)) if lazy else iter(())
-    b, st, o = (sum(int(c) if isinstance(c, (int, np.integer))
-                    else int(next(got)) for c in g) for g in groups)
+    from ..columnar.packing import sum_counts
+    b, st, o = sum_counts((build, stream, out))
     tr.counter("join.rows", {"build": b, "stream": st, "out": o,
                              "parts": int(parts),
                              "op": int(exec_id.rsplit("@", 1)[-1])},
                cat="exec")
 
 
-def _span(name: str, exec_id: str, **args):
-    """A ``with`` span of the installed tracer (so that it reaches the
-    profiler's clock, nested under the operator's own span), or nothing."""
-    tr = trace_core.TRACER
-    if tr is None:
-        return contextlib.nullcontext()
-    return tr.span(name, cat="exec", args=dict(args, exec=exec_id))
+def _intake(batch: ColumnarBatch) -> ColumnarBatch:
+    """A join input as it is taken in: on the device, list rectangles on
+    the host (the gathers move 1-D lanes), string rectangles kept: the
+    unique-key probe gathers them as their word lanes, and ``_join``
+    demotes them for every other kernel."""
+    return batch.ensure_device().with_lists_on_host(strings=False)
 
 
 def _resolve_counts(spillables) -> None:
@@ -186,14 +189,22 @@ _DEAD_KEY = np.iinfo(np.int64).max
 
 
 class _SortedBuild:
-    """A build side ready for ``_probe_kernel``: ``batch`` holds its rows
-    in key order, the ``rows`` that can match first; ``keys`` their keys
-    as int64, ``_DEAD_KEY`` past ``rows``."""
+    """A build side ready for ``_probe_kernel``: ``keys`` its keys as
+    int64 in key order, the ``rows`` that can match first, ``_DEAD_KEY``
+    past them; ``order`` the row of ``batch`` each of them came from.
+    ``batch`` itself stays as it was: only the rows a probe matches are
+    ever gathered from it (through ``order``), not the whole side once a
+    query (1.5M rows of ``customer`` with their names, where a handful
+    match)."""
 
-    __slots__ = ("keys", "batch", "rows")
+    __slots__ = ("keys", "order", "batch", "rows", "lanes", "spans")
 
-    def __init__(self, keys, batch: ColumnarBatch, rows: int):
-        self.keys, self.batch, self.rows = keys, batch, rows
+    def __init__(self, keys, order, batch: ColumnarBatch, rows):
+        self.keys, self.order, self.batch, self.rows = (keys, order, batch,
+                                                        rows)
+        #: its columns as the gather takes them, made once (a string
+        #: rectangle rides as its word lanes)
+        self.lanes, self.spans = _lane_pairs(list(enumerate(batch.columns)))
 
 
 def _key_lane(key_expr, schema, dtypes, cols, n, p):
@@ -207,9 +218,9 @@ def _key_lane(key_expr, schema, dtypes, cols, n, p):
 
 
 def _build_sort_kernel(key_expr, schema):
-    """Build side -> (sorted keys, the permutation, rows that can match,
-    whether the probe must not be used: a key twice, or a live key equal
-    to ``_DEAD_KEY``)."""
+    """One side -> (sorted keys, the permutation, rows that can match,
+    whether a key is there twice, whether a live key equals ``_DEAD_KEY``
+    and so cannot be told from padding)."""
     dtypes = [f.dtype for f in schema.fields]
 
     def join_build(cols, n, p):
@@ -224,10 +235,8 @@ def _build_sort_kernel(key_expr, schema):
             skey == jnp.roll(skey, 1),
             jnp.logical_and(jnp.arange(p, dtype=jnp.int32) < rows,
                             jnp.arange(p) > 0))
-        unusable = jnp.logical_or(
-            jnp.any(again),
-            jnp.any(jnp.logical_and(live, key == _DEAD_KEY)))
-        return skey, order, rows, unusable
+        return (skey, order, rows, jnp.any(again),
+                jnp.any(jnp.logical_and(live, key == _DEAD_KEY)))
 
     return join_build
 
@@ -271,13 +280,16 @@ def _build_probe_kernel(key_expr, schema):
     return join_probe
 
 
-def _pairs_gather(total, s_row, b_row, scols, bcols, out_p):
+def _pairs_gather(total, s_row, b_row, order, scols, bcols, out_p):
     """The first ``out_p`` (stream row, build row) pairs of a probe, -1
-    past ``total``, and both sides' columns gathered by them."""
+    past ``total``, and both sides' columns gathered by them. A build row
+    is a place in key order: ``order`` says which row of the build side
+    stands there."""
     short = max(0, out_p - s_row.shape[0])
     live = jnp.arange(out_p, dtype=jnp.int32) < total
     s_row = jnp.where(live, jnp.pad(s_row, (0, short))[:out_p], -1)
-    b_row = jnp.where(live, jnp.pad(b_row, (0, short))[:out_p], -1)
+    b_row = jnp.where(live, jnp.take(
+        order, jnp.pad(b_row, (0, short))[:out_p], mode="clip"), -1)
     return (_packed_gather(scols, s_row, out_p),
             _packed_gather(bcols, b_row, out_p))
 
@@ -653,10 +665,8 @@ class TpuHashJoinExec(TpuExec):
         # 1D lanes only (columnar/nested.py with_lists_on_host)
         right_batches, left_batches = wrap_spillable_sides(
             ctx.memory,
-            (b.ensure_device().with_lists_on_host()
-             for b in self.children[1].execute(ctx)),
-            (b.ensure_device().with_lists_on_host()
-             for b in self.children[0].execute(ctx)))
+            (_intake(b) for b in self.children[1].execute(ctx)),
+            (_intake(b) for b in self.children[0].execute(ctx)))
         ls, rs = (self.children[0].output_schema(),
                   self.children[1].output_schema())
         sides = {"left": left_batches, "right": right_batches}
@@ -749,18 +759,24 @@ class TpuHashJoinExec(TpuExec):
                         return _empty_batch(rsch if bi else lsch)
                     return concat_batches(
                         [_counted(s) for s in build_batches], BUILD_BUCKETS)
+            bound = self._out_bound(ctx, bi)
+            with self.child_span("join.build", rows=rows[build],
+                                 cols=(rsch if bi else lsch).names()):
+                bb = with_retry_no_split(make_build, ctx=ctx,
+                                         op=self._exec_id)
+                self._prepare_probe(ctx, bb, bound)
+            if bound is None or not bound.probing:
+                # the general kernel moves 1-D lanes: a string rectangle
+                # of the build side leaves the device once, not a batch
+                bb = bb.with_lists_on_host()
+                if self.join_type == "leftsemi":
+                    bound = None       # its output keeps the hard bound
 
             def build_bloom_run():
                 with ctx.semaphore.held():
                     return self._build_bloom(ctx, lsch, bb)
-            bound = self._out_bound(ctx, bi)
-            with _span("join.build", self._exec_id, rows=rows[build],
-                       cols=(rsch if bi else lsch).names()):
-                bb = with_retry_no_split(make_build, ctx=ctx,
-                                         op=self._exec_id)
-                bloom = with_retry_no_split(build_bloom_run, ctx=ctx,
-                                            op=self._exec_id) if bi else None
-                self._prepare_probe(ctx, bb, bound)
+            bloom = with_retry_no_split(build_bloom_run, ctx=ctx,
+                                        op=self._exec_id) if bi else None
             self._record(sides["left"], sides["right"], lsch, rsch)
             for s in build_batches:
                 s.close()
@@ -774,8 +790,8 @@ class TpuHashJoinExec(TpuExec):
                             sb = self._apply_bloom(ctx, bloom, sb)
                         return (self._join(sb, bb, ctx, bound) if bi
                                 else self._join(bb, sb, ctx, bound))
-                with _span("join.probe", self._exec_id,
-                           cols=(lsch if bi else rsch).names()):
+                with self.child_span(
+                        "join.probe", cols=(lsch if bi else rsch).names()):
                     outs.append(with_retry_no_split(run, ctx=ctx,
                                                     op=self._exec_id))
                 if s is not None:
@@ -800,7 +816,8 @@ class TpuHashJoinExec(TpuExec):
         multiplicity of rows where the join emits it once per match or
         once null-extended: there the output can be bound by the input."""
         if ctx.speculate and self.join_type in (
-                ("inner", "left") if bi == 1 else ("inner", "right")) \
+                ("inner", "left", "leftsemi") if bi == 1
+                else ("inner", "right")) \
                 and (self.condition is None or self.join_type == "inner"):
             return _OutBound(stream_left=(bi == 1))
         return None
@@ -998,6 +1015,17 @@ class TpuHashJoinExec(TpuExec):
     def _join(self, lb: ColumnarBatch, rb: ColumnarBatch,
               ctx: Optional[ExecContext] = None,
               bound: Optional[_OutBound] = None) -> ColumnarBatch:
+        probing = bound is not None and bound.probing \
+            and ctx.speculate and lb.all_device and rb.all_device
+        if probing and bound.swap:
+            sb, bb = (lb, rb) if bound.stream_left else (rb, lb)
+            out = self._join_swapped(ctx, sb, bb, bound)
+            if out is not None:
+                return out
+            probing = False
+        if not probing:
+            # every kernel but the probe's gather moves 1-D lanes
+            lb, rb = lb.with_lists_on_host(), rb.with_lists_on_host()
         if self.join_type == "cross" or not self.left_keys:
             return self._cross(lb, rb)
         if (self.condition is not None and
@@ -1014,8 +1042,7 @@ class TpuHashJoinExec(TpuExec):
               tuple((f.name, f.dtype.name) for f in ls.fields),
               tuple((f.name, f.dtype.name) for f in rs.fields),
               self.join_type)
-        if bound is not None and bound.probe is not None \
-                and ctx.speculate and lb.all_device and rb.all_device:
+        if probing:
             return self._join_probe(ctx, lb if bound.stream_left else rb,
                                     bound, ck)
         kern = _COUNT_CACHE.get(ck)
@@ -1111,10 +1138,14 @@ class TpuHashJoinExec(TpuExec):
     # -- the unique-key probe (kernels above _build_count_kernel) ----------
     def _probe_key(self, build_left: bool, ls: Schema, rs: Schema):
         """(build side's key, its schema) where the probe applies: an
-        inner join on ONE key that is an integer lane on both sides
-        (integers, dates, timestamps; a decimal's lane means another
-        number at another scale)."""
-        if self.join_type != "inner" or len(self.left_keys) != 1:
+        inner or left semi join on ONE key that is an integer lane on both
+        sides (integers, dates, timestamps; a decimal's lane means another
+        number at another scale), without a residual condition on the
+        semi join (it would have to see the pairs)."""
+        if self.join_type not in ("inner", "leftsemi") \
+                or len(self.left_keys) != 1 \
+                or (self.join_type == "leftsemi"
+                    and (build_left or self.condition is not None)):
             return None
         for k, sch in ((self.left_keys[0], ls), (self.right_keys[0], rs)):
             dt = k.data_type(sch)
@@ -1126,11 +1157,15 @@ class TpuHashJoinExec(TpuExec):
 
     def _prepare_probe(self, ctx, bb: ColumnarBatch,
                        bound: Optional[_OutBound]) -> None:
-        """Sort the build side by its key, once a join a query, and read
-        (ONE small fetch) how many of its rows can match and whether each
-        key is there once: then ``bound`` carries it and every stream
-        batch is probed against it. Otherwise ``bound`` stays as it was
-        and the first stream batch measures the key multiplicity."""
+        """Sort the build side's KEYS, once a join a query (its rows stay
+        where they are: ``_SortedBuild``), and read (ONE small fetch) how
+        many of its rows can match and whether each key is there once:
+        then ``bound`` carries it and every stream batch is probed against
+        it. A semi join asks whether a key is there, so a key there twice
+        changes nothing for it. An inner join whose build side holds a key
+        twice notes that (``bound.swap``): the stream side's keys may
+        still be unique. Otherwise ``bound`` stays as it was and the first
+        stream batch measures the key multiplicity."""
         if bound is None or not bb.all_device:
             return
         found = self._probe_key(not bound.stream_left,
@@ -1142,26 +1177,33 @@ class TpuHashJoinExec(TpuExec):
 
         def run():
             with ctx.semaphore.held():
-                keys, order, rows, unusable = kern(
+                keys, order, rows, twice, dead = kern(
                     _lanes(bb), jnp.int32(bb.num_rows_raw), bb.padded_len)
-                rows, unusable = traced_device_get((rows, unusable),
-                                                   "d2h.join_count")
-                if unusable:
-                    return None
-                return _SortedBuild(keys, gather_batch_device(
-                    bb, order, int(rows), bb.padded_len), int(rows))
-        bound.probe = with_retry_no_split(run, ctx=ctx, op=self._exec_id)
-        if bound.probe is not None:
-            bound.mult = min(bound.probe.rows, 1)
+                rows, twice, dead = traced_device_get(
+                    (rows, twice, dead), "d2h.join_count")
+                return _SortedBuild(keys, order, bb, int(rows)), \
+                    bool(twice), bool(dead)
+        build, twice, dead = with_retry_no_split(run, ctx=ctx,
+                                                 op=self._exec_id)
+        if dead:
+            return
+        if not twice or self.join_type == "leftsemi":
+            bound.probe = build
+            # a stream row matches at most once: its output is bound hard
+            bound.mult = min(build.rows, 1)
+        else:
+            bound.swap = True
 
     def _join_probe(self, ctx, sb: ColumnarBatch, bound: _OutBound,
                     ck) -> ColumnarBatch:
-        """One stream batch against the sorted build side: the probe,
-        then the gather of the matched pairs. The output is sized from the last observed total of this join
-        shape where that takes a smaller bucket than the stream batch's
-        own (the hard bound: each stream row matches at most once); the
-        first batch ever of a shape reads its total."""
+        """One stream batch against the sorted build keys: the probe, then
+        the gather of the matched pairs. The output is sized from the last
+        observed total of this join shape where that takes a smaller
+        bucket than the stream batch's own (the hard bound: each stream
+        row matches at most once); the first batch ever of a shape reads
+        its total."""
         build = bound.probe
+        semi = self.join_type == "leftsemi"
         key_expr = (self.left_keys if bound.stream_left
                     else self.right_keys)[0]
         kern = _probe_kernel("probe", key_expr, sb.schema)
@@ -1171,26 +1213,66 @@ class TpuHashJoinExec(TpuExec):
             else bucket_for(max(int(stat * 1.5), 1))
         speculative = guess < out_p
         out_p = min(out_p, guess)
-        from ..plan import exec_cache
-        scols = _lanes(sb)
         total, s_row, b_row = kern(
-            build.keys, jnp.int32(build.rows), scols,
+            build.keys, jnp.int32(build.rows), _lanes(sb),
             jnp.int32(sb.num_rows_raw), sb.padded_len)
-        souts, bouts = exec_cache.get_or_build_jit(
-            "joins.pairs_gather", _pairs_gather, static_argnums=(5,))(
-                total, s_row, b_row, scols, _lanes(build.batch), out_p)
         if speculative:
             ctx.speculations.append((total, out_p, ck,
                                      getattr(self, 'plan_sig', None)))
         elif stat is None:
             total = int(traced_device_get(total, "d2h.join_count"))
             _note_total(ctx, ck, total)
-        s_out = [c.with_arrays(d, v)
-                 for c, (d, v) in zip(sb.columns, souts)]
-        b_out = [c.with_arrays(d, v)
-                 for c, (d, v) in zip(build.batch.columns, bouts)]
-        out = ColumnarBatch(s_out + b_out if bound.stream_left
-                            else b_out + s_out, total, self._schema)
+        # a semi join's output is its stream side's rows
+        return self._pairs_batch(total, s_row, b_row, sb,
+                                 None if semi else build, out_p,
+                                 bound.stream_left)
+
+    def _join_swapped(self, ctx, sb: ColumnarBatch, bb: ColumnarBatch,
+                      bound: _OutBound) -> Optional[ColumnarBatch]:
+        """One stream batch of an inner join whose build side holds a key
+        twice, with the sides' parts exchanged: the stream batch's keys
+        are sorted (``join_build``: one small fetch says whether THEY are
+        unique) and the build side's rows are probed against them, each
+        matching at most one row of this batch, so the output keeps the
+        build side's bucket. None where the batch's keys repeat too: the
+        general kernel's case."""
+        skey, bkey = (self.left_keys[0], self.right_keys[0]) \
+            if bound.stream_left else (self.right_keys[0], self.left_keys[0])
+        keys, order, rows, twice, dead = _probe_kernel(
+            "build", skey, sb.schema)(
+                _lanes(sb), jnp.int32(sb.num_rows_raw), sb.padded_len)
+        if any(traced_device_get((twice, dead), "d2h.join_count")):
+            return None
+        total, b_row, s_row = _probe_kernel("probe", bkey, bb.schema)(
+            keys, rows, _lanes(bb), jnp.int32(bb.num_rows_raw),
+            bb.padded_len)
+        return self._pairs_batch(
+            total, b_row, s_row, bb, _SortedBuild(keys, order, sb, rows),
+            bucket_for(max(bb.padded_len, 1)), not bound.stream_left)
+
+    def _pairs_batch(self, total, p_row, s_row, probing: ColumnarBatch,
+                     build: Optional[_SortedBuild], out_p: int,
+                     probing_left: bool) -> ColumnarBatch:
+        """The join's output from a probe's matched pairs: the probing
+        batch's columns at ``p_row`` and the sorted side's at ``s_row``
+        (none of a sorted side that is None) in ONE gather, the probing
+        batch's on the left where ``probing_left``, then the residual
+        condition."""
+        from ..plan import exec_cache
+        p_lanes, p_spans = _lane_pairs(list(enumerate(probing.columns)))
+        pouts, bouts = exec_cache.get_or_build_jit(
+            "joins.pairs_gather", _pairs_gather, static_argnums=(6,))(
+                total, p_row, s_row,
+                s_row if build is None else build.order, p_lanes,
+                [] if build is None else build.lanes, out_p)
+        p_out = [None] * len(probing.columns)
+        _lane_rebuild(probing, p_spans, pouts, p_out)
+        b_out = []
+        if build is not None:
+            b_out = [None] * len(build.batch.columns)
+            _lane_rebuild(build.batch, build.spans, bouts, b_out)
+        out = ColumnarBatch(p_out + b_out if probing_left
+                            else b_out + p_out, total, self._schema)
         if self.condition is not None:
             out = filter_batch_device(self.condition, out)
         return out
@@ -1420,15 +1502,19 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
         build = self.children[bi]
         rows_m = ctx.metric(self._exec_id, "numOutputRows", ESSENTIAL)
         bound = self._out_bound(ctx, bi)
-        with _span("join.build", self._exec_id,
-                   cols=build.output_schema().names()):
+        with self.child_span("join.build",
+                             cols=build.output_schema().names()):
             bb = build.broadcast(ctx)
             if bb is not None:
-                self._prepare_probe(ctx, bb.with_lists_on_host(), bound)
-        if bb is not None:
-            # list payloads demote like every other join intake: the
-            # gather path moves 1D lanes only
+                bb = _intake(bb)
+                self._prepare_probe(ctx, bb, bound)
+        if bb is not None and (bound is None or not bound.probing):
+            # list payloads demote like every other join intake (the
+            # gather path moves 1D lanes only), and without the probe
+            # string rectangles too, once and not a stream batch
             bb = bb.with_lists_on_host()
+            if self.join_type == "leftsemi":
+                bound = None           # its output keeps the hard bound
         sigs = getattr(self, "side_sigs", None)
         if sigs is not None and bb is not None:
             # record the build side's MEASURED logical bytes: an
@@ -1472,7 +1558,7 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
         n_stream, n_out = [], []   # counts as they are: no read a batch
         try:
             for sb in self.children[1 - bi].execute(ctx):
-                sb = sb.ensure_device().with_lists_on_host()
+                sb = _intake(sb)
                 def run(sb=sb):
                     with ctx.semaphore.held():
                         if bloom is not None and sb.num_rows > 0:
@@ -1481,8 +1567,7 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
                             sb2 = sb
                         return (self._join(sb2, bb, ctx, bound) if bi == 1
                                 else self._join(bb, sb2, ctx, bound))
-                with _span("join.probe", self._exec_id,
-                           cols=sb.schema.names()):
+                with self.child_span("join.probe", cols=sb.schema.names()):
                     out = with_retry_no_split(run, ctx=ctx,
                                               op=self._exec_id)
                 rows_m.add(out.num_rows_raw)

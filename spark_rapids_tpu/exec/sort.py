@@ -180,9 +180,10 @@ def _build_topn_kernel(orders: List[SortOrder], schema: Schema, n: int):
             0, n, pick, (ctx.row_mask(), jnp.zeros(out_p, jnp.int32)))
         count = jnp.minimum(jnp.sum(ctx.row_mask()), n).astype(jnp.int32)
         live = jnp.arange(out_p, dtype=jnp.int32) < count
-        return [(jnp.take(dv.data, picks, axis=0),
+        return [None if dv is None else
+                (jnp.take(dv.data, picks, axis=0),
                  jnp.logical_and(jnp.take(dv.validity, picks), live))
-                for dv in dvals], count
+                for dv in dvals], count, picks
 
     # ONE callable a process (the executable cache's, so its compiles are
     # counted), as the joins' probe kernels
@@ -194,15 +195,35 @@ def _build_topn_kernel(orders: List[SortOrder], schema: Schema, n: int):
 
 def topn_batch_device(orders: List[SortOrder], batch: ColumnarBatch,
                       n: int) -> ColumnarBatch:
-    """``sort_batch_device`` cut to its first ``n`` rows, in their order."""
+    """``sort_batch_device`` cut to its first ``n`` rows, in their order.
+    The rows are picked by the keys; every other column is gathered by the
+    picked rows in the form it has: a device lane (dictionary codes
+    included) inside the kernel, a string rectangle as its word lanes by
+    the same rows, a column on the host by the fetched rows (``n`` of
+    them)."""
+    from ..exprs.compiler import gather_batch_device
     kernel = _build_topn_kernel(orders, batch.schema, n)
-    cols = [(c.data, c.validity) for c in batch.columns]
-    outs, count = kernel(cols, jnp.int32(batch.num_rows_raw),
-                         batch.padded_len)
+    plain = [isinstance(c, DeviceColumn) and not hasattr(c, "kernel_lanes")
+             for c in batch.columns]
+    cols = [(c.data, c.validity) if ok else None
+            for c, ok in zip(batch.columns, plain)]
+    outs, count, picks = kernel(cols, jnp.int32(batch.num_rows_raw),
+                                batch.padded_len)
     rows = batch.num_rows_raw
-    return ColumnarBatch(
-        [c.with_arrays(d, v) for (d, v), c in zip(outs, batch.columns)],
-        min(rows, n) if isinstance(rows, int) else count, batch.schema)
+    rows = min(rows, n) if isinstance(rows, int) else count
+    new_cols = [c.with_arrays(*o) if ok else None
+                for o, c, ok in zip(outs, batch.columns, plain)]
+    rest = [i for i, ok in enumerate(plain) if not ok]
+    if rest:
+        sub = ColumnarBatch([batch.columns[i] for i in rest],
+                            batch.num_rows_raw,
+                            Schema([batch.schema.fields[i] for i in rest]))
+        live = jnp.arange(picks.shape[0], dtype=jnp.int32) < count
+        got = gather_batch_device(sub, jnp.where(live, picks, -1), rows,
+                                  int(picks.shape[0]))
+        for i, c in zip(rest, got.columns):
+            new_cols[i] = c
+    return ColumnarBatch(new_cols, rows, batch.schema)
 
 
 _KEYENC_CACHE: Dict[Tuple, object] = {}
@@ -283,8 +304,8 @@ class TpuSortExec(TpuExec):
             with ctx.semaphore.held():
                 return topn_batch_device(self.orders, batch, self.limit)
         tops = [with_retry_no_split(
-                    lambda b=b: top(b.ensure_device().with_lists_on_host()),
-                    ctx=ctx, op=self._exec_id)
+                    lambda b=b: top(b.ensure_device().with_lists_on_host(
+                        strings=False)), ctx=ctx, op=self._exec_id)
                 for b in self.children[0].execute(ctx)]
         if len(tops) > 1:
             tops = [with_retry_no_split(lambda: top(concat_batches(tops)),

@@ -664,8 +664,13 @@ class SortMeta(PlanMeta):
                 self.will_not_work_on_tpu(
                     f"sort key <{o.expr.name_hint}>: {r}",
                     code=T.EXPR_UNSUPPORTED, expr=o.expr.name_hint)
+        from ..types import STRING
         for f in schema.fields:
-            if not f.dtype.device_backed:
+            # the selection kernel of a LIMIT above (exec/sort.py) picks
+            # rows by the keys alone and gathers a string payload by the
+            # picked rows, in whatever form the column has
+            if not f.dtype.device_backed and not (
+                    f.dtype == STRING and self.plan.limit is not None):
                 self.will_not_work_on_tpu(
                     f"column {f.name}: {f.dtype.name} payload is host-only",
                     code=T.DTYPE_HOST_ONLY)

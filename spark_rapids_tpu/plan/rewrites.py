@@ -264,22 +264,62 @@ def _filtered(conds, plan: L.LogicalPlan) -> L.LogicalPlan:
     return L.Filter(_and_all(conds), plan) if conds else plan
 
 
+def _sink(semi: L.Join, into: L.LogicalPlan, counts: list):
+    """``into`` with the semi-join placed below the joins it can cross, or
+    None where it crosses none. A left semi or anti join against a
+    relation of its own (``expr IN (select ...)``) is a filter of its left
+    input's rows: where that input is a join and the semi-join's keys name
+    columns of ONE of its inputs, it goes onto that input, by the rule a
+    filter's conjunct follows (``_PUSH_SIDES``), and through a filter in
+    its way. ``counts`` as in :func:`push_filters_below_joins`: a
+    semi-join counts as one conjunct, however far it goes."""
+    if isinstance(into, L.Filter):
+        below = _sink(semi, into.children[0], counts)
+        return None if below is None else L.Filter(into.condition, below)
+    if not isinstance(into, L.Join):
+        return None
+    refs: set = set()
+    for k in semi.left_keys:
+        _expr_refs(k, refs)
+    sides = [set(c.schema().names()) for c in into.children]
+    i = next((i for i in (0, 1)
+              if refs and refs <= sides[i] and not refs & sides[1 - i]),
+             None)
+    if i not in _PUSH_SIDES[into.join_type]:
+        counts[1] += i is not None
+        return None
+    counts[0] += 1
+    kids = list(into.children)
+    kids[i] = _sink(semi, kids[i], [0, 0]) \
+        or _with_children(semi, [kids[i], semi.children[1]])
+    return _with_children(into, kids)
+
+
+def _with_children(node, children):
+    node = copy.copy(node)
+    node.children = list(children)
+    return node
+
+
 def push_filters_below_joins(plan: L.LogicalPlan):
     """(plan', pushed, above_joins): every filter directly above a join
     split into its conjuncts, each deterministic conjunct that names
     columns of ONE join input moved onto that input (recursively: below
-    the next join too), the others kept above. ``pushed`` counts the
-    conjuncts that crossed a join; ``above_joins`` the one-input conjuncts
-    that could not (the null-producing side of an outer join, a value
-    with per-task state). The plan comes back as the same object when
-    nothing moves."""
+    the next join too), the others kept above; a left semi or anti join
+    whose keys name columns of one input of a join below it likewise
+    (:func:`_sink`). ``pushed`` counts the conjuncts that crossed a join;
+    ``above_joins`` the one-input conjuncts that could not (the
+    null-producing side of an outer join, a value with per-task state).
+    The plan comes back as the same object when nothing moves."""
     counts = [0, 0]
 
     def walk(node):
         kids = [walk(c) for c in node.children]
         if any(n is not o for n, o in zip(kids, node.children)):
-            node = copy.copy(node)
-            node.children = kids
+            node = _with_children(node, kids)
+        if isinstance(node, L.Join) and node.condition is None \
+                and node.join_type in ("leftsemi", "leftanti"):
+            return _sink(node, node.children[0], counts) or node
         if not (isinstance(node, L.Filter)
                 and isinstance(node.children[0], L.Join)):
             return node

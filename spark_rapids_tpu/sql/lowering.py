@@ -142,8 +142,20 @@ class _Lowerer:
                     continue
             df = self._lower_join(df, right, j, alias_cols)
 
+        # expr IN (select ...) conjuncts become semi-joins of the joined
+        # frame (the planner moves each below the joins it can cross,
+        # plan/rewrites.py); the subqueries lower first: a nested lowering
+        # has an alias scope of its own
+        in_subs = [c for c in conjuncts if _is_in_subquery(c)]
+        keysets = [self._in_subquery_keys(c) for c in in_subs]
         self._aliases = alias_cols
-        remaining = _and_all(conjuncts)
+        for n, (c, keys) in enumerate(zip(in_subs, keysets)):
+            name = f"__in{n}_{keys.columns[0]}"
+            df = df.join(keys.select(F.col(keys.columns[0]).alias(name)),
+                         on=[(self._expr(c[1]), F.col(name))],
+                         how="leftsemi")
+        remaining = _and_all([c for c in conjuncts
+                              if not _is_in_subquery(c)])
         if remaining is not None:
             df = df.filter(self._expr(remaining))
 
@@ -302,6 +314,35 @@ class _Lowerer:
         return df, new_sel
 
     # -- joins ----------------------------------------------------------
+    def _in_subquery_keys(self, ast):
+        """The frame of ``expr IN (select ...)``'s subquery: uncorrelated,
+        one column. IN is a left semi join on it, with Spark's NULL
+        semantics (a NULL probe value matches nothing, a NULL among the
+        subquery's values matches nothing). NOT IN is null-aware (one NULL
+        in the subquery empties the answer) and is refused by name: an
+        anti join would answer it wrongly where a NULL is. So is a
+        correlated subquery (a column of the outer query inside it)."""
+        if ast[3]:
+            raise SqlError(
+                "NOT IN (select ...) is not supported: it needs the "
+                "null-aware anti join; write NOT EXISTS or a LEFT ANTI "
+                "JOIN where the subquery's column holds no NULL")
+        keys = self.lower(ast[2])
+        # the subquery lowers in a scope of its own and columns resolve
+        # when a plan is made: a column of the OUTER query in one of its
+        # predicates would surface there as a KeyError
+        outer = _unresolved_filter_column(keys.plan)
+        if outer is not None:
+            raise SqlError(
+                f"IN (select ...): column '{outer}' does not resolve "
+                "inside the subquery; a correlated subquery is not "
+                "supported")
+        if len(keys.columns) != 1:
+            raise SqlError(
+                f"IN (select ...) needs a subquery of ONE column, this "
+                f"one has {len(keys.columns)}: {keys.columns}")
+        return keys
+
     def _side_of(self, ast, lcols, rcols, alias_cols, ralias=None):
         """Which join side a column AST belongs to, or (None, None).
         ``ralias`` is the alias of the table being joined in (the right
@@ -519,9 +560,10 @@ class _Lowerer:
 
         # final projection restores select order/names over agg output
         out_cols, final_alias = [], {}
-        for e, alias in proj_items:
+        for (e, alias), (written, _) in zip(proj_items, items):
             c = self._expr(e)
-            name = alias or self._default_name(e, c)
+            name = alias or _agg_call_name(written) \
+                or self._default_name(e, c)
             out_cols.append(c.alias(name))
             final_alias[name.lower()] = ("col", (name,))
         df = df.select(*out_cols)
@@ -638,6 +680,10 @@ class _Lowerer:
         if kind == "like":
             c = F.like(self._expr(ast[1]), ast[2])
             return ~c if ast[3] else c
+        if kind == "in_subquery":
+            raise SqlError(
+                "IN (select ...) is supported as a conjunct of WHERE "
+                "only (not under OR / NOT, in SELECT, HAVING or ON)")
         if kind == "between":
             e = self._expr(ast[1])
             c = (e >= self._expr(ast[2])) & (e <= self._expr(ast[3]))
@@ -709,6 +755,38 @@ class _Lowerer:
         b = self._expr(base_ast)
         return (F.date_add(b, n * days * sign) if sign > 0
                 else F.date_sub(b, n * days))
+
+
+def _unresolved_filter_column(plan) -> Optional[str]:
+    """A column that a filter of ``plan`` reads and its input does not
+    have, or None."""
+    from ..plan import logical as L
+    from ..plan.rewrites import _expr_refs
+    if isinstance(plan, L.Filter):
+        refs: set = set()
+        _expr_refs(plan.condition, refs)
+        missing = sorted(refs - set(plan.children[0].schema().names()))
+        if missing:
+            return missing[0]
+    for c in plan.children:
+        found = _unresolved_filter_column(c)
+        if found is not None:
+            return found
+    return None
+
+
+def _is_in_subquery(ast) -> bool:
+    return isinstance(ast, tuple) and ast[0] == "in_subquery"
+
+
+def _agg_call_name(ast) -> Optional[str]:
+    """Spark's name for an unaliased select item that is one aggregate
+    call over a column: ``sum(l_quantity)``; None for anything else."""
+    if isinstance(ast, tuple) and ast[0] == "fn" and ast[1] in _AGG_FNS \
+            and not ast[3] and len(ast[2]) == 1 \
+            and isinstance(ast[2][0], tuple) and ast[2][0][0] == "col":
+        return f"{ast[1]}({ast[2][0][1][-1]})"
+    return None
 
 
 def _str_lit(ast, what) -> str:

@@ -579,6 +579,13 @@ class _Parser:
             if t.kind == "kw" and t.val == "in":
                 self.next()
                 self.expect("op", "(")
+                if self.at_kw("select", "with"):
+                    # expr [NOT] IN (select ...): the lowering makes it a
+                    # semi-join, not a list of values
+                    sub = self.parse_query()
+                    self.expect("op", ")")
+                    e = ("in_subquery", e, sub, neg)
+                    continue
                 vals = [self.parse_expr()]
                 while self.accept("op", ","):
                     vals.append(self.parse_expr())
@@ -630,9 +637,18 @@ class _Parser:
         t = self.peek()
         if t.kind == "op" and t.val == "(":
             self.next()
+            if self.at_kw("select", "with"):
+                raise SqlError(
+                    f"a scalar subquery (select ...) at {t.pos} is not "
+                    "supported: join the subquery's frame in")
             e = self.parse_expr()
             self.expect("op", ")")
             return e
+        if t.kind == "kw" and t.val == "exists":
+            raise SqlError(
+                f"EXISTS (select ...) at {t.pos} is not supported: write "
+                "the uncorrelated form as expr IN (select ...) or a LEFT "
+                "SEMI / LEFT ANTI JOIN")
         if t.kind == "num":
             self.next()
             v = t.val

@@ -137,7 +137,9 @@ def test_count_is_never_null():
 # Re-partition merge fallback (ref GpuAggregateExec.scala:718-780)
 # ---------------------------------------------------------------------------
 
-_REPART_CONF = {"spark.rapids.tpu.sql.batchSizeBytes": 2048}
+# the partials of 8 batches of 1,024 rows hold more rows together than one
+# 1,024-row bucket: the aggregate finishes in partitions
+_REPART_CONF = {"spark.rapids.tpu.sql.batchSizeRows": 1024}
 
 
 def test_agg_repartition_fallback_differential():
@@ -159,7 +161,7 @@ def test_agg_repartition_emits_disjoint_groups():
     from harness import tpu_session
     s = tpu_session(_REPART_CONF)
     df = s.create_dataframe(gen_df(
-        {"k": IntGen(lo=0, hi=200, nullable=False), "v": IntGen()},
+        {"k": IntGen(lo=0, hi=600, nullable=False), "v": IntGen()},
         n=8192), num_partitions=4)
     out = df.group_by("k").agg(F.count_star().with_name("n"))
     phys = out._physical()
@@ -398,25 +400,210 @@ def test_agg_multibatch_string_keys_high_cardinality_sort_path():
     assert_tpu_and_cpu_equal(q)
 
 
-def test_agg_tree_merge_bounded_fanin():
-    """Force the bounded-fan-in tree merge (r4): partials at the 1024
-    bucket with batchSizeRows=2048 make every level chunk at fan-in 2;
-    results must match the host oracle exactly."""
+def _double_keys_table():
+    """6,000 double keys four rows each, among them the ones a hash must
+    not tell apart (0.0 and -0.0, NaNs) and the infinities. No NULLs: the
+    host oracle groups them with NaN (the string keys below hold some)."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(5)
+    k = np.repeat(rng.normal(0, 1e6, 6000), 4)
+    k[:40] = np.tile([0.0, -0.0, np.nan, -np.nan, np.inf, -np.inf, 1e300,
+                      -1e-300], 5)
+    rng.shuffle(k)
+    return pa.table({"k": k, "v": rng.integers(-50, 50, k.size),
+                     "w": rng.normal(0, 1, k.size)})
+
+
+def _string_keys_table():
+    """12,000 string keys two rows each, every batch's keys distinct (a
+    byte rectangle at that cardinality) and found again three batches on,
+    the later keys longer: the batches' rectangles differ in width."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(6)
+    ids = np.tile(np.arange(12_000), 2)
+    k = np.asarray([f"key-{i:05d}" + "x" * (i // 2000 * 4) for i in ids],
+                   dtype=object)
+    k[rng.random(k.size) < 0.01] = None
+    return pa.table({"k": pa.array(k), "v": rng.integers(-50, 50, k.size),
+                     "w": rng.normal(0, 1, k.size)})
+
+
+@pytest.mark.parametrize("table,conf,rect", [
+    # a DOUBLE key: the device cannot hash it as Spark does, and need not
+    (_double_keys_table,
+     {"spark.rapids.tpu.sql.batchSizeRows": 2048}, False),
+    # a string key held as a byte rectangle, its width a batch's own
+    (_string_keys_table,
+     {"spark.rapids.tpu.sql.batchSizeRows": 2048}, True),
+    # few rows but wide: under batchSizeRows, over batchSizeBytes together
+    (_double_keys_table,
+     {"spark.rapids.tpu.sql.batchSizeBytes": 1 << 16}, False),
+], ids=["double_key", "rectangle_key", "byte_cap"])
+def test_partitioned_finish_takes_every_key_and_the_byte_cap(
+        table, conf, rect, monkeypatch):
+    """Partials that pass the cap finish in partitions whatever their key
+    (at the parent of PR 35 a DOUBLE or a string rectangle kept a
+    bounded tree of merges that ended in one oversized kernel), and the
+    cap is ``batchSizeBytes``' where the rows are wide: the host engine's
+    answer, and no merge kernel above the cap's bucket."""
+    from spark_rapids_tpu.columnar.bucketing import bucket_for
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    t = table()
+    seen = {"caps": [], "merged_at": [], "rect": []}
+    real_cap = TpuHashAggregateExec._merge_cap
+    real_run = TpuHashAggregateExec._run_kernel
+    real_fin = TpuHashAggregateExec._repartitioned_merge
+
+    def cap(self, ctx, partials):
+        seen["caps"].append(real_cap(self, ctx, partials))
+        return seen["caps"][-1]
+
+    def run(self, kernel, batch, *a, **kw):
+        seen["merged_at"].append(batch.padded_len)
+        return real_run(self, kernel, batch, *a, **kw)
+
+    def finish(self, ctx, partials, *a, **kw):
+        seen["rect"].append(self._rect_mode)
+        if self._rect_mode:
+            # (the first division's: a second one takes collected pieces)
+            seen.setdefault(
+                "widths", {sb.get().columns[0].width for sb in partials})
+        return real_fin(self, ctx, partials, *a, **kw)
+    monkeypatch.setattr(TpuHashAggregateExec, "_merge_cap", cap)
+    monkeypatch.setattr(TpuHashAggregateExec, "_run_kernel", run)
+    monkeypatch.setattr(TpuHashAggregateExec, "_repartitioned_merge", finish)
+
     def q(s):
-        df = s.create_dataframe(gen_df(
-            {"k": IntGen(lo=0, hi=400, nullable=False),
-             "v": IntGen(), "w": DoubleGen(with_special=False)}, n=24000),
-            num_partitions=6)
+        df = s.create_dataframe(t, num_partitions=6)
         return df.group_by("k").agg(
             F.sum(F.col("v")).with_name("s"),
             F.count_star().with_name("n"),
             F.min(F.col("w")).with_name("mn"),
             F.max(F.col("w")).with_name("mx"))
-    assert_tpu_and_cpu_equal(
-        q, approximate_float=True,
-        conf={"spark.rapids.tpu.sql.batchSizeRows": 2048,
-              # keep the byte-trigger repartition path out of the way
-              "spark.rapids.tpu.sql.batchSizeBytes": 1 << 30})
+    got = assert_tpu_and_cpu_equal(q, approximate_float=True, conf=conf)
+    assert seen["rect"] and all(r == rect for r in seen["rect"]), seen
+    assert not rect or len(seen["widths"]) > 1, seen
+    assert len(got) > max(seen["caps"])
+    assert max(seen["merged_at"]) <= bucket_for(max(seen["caps"])), seen
+    if "spark.rapids.tpu.sql.batchSizeBytes" in conf:
+        # the byte rule it was: fewer rows than batchSizeRows allows
+        assert max(seen["caps"]) < 1 << 16, seen
+
+
+# ---------------------------------------------------------------------------
+# The partitioned finish of a high-cardinality aggregate (PR 35)
+# ---------------------------------------------------------------------------
+
+def _disjoint_keys_table(n=40_000, nulls=True):
+    """Keys that come in runs of four (an order's lines): every partial
+    of a scan in row order holds other keys than its neighbours, so
+    merging them reduces nothing."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.default_rng(11)
+    k = np.repeat(np.arange(n // 4, dtype=np.int64), 4)[:n] * 7 + 3
+    return pa.table({
+        "k": pa.array(k, mask=rng.random(n) < 0.002 if nulls else None),
+        "v": rng.integers(1, 51, n).astype(np.float64),
+        "w": rng.integers(0, 1000, n).astype(np.int32)})
+
+
+@pytest.mark.parametrize("cap,parts,rows,most", [
+    (2048, 24, 40_000, 64),    # 64 hash buckets packed into 5 or 6
+    (1024, 40, 40_000, 64),    # ... into a dozen or so
+    (2048, 24, 40_000, 4),     # four buckets, each over the cap: every
+                               # one divided again
+])
+def test_partitioned_finish_of_partials_that_pass_the_cap(cap, parts, rows,
+                                                          most,
+                                                          monkeypatch):
+    """20+ partials of disjoint keys whose rows pass ``batchSizeRows``
+    together: the answer is pandas', every key leaves in exactly one
+    partition's output, the outputs add up to the whole, no merge kernel
+    is built above the cap's bucket, and the counter says what ran."""
+    import pandas as pd
+    import pyarrow as pa
+    from harness import OPERATOR_CONF, tpu_session
+    from spark_rapids_tpu import trace
+    from spark_rapids_tpu.columnar.bucketing import bucket_for
+    from spark_rapids_tpu.exec import aggregate
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    monkeypatch.setattr(aggregate, "_HASH_BUCKETS", most)
+    t = _disjoint_keys_table(rows)
+    s = tpu_session({**OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.batchSizeRows": cap})
+    s.create_dataframe(t, num_partitions=parts) \
+        .create_or_replace_temp_view("t")
+    merged_at = []
+    real = TpuHashAggregateExec._run_kernel
+
+    def watched(self, kernel, batch, *a, **kw):
+        merged_at.append(batch.padded_len)
+        return real(self, kernel, batch, *a, **kw)
+    monkeypatch.setattr(TpuHashAggregateExec, "_run_kernel", watched)
+    df = s.sql("select k, sum(v) s, count(*) c, max(w) m from t group by k")
+    tracer = trace.Tracer(max_events=1 << 16, proc_name="t")
+    trace.install_tracer(tracer)
+    try:
+        phys = df._physical()
+        batches = [b.to_arrow() for b in phys.execute(s.exec_context())]
+    finally:
+        trace.install_tracer(None)
+    events, _ = tracer.export_events()
+    # one output a partition, keys disjoint across them
+    assert len(batches) > 1
+    got = pa.concat_tables(batches).to_pandas()
+    assert got.k.nunique(dropna=False) == len(got)
+    want = t.to_pandas().groupby("k", dropna=False).agg(
+        s=("v", "sum"), c=("v", "size"), m=("w", "max")).reset_index()
+    pd.testing.assert_frame_equal(
+        got.sort_values("k").reset_index(drop=True).astype(
+            {"c": "int64", "m": "int64"}),
+        want.sort_values("k").reset_index(drop=True).astype(
+            {"m": "int64"}), check_dtype=False)
+    # no merge kernel above the bucket the cap has
+    assert merged_at and max(merged_at) <= bucket_for(cap), merged_at
+    [count] = [e["args"] for e in events
+               if e.get("ph") == "C" and e["name"] == "agg.highcard"]
+    merges = [e for e in events
+              if e.get("ph") == "X" and e["name"] == "agg.merge_part"]
+    assert count["partials"] >= 20 and count["rows_in"] == rows
+    assert count["groups"] == len(want)
+    assert count["partitions"] == len(merges) == len(batches)
+    assert -(-len(want) // cap) <= count["partitions"] \
+        <= 2 * -(-len(want) // cap) + most
+    assert 0 < count["largest_partition_rows"] <= cap
+    divisions = [e for e in events
+                 if e.get("ph") == "X" and e["name"] == "agg.partition"]
+    # partitions are packed from the buckets' counts, so none comes out
+    # over the cap unless ONE bucket does: only then a second division
+    assert len(divisions) == (1 + most if len(want) > most * cap else 1)
+    assert all(e["args"]["cols"] == ["k", "v", "w"]
+               for e in merges + divisions)
+
+
+def test_partials_that_fit_one_bucket_merge_as_before():
+    """Many partials of the SAME few keys: their rows fit the cap
+    together, one merge kernel, one output, no partitions."""
+    from harness import OPERATOR_CONF, tpu_session
+    from spark_rapids_tpu import trace
+    s = tpu_session({**OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.batchSizeRows": 1024})
+    df = s.create_dataframe(gen_df(
+        {"k": IntGen(lo=0, hi=20, nullable=False), "v": IntGen()}, n=8192),
+        num_partitions=8).group_by("k").agg(F.count_star().with_name("n"))
+    tracer = trace.Tracer(max_events=1 << 14, proc_name="t")
+    trace.install_tracer(tracer)
+    try:
+        batches = list(df._physical().execute(s.exec_context()))
+    finally:
+        trace.install_tracer(None)
+    events, _ = tracer.export_events()
+    assert len(batches) == 1 and batches[0].num_rows == 20
+    assert not [e for e in events if e["name"].startswith("agg.highcard")
+                or e["name"] == "agg.merge_part"]
 
 
 def test_agg_multibatch_first_last_order_dependent():

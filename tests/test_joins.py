@@ -889,3 +889,145 @@ def test_filtered_side_is_sized_by_what_its_filter_is_taken_to_keep(
     tree = s.create_dataframe(fact, num_partitions=2).join(
         side, on=[("k", "dk")], how="inner")._physical().tree_string()
     assert ("BroadcastHashJoin" in tree) == (80_000 * share <= 30_000), tree
+
+
+# ---------------------------------------------------------------------------
+# PR 35: the left semi join through the sort-and-scan probe, string
+# rectangles through the probe's gather, and the stream side's keys as the
+# unique ones where the build side holds a key twice
+# ---------------------------------------------------------------------------
+
+def _probe_calls(monkeypatch):
+    """The stream batches the sort-and-scan probe joined, by join type
+    (``swapped``: with the stream batch's keys as the sorted side)."""
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    calls = []
+    real = TpuHashJoinExec._join_probe
+    real_swapped = TpuHashJoinExec._join_swapped
+
+    def counted(self, ctx, sb, bound, ck):
+        calls.append(self.join_type)
+        return real(self, ctx, sb, bound, ck)
+
+    def swapped(self, ctx, sb, bb, bound):
+        out = real_swapped(self, ctx, sb, bb, bound)
+        calls.append("swapped" if out is not None else "refused")
+        return out
+    monkeypatch.setattr(TpuHashJoinExec, "_join_probe", counted)
+    monkeypatch.setattr(TpuHashJoinExec, "_join_swapped", swapped)
+    return calls
+
+
+@pytest.mark.parametrize("broadcast", [False, True])
+@pytest.mark.parametrize("unique_build", [True, False])
+def test_left_semi_join_through_the_probe_is_the_general_kernels(
+        unique_build, broadcast, monkeypatch):
+    """A left semi join on one integer key takes ``join_build`` /
+    ``join_probe`` (no pair is gathered from the build side), whether its
+    build side holds each key once or some of them many times over (a
+    semi join asks whether a key is there). The rows are CpuJoinExec's,
+    NULL keys on both sides included."""
+    conf = dict(_SHUFFLED) if not broadcast else {
+        "spark.rapids.tpu.sql.fusedPipeline.enabled": False}
+    calls = _probe_calls(monkeypatch)
+
+    def q(s):
+        l, r = _big_sides(s, unique_build)
+        return l.join(r, on=[("lk", "rk")], how="leftsemi")
+    assert_tpu_and_cpu_equal(q, conf=conf)
+    assert set(calls) == {"leftsemi"}, calls
+    if not broadcast:
+        assert calls.count("leftsemi") == 4       # one a stream batch
+
+
+def test_a_string_rectangle_rides_the_probe_on_the_device(monkeypatch):
+    """A high-cardinality string column (a byte rectangle on the device)
+    of either side goes through the unique-key probe as its word lanes:
+    it leaves the join as a rectangle, not by way of the host."""
+    import numpy as np
+    import pyarrow as pa
+    from harness import OPERATOR_CONF, tpu_session
+    from spark_rapids_tpu.columnar.strrect import ByteRectColumn
+    calls = _probe_calls(monkeypatch)
+    n = 200_000
+    rng = np.random.default_rng(2)
+    cust = pa.table({"ck": np.arange(1, n + 1),
+                     "name": pa.array([f"Customer#{i:09d}"
+                                       for i in range(1, n + 1)])})
+    orders = pa.table({"ok": np.arange(500) * 3,
+                       "oc": rng.permutation(n)[:500] + 1})
+    lines = pa.table({"lo": np.repeat(np.arange(1500), 2),
+                      "q": np.arange(3000) % 50})
+
+    def q(s):
+        c = s.create_dataframe(cust, num_partitions=2)
+        o = s.create_dataframe(orders)
+        li = s.create_dataframe(lines, num_partitions=3)
+        return (c.join(o, on=[("ck", "oc")], how="inner")
+                .join(li, on=[("ok", "lo")], how="inner")
+                .select("name", "ck", "ok", "q"))
+    s = tpu_session({**OPERATOR_CONF, **_SHUFFLED})
+    out = list(q(s)._physical().execute(s.exec_context()))
+    assert all(isinstance(b.columns[0], ByteRectColumn) for b in out)
+    assert calls.count("inner") == 2 + 3          # the stream batches
+    assert_tpu_and_cpu_equal(q, conf={**OPERATOR_CONF, **_SHUFFLED})
+
+
+@pytest.mark.parametrize("broadcast", [False, True])
+def test_a_duplicated_build_key_makes_the_stream_side_the_sorted_one(
+        broadcast, monkeypatch):
+    """A foreign key joined to its primary key with the referencing side
+    the smaller one, so the build side holds a key twice and thrice: the
+    probe needs ONE side with unique keys, and each stream batch's are
+    (a primary key), so the batch is sorted and the build side's rows are
+    probed against it. A high-cardinality string column (a rectangle on
+    the device) rides along on the stream side."""
+    import numpy as np
+    import pyarrow as pa
+    from harness import OPERATOR_CONF
+    calls = _probe_calls(monkeypatch)
+    big = pa.table({"pk": np.arange(5000), "pv": np.arange(5000) * 2,
+                    "name": pa.array([f"Customer#{i:09d}"
+                                      for i in range(5000)])})
+    small = pa.table({"fk": pa.array([7, 7, 9, 4000, 4999, None, 9, 9]),
+                      "fv": np.arange(8)})
+
+    def q(s):
+        return s.create_dataframe(small).join(
+            s.create_dataframe(big, num_partitions=3),
+            on=[("fk", "pk")], how="inner")
+    conf = {**OPERATOR_CONF, **(
+        {} if broadcast else _SHUFFLED)}
+    got = assert_tpu_and_cpu_equal(q, conf=conf)
+    assert len(got) == 7
+    assert calls == ["swapped"] * 3       # the big side's three batches
+
+
+@pytest.mark.parametrize("stream_left", [False, True])
+def test_keys_twice_on_both_sides_keep_the_general_kernel(stream_left,
+                                                          monkeypatch):
+    """Both sides hold a key twice: no side is the probe's sorted one, a
+    stream batch whose keys repeat is refused by the swapped probe (its
+    one fetch says so) and the general kernel joins it; a batch of the
+    same join whose keys are unique still takes the probe."""
+    import numpy as np
+    import pyarrow as pa
+    from harness import OPERATOR_CONF
+    calls = _probe_calls(monkeypatch)
+    pk = np.arange(4000)
+    pk[10] = 11                     # the first batch holds 11 twice
+    # (the string column keeps the scan's two batches apart)
+    big = pa.table({"pk": pk, "pv": np.arange(4000) * 2,
+                    "name": pa.array([f"Customer#{i:09d}"
+                                      for i in range(4000)])})
+    small = pa.table({"fk": pa.array([11, 7, 7, 3999, None, 2500]),
+                      "fv": np.arange(6)})
+
+    def q(s):
+        b = s.create_dataframe(big, num_partitions=2)
+        sm = s.create_dataframe(small)
+        return (b.join(sm, on=[("pk", "fk")], how="inner") if stream_left
+                else sm.join(b, on=[("fk", "pk")], how="inner"))
+    got = assert_tpu_and_cpu_equal(q, conf=OPERATOR_CONF)
+    assert len(got) == 6
+    assert calls == ["refused", "swapped"], calls

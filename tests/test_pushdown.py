@@ -155,3 +155,40 @@ def test_plan_pushdown_counter_once_a_planned_query():
     counters = [e["args"] for e in tr.snapshot()
                 if e["ph"] == "C" and e["name"] == "plan.pushdown"]
     assert counters == [{"pushed": 1, "above_joins": 1}]
+
+
+@pytest.mark.parametrize("how,side,moves", [
+    ("inner", "l", True), ("inner", "r", True), ("left", "l", True),
+    ("left", "r", False), ("full", "l", False)])
+@pytest.mark.parametrize("kind", ["leftsemi", "leftanti"])
+def test_a_semi_join_goes_below_the_join_whose_one_input_its_keys_name(
+        how, side, moves, kind):
+    """``expr IN (select ...)`` is a left semi join of the joined frame
+    against a relation of its own (PR 35): a filter of the rows of ONE
+    input where its keys name that input's columns alone, so it moves as a
+    conjunct would (and counts as one), and the answer is the host
+    engine's un-pushed one."""
+    def q(s):
+        l, r = _sides(s)
+        keys = s.create_dataframe(pa.table({
+            "kk": pa.array([3, 5, 5, None, 8, 40, 41])}))
+        col = {"l": "lv", "r": "rv"}[side]
+        return (l.join(r, on=[("lk", "rk")], how=how)
+                .filter(F.col("lv") > 10)
+                .join(keys, on=[(F.col(col) % 50, F.col("kk"))], how=kind))
+
+    plan, pushed, above = push_filters_below_joins(q(tpu_session()).plan)
+
+    def joins_in(node):
+        return isinstance(node, L.Join) + sum(map(joins_in, node.children))
+
+    def semi(node):
+        if isinstance(node, L.Join) and node.join_type == kind:
+            return node
+        return next((w for w in map(semi, node.children) if w), None)
+    # through the filter in its way and below the join, or where it was
+    assert joins_in(semi(plan).children[0]) == int(not moves)
+    lv_pushed = how in ("inner", "left")
+    assert (pushed, above) == (int(moves) + int(lv_pushed),
+                               int(not moves) + int(not lv_pushed))
+    assert_tpu_and_cpu_equal(q, conf=CONF)

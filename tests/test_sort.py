@@ -178,3 +178,51 @@ def test_the_selection_kernel_traces_no_sort():
             cols, jnp.int32(b.num_rows), b.padded_len))
     assert " sort[" not in text
     assert "while[" in text or "scan[" in text      # the n rounds, one loop
+
+
+@pytest.mark.parametrize("strings", ["dictionary", "rectangle", "host"])
+@pytest.mark.parametrize("parts", [1, 4])
+def test_top_n_with_a_string_payload_stays_on_the_device(strings, parts,
+                                                         monkeypatch):
+    """``ORDER BY ... LIMIT n`` (n <= 128) with a STRING column that is no
+    sort key (PR 35): the selection kernel picks rows by the keys and the
+    string column is gathered by the picked rows in the form it has:
+    dictionary codes, a byte rectangle, or an Arrow array on the host.
+    The rows are the host engine's stable sort's, ties included (the key
+    has 40 values over 6,000 rows)."""
+    import numpy as np
+    import pandas as pd
+    import pyarrow as pa
+    from harness import OPERATOR_CONF, cpu_session, tpu_session
+    from spark_rapids_tpu.columnar import batch as B
+    rng = np.random.default_rng(8)
+    n = 6000
+    card = 30 if strings == "dictionary" else n
+    if strings == "host":
+        # neither dictionary codes nor a rectangle: an Arrow column
+        monkeypatch.setattr(B, "DICT_ENCODE_MAX_FRACTION", 0)
+        from spark_rapids_tpu.columnar import strrect
+        monkeypatch.setattr(strrect, "encode_string_rect",
+                            lambda *a, **k: None)
+    t = pa.table({
+        "price": pa.array(rng.integers(0, 40, n) / 4.0,
+                          mask=rng.random(n) < 0.05),
+        "day": pa.array(rng.integers(8000, 8010, n).astype(np.int32)) \
+        .cast(pa.date32()),
+        "name": pa.array([f"Customer#{i % card:09d}" for i in range(n)],
+                         mask=rng.random(n) < 0.05),
+        "r": np.arange(n)})
+    got = []
+    for make in (tpu_session, cpu_session):
+        s = make({**OPERATOR_CONF,
+                  "spark.rapids.tpu.sql.batchSizeRows": 2048})
+        s.create_dataframe(t, num_partitions=parts) \
+            .create_or_replace_temp_view("t")
+        df = s.sql("select name, r, price, day from t "
+                   "order by price desc, day limit 100")
+        if make is tpu_session:
+            plan = df._physical().tree_string()
+            assert "Cpu" not in plan and "!" not in plan, plan
+            assert "; first 100]" in plan, plan
+        got.append(df.collect_arrow().to_pandas())
+    pd.testing.assert_frame_equal(got[0], got[1], check_dtype=False)

@@ -386,3 +386,119 @@ def test_qualified_refs_with_colliding_join_columns():
         assert both.column_names == ["k", "k"]
         star = s.sql("SELECT r.* FROM t JOIN r ON t.k = r.k").collect()
         assert star == [{"k": 2, "name": "a"}, {"k": 2, "name": "a"}]
+
+
+# ---------------------------------------------------------------------------
+# expr [NOT] IN (select ...) in a WHERE (PR 35): a left semi join
+# ---------------------------------------------------------------------------
+
+def _in_subquery_tables():
+    import numpy as np
+    rng = np.random.default_rng(5)
+    n = 600
+    t = pa.table({
+        "k": pa.array(rng.integers(0, 40, n), mask=rng.random(n) < 0.15),
+        "v": pa.array(np.arange(n))})
+    # duplicates and NULLs among the subquery's values
+    u = pa.table({
+        "k2": pa.array(rng.integers(0, 80, 90), mask=rng.random(90) < 0.2),
+        "w": pa.array(rng.integers(0, 10, 90))})
+    return t, u
+
+
+@pytest.mark.parametrize("where,keep", [
+    # NULLs in the probe column never match; duplicates and NULLs among
+    # the subquery's values change nothing
+    ("k in (select k2 from u)",
+     lambda t, u: t.k.isin(u.k2.dropna())),
+    # an expression on the probe side, an aggregate in the subquery
+    ("k + 1 in (select k2 from u group by k2 having sum(w) > 6)",
+     lambda t, u: (t.k + 1).isin(
+         u.groupby("k2").w.sum().loc[lambda s: s > 6].index)),
+    # an empty subquery keeps nothing
+    ("k in (select k2 from u where w > 99)",
+     lambda t, u: t.k.isin([])),
+    # beside other conjuncts
+    ("v > 100 and k in (select k2 from u where w < 5) and v < 500",
+     lambda t, u: (t.v > 100) & (t.v < 500)
+     & t.k.isin(u[u.w < 5].k2.dropna())),
+])
+def test_in_subquery_is_a_semi_join_with_sparks_null_semantics(where, keep):
+    t, u = _in_subquery_tables()
+    tp, up = t.to_pandas(), u.to_pandas()
+    want = tp[keep(tp, up)].v.tolist()
+    for enabled in (True, False):
+        s = tpu_session({"spark.rapids.tpu.sql.enabled": enabled,
+                         "spark.rapids.tpu.sql.fusedPipeline.enabled":
+                         False})
+        s.create_dataframe(t).create_or_replace_temp_view("t")
+        s.create_dataframe(u).create_or_replace_temp_view("u")
+        df = s.sql(f"select v from t where {where} order by v")
+        if enabled:
+            tree = df._physical().tree_string()
+            assert "Join[leftsemi" in tree and "Cpu" not in tree, tree
+        assert df.collect_arrow().column("v").to_pylist() == want
+
+
+def test_in_subquery_over_a_join_runs_below_it():
+    """The semi-join names columns of ONE input of the implicit join, so
+    it filters that input before the join (plan/rewrites.py)."""
+    t, u = _in_subquery_tables()
+    from harness import OPERATOR_CONF
+    s = tpu_session({**OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 0})
+    s.create_dataframe(t).create_or_replace_temp_view("t")
+    s.create_dataframe(u).create_or_replace_temp_view("u")
+    df = s.sql("""select v, w from t, u
+                  where k = k2 and w in (select k2 from u) order by v, w""")
+    lines = [ln.strip() for ln in df._physical().tree_string().splitlines()]
+    semi = next(i for i, ln in enumerate(lines) if "Join[leftsemi" in ln)
+    assert "Cpu" not in "".join(lines), lines
+    assert any("Join[inner" in ln for ln in lines[:semi]), lines
+    assert not any("Join[inner" in ln for ln in lines[semi:]), lines
+    tp, up = t.to_pandas(), u.to_pandas()
+    # (pandas joins NaN to NaN; SQL's NULL keys match nothing)
+    want = tp.dropna(subset=["k"]).merge(
+        up[up.w.isin(up.k2.dropna())].dropna(subset=["k2"]), left_on="k",
+        right_on="k2").sort_values(["v", "w"])
+    got = df.collect_arrow().to_pandas()
+    assert got.v.tolist() == want.v.tolist()
+    assert got.w.tolist() == want.w.tolist()
+
+
+@pytest.mark.parametrize("text,says", [
+    ("select v from t where k not in (select k2 from u)",
+     "NOT IN (select ...) is not supported"),
+    ("select v from t where k in (select k2, w from u)", "ONE column"),
+    ("select v from t where v > 3 or k in (select k2 from u)",
+     "conjunct of WHERE"),
+    ("select k in (select k2 from u) from t", "conjunct of WHERE"),
+    ("select v from t where k in (select k2 from u where w = v)",
+     "correlated subquery is not supported"),
+    ("select v from t where exists (select k2 from u where w > 3)",
+     "EXISTS (select ...)"),
+    ("select v from t where not exists (select k2 from u)",
+     "EXISTS (select ...)"),
+    ("select v from t where k = (select max(k2) from u)",
+     "scalar subquery (select ...)"),
+    ("select v, (select max(k2) from u) m from t",
+     "scalar subquery (select ...)"),
+])
+def test_subqueries_that_are_not_supported_are_refused_by_name(text, says):
+    from spark_rapids_tpu.sql.parser import SqlError
+    t, u = _in_subquery_tables()
+    s = tpu_session()
+    s.create_dataframe(t).create_or_replace_temp_view("t")
+    s.create_dataframe(u).create_or_replace_temp_view("u")
+    with pytest.raises(SqlError, match=says.replace("(", r"\(")
+                       .replace(")", r"\)").replace(".", r"\.")):
+        s.sql(text)
+
+
+def test_an_unaliased_aggregate_is_named_as_spark_names_it():
+    s = tpu_session()
+    s.create_dataframe(pa.table({"k": [1, 1, 2], "v": [1.0, 2.0, 3.0]})) \
+        .create_or_replace_temp_view("t")
+    got = s.sql("select k, sum(v), max(v) m from t group by k order by k") \
+        .collect_arrow()
+    assert got.column_names == ["k", "sum(v)", "m"]

@@ -469,7 +469,8 @@ def test_join_counters_cost_nothing_with_tracing_off(monkeypatch):
     monkeypatch.setattr(packing, "fetch_packed", no_fetch)
     joins._count_join_rows("TpuHashJoinExec@7", object(), [object()],
                            object(), 1)
-    assert isinstance(joins._span("join.probe", "TpuHashJoinExec@7"),
+    from spark_rapids_tpu.exec.base import TpuExec
+    assert isinstance(TpuExec([]).child_span("join.probe"),
                       contextlib.nullcontext)
     # an annotate-only tracer (a profiler session) records no counter
     # either: spans reach the profiler, counters have nowhere to go
@@ -598,6 +599,167 @@ def test_direct_aggregate_is_one_fetch_a_query():
     assert [e["args"] for e in counters if e["name"] == "agg.carry"] == \
         [{"batches": 0, "flushes": 0}]
     assert agg_m["updateDispatches"] > 7, agg_m
+
+
+def _disjoint_keys(n=12_000):
+    """Keys in runs of four (an order's lines): every batch of a scan in
+    row order holds other keys than its neighbours."""
+    return pa.table({
+        "k": pa.array(np.repeat(np.arange(n // 4, dtype=np.int64), 4) * 7),
+        "v": pa.array(np.arange(n, dtype=np.float64) % 50)})
+
+
+#: six partials of 500 groups against a cap of 1,024 rows: the aggregate
+#: finishes in partitions
+_HIGHCARD_CONF = {"spark.rapids.tpu.sql.batchSizeRows": 1024}
+
+
+def test_partitioned_finish_spans_and_counter_in_the_ring_buffer():
+    """``agg.partition`` (once a division: every partial sorted by its
+    keys' hash bucket, and the ONE fetch of the buckets' counts, under a
+    ``d2h.*`` label) and ``agg.merge_part`` (one a partition, an enqueue
+    that reads nothing) are ``cat="exec"`` children of the aggregate's own
+    span carrying its id and the columns it reads; ``agg.highcard`` is
+    written once an execution with what the spans add up to."""
+    spans, counters, agg_m = _agg_trace(_disjoint_keys(), "k",
+                                        conf=_HIGHCARD_CONF)
+    by_id = {e["id"]: e for e in spans}
+    (count,) = [e["args"] for e in counters if e["name"] == "agg.highcard"]
+    (part,) = [e for e in spans if e["name"] == "agg.partition"]
+    merges = [e for e in spans if e["name"] == "agg.merge_part"]
+    assert count["partials"] == part["args"]["partials"] >= 6
+    assert count["rows_in"] == 12_000 and count["groups"] == 3000
+    assert count["partitions"] == len(merges) >= 3
+    assert max(e["args"]["rows"] for e in merges) \
+        == count["largest_partition_rows"] <= 1024
+    assert sum(e["args"]["rows"] for e in merges) == 3000
+    assert agg_m["aggRepartitions"] == count["partitions"]
+    for e in [part] + merges:
+        parent = by_id[e["parent"]]
+        assert e["cat"] == "exec" \
+            and parent["name"] == "TpuHashAggregateExec"
+        assert e["args"]["exec"] == parent["args"]["exec"]
+        assert int(e["args"]["exec"].rsplit("@", 1)[1]) == count["op"]
+        assert e["args"]["cols"] == ["k", "v"]
+        assert e["q"] == parent["q"] and e["q"] is not None
+        assert parent["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    fetches = [e for e in spans if e["name"].startswith("d2h")
+               and e["name"].endswith(".transfer")]
+    # the buckets' counts of all partials: one transfer, inside the span
+    assert [e["name"] for e in fetches
+            if by_id.get(e["parent"], {}).get("name") == "agg.partition"] \
+        == ["d2h.agg_parts.transfer"]
+    assert not [e for e in fetches
+                if by_id.get(e["parent"], {}).get("name")
+                == "agg.merge_part"]
+
+
+def test_partitioned_finish_spans_under_the_profilers_tracer(tmp_path):
+    """Under a jax.profiler session and no installed tracer the finish's
+    spans are annotations on the profiler's clock, nested in the
+    aggregate's own and carrying its id as ``exec`` (``cols`` is the ring
+    buffer's: an annotation carries ``q`` and ``exec``); the counter,
+    which has nowhere to go there, makes no fetch."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from spark_rapids_tpu.columnar import packing
+    s = tpu_session({**_OPERATOR_CONF, **_HIGHCARD_CONF})
+    df = s.create_dataframe(_disjoint_keys(), num_partitions=6) \
+        .group_by("k").agg(F.sum(F.col("v")).with_name("sv"))
+    assert df.collect_arrow().num_rows == 3000
+    real, fetched = packing.fetch_packed, []
+
+    def counted(arrays, label="d2h"):
+        fetched.append(label)
+        return real(arrays, label)
+    packing.fetch_packed = counted
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert df.collect_arrow().num_rows == 3000
+    finally:
+        jax.profiler.stop_trace()
+        packing.fetch_packed = real
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("srtpu/")]
+    aggs = [sp for sp in spans
+            if sp[0] == "srtpu/exec/TpuHashAggregateExec"]
+    (part,) = [sp for sp in spans if sp[0] == "srtpu/exec/agg.partition"]
+    merges = [sp for sp in spans if sp[0] == "srtpu/exec/agg.merge_part"]
+    assert len(merges) >= 3
+    for name, a, b, stats in [part] + merges:
+        assert stats["exec"].startswith("TpuHashAggregateExec@")
+        assert any(c <= a and b <= d and st["exec"] == stats["exec"]
+                   for _, c, d, st in aggs), (name, a, b)
+    assert any(n == "srtpu/transfer/d2h.agg_parts.transfer"
+               and part[1] <= a and b <= part[2] for n, a, b, _ in spans)
+    assert fetched and "d2h.agg_highcard" not in fetched, fetched
+
+
+def test_highcard_counter_costs_nothing_with_tracing_off(monkeypatch):
+    """No recording tracer, no work: the partitions' group counts stay on
+    the device and no fetch is made for a counter nobody records."""
+    from spark_rapids_tpu.columnar import packing
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.trace import core as trace_core
+    assert trace_core.TRACER is None
+
+    def no_fetch(*a, **kw):
+        raise AssertionError("a fetch for a counter nobody records")
+    monkeypatch.setattr(packing, "fetch_packed", no_fetch)
+    seen = {"partials": 2, "partitions": 2, "largest": 9,
+            "groups": [object(), object()]}
+    TpuHashAggregateExec._count_highcard(
+        type("A", (), {"_exec_id": "TpuHashAggregateExec@3"})(), seen,
+        [object()])
+    install_tracer(Tracer(recording=False))
+    try:
+        TpuHashAggregateExec._count_highcard(
+            type("A", (), {"_exec_id": "TpuHashAggregateExec@3"})(), seen,
+            [object()])
+    finally:
+        install_tracer(None)
+
+
+def test_semi_join_writes_the_joins_spans_and_counter():
+    """``expr IN (select ...)`` runs as a left semi join: ``join.build``,
+    a ``join.probe`` a stream batch and ``join.rows``, as an inner join
+    writes them."""
+    s = tpu_session({**_OPERATOR_CONF,
+                     "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 0})
+    s.create_dataframe(_disjoint_keys(), num_partitions=3) \
+        .create_or_replace_temp_view("t")
+    s.create_dataframe(pa.table({"kk": pa.array([7, 7, 14, None, 5])})) \
+        .create_or_replace_temp_view("u")
+    df = s.sql("select k, v from t where k in (select kk from u)")
+    assert df.collect_arrow().num_rows == 8
+    tr = install_tracer(Tracer())
+    try:
+        assert df.collect_arrow().num_rows == 8
+    finally:
+        install_tracer(None)
+    spans = _xs(tr)
+    by_id = {e["id"]: e for e in spans}
+    (rows,) = [e["args"] for e in tr.snapshot()
+               if e["ph"] == "C" and e["name"] == "join.rows"]
+    assert (rows["build"], rows["stream"], rows["out"]) == (5, 12_000, 8)
+    (build,) = [e for e in spans if e["name"] == "join.build"]
+    probes = [e for e in spans if e["name"] == "join.probe"]
+    assert len(probes) == rows["parts"] >= 1
+    for e in [build] + probes:
+        assert by_id[e["parent"]]["name"] == "TpuHashJoinExec"
+        assert int(e["args"]["exec"].rsplit("@", 1)[1]) == rows["op"]
+    assert probes[0]["args"]["cols"] == ["k", "v"]
 
 
 def test_decimal_aggregate_is_one_fetch_and_counts_its_checks():
