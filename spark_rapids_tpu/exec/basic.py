@@ -5,7 +5,7 @@ GpuFilterExec:806, GpuRangeExec:1137; GpuCoalesceBatches.scala:112).
 from __future__ import annotations
 
 import time
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -60,9 +60,12 @@ _SCAN_CACHE_LRU: Dict[tuple, int] = {}
 _SCAN_CACHE_TICK = [0]
 
 
-def _scan_cache_get(t, key):
-    if _SCAN_CACHE.get(id(t)) is t:
-        k = (id(t),) + key
+def _scan_cache_get(tables, key):
+    """The batches cached for ``tables`` (one table, or the tables of a
+    filling scan) under ``key``; None where any of them has been freed or
+    its id taken by another table."""
+    if all(_SCAN_CACHE.get(id(t)) is t for t in tables):
+        k = (tuple(map(id, tables)),) + key
         got = _SCAN_CACHE_BATCHES.get(k)
         if got is not None:
             _SCAN_CACHE_TICK[0] += 1
@@ -78,7 +81,9 @@ def _scan_cache_bytes() -> int:
                for bs in list(_SCAN_CACHE_BATCHES.values()) for b in bs)
 
 
-def _scan_cache_put(t, key, batches, limit: int):
+def _scan_cache_put(tables, key, batches, limit: int):
+    """Cache ``batches`` as ONE entry for ``tables``: it fits the budget
+    or evicts whole, and leaves when any of its tables is freed."""
     if limit <= 0:
         return
     new_bytes = sum(b.device_size_bytes() for b in batches)
@@ -86,34 +91,50 @@ def _scan_cache_put(t, key, batches, limit: int):
         return
     # LRU-evict until the new entry fits
     while _SCAN_CACHE_BATCHES and _scan_cache_bytes() + new_bytes > limit:
-        coldest = min(_SCAN_CACHE_LRU, key=_SCAN_CACHE_LRU.get)
-        del _SCAN_CACHE_BATCHES[coldest]
-        del _SCAN_CACHE_LRU[coldest]
-    tid = id(t)
-    if _SCAN_CACHE.get(tid) is not t:
-        # new table under a reused id: drop stale entries for that id
-        _scan_cache_evict(tid)
-        try:
-            _SCAN_CACHE[tid] = t
-        except TypeError:
-            return      # not weak-referenceable: skip caching
-        weakref.finalize(t, _scan_cache_evict, tid)
-    k = (tid,) + key
+        _scan_cache_evict_key(min(_SCAN_CACHE_LRU, key=_SCAN_CACHE_LRU.get))
+    for t in tables:
+        tid = id(t)
+        if _SCAN_CACHE.get(tid) is not t:
+            # new table under a reused id: drop stale entries for that id
+            _scan_cache_evict(tid)
+            try:
+                _SCAN_CACHE[tid] = t
+            except TypeError:
+                return      # not weak-referenceable: skip caching
+            weakref.finalize(t, _scan_cache_evict, tid)
+    k = (tuple(map(id, tables)),) + key
     _SCAN_CACHE_BATCHES[k] = batches
     _SCAN_CACHE_TICK[0] += 1
     _SCAN_CACHE_LRU[k] = _SCAN_CACHE_TICK[0]
 
 
 def _scan_cache_evict(tid):
-    for k in [k for k in _SCAN_CACHE_BATCHES if k[0] == tid]:
-        del _SCAN_CACHE_BATCHES[k]
-        _SCAN_CACHE_LRU.pop(k, None)
+    for k in [k for k in _SCAN_CACHE_BATCHES if tid in k[0]]:
+        _scan_cache_evict_key(k)
+
+
+def _scan_cache_evict_key(k):
+    _SCAN_CACHE_BATCHES.pop(k, None)
+    _SCAN_CACHE_LRU.pop(k, None)
 
 
 class InMemoryScanExec(TpuExec):
     """Scan over pre-partitioned Arrow tables (ref GpuInMemoryTableScanExec).
-    Device batches are cached per (table, split) so re-running a query over
-    the same in-memory data skips the H2D transfer entirely."""
+    Each table is cut into slices of ``batch_rows`` rows (an empty table
+    into one empty slice), and each slice is one batch. Device batches are
+    cached per table so re-running a query over the same in-memory data
+    skips the H2D transfer entirely.
+
+    With a ``fill`` goal (rows, bytes), which ``plan/overrides.py:
+    insert_coalesce`` sets where a per-batch operator reads the scan,
+    consecutive slices are grouped by TRUE rows as
+    ``CoalesceBatchesExec`` groups batches: a group leaves when the next
+    slice would pass the goal's rows or bytes. Each group is joined on the
+    host and uploaded once, a group of one slice is that slice's batch,
+    and a batch carries its first slice's ``meta``. The filled batches are
+    the scan's ONE cache entry, in place of the per-table ones; it is
+    never served once any of its tables is freed. Tracer: a counter
+    ``scan.fill`` {slices, batches, cached, op} once such an execution."""
 
     def __init__(self, tables, schema: Schema, batch_rows: int = 1 << 20,
                  columns=None):
@@ -123,44 +144,111 @@ class InMemoryScanExec(TpuExec):
             [schema[c] for c in columns])
         self.columns = list(columns) if columns is not None else None
         self.batch_rows = batch_rows
+        #: (rows, bytes) the batches are filled to; None: a slice a batch
+        self.fill: Optional[Tuple[int, int]] = None
 
     def output_schema(self) -> Schema:
         return self._schema
+
+    def slices(self) -> List[Tuple[int, int, int]]:
+        """(table index, offset, rows) of each slice, in scan order."""
+        b = self.batch_rows
+        return [(i, off, min(b, t.num_rows - off))
+                for i, t in enumerate(self.tables)
+                for off in range(0, max(t.num_rows, 1), b)]
+
+    def fill_groups(self, max_rows: int, max_bytes: int
+                    ) -> List[List[Tuple[int, int, int]]]:
+        """The slices of each filled batch under the goal, a slice counted
+        at the padded bytes of its own batch (all columns device-backed)."""
+        row_bytes = sum(f.dtype.np_dtype.itemsize + 1 for f in self._schema)
+        groups: List[List[Tuple[int, int, int]]] = []
+        rows = nbytes = 0
+        for s in self.slices():
+            n, nb = s[2], bucket_for(s[2]) * row_bytes
+            if not groups or rows + n > max_rows or nbytes + nb > max_bytes:
+                groups.append([])
+                rows = nbytes = 0
+            groups[-1].append(s)
+            rows += n
+            nbytes += nb
+        return groups
+
+    def _upload(self, ctx: ExecContext, parts, pid: int) -> ColumnarBatch:
+        import pyarrow as pa
+        from ..columnar.strrect import RECT_MAX_BYTES
+        table = parts[0] if len(parts) == 1 else pa.concat_tables(parts)
+        with ctx.semaphore.held():
+            b = ColumnarBatch.from_arrow(
+                table, rect_cap=int(ctx.conf.get(RECT_MAX_BYTES)))
+        b.meta = {"partition_id": pid}
+        return b
+
+    def _source(self, i: int):
+        t = self.tables[i]
+        return t if self.columns is None else t.select(self.columns)
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         rows_m = ctx.metric(self._exec_id, "numOutputRows", ESSENTIAL)
         names = tuple(self._schema.names())
         limit = int(ctx.conf.get(SCAN_CACHE_MAX_BYTES))
+        if self.fill is not None:
+            yield from self._filled(ctx, rows_m, names, limit)
+            return
+        key = (self.batch_rows, names)
         for pid, t in enumerate(self.tables):
-            key = (self.batch_rows, names)
-            cached = _scan_cache_get(t, key)
+            cached = _scan_cache_get((t,), key)
             if cached is not None:
                 for b in cached:
                     rows_m.add(b.num_rows)
                     yield b
                 continue
             built = []
-            src = t if self.columns is None else t.select(self.columns)
-            off = 0
-            while off < src.num_rows or (src.num_rows == 0 and off == 0):
-                chunk = src.slice(off, self.batch_rows)
-                if chunk.num_rows == 0 and off > 0:
-                    break
-                with ctx.semaphore.held():
-                    from ..columnar.strrect import RECT_MAX_BYTES
-                    b = ColumnarBatch.from_arrow(
-                        chunk, rect_cap=int(ctx.conf.get(RECT_MAX_BYTES)))
-                b.meta = {"partition_id": pid}
+            src = self._source(pid)
+            for off in range(0, max(src.num_rows, 1), self.batch_rows):
+                b = self._upload(ctx, [src.slice(off, self.batch_rows)], pid)
                 rows_m.add(b.num_rows)
                 built.append(b)
                 yield b
-                off += self.batch_rows
-                if src.num_rows == 0:
-                    break
-            _scan_cache_put(t, key, built, limit)
+            _scan_cache_put((t,), key, built, limit)
+
+    def _filled(self, ctx, rows_m, names, limit):
+        key = (self.batch_rows, names, self.fill)
+        groups = self.fill_groups(*self.fill)
+        cached = _scan_cache_get(self.tables, key)
+        n_slices = n_out = 0
+        try:
+            built = []
+            for i, g in enumerate(groups):
+                if cached is not None:
+                    b = cached[i]
+                else:
+                    b = self._upload(ctx, [self._source(t).slice(off, n)
+                                           for t, off, n in g], g[0][0])
+                    built.append(b)
+                rows_m.add(b.num_rows)
+                n_slices += len(g)
+                n_out += 1
+                yield b
+            if cached is None:
+                # the same rows are not held twice: the per-table entries
+                # of these tables go first
+                for t in self.tables:
+                    _scan_cache_evict_key(((id(t),), self.batch_rows, names))
+                _scan_cache_put(self.tables, key, built, limit)
+        finally:
+            tr = trace_core.TRACER
+            if tr is not None:
+                tr.counter("scan.fill",
+                           {"slices": n_slices, "batches": n_out,
+                            "cached": int(cached is not None),
+                            "op": int(self._exec_id.rsplit("@", 1)[-1])},
+                           cat="exec")
 
     def describe(self):
-        return f"InMemoryScan[{len(self.tables)} partitions]"
+        fill = "" if self.fill is None else \
+            "; filled to TargetSize(rows={}, bytes={})".format(*self.fill)
+        return f"InMemoryScan[{len(self.tables)} partitions{fill}]"
 
 
 class TpuProjectExec(TpuExec):
@@ -654,8 +742,9 @@ class CoalesceBatchesExec(TpuExec):
     first batch's ``meta`` (``plan/overrides.py`` plans this operator in
     no plan that reads it).
 
-    The goal is applied to TRUE row counts. Where the input's counts are
-    host ints (above a scan) each batch is decided as it arrives and
+    The goal is applied to TRUE row counts (an in-memory scan fills its
+    own batches by the same rule: ``InMemoryScanExec.fill``). Where the
+    input's counts are host ints each batch is decided as it arrives and
     nothing is read. Where they are on the device (above a streaming
     broadcast join) the input is taken ``COUNT_WINDOW`` batches at a time
     and a window's counts are read in ONE packed transfer (span

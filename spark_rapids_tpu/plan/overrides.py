@@ -192,17 +192,18 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
 # ---------------------------------------------------------------------------
 
 def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
-    """Put ``CoalesceBatches[TargetSize]`` where a per-batch operator (the
-    stream side of a streaming broadcast join, the input of an aggregate)
-    would be handed under-filled batches, through device-only filters and
-    projections, by
+    """Fill the batches a per-batch operator (the stream side of a
+    streaming broadcast join, the input of an aggregate) would be handed
+    under-filled, through device-only filters and projections, by
 
     - an in-memory scan whose neighbouring batches fit the target
-      (``batchSizeRows``) together: the operator goes above the scan;
+      (``batchSizeRows``) together: the scan fills its own batches
+      (``InMemoryScanExec.fill``) and caches them filled, so a repeated
+      query concatenates nothing;
     - a streaming broadcast join: it hands on a batch a stream batch with
       whatever matched, so a selective one hands on buckets of padding.
-      The operator goes above the join and reads the counts, which are
-      on the device, a window at a time (exec/basic.py).
+      ``CoalesceBatches[TargetSize]`` goes above the join and reads the
+      counts, which are on the device, a window at a time (exec/basic.py).
 
     Those operators pay their host and device work per BATCH at the padded
     shape, so two half-empty buckets cost twice what one full one does.
@@ -212,10 +213,11 @@ def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
     of two big sides, an exchange, the sink). Everything is read off the
     plan: where nothing qualifies the plan is the one it was. No plan
     that reads ``batch.meta`` (``spark_partition_id`` and its kin) gets
-    one either: the merged batch carries its first batch's ``meta``."""
+    one either: a filled or merged batch carries its first batch's
+    ``meta``."""
     from ..exec.wholestage import _fusible
-    target_rows = conf.batch_size_rows
-    sites = []      # (parent, child index) of each scan or join to wrap
+    goal = (conf.batch_size_rows, conf.batch_size_bytes)
+    sites = []      # (parent, child index) of each scan or join to fill
 
     def visit(node: TpuExec) -> None:
         fed = None  # index of the child this node consumes batch by batch
@@ -227,7 +229,7 @@ def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
             parent, i = node, fed
             while _fusible(parent.children[i]):
                 parent, i = parent.children[i], 0
-            if _fills(parent.children[i], target_rows):
+            if _fills(parent.children[i], goal):
                 sites.append((parent, i))
         for c in node.children:
             visit(c)
@@ -236,9 +238,11 @@ def insert_coalesce(physical: TpuExec, conf: TpuConf) -> TpuExec:
     if not sites or _reads_task_context(physical):
         return physical
     for parent, i in sites:
-        parent.children[i] = B.CoalesceBatchesExec(
-            parent.children[i], target_rows=target_rows,
-            target_bytes=conf.batch_size_bytes)
+        if isinstance(parent.children[i], B.InMemoryScanExec):
+            parent.children[i].fill = goal
+        else:
+            parent.children[i] = B.CoalesceBatchesExec(
+                parent.children[i], target_rows=goal[0], target_bytes=goal[1])
     return physical
 
 
@@ -249,16 +253,16 @@ def _streams(node: TpuExec) -> bool:
     return isinstance(node, TpuBroadcastHashJoinExec) and node.streams
 
 
-def _fills(source: TpuExec, target_rows: int) -> bool:
-    """Whether ``source``'s batches are worth filling and concatenate on
-    the device: a dictionary, rectangle or host column from a scan would
-    send the concat through Arrow and back (each partition has a
-    dictionary of its own); a join's build side hands every output ONE
-    dictionary, so its strings concatenate as codes."""
+def _fills(source: TpuExec, goal) -> bool:
+    """Whether ``source``'s batches are worth filling to ``goal`` (rows,
+    bytes) and fill without a detour: a scan fills batches of fixed-width
+    lanes only (it counts a slice's bytes by them; a dictionary, rectangle
+    or host column keeps the scan's batches); a join's build side hands
+    every output ONE dictionary, so its strings concatenate as codes."""
     if isinstance(source, B.InMemoryScanExec):
         return (all(f.dtype.device_backed for f in source.output_schema())
-                and _scan_underfilled(source, target_rows))
-    if not _streams(source) or _stream_batches(source) == 1:
+                and _scan_underfilled(source, goal[0]))
+    if not _streams(source) or _stream_batches(source, goal) == 1:
         return False
     from ..types import STRING
     n_left = len(source.children[0].output_schema())
@@ -268,36 +272,27 @@ def _fills(source: TpuExec, target_rows: int) -> bool:
                for i, f in enumerate(source.output_schema()))
 
 
-def _stream_batches(join: TpuExec) -> Optional[int]:
+def _stream_batches(join: TpuExec, goal) -> Optional[int]:
     """The most batches a streaming broadcast join hands on, where the
     plan says: one a batch of its stream side, which filters, projections
-    and further such joins pass on one for one from an in-memory scan."""
+    and further such joins pass on one for one from an in-memory scan
+    (its filled batches where it fills to ``goal``)."""
     from ..exec.wholestage import _fusible
     node = join.children[0 if join.build_side == "right" else 1]
     while _fusible(node):
         node = node.children[0]
     if isinstance(node, B.InMemoryScanExec):
-        b = max(int(node.batch_rows), 1)
-        return sum(max(-(-t.num_rows // b), 1) for t in node.tables)
-    return _stream_batches(node) if _streams(node) else None
+        if _fills(node, goal):
+            return len(node.fill_groups(*goal))
+        return len(node.slices())
+    return _stream_batches(node, goal) if _streams(node) else None
 
 
 def _scan_underfilled(scan, target_rows: int) -> bool:
     """Whether two neighbouring batches of the scan fit ``target_rows``
-    together: each table yields ``batch_rows`` rows a batch and then the
-    rest, so the first and last batch of each table say it all."""
-    b = max(int(scan.batch_rows), 1)
-    last = None
-    for t in scan.tables:
-        full, rest = divmod(t.num_rows, b)
-        first = b if full else rest
-        if last is not None and last + first <= target_rows:
-            return True
-        if full and ((full > 1 and 2 * b <= target_rows)
-                     or (rest and b + rest <= target_rows)):
-            return True
-        last = rest if rest or not full else b
-    return False
+    together."""
+    rows = [n for _, _, n in scan.slices()]
+    return any(a + b <= target_rows for a, b in zip(rows, rows[1:]))
 
 
 def _reads_task_context(plan: TpuExec) -> bool:

@@ -1,7 +1,8 @@
 """The post-conversion coalesce rule (plan/overrides.py:insert_coalesce):
 where a scan or a streaming broadcast join hands under-filled batches to a
-per-batch operator the plan gets ``CoalesceBatches[TargetSize]`` above it,
-and where it does not the plan is the one it was."""
+per-batch operator, the scan fills its own batches (and caches them
+filled) and the join gets ``CoalesceBatches[TargetSize]`` above it; where
+neither does the plan is the one it was."""
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -10,7 +11,8 @@ from harness import OPERATOR_CONF, tpu_session
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
 from spark_rapids_tpu.exec.base import ExecContext
-from spark_rapids_tpu.exec.basic import CoalesceBatchesExec, TpuProjectExec
+from spark_rapids_tpu.exec.basic import (CoalesceBatchesExec,
+                                         InMemoryScanExec, TpuProjectExec)
 from spark_rapids_tpu.exec.joins import TpuBroadcastHashJoinExec
 from spark_rapids_tpu.exec.wholestage import WholeStageExec
 from spark_rapids_tpu.plan import overrides
@@ -19,6 +21,7 @@ from spark_rapids_tpu.plan import overrides
 #: pipeline, 4096-row partitions against a target of 8192
 Q3_CONF = {**OPERATOR_CONF, "spark.rapids.tpu.sql.batchSizeRows": 8192}
 PART = 4096
+GOAL = "TargetSize(rows=8192, bytes=536870912)"
 
 
 def _star(s, rows: int, parts: int, seed: int = 7):
@@ -64,24 +67,23 @@ def _without_rule(monkeypatch):
 @pytest.mark.parametrize("rows,parts", [
     (4 * PART, 4), (5 * PART, 5), (5 * PART - 1500, 5), (2 * PART + 1, 3)])
 def test_star_plan_fills_the_lowest_joins_stream(rows, parts):
-    """The operator sits on the stream side of the lowest join, directly
-    above the fact scan; that join sees half as many batches (rounded up),
-    each in the 8,192-row bucket with at most 8,192 live rows, no row lost
-    or reordered."""
+    """The fact scan on the stream side of the lowest join fills its own
+    batches, with no operator between them; that join sees half as many
+    batches (rounded up), each in the 8,192-row bucket with at most 8,192
+    live rows, no row lost or reordered against the unfilled scan."""
     s = tpu_session(Q3_CONF)
     physical = _star(s, rows, parts)._physical()
     joins = _nodes(physical, TpuBroadcastHashJoinExec)
     lowest = joins[-1]
-    (co,) = _nodes(lowest, CoalesceBatchesExec)
-    assert co.describe() == \
-        "CoalesceBatches[TargetSize(rows=8192, bytes=536870912)]"
-    assert co in lowest.children
-    (scan,) = co.children
-    assert scan.describe() == f"InMemoryScan[{parts} partitions]"
+    assert not _nodes(lowest, CoalesceBatchesExec)
+    (scan,) = [c for c in lowest.children if isinstance(c, InMemoryScanExec)]
+    assert scan.describe() == \
+        f"InMemoryScan[{parts} partitions; filled to {GOAL}]"
     ctx = ExecContext(parent=s.exec_context())
     try:
+        merged = list(scan.execute(ctx))
+        scan.fill = None
         scanned = list(scan.execute(ctx))
-        merged = list(co.execute(ctx))
     finally:
         ctx.close()
     assert len(scanned) == parts and len(merged) == -(-parts // 2)
@@ -91,9 +93,13 @@ def test_star_plan_fills_the_lowest_joins_stream(rows, parts):
         for i in range(0, parts, 2)]
     assert pa.concat_tables([b.to_arrow() for b in merged]).equals(
         pa.concat_tables([b.to_arrow() for b in scanned]))
-    # an odd one out passes through as the object the scan made
+    assert [b.meta for b in merged] == [b.meta for b in scanned[::2]]
+    # an odd one out is uploaded alone: the batch the scan made
     if parts % 2:
-        assert merged[-1] is scanned[-1]
+        one, alone = merged[-1], scanned[-1]
+        assert (one.num_rows, one.padded_len, one.meta) == \
+            (alone.num_rows, alone.padded_len, alone.meta)
+        assert one.to_arrow().equals(alone.to_arrow())
 
 
 def test_full_partitions_fill_the_joins_and_not_the_scan():
@@ -102,13 +108,15 @@ def test_full_partitions_fill_the_joins_and_not_the_scan():
     physical = _star(tpu_session(Q3_CONF), 2 * 8192, 2)._physical()
     filled = [co.children[0] for co in _nodes(physical, CoalesceBatchesExec)]
     assert filled == _nodes(physical, TpuBroadcastHashJoinExec)
+    assert all(scan.fill is None
+               for scan in _nodes(physical, InMemoryScanExec))
 
 
 def test_star_plan_fills_above_both_joins():
     """Each streaming broadcast join of the star hands its output to
     another per-batch operator (the next join's stream side through a
     projection, the aggregate), so each gets the operator directly above
-    it, and ``explain`` shows all three."""
+    it, and ``explain`` shows both and the filling scan."""
     physical = _star(tpu_session(Q3_CONF), 4 * PART, 4)._physical()
     upper, lowest = _nodes(physical, TpuBroadcastHashJoinExec)
     agg = _nodes(physical, TpuHashAggregateExec)[-1]
@@ -121,9 +129,10 @@ def test_star_plan_fills_above_both_joins():
         assert isinstance(fed, (TpuProjectExec, WholeStageExec)), fed
         (fed,) = fed.children
     assert fed.children == [lowest]
-    assert len(_nodes(physical, CoalesceBatchesExec)) == 3
-    assert physical.tree_string().count(
-        "CoalesceBatches[TargetSize(rows=8192, bytes=536870912)]") == 3
+    assert len(_nodes(physical, CoalesceBatchesExec)) == 2
+    tree = physical.tree_string()
+    assert tree.count(f"CoalesceBatches[{GOAL}]") == 2
+    assert tree.count(f"InMemoryScan[4 partitions; filled to {GOAL}]") == 1
 
 
 def _join_into(s, above: str):
@@ -170,7 +179,8 @@ def test_strings_from_the_stream_side_stop_the_join_site():
     """A string column of the stream side has a dictionary per stream
     batch: its concat would go through Arrow, so no operator; the same
     column from the build side shares ONE dictionary and gets it."""
-    s = tpu_session(Q3_CONF)
+    # two of the scan's 2,000-row batches pass 3,000: the join sees three
+    s = tpu_session({**Q3_CONF, "spark.rapids.tpu.sql.batchSizeRows": 3000})
     fact = pa.table({"f_k": pa.array(np.arange(6000) % 50),
                      "f_s": pa.array([f"s{i % 9}" for i in range(6000)])})
     dim = pa.table({"d_k": pa.array(np.arange(50)),
@@ -281,14 +291,15 @@ def test_task_context_readers_keep_their_batches(col, distinct):
     df = s.create_dataframe(t, num_partitions=4)
     tagged = df.select(F.col("k"), F.col("v"), col().alias("tag"))
     agg = tagged.group_by("tag").agg(F.sum(F.col("v")).with_name("sv"))
-    assert "CoalesceBatches" not in agg._physical().tree_string()
+    tree = agg._physical().tree_string()
+    assert "CoalesceBatches" not in tree and "filled to" not in tree
     out = agg.collect_arrow()
     assert out.num_rows == distinct
     assert abs(sum(out["sv"].to_pylist()) - float(np.arange(4000).sum())) \
         < 1e-6
     # the same aggregate without the tag is filled
     filled = df.group_by("k").agg(F.sum(F.col("v")).with_name("sv"))
-    assert "CoalesceBatches[TargetSize" in filled._physical().tree_string()
+    assert f"filled to {GOAL}" in filled._physical().tree_string()
     assert sorted(r["sv"] for r in filled.collect()) == sorted(
         float(np.arange(4000)[np.arange(4000) % 5 == k].sum())
         for k in range(5))
@@ -305,9 +316,9 @@ def test_a_reader_anywhere_in_the_plan_stops_the_rule(col):
                   "v": pa.array(np.arange(4000, dtype=np.float64))})
     df = s.create_dataframe(t, num_partitions=4).group_by("k").agg(
         F.sum(F.col("v")).with_name("sv"))
-    assert "CoalesceBatches[TargetSize" in df._physical().tree_string()
+    assert f"filled to {GOAL}" in df._physical().tree_string()
     tagged = df.select(F.col("k"), F.col("sv"), col().alias("tag"))
-    assert "CoalesceBatches" not in tagged._physical().tree_string()
+    assert "filled to" not in tagged._physical().tree_string()
     assert sorted(r["sv"] for r in tagged.collect()) == \
         sorted(r["sv"] for r in df.collect())
 
@@ -362,3 +373,259 @@ def test_sorts_keep_their_batches(local):
     if local:
         assert [len(r) for r in runs] == [1000, 1000, 1000]
     assert sorted(v for r in runs for v in r) == list(range(3000))
+
+
+# -- the filling scan and its cache entry (exec/basic.py InMemoryScanExec) --
+
+def _parts(n_parts=6, rows=1000, seed=0):
+    rng = np.random.RandomState(seed)
+    return [pa.table({"k": pa.array(rng.randint(0, 5, rows)),
+                      "v": pa.array(rng.rand(rows))})
+            for _ in range(n_parts)]
+
+
+def _filling_scan(tables, goal):
+    from spark_rapids_tpu.types import Schema, from_arrow
+    scan = InMemoryScanExec(tables, Schema.of(
+        **{f.name: from_arrow(f.type) for f in tables[0].schema}))
+    scan.fill = goal
+    return scan
+
+
+#: (k int64, v float64): 9 bytes a lane and a validity byte each
+ROW_BYTES = 18
+
+
+def _run(s, scan):
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+    ctx = ExecContext(parent=s.exec_context())
+    tr = install_tracer(Tracer())
+    try:
+        out = list(scan.execute(ctx))
+    finally:
+        install_tracer(None)
+        ctx.close()
+    (fill,) = [e["args"] for e in tr.snapshot()
+               if e["ph"] == "C" and e["name"] == "scan.fill"]
+    return out, fill
+
+
+def _count_uploads_and_concats(monkeypatch):
+    from spark_rapids_tpu.columnar import batch as batch_mod
+    calls = {"upload": 0, "concat": 0}
+    upload = batch_mod.ColumnarBatch.from_arrow
+    concat = batch_mod.concat_batches_device
+
+    def counted_upload(*a, **kw):
+        calls["upload"] += 1
+        return upload(*a, **kw)
+
+    def counted_concat(*a, **kw):
+        calls["concat"] += 1
+        return concat(*a, **kw)
+
+    monkeypatch.setattr(batch_mod.ColumnarBatch, "from_arrow",
+                        staticmethod(counted_upload))
+    monkeypatch.setattr(batch_mod, "concat_batches_device", counted_concat)
+    return calls
+
+
+def test_a_repeated_plan_serves_the_filled_batches_from_the_cache(
+        monkeypatch):
+    """The second execution of the same plan (a streaming broadcast join
+    into the sink) takes the scan's filled batches from the cache: the
+    same objects, no upload and no concat anywhere in the query, and
+    ``scan.fill`` says ``cached`` 1."""
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+    s = tpu_session({**Q3_CONF, "spark.rapids.tpu.sql.batchSizeRows": 2000})
+    dim = pa.table({"dk": pa.array(np.arange(5)),
+                    "w": pa.array(np.arange(5, dtype=np.float64) / 2)})
+    df = s.create_dataframe(pa.concat_tables(_parts()), num_partitions=6) \
+        .join(F.broadcast(s.create_dataframe(dim)), on=[("k", "dk")])
+    physical = df._physical()
+    assert "CoalesceBatches" not in physical.tree_string()
+    (scan,) = [n for n in _nodes(physical, InMemoryScanExec) if n.fill]
+    assert scan.fill == (2000, 536870912)
+
+    def execute():
+        ctx = ExecContext(parent=s.exec_context())
+        tr = install_tracer(Tracer())
+        try:
+            out = pa.concat_tables(b.to_arrow()
+                                   for b in physical.execute(ctx))
+        finally:
+            install_tracer(None)
+            ctx.close()
+        (fill,) = [e["args"] for e in tr.snapshot()
+                   if e["ph"] == "C" and e["name"] == "scan.fill"]
+        assert fill["op"] == int(scan._exec_id.rsplit("@", 1)[1])
+        return out, (fill["slices"], fill["batches"], fill["cached"])
+
+    want, fill = execute()
+    assert fill == (6, 3, 0)
+    first, _ = _run(s, scan)
+    calls = _count_uploads_and_concats(monkeypatch)
+    got, fill = execute()
+    assert calls == {"upload": 0, "concat": 0}
+    assert fill == (6, 3, 1)
+    assert got.equals(want) and got.num_rows == 6000
+    again, _ = _run(s, scan)
+    assert len(again) == 3 and all(a is b for a, b in zip(again, first))
+
+
+def test_a_freed_or_replaced_table_evicts_the_filled_entry():
+    """The entry belongs to every table of the scan: freeing one of them
+    drops it, and a new table, under that id if the allocator hands it
+    out again, is read anew and never answered with the old rows."""
+    import gc
+
+    from spark_rapids_tpu.exec import basic as B
+    s = tpu_session(Q3_CONF)
+    goal = (2000, 1 << 29)
+    tables = _parts()
+    before, _ = _run(s, _filling_scan(tables, goal))
+    _, fill = _run(s, _filling_scan(tables, goal))
+    assert fill["cached"] == 1
+    old_id = id(tables[1])
+    assert [k for k in B._SCAN_CACHE_BATCHES if old_id in k[0]]
+    tables[1] = None
+    gc.collect()
+    assert not [k for k in B._SCAN_CACHE_BATCHES if old_id in k[0]]
+    for attempt in range(200):       # try for the freed table's id
+        fresh = _parts(1, seed=100 + attempt)[0]
+        if id(fresh) == old_id:
+            break
+    tables[1] = fresh
+    got, fill = _run(s, _filling_scan(tables, goal))
+    assert fill["cached"] == 0
+    assert got[0].to_arrow().equals(pa.concat_tables(tables[:2]))
+    assert not got[0].to_arrow().equals(before[0].to_arrow())
+    assert all(a.to_arrow().equals(b.to_arrow())
+               for a, b in zip(got[1:], before[1:]))
+
+
+@pytest.mark.parametrize("slices_a_batch", [1, 3])
+def test_a_byte_goal_splits_the_groups(slices_a_batch):
+    """The goal's bytes bind as its rows do, each slice counted at the
+    padded bytes of the batch it would be alone (what the operator above
+    a scan counted): under two slices' bytes every slice is a batch of its
+    own, at three slices' bytes the rows' goal of four is cut to three."""
+    one_slice = 1024 * ROW_BYTES          # 1,000 rows in the 1,024 bucket
+    s = tpu_session({**Q3_CONF, "spark.rapids.tpu.sql.batchSizeRows": 4000,
+                     "spark.rapids.tpu.sql.batchSizeBytes":
+                     one_slice * slices_a_batch + one_slice - 1})
+    df = s.create_dataframe(pa.concat_tables(_parts()), num_partitions=6) \
+        .group_by("k").agg(F.sum(F.col("v")).with_name("sv"))
+    (scan,) = _nodes(df._physical(), InMemoryScanExec)
+    assert scan.fill is not None
+    out, fill = _run(s, scan)
+    assert fill["batches"] == len(out) == 6 // slices_a_batch
+    assert [b.num_rows for b in out] == [1000 * slices_a_batch] * len(out)
+    scan.fill = None
+    ctx = ExecContext(parent=s.exec_context())
+    try:
+        alone = list(scan.execute(ctx))
+    finally:
+        ctx.close()
+    assert alone[0].size_bytes() == one_slice
+    assert pa.concat_tables(b.to_arrow() for b in out).equals(
+        pa.concat_tables(b.to_arrow() for b in alone))
+
+
+def test_the_filled_entry_counts_against_the_cache_budget(monkeypatch):
+    """The filled batches are ONE entry of ``scanCache.maxBytes``: a byte
+    under their size and none is cached (every run uploads every group);
+    at their size they are, and another scan's entry that needs the room
+    evicts them whole."""
+    from spark_rapids_tpu.exec import basic as B
+    goal = (2000, 1 << 29)
+    entry = 3 * 8192 * ROW_BYTES          # three groups of 2,000 rows
+    tables, others = _parts(seed=1), _parts(seed=2)
+    key = tuple(map(id, tables))
+    calls = _count_uploads_and_concats(monkeypatch)
+    tight = tpu_session({**Q3_CONF,
+                         "spark.rapids.tpu.sql.scanCache.maxBytes": entry - 1})
+    for _ in range(2):
+        out, fill = _run(tight, _filling_scan(tables, goal))
+        assert fill["cached"] == 0
+    assert sum(b.device_size_bytes() for b in out) == entry
+    assert calls["upload"] == 6 and calls["concat"] == 0
+    assert not [k for k in B._SCAN_CACHE_BATCHES if k[0] == key]
+    fits = tpu_session({**Q3_CONF,
+                        "spark.rapids.tpu.sql.scanCache.maxBytes": entry})
+    _run(fits, _filling_scan(tables, goal))
+    _, fill = _run(fits, _filling_scan(tables, goal))
+    assert fill["cached"] == 1 and calls["upload"] == 9
+    _run(fits, _filling_scan(others, goal))
+    assert not [k for k in B._SCAN_CACHE_BATCHES if k[0] == key]
+    _, fill = _run(fits, _filling_scan(tables, goal))
+    assert fill["cached"] == 0 and calls["upload"] == 15
+
+
+@pytest.mark.parametrize("rows,parts", [(5 * PART - 1500, 5),
+                                        (2 * PART + 1, 3)])
+def test_star_answers_equal_with_and_without_the_fill(rows, parts,
+                                                      monkeypatch):
+    """The q3-shaped star (NULL keys, a short last partition) answers the
+    same whether the scan fills its batches or the operator above it
+    concatenates them, as the plans before the filling scan did: the same
+    batches reach the joins, so the same sums to the last bit."""
+    got = _star(tpu_session(Q3_CONF), rows, parts).collect_arrow()
+    rule = overrides.insert_coalesce
+
+    def concat_above_the_scan(physical, conf):
+        physical = rule(physical, conf)
+        for node in _nodes(physical, TpuBroadcastHashJoinExec):
+            for i, c in enumerate(node.children):
+                if isinstance(c, InMemoryScanExec) and c.fill is not None:
+                    c.fill = None
+                    node.children[i] = CoalesceBatchesExec(
+                        c, target_rows=conf.batch_size_rows,
+                        target_bytes=conf.batch_size_bytes)
+        return physical
+
+    monkeypatch.setattr(overrides, "insert_coalesce", concat_above_the_scan)
+    df = _star(tpu_session(Q3_CONF), rows, parts)
+    tree = df._physical().tree_string()
+    assert "filled to" not in tree and tree.count("CoalesceBatches") == 3
+    want = df.collect_arrow()
+    assert got.num_rows == want.num_rows > 0
+    assert got.equals(want)
+
+
+def _lineitem_like(s, query):
+    """A table cut into partitions of about the goal's rows, as the TPC-H
+    configurations register ``lineitem``: no neighbouring pair fits."""
+    rng = np.random.RandomState(4)
+    n = 5 * 8192 - 3000
+    t = pa.table({"l_k": pa.array(rng.randint(0, 900, n)),
+                  "l_flag": pa.array(rng.randint(0, 4, n).astype(np.int32)),
+                  "l_v": pa.array(rng.rand(n))})
+    li = s.create_dataframe(t, num_partitions=-(-n // 8192))
+    if query == "q6":
+        return li.filter(F.col("l_v") < 0.5).agg(
+            F.sum(F.col("l_v")).with_name("s"))
+    if query == "q1":
+        return li.filter(F.col("l_v") < 0.9).group_by("l_flag").agg(
+            F.sum(F.col("l_v")).with_name("s"))
+    orders = pa.table({"o_k": pa.array(np.arange(900)),
+                       "o_d": pa.array(rng.randint(0, 9, 900))})
+    return li.join(s.create_dataframe(orders), on=[("l_k", "o_k")]) \
+        .group_by("o_d").agg(F.sum(F.col("l_v")).with_name("s"))
+
+
+@pytest.mark.parametrize("query", ["q6", "q1", "q3"])
+def test_full_batches_get_no_fill_target(query, monkeypatch):
+    """Partitions that already fill ``batchSizeRows`` (the TPC-H cells'):
+    no scan gets a fill target and the plan is the one without the rule
+    (Q3's join of two sides too large to broadcast materializes both)."""
+    conf = Q3_CONF if query != "q3" else {
+        **Q3_CONF, "spark.rapids.tpu.sql.autoBroadcastJoinThreshold": 0}
+    physical = _lineitem_like(tpu_session(conf), query)._physical()
+    assert all(scan.fill is None
+               for scan in _nodes(physical, InMemoryScanExec))
+    tree = physical.tree_string()
+    assert "filled to" not in tree
+    _without_rule(monkeypatch)
+    assert _lineitem_like(tpu_session(conf), query)._physical() \
+        .tree_string() == tree

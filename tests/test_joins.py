@@ -560,7 +560,7 @@ def test_overflow_inside_a_coalesce_window_still_raises_and_reruns():
 
 def test_a_repeated_star_query_compiles_nothing_from_its_third_run_on():
     """Two selective broadcast joins under an aggregate, each filled by
-    its own operator: run 0 measures (a first batch ever leaves in its
+    its own operator above a filling scan: run 0 measures (a first batch ever leaves in its
     stream batch's bucket), run 1 may still meet a new shape, and from
     run 2 on the concats' arities and buckets repeat: no executable-cache
     miss and no backend compile, the same rows every time."""
@@ -596,7 +596,8 @@ def test_a_repeated_star_query_compiles_nothing_from_its_third_run_on():
     s = tpu_session(conf)
     with _fresh_total_stats():
         tree = q(s)._physical().tree_string()
-        assert tree.count("CoalesceBatches[TargetSize(rows=1024") == 3, tree
+        assert tree.count("CoalesceBatches[TargetSize(rows=1024") == 2, tree
+        assert tree.count("filled to TargetSize(rows=1024") == 1, tree
         answers, misses, compile_s = [], [], []
         for _ in range(4):
             before = exec_cache.stats()
