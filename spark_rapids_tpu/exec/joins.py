@@ -183,6 +183,20 @@ def _count_join_rows(exec_id: str, build, stream, out, parts: int) -> None:
                cat="exec")
 
 
+def _count_join_gather(join: "TpuHashJoinExec") -> None:
+    """Tracer counter ``join.gather``, once per equi-join execution beside
+    ``join.rows``: the columns the join hands on, and the columns of its
+    inputs it no longer gathers because nothing above it reads them.
+    Plan-time numbers: no device read."""
+    tr = trace_core.TRACER
+    if tr is not None:
+        cols = len(join.output_schema())
+        tr.counter("join.gather",
+                   {"cols": cols, "dropped": len(join._full_schema) - cols,
+                    "op": int(join._exec_id.rsplit("@", 1)[-1])},
+                   cat="exec")
+
+
 def _intake(batch: ColumnarBatch) -> ColumnarBatch:
     """A join input as it is taken in: on the device, list rectangles on
     the host (the gathers move 1-D lanes), string rectangles kept: the
@@ -239,16 +253,19 @@ class _SortedBuild:
     query (1.5M rows of ``customer`` with their names, where a handful
     match)."""
 
-    __slots__ = ("keys", "order", "batch", "rows", "lanes", "spans",
-                 "span", "narrow", "pack")
+    __slots__ = ("keys", "order", "batch", "rows", "cols", "lanes",
+                 "spans", "span", "narrow", "pack")
 
-    def __init__(self, keys, order, batch: ColumnarBatch, rows, span=None,
-                 narrow=False, pack=None):
+    def __init__(self, keys, order, batch: ColumnarBatch, rows, cols,
+                 span=None, narrow=False, pack=None):
         self.keys, self.order, self.batch, self.rows = (keys, order, batch,
                                                         rows)
-        #: its columns as the gather takes them, made once (a string
-        #: rectangle rides as its word lanes)
-        self.lanes, self.spans = _lane_pairs(list(enumerate(batch.columns)))
+        #: the columns of ``batch`` the join hands on, and those as the
+        #: gather takes them, made once (a string rectangle rides as its
+        #: word lanes)
+        self.cols = cols
+        self.lanes, self.spans = _lane_pairs(
+            [(i, batch.columns[i]) for i in cols])
         #: the first and last live key (int64[2], on the device), and
         #: whether they lie less than 2^31 apart: for the dense probe
         self.span, self.narrow = span, narrow
@@ -864,10 +881,48 @@ class TpuHashJoinExec(TpuExec):
         self.right_keys = list(right_keys)
         self.condition = condition
         ls, rs = left.output_schema(), right.output_schema()
-        self._schema = _join_schema(ls, rs, join_type)
+        self._full_schema = _join_schema(ls, rs, join_type)
+        self._set_keep(None)
 
     def output_schema(self) -> Schema:
         return self._schema
+
+    def narrow(self, names) -> None:
+        """Hand on only the output columns named in ``names`` (the plan
+        reads nothing else above the join), and those the residual
+        condition reads, which is evaluated on the join's output: the
+        others are never gathered. Nothing changes where the output's
+        names repeat (a name would no longer say which column), or where
+        nothing or everything would be kept."""
+        full = self._full_schema.names()
+        want = set(names)
+        if self.condition is not None:
+            want.update(self.condition.references())
+        keep = tuple(i for i, n in enumerate(full) if n in want)
+        if len(set(full)) == len(full) and 0 < len(keep) < len(full):
+            self._set_keep(keep)
+
+    def _set_keep(self, keep: Optional[Tuple[int, ...]]) -> None:
+        """``keep``: the places in the full output of the columns handed
+        on, in output order (None: all). Per side, the columns of that
+        input they are (``_kept``), so that a gather takes only those."""
+        #: places in the full output handed on; None: every column
+        self.keep = keep
+        full = self._full_schema
+        self._schema = full if keep is None else \
+            Schema([full.fields[i] for i in keep])
+        n_left = len(self.children[0].output_schema())
+        places = range(len(full)) if keep is None else keep
+        right = self.join_type not in ("leftsemi", "leftanti", "existence")
+        self._kept = ([i for i in places if i < n_left],
+                      [i - n_left for i in places if i >= n_left and right])
+
+    def _handed_on(self, out: ColumnarBatch) -> ColumnarBatch:
+        """A full output as the join hands it on: its kept columns."""
+        if self.keep is None:
+            return out
+        return out.with_columns([out.columns[i] for i in self.keep],
+                                self._schema)
 
     #: join types whose result is the union, over the batches of the
     #: OTHER side, of that batch joined with the whole build side (no
@@ -936,6 +991,7 @@ class TpuHashJoinExec(TpuExec):
         _count_join_keys(self._exec_id, len(self.left_keys), None, 1)
         _count_join_rows(self._exec_id, rows["right"], rows["left"],
                          out.num_rows_raw, 1)
+        _count_join_gather(self)
         rows_m.add(out.num_rows_raw)
         yield out
 
@@ -1036,6 +1092,7 @@ class TpuHashJoinExec(TpuExec):
         _count_join_rows(self._exec_id, rows[build],
                          rows["left" if bi else "right"], out.num_rows_raw,
                          len(outs))
+        _count_join_gather(self)
         rows_m.add(out.num_rows_raw)
         yield out
 
@@ -1227,6 +1284,7 @@ class TpuHashJoinExec(TpuExec):
         _count_join_keys(self._exec_id, len(self.left_keys), None, n_parts)
         _count_join_rows(self._exec_id, rows["right"], rows["left"],
                          [s._num_rows for s in outs], n_parts)
+        _count_join_gather(self)
         try:
             for s in outs:
                 b = s.get()
@@ -1244,6 +1302,9 @@ class TpuHashJoinExec(TpuExec):
     def _join(self, lb: ColumnarBatch, rb: ColumnarBatch,
               ctx: Optional[ExecContext] = None,
               bound: Optional[_OutBound] = None) -> ColumnarBatch:
+        """Two batches joined, the output as the join hands it on: the
+        probe gathers the kept columns alone, every other path selects
+        them from the full output it builds."""
         probing = bound is not None and bound.probing \
             and ctx.speculate and lb.all_device and rb.all_device
         if probing and bound.swap:
@@ -1251,12 +1312,28 @@ class TpuHashJoinExec(TpuExec):
             out = self._join_swapped(ctx, sb, bb, bound)
             if out is not None:
                 return out
-            probing = False
-        if not probing:
-            if bound is not None:
-                bound.general += 1
-            # every kernel but the probe's gather moves 1-D lanes
-            lb, rb = lb.with_lists_on_host(), rb.with_lists_on_host()
+        elif probing:
+            return self._join_probe(ctx, lb if bound.stream_left else rb,
+                                    bound, self._shape_key(lb, rb))
+        if bound is not None:
+            bound.general += 1
+        # every kernel but the probe's gather moves 1-D lanes
+        lb, rb = lb.with_lists_on_host(), rb.with_lists_on_host()
+        return self._handed_on(self._join_general(lb, rb, ctx, bound))
+
+    def _shape_key(self, lb: ColumnarBatch, rb: ColumnarBatch) -> Tuple:
+        """The join shape its output statistic is kept under: the keys,
+        the inputs' schemas, the join type."""
+        return (tuple(e.key() for e in self.left_keys),
+                tuple(e.key() for e in self.right_keys),
+                tuple((f.name, f.dtype.name) for f in lb.schema.fields),
+                tuple((f.name, f.dtype.name) for f in rb.schema.fields),
+                self.join_type)
+
+    def _join_general(self, lb: ColumnarBatch, rb: ColumnarBatch,
+                      ctx: Optional[ExecContext],
+                      bound: Optional[_OutBound]) -> ColumnarBatch:
+        """Two batches joined by the general kernels: the full output."""
         if self.join_type == "cross" or not self.left_keys:
             return self._cross(lb, rb)
         if (self.condition is not None and
@@ -1266,16 +1343,9 @@ class TpuHashJoinExec(TpuExec):
             # pair machinery (ref JoinGatherer conditional gathers)
             l_row, r_row, live = self._candidate_pairs(lb, rb)
             return _finish_pair_join(self.join_type, lb, rb, l_row, r_row,
-                                     live, self.condition, self._schema)
+                                     live, self.condition, self._full_schema)
         ls, rs = lb.schema, rb.schema
-        ck = (tuple(e.key() for e in self.left_keys),
-              tuple(e.key() for e in self.right_keys),
-              tuple((f.name, f.dtype.name) for f in ls.fields),
-              tuple((f.name, f.dtype.name) for f in rs.fields),
-              self.join_type)
-        if probing:
-            return self._join_probe(ctx, lb if bound.stream_left else rb,
-                                    bound, ck)
+        ck = self._shape_key(lb, rb)
         kern = _COUNT_CACHE.get(ck)
         if kern is None:
             kern = _build_count_kernel(self.left_keys, self.right_keys,
@@ -1359,9 +1429,10 @@ class TpuHashJoinExec(TpuExec):
         r_row = jnp.where(live, r_row, -1)
         lo = gather_batch_device(lb, l_row, n_out, out_p)
         if semi_like:
-            return ColumnarBatch(lo.columns, n_out, self._schema)
+            return ColumnarBatch(lo.columns, n_out, self._full_schema)
         ro = gather_batch_device(rb, r_row, n_out, out_p)
-        out = ColumnarBatch(lo.columns + ro.columns, n_out, self._schema)
+        out = ColumnarBatch(lo.columns + ro.columns, n_out,
+                            self._full_schema)
         if self.condition is not None:
             out = filter_batch_device(self.condition, out)
         return out
@@ -1404,7 +1475,8 @@ class TpuHashJoinExec(TpuExec):
         stream batch measures the key multiplicity."""
         if bound is None or not bb.all_device:
             return
-        found = self._probe_key(not bound.stream_left,
+        build_left = not bound.stream_left
+        found = self._probe_key(build_left,
                                 self.children[0].output_schema(),
                                 self.children[1].output_schema())
         if found is None:
@@ -1423,8 +1495,9 @@ class TpuHashJoinExec(TpuExec):
                     (rows, twice, dead, span, *pack), "d2h.join_count")
                 bits = sum((int(h) - int(l)).bit_length()
                            for l, h in zip(*spans[0][:2])) if spans else 0
-                return _SortedBuild(keys, order, bb, int(rows), span,
-                                    int(hi) - int(lo) < 2 ** 31,
+                return _SortedBuild(keys, order, bb, int(rows),
+                                    self._kept[0 if build_left else 1],
+                                    span, int(hi) - int(lo) < 2 ** 31,
                                     pack[0] if pack else None), \
                     bool(twice), bool(dead), bits
         build, twice, dead, bound.bits = with_retry_no_split(
@@ -1511,7 +1584,9 @@ class TpuHashJoinExec(TpuExec):
             keys, rows, _lanes(bb), jnp.int32(bb.num_rows_raw),
             bb.padded_len, *pack)
         return self._pairs_batch(
-            total, b_row, s_row, bb, _SortedBuild(keys, order, sb, rows),
+            total, b_row, s_row, bb,
+            _SortedBuild(keys, order, sb, rows,
+                         self._kept[0 if bound.stream_left else 1]),
             bucket_for(max(bb.padded_len, 1)), not bound.stream_left)
 
     def _pairs_batch(self, total, p_row, s_row, probing: ColumnarBatch,
@@ -1521,9 +1596,13 @@ class TpuHashJoinExec(TpuExec):
         batch's columns at ``p_row`` and the sorted side's at ``s_row``
         (none of a sorted side that is None) in ONE gather, the probing
         batch's on the left where ``probing_left``, then the residual
-        condition."""
+        condition. Only the columns the join hands on are gathered: the
+        probing batch's kept ones, and the sorted side's it chose once
+        (``_SortedBuild.cols``)."""
         from ..plan import exec_cache
-        p_lanes, p_spans = _lane_pairs(list(enumerate(probing.columns)))
+        p_cols = self._kept[0 if probing_left else 1]
+        p_lanes, p_spans = _lane_pairs(
+            [(i, probing.columns[i]) for i in p_cols])
         pouts, bouts = exec_cache.get_or_build_jit(
             "joins.pairs_gather", _pairs_gather, static_argnums=(6,))(
                 total, p_row, s_row,
@@ -1531,10 +1610,12 @@ class TpuHashJoinExec(TpuExec):
                 [] if build is None else build.lanes, out_p)
         p_out = [None] * len(probing.columns)
         _lane_rebuild(probing, p_spans, pouts, p_out)
+        p_out = [p_out[i] for i in p_cols]
         b_out = []
         if build is not None:
             b_out = [None] * len(build.batch.columns)
             _lane_rebuild(build.batch, build.spans, bouts, b_out)
+            b_out = [b_out[i] for i in build.cols]
         out = ColumnarBatch(p_out + b_out if probing_left
                             else b_out + p_out, total, self._schema)
         if self.condition is not None:
@@ -1575,7 +1656,7 @@ class TpuHashJoinExec(TpuExec):
         if not semi_like:
             new_cols += [c.with_arrays(d, v)
                          for c, (d, v) in zip(rb.columns, routs)]
-        return ColumnarBatch(new_cols, total, self._schema)
+        return ColumnarBatch(new_cols, total, self._full_schema)
 
     def _cross(self, lb: ColumnarBatch, rb: ColumnarBatch) -> ColumnarBatch:
         n_out = lb.num_rows * rb.num_rows
@@ -1588,7 +1669,8 @@ class TpuHashJoinExec(TpuExec):
         ri = jnp.where(live, ri, -1)
         lo = gather_batch_device(lb, li, n_out, out_p)
         ro = gather_batch_device(rb, ri, n_out, out_p)
-        out = ColumnarBatch(lo.columns + ro.columns, n_out, self._schema)
+        out = ColumnarBatch(lo.columns + ro.columns, n_out,
+                            self._full_schema)
         if self.condition is not None:
             out = filter_batch_device(self.condition, out)
         return out
@@ -1619,10 +1701,23 @@ class TpuHashJoinExec(TpuExec):
                 live)
 
     def describe(self):
+        return f"HashJoin[{self._describe_args()}{self._drops()}]"
+
+    def _describe_args(self) -> str:
         k = ", ".join(f"{a.name_hint}={b.name_hint}"
                       for a, b in zip(self.left_keys, self.right_keys))
         c = f", cond={self.condition.name_hint}" if self.condition else ""
-        return f"HashJoin[{self.join_type}, keys=({k}){c}]"
+        return f"{self.join_type}, keys=({k}){c}"
+
+    def _drops(self) -> str:
+        """The output columns the join no longer hands on, by name."""
+        if self.keep is None:
+            return ""
+        kept = set(self.keep)
+        dropped = sorted(f.name for i, f in
+                         enumerate(self._full_schema.fields)
+                         if i not in kept)
+        return f", drops=[{', '.join(dropped)}]"
 
 
 def _common_arrow_type(a, b):
@@ -1748,6 +1843,12 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
         assert build_side in ("left", "right")
         self.build_side = build_side
 
+    def from_build_side(self) -> List[bool]:
+        """Per column handed on, whether it comes from the build side."""
+        n_left, n = len(self._kept[0]), len(self._schema)
+        on_right = self.build_side == "right"
+        return [(i >= n_left) == on_right for i in range(n)]
+
     @property
     def streams(self) -> bool:
         """Whether the stream side is joined batch by batch against the
@@ -1858,10 +1959,11 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
         _count_join_rows(self._exec_id,
                          bb.num_rows_raw if bb is not None else 0,
                          n_stream, n_out, len(n_out))
+        _count_join_gather(self)
 
     def describe(self):
-        return "Broadcast" + super().describe()[:-1] + \
-            f", build={self.build_side}]"
+        return (f"BroadcastHashJoin[{self._describe_args()}, "
+                f"build={self.build_side}{self._drops()}]")
 
 
 class CpuJoinExec(TpuExec):

@@ -955,6 +955,15 @@ class _Planner:
         # semi/anti joins emit probe-side fields only
         if node.join_type in ("leftsemi", "leftanti"):
             frag.fields = list(left.fields)
+        if node.keep is not None:
+            # the operator hands on only what the projection above reads
+            # (TpuHashJoinExec.narrow): the fragment's output too, and the
+            # program leaves the other columns' gathers out
+            from ..exprs.base import ColumnRef
+            kept = [frag.fields[i] for i in node.keep]
+            frag = _LocalFrag(frag, [("project", [ColumnRef(f.name)
+                                                  for f in kept], kept)],
+                              kept)
         return frag
 
     def _lower_agg(self, node, replicated: bool) -> _Frag:

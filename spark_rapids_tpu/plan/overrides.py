@@ -151,6 +151,7 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
             only_not_on_device=(pexplain == "NOT_ON_DEVICE")))
     physical = meta.convert()
     if conf.sql_enabled:
+        physical = prune_join_outputs(physical)
         physical = insert_coalesce(physical, conf)
         from ..parallel.planner import (FUSED_PIPELINE, distribution_gate,
                                         maybe_fuse_single_chip,
@@ -184,6 +185,27 @@ def plan_query(plan: L.LogicalPlan, conf: TpuConf, mesh=None,
     #: (exec_cache.record_plan_compiled) instead of re-hashing the tree
     physical.plan_digest = digest
     return physical
+
+
+# ---------------------------------------------------------------------------
+# post-conversion: a hash join hands on what the projection above reads
+# ---------------------------------------------------------------------------
+
+def prune_join_outputs(node: TpuExec) -> TpuExec:
+    """A hash join directly below a projection hands on only the columns
+    the projection's expressions reference (``TpuHashJoinExec.narrow``):
+    ``prune_columns`` keeps a join's keys in its inputs because the probe
+    reads them, and the projection above then drops them, so they were
+    gathered at every probe for nothing. The projection resolves its
+    inputs by name and needs no change. Read off the plan: no option."""
+    from ..exec.joins import TpuHashJoinExec
+    for c in node.children:
+        prune_join_outputs(c)
+    if isinstance(node, B.TpuProjectExec) \
+            and isinstance(node.children[0], TpuHashJoinExec):
+        node.children[0].narrow(
+            {r for e in node.exprs for r in e.references()})
+    return node
 
 
 # ---------------------------------------------------------------------------
@@ -265,11 +287,9 @@ def _fills(source: TpuExec, goal) -> bool:
     if not _streams(source) or _stream_batches(source, goal) == 1:
         return False
     from ..types import STRING
-    n_left = len(source.children[0].output_schema())
-    build = (range(n_left, len(source.output_schema()))
-             if source.build_side == "right" else range(n_left))
-    return all(f.dtype.device_backed or (f.dtype == STRING and i in build)
-               for i, f in enumerate(source.output_schema()))
+    return all(f.dtype.device_backed or (f.dtype == STRING and b)
+               for f, b in zip(source.output_schema(),
+                               source.from_build_side()))
 
 
 def _stream_batches(join: TpuExec, goal) -> Optional[int]:

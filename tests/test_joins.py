@@ -1269,3 +1269,161 @@ def test_packed_keys_give_the_general_kernels_rows(case, how, broadcast,
     pd.testing.assert_frame_equal(
         general.sort_values(cols).reset_index(drop=True),
         got.sort_values(cols).reset_index(drop=True))
+
+
+# -- a join hands on only what the projection above it reads
+# (plan/overrides.py prune_join_outputs, TpuHashJoinExec.narrow) ---------
+
+def _gather_sides(s, dup_build=False):
+    """A stream of three 1024-row batches (NULL keys, a second key, two
+    payloads) and a build side of 300 rows with unique keys (or a key
+    there twice and thrice, the stream's keys unique in each batch)."""
+    import numpy as np
+    import pyarrow as pa
+    rng = np.random.RandomState(40)
+    n = 3 * 1024
+    if dup_build:
+        sk = pa.array(np.arange(n))
+        bk = pa.array([7, 7, 9, 2000, 3071, None, 9, 9] * 2)
+    else:
+        sk = pa.array(rng.randint(0, 400, n), mask=rng.rand(n) < .05)
+        bk = pa.array(np.arange(300))
+    stream = s.create_dataframe(pa.table({
+        "sk": sk, "s2": pa.array(rng.randint(0, 4, n)),
+        "sv": pa.array(rng.rand(n)),
+        "sx": pa.array(rng.randint(0, 1000, n))}), num_partitions=3)
+    build = s.create_dataframe(pa.table({
+        "bk": bk, "b2": pa.array(np.arange(len(bk)) % 4),
+        "bv": pa.array(rng.randint(0, 1000, len(bk)))}))
+    return stream, build
+
+
+def _gather_inner(s):
+    st, b = _gather_sides(s)
+    return st.join(b, on=[("sk", "bk")]).select("sv", "bv")
+
+
+def _gather_two_keys(s):
+    st, b = _gather_sides(s)
+    return st.join(b, on=[("sk", "bk"), ("s2", "b2")]).select("sv", "b2")
+
+
+def _gather_semi(s):
+    st, b = _gather_sides(s)
+    return st.join(b, on=[("sk", "bk")], how="leftsemi").select("sv", "sx")
+
+
+def _gather_left(s):
+    st, b = _gather_sides(s)
+    return st.join(b, on=[("sk", "bk")], how="left").select("sk", "bv")
+
+
+def _gather_condition(s):
+    st, b = _gather_sides(s)
+    return st.join(b, on=[("sk", "bk")],
+                   condition=F.col("sx") > F.col("bv")).select("sv")
+
+
+def _gather_coalesced(s):
+    import numpy as np
+    import pyarrow as pa
+    st, b = _gather_sides(s)
+    dim = s.create_dataframe(pa.table({"d2": pa.array(np.arange(4)),
+                                       "dv": pa.array(np.arange(4) * 10)}))
+    return st.join(b, on=[("sk", "bk")]).select("sv", "s2", "bv") \
+        .join(dim, on=[("s2", "d2")]).select("sv", "bv", "dv")
+
+
+def _gather_swapped(s):
+    st, b = _gather_sides(s, dup_build=True)
+    return b.join(st, on=[("bk", "sk")]).select("bv", "sv")
+
+
+#: query, conf, the lowest join's columns handed on, and the columns of
+#: its inputs it no longer gathers: the join keys, which the planner's
+#: column pruning keeps in the inputs for the probe
+_GATHER_CASES = {
+    "inner": (_gather_inner, _SHUFFLED, ["sv", "bv"], 2),
+    "two_keys": (_gather_two_keys, {}, ["sv", "b2"], 3),
+    "leftsemi": (_gather_semi, {}, ["sv", "sx"], 1),
+    "left": (_gather_left, {}, ["sk", "bv"], 1),
+    # the condition is evaluated on the join's output: its columns stay
+    "condition": (_gather_condition, {}, ["sv", "sx", "bv"], 2),
+    "coalesced": (_gather_coalesced, {}, ["s2", "sv", "bv"], 2),
+    "swapped": (_gather_swapped, {}, ["bv", "sv"], 2),
+    "subpartitioned": (_gather_inner, {**_SHUFFLED, **_SUBPART_CONF},
+                       ["sv", "bv"], 2),
+}
+
+
+def _gathered_lanes(monkeypatch):
+    """The lanes each call of the probe's pair gather takes, both sides'."""
+    from spark_rapids_tpu.plan import exec_cache
+    real, lanes = exec_cache.get_or_build_jit, []
+
+    def spy(name, fn, **kw):
+        jitted = real(name, fn, **kw)
+        if name != "joins.pairs_gather":
+            return jitted
+
+        def call(*args):
+            lanes.append(len(args[4]) + len(args[5]))
+            return jitted(*args)
+        return call
+    monkeypatch.setattr(exec_cache, "get_or_build_jit", spy)
+    return lanes
+
+
+@pytest.mark.parametrize("case", sorted(_GATHER_CASES))
+def test_a_join_hands_on_only_the_columns_read_above_it(case, monkeypatch):
+    """The lowest join of each plan hands on the columns the projection
+    directly above it reads (and a residual condition's), and gathers no
+    other: its batches hold those alone, ``join.gather`` counts them and
+    the columns no longer gathered, ``explain`` names those, and the
+    answer is the host engine's. The probe paths (one key, a packed key
+    pair, a semi join, the exchanged sides) gather fewer lanes; the
+    general kernels (the outer join, the sub-partitions) select them
+    from what they built. Where
+    ``CoalesceBatches`` is planned between the join and the projection,
+    it passes the narrowed batches on."""
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.exec.basic import CoalesceBatchesExec
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    from spark_rapids_tpu.trace import Tracer, install_tracer
+    query, extra, kept, dropped = _GATHER_CASES[case]
+    conf = {**_BOUND_CONF, **extra}
+    calls = _probe_calls(monkeypatch)
+    s = tpu_session(conf)
+    physical = query(s)._physical()
+
+    def joins(node, parent=None):
+        found = [(node, parent)] if isinstance(node, TpuHashJoinExec) \
+            else []
+        return found + [j for c in node.children for j in joins(c, node)]
+    join, parent = joins(physical)[-1]
+    assert join.output_schema().names() == kept
+    names = [f.name for f in join._full_schema.fields]
+    assert f"drops=[{', '.join(sorted(set(names) - set(kept)))}]" \
+        in join.describe()
+    assert isinstance(parent, CoalesceBatchesExec) == (case == "coalesced")
+    lanes = _gathered_lanes(monkeypatch)
+    ctx = ExecContext(parent=s.exec_context())
+    tr = install_tracer(Tracer())
+    try:
+        with _fresh_total_stats():
+            outs = list(join.execute(ctx))
+            ctx.check_speculations()
+    finally:
+        install_tracer(None)
+        ctx.close()
+    assert outs and all(b.schema.names() == kept
+                        and len(b.columns) == len(kept) for b in outs)
+    (gather,) = [e["args"] for e in tr.snapshot()
+                 if e["ph"] == "C" and e["name"] == "join.gather"]
+    assert (gather["cols"], gather["dropped"]) == (len(kept), dropped)
+    assert ("swapped" in calls) == (case == "swapped")
+    assert bool(calls) == (case not in ("left", "subpartitioned"))
+    # the probe's gather takes the kept columns' lanes alone
+    assert lanes == [len(kept)] * len(calls)
+    got = assert_tpu_and_cpu_equal(query, conf=conf)
+    assert len(got) > 0

@@ -3,6 +3,7 @@ DataFrame formulation and vs the host oracle (the reference consumes SQL
 through Spark's parser; this framework ships its own ANSI analytics
 subset — spark_rapids_tpu/sql/)."""
 import os
+import re
 import sys
 
 import numpy as np
@@ -616,3 +617,113 @@ def test_tpch_q5_at_a_small_size_is_the_plain_reference(monkeypatch):
         assert len(want) == 5
         compared = ref.compare("tpch_q5", got, want)
         assert all(compared[k] <= limits[k] for k in limits), compared
+
+
+# ---------------------------------------------------------------------------
+# a join below a projection hands on only what the projection reads
+# (plan/overrides.py prune_join_outputs)
+# ---------------------------------------------------------------------------
+
+def _q5_tables(seed):
+    """The six tables TPC-H Q5 reads, small enough for the fused fragment
+    to take the query whole: 600 customers, 6,000 orders of one to seven
+    lines, 100 suppliers, dbgen's 25 nations in 5 regions."""
+    rng = np.random.default_rng(seed)
+    n_cust, n_orders, n_supp = 600, 6_000, 100
+    start = np.datetime64("1992-01-01")
+    days = rng.integers(0, 2406, n_orders)
+    of = np.repeat(np.arange(n_orders), rng.integers(1, 8, n_orders))
+    return {
+        "region": pa.table({"r_regionkey": np.arange(5),
+                            "r_name": ["AFRICA", "AMERICA", "ASIA", "EUROPE",
+                                       "MIDDLE EAST"]}),
+        "nation": pa.table({"n_nationkey": np.arange(25),
+                            "n_name": [f"NATION{i:02d}" for i in range(25)],
+                            "n_regionkey": np.arange(25) % 5}),
+        "customer": pa.table({
+            "c_custkey": np.arange(1, n_cust + 1),
+            "c_nationkey": rng.integers(0, 25, n_cust)}),
+        "supplier": pa.table({
+            "s_suppkey": np.arange(1, n_supp + 1),
+            "s_nationkey": rng.integers(0, 25, n_supp)}),
+        "orders": pa.table({
+            "o_orderkey": np.arange(1, n_orders + 1) * 4,
+            "o_custkey": rng.integers(1, n_cust + 1, n_orders),
+            "o_orderdate": (start + days).astype("datetime64[D]")}),
+        "lineitem": pa.table({
+            "l_orderkey": (of + 1) * 4,
+            "l_suppkey": rng.integers(1, n_supp + 1, len(of)),
+            "l_extendedprice": rng.integers(90_000, 10_000_000,
+                                            len(of)) / 100.0,
+            "l_discount": rng.integers(0, 11, len(of)) / 100.0})}
+
+
+#: each join below a projection, by what it no longer hands on (Q3's
+#: second join and Q5's last feed an aggregate, which folds the projection
+#: above them into its own kernel: they hand on everything)
+_DROPS = {
+    "q3": ["drops=[c_custkey, o_custkey]"],
+    "q5": ["drops=[c_custkey, o_custkey]", "drops=[l_orderkey, o_orderkey]",
+           "drops=[c_nationkey, l_suppkey, s_suppkey]",
+           "drops=[n_nationkey, s_nationkey]"],
+}
+
+
+@pytest.mark.parametrize("query", sorted(_DROPS))
+def test_a_join_gathers_only_what_the_projection_above_it_reads(query):
+    """TPC-H Q3's and Q5's texts at a CPU size: ``explain`` shows every
+    join directly below a projection dropping the join keys that the
+    projection does not read, and the answers of the operator pipeline
+    and of the fused fragment (below its row ceiling it takes the query
+    whole and leaves those columns out of its program) are the host
+    engine's."""
+    from harness import OPERATOR_CONF, assert_tpu_and_cpu_equal
+    from test_tpch_q3 import Q3, _tables
+    if query == "q3":
+        tables, text = _tables(2_154_040_001), Q3
+    else:
+        tables = _q5_tables(2_154_040_001)
+        with open(os.path.join(os.path.dirname(__file__), "..", "perfbench",
+                               "queries", "tpch_q5.sql")) as f:
+            text = f.read()
+
+    def q(s):
+        for name, t in tables.items():
+            s.create_dataframe(t, num_partitions=3) \
+                .create_or_replace_temp_view(name)
+        return s.sql(text)
+    tree = q(tpu_session(OPERATOR_CONF))._physical().tree_string()
+    assert "Cpu" not in tree.replace("CpuSort", ""), tree
+    assert sorted(re.findall(r"drops=\[[^]]*\]", tree)) \
+        == sorted(_DROPS[query]), tree
+    fused = {**OPERATOR_CONF, "spark.rapids.tpu.sql.fusedPipeline.enabled":
+             True}
+    assert "DistributedPipeline" in q(tpu_session(fused))._physical() \
+        .tree_string()
+    for conf in (OPERATOR_CONF, fused):
+        got = assert_tpu_and_cpu_equal(q, conf=conf, approximate_float=True)
+        assert len(got) > 0
+
+
+def test_a_join_with_no_projection_above_it_drops_nothing():
+    """A join handed to the sink whole, or to a projection that reads all
+    of it, keeps every column and its ``explain``."""
+    from harness import OPERATOR_CONF
+    from spark_rapids_tpu.exec.joins import TpuHashJoinExec
+    s = tpu_session(OPERATOR_CONF)
+    s.create_dataframe(pa.table({"k": [1, 2, 2], "v": [1.0, 2.0, 3.0]})) \
+        .create_or_replace_temp_view("t")
+    s.create_dataframe(pa.table({"k2": [2, 3], "w": [5, 6]})) \
+        .create_or_replace_temp_view("r")
+    for text in ("select * from t join r on k = k2",
+                 "select k, v, k2, w from t join r on k = k2"):
+        physical = s.sql(text)._physical()
+        assert "drops=" not in physical.tree_string()
+        node = physical
+        while not isinstance(node, TpuHashJoinExec):
+            (node,) = node.children
+        assert node.keep is None
+        assert node.output_schema().names() == ["k", "v", "k2", "w"]
+        assert sorted(s.sql(text).collect(), key=lambda r: r["v"]) == [
+            {"k": 2, "v": 2.0, "k2": 2, "w": 5},
+            {"k": 2, "v": 3.0, "k2": 2, "w": 5}]
